@@ -41,7 +41,7 @@ import jax.numpy as jnp
 
 from singa_tpu.ops.norm import batch_norm_train
 
-# (shape, count) — ResNet-50 batch-128 BN instances (BASELINE.md r5)
+# (shape, count) — ResNet-50 batch-128 BN instances
 SHAPES = [
     ((128, 64, 112, 112), 1),
     ((128, 64, 56, 56), 6),

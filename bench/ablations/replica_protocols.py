@@ -1,6 +1,6 @@
 """Replica-protocol engine ablation on the 8-virtual-device geometry.
 
-The BASELINE.md r3/r4 engine-comparison methodology, now with the
+The engine-comparison methodology, with the
 RandomSync ratios the protocol actually exists for (the reference's
 bandwidth throttle SUBSAMPLES coordinates, param_manager.cc:85-93;
 ratio 1.0 is the degenerate case its fast path special-cases away):

@@ -1025,14 +1025,15 @@ class KernelsConfig(Message):
     layer). Output is allclose to the reference (online softmax
     reorders the reduction); greedy token streams are identical.
     ``reference`` (default, = no block) keeps the bitwise-pinned
-    oracle path untouched. ``interpret`` (default true) runs the
-    kernel through the Pallas interpreter (or, for the ring, the
-    pure-ppermute XLA form) — plain XLA ops, CPU-safe and
-    GSPMD-shardable, what CI exercises — set false on a real TPU
-    to compile through Mosaic, which constrains the geometry
-    (kv_block_len a multiple of 8, head_dim a multiple of 128; the
-    engine rejects violations at construction, netlint KRN001 flags
-    them statically).
+    oracle path untouched. Left unset, ``interpret`` follows the
+    platform for the serving kernel: compiled through Mosaic on a
+    TPU, the Pallas interpreter (plain XLA ops, CPU-safe and
+    GSPMD-shardable, what CI exercises) elsewhere; true/false pin it.
+    For the RING the word does not mean the Pallas interpreter: true
+    (also its unset default) selects the hop's pure-ppermute
+    plain-XLA form — a real compiled program on the chip — and false
+    the Pallas ``quant_acc`` hop, which needs (8, 128)-aligned chunks
+    no shipped conf has (every bias/LayerNorm vector breaks it).
 
     ``grad_allreduce: quantized_ring`` swaps the trainer's data-axis
     gradient collective — PR 8's ``grad_comm { mode: quantized }``
@@ -1069,10 +1070,11 @@ class KernelsConfig(Message):
         "grad_allreduce": Field(
             "enum", "reference", enum=GRAD_ALLREDUCE_IMPLS
         ),
-        # run the fused kernel in the Pallas interpreter / the ring in
-        # its pure-XLA ppermute form (CPU-safe); false = compile the
-        # inner kernels through Mosaic (TPU, geometry-gated)
-        "interpret": Field("bool", True),
+        # unset = the serving kernel follows the platform (Mosaic on a
+        # TPU, the Pallas interpreter elsewhere) and the ring runs its
+        # plain-XLA hop; for the ring "interpret" names that XLA form,
+        # NOT the Pallas interpreter (see the class docstring)
+        "interpret": Field("bool"),
     }
 
 
@@ -1204,13 +1206,6 @@ class ClusterConfig(Message):
         "nseq_per_group": Field("int", 1),
         "nexperts_per_group": Field("int", 1),
         "npipes_per_group": Field("int", 1),
-        # ---- singa-tpu extension: persistent XLA compilation cache.
-        # main.py wires jax's compile cache to this directory so repeat
-        # runs skip recompilation (BENCH_r05 measured 60-135 ms of fixed
-        # per-run startup, mostly XLA compiles). "" = default
-        # <workspace>/compile_cache; "off" disables; the
-        # SINGA_TPU_COMPILE_CACHE env var overrides either.
-        "compile_cache_dir": Field("string", ""),
         # ---- singa-tpu extension: per-device HBM budget in bytes for
         # the cost-aware shardlint (lint/cost_model.py). When > 0,
         # netlint's MEM001 errors on any model conf whose predicted

@@ -199,8 +199,7 @@ def structured_rgb(
     per-class delta against the U(0, 95) pixel noise, so the task has a
     real Bayes error: pairwise template separation is A*sqrt(3072/6) ~
     22.6*A against sample noise sigma 27.4 along the discriminant —
-    A ~ 6 targets ~90% optimal accuracy for 10 classes (BASELINE.md r5
-    records the measured landing point of the full AlexNet run)."""
+    A ~ 6 targets ~90% optimal accuracy for 10 classes."""
     rng = np.random.RandomState(seed)
     if class_amplitude is None:
         small = rng.rand(classes, 3, 8, 8) * 160

@@ -2,7 +2,7 @@
 
 The reference predates batch normalization and residual networks (its
 layer registry tops out at LRN, src/worker/neuralnet.cc:13-33); these
-layers extend the same config surface so BASELINE.md's stretch target —
+layers extend the same config surface so BASELINE.json's stretch target —
 ImageNet ResNet-50 (config 5) — is expressible as a plain job file.
 
 kBatchNorm's running statistics are the framework's first *buffers*:
@@ -93,7 +93,7 @@ class BatchNormLayer(Layer):
                 )
             else:
                 # fused one-pass BN (ops/norm.py custom VJP — stats in
-                # fp32, minimal HBM traffic; BASELINE.md r4 ablation)
+                # fp32, minimal HBM traffic)
                 y, mean, var = ops.batch_norm_train(
                     x,
                     params[self.gname],

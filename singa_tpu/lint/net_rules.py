@@ -83,8 +83,8 @@ FLT001 = rule(
 KRN001 = rule(
     "KRN001",
     ERROR,
-    "fused paged_attention selected with a geometry the compiled "
-    "kernel cannot tile",
+    "fused paged_attention selected with a geometry the kernel "
+    "cannot serve",
 )
 KRN002 = rule(
     "KRN002",
@@ -1066,18 +1066,14 @@ def elastic_rules(
 def kernel_rules(model_cfg: ModelConfig, path: str, col: Collector) -> None:
     """KRN001 — static mirror of the serving engine's fused-kernel
     geometry rejection (serve/engine.py consults the SAME
-    ops.paged_attention.fusable predicate at construction). A conf
-    that selects ``kernels { paged_attention: fused, interpret: false }``
-    with a ``kv_block_len`` or head_dim the compiled (Mosaic) kernel
-    cannot tile would reject at engine build time, after pod time is
-    already burned; flag it at lint time instead. Interpret mode tiles
-    anything, so ``interpret: true`` (the default) never fires. The
-    head_dim comes from the conf's declared dims — the kEmbedding
-    layer's ``embedding_dim`` over the kAttention layer's
-    ``num_heads`` — and is skipped when either is undeclared (not
-    statically decidable, like SRV001's window)."""
+    ops.paged_attention.fusable predicate at construction), so a conf
+    the engine would refuse is flagged at lint time, before pod time is
+    burned. The predicate holds what the v5e compiler really refuses
+    (tests/test_chip_compile.py asks it): any ``kv_block_len`` /
+    head_dim tiles, compiled or interpreted, so a pool block with no
+    positions is the one geometry left to flag."""
     kern = getattr(model_cfg, "kernels", None)
-    if kern is None or kern.paged_attention != "fused" or kern.interpret:
+    if kern is None or kern.paged_attention != "fused":
         return
     from ..ops.paged_attention import fusable
 
@@ -1085,49 +1081,15 @@ def kernel_rules(model_cfg: ModelConfig, path: str, col: Collector) -> None:
     block_len = srv.kv_block_len if srv is not None else (
         schema.ServingConfig.FIELDS["kv_block_len"].default
     )
-    head_dim = 0
-    net_cfg = model_cfg.neuralnet
-    if net_cfg is not None:
-        dim = max(
-            (
-                l.embedding_param.embedding_dim
-                for l in net_cfg.layer
-                if l.embedding_param is not None
-            ),
-            default=0,
-        )
-        heads = max(
-            (
-                l.attention_param.num_heads
-                for l in net_cfg.layer
-                if l.attention_param is not None
-            ),
-            default=0,
-        )
-        if dim and heads and dim % heads == 0:
-            head_dim = dim // heads
-    # check each declared dimension independently (a missing head_dim
-    # must not mask an untileable block_len and vice versa), but dedupe
-    # dimension-independent reasons — a missing pallas install is ONE
-    # problem, not one per probed dim
-    reasons = dict.fromkeys(
-        r
-        for r in (
-            fusable(block_len, 128, interpret=False),
-            fusable(8, head_dim, interpret=False) if head_dim else None,
-        )
-        if r is not None
-    )
-    for reason in reasons:
+    reason = fusable(block_len)
+    if reason is not None:
         col.emit(
             KRN001,
             path,
-            f"kernels.paged_attention 'fused' with interpret off, but "
-            f"{reason} — the engine will reject this config at "
-            "construction",
-            fix_hint="pick a tileable geometry (kv_block_len % 8 == 0, "
-            "head_dim % 128 == 0), or set kernels { interpret: true }, "
-            "or keep paged_attention: reference",
+            f"kernels.paged_attention 'fused', but {reason} — the "
+            "engine will reject this config at construction",
+            fix_hint="give serving { kv_block_len } at least one "
+            "position per block, or keep paged_attention: reference",
         )
 
 

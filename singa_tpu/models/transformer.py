@@ -147,7 +147,7 @@ def _attend(q, k, v, cfg: TransformerConfig, mesh):
     if cfg.attn == "flash":
         # dense below the per-device score-footprint threshold, kernel
         # above — "flash" means "don't blow memory", not "always
-        # kernel" (ops.attention.auto_attention, BASELINE.md r3)
+        # kernel" (ops.attention.auto_attention)
         from ..ops.attention import auto_attention
 
         return auto_attention(

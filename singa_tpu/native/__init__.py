@@ -6,10 +6,11 @@ here: `shardcodec.cc` scans shard files, decodes/encodes proto2 Records,
 and materializes whole datasets without Python in the per-record loop.
 
 The library builds on demand with g++ (one small TU, ~1s) into this
-directory; every entry point degrades gracefully to the pure-Python codec
-in singa_tpu.data when the toolchain or platform is unavailable, so the
-framework stays importable everywhere. `singa_tpu.data.pipeline` routes
-through `load_dataset` automatically.
+directory; every entry point degrades to the pure-Python codec in
+singa_tpu.data when the toolchain or platform is unavailable (a failed
+build says so once, as a warning), so the framework stays importable
+everywhere. `singa_tpu.data.pipeline` routes through `load_dataset`
+automatically.
 """
 
 from __future__ import annotations
@@ -17,6 +18,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 
 import numpy as np
 
@@ -41,7 +43,15 @@ def _build(src: str, lib: str) -> bool:
             timeout=120,
         )
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", None) or b""
+        warnings.warn(
+            f"native codec build of {os.path.basename(src)} failed "
+            f"({e}) {stderr.decode(errors='replace')[-300:]}— the "
+            "pure-Python codec serves instead",
+            RuntimeWarning,
+            stacklevel=2,
+        )
         return False
 
 
@@ -150,6 +160,21 @@ def load_lmdb_dataset(path: str) -> tuple[np.ndarray, np.ndarray] | None:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def rebuild() -> bool:
+    """Drop any prebuilt library and build both codecs from the tracked
+    ``.cc`` sources; -> whether the shard codec loaded. For callers
+    that must not trust a ``.so`` that came with the tree (git ignores
+    them; a copy of the tree as it stands on disk does not)."""
+    global _lib, _tried, _lmdb_lib, _lmdb_tried
+    for lib in (_LIB, _LMDB_LIB):
+        if os.path.exists(lib):
+            os.remove(lib)
+    _lib = _lmdb_lib = None
+    _tried = _lmdb_tried = False
+    get_lmdb_lib()
+    return available()
 
 
 def scan(path: str) -> tuple[int, int] | None:

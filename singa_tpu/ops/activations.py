@@ -20,9 +20,8 @@ def relu(x: jnp.ndarray, negative_slope: float = 0.0) -> jnp.ndarray:
     """max(x, 0), with optional leaky slope (ReLUProto.negative_slope).
 
     Plain autodiff. (An output-masked custom VJP — saving y instead of
-    the pre-activation for the backward mask — was A/B-measured
-    time-neutral on ResNet-50: XLA already shares/fuses the residual.
-    r4 perf notes, BASELINE.md.)"""
+    the pre-activation for the backward mask — buys nothing: XLA
+    already shares/fuses the residual.)"""
     # jnp.where (not jnp.maximum) so grad at exactly 0 is 0, matching
     # relu_grad's strict `a > 0 ? 1 : 0` (cxxnet_op.h:31-35)
     return jnp.where(x > 0, x, negative_slope * x if negative_slope else 0.0)

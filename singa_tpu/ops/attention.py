@@ -13,9 +13,7 @@ Each of the three kernels (fwd, dq, dkv) ships in two variants chosen
 per call by K/V footprint (_variant): *staged* keeps the whole K/V in
 VMEM per program (fastest while it fits), *streamed* keeps K/V in HBM
 and double-buffers (D, block) slices through async DMA — VMEM holds
-O(block), so sequence length is bounded by HBM, not VMEM (measured
-S=131072 single-chip; ~50 TF/s flat across S=8k-131k on v5e, which is
-the d=64 MXU roofline — BASELINE.md r4).
+O(block), so sequence length is bounded by HBM, not VMEM.
 
 All shapes are (batch, heads, seq, head_dim).
 """
@@ -25,9 +23,12 @@ from __future__ import annotations
 import functools
 import math
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -114,25 +115,6 @@ def block_attn_finish(out, m, l):
 # ---------------------------------------------------------------------
 # Pallas flash-attention kernel
 # ---------------------------------------------------------------------
-
-try:  # pallas import kept soft: CPU-only environments use interpret mode
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
-
-if HAS_PALLAS:
-    #: jax 0.4.x spells the HBM/unpinned memory space ANY; newer jax,
-    #: HBM (or the MemorySpace enum). Chained getattrs never raise, so
-    #: an unknown spelling degrades to BlockSpec's default memory space
-    #: instead of silently disabling pallas entirely.
-    _HBM = (
-        getattr(pltpu, "HBM", None)
-        or getattr(pltpu, "ANY", None)
-        or getattr(getattr(pltpu, "MemorySpace", None), "ANY", None)
-    )
 
 
 def _causal_nlive(q_offset, bq, block_k):
@@ -515,10 +497,11 @@ def flash_attention(
 ):
     """Flash attention: Pallas forward AND backward.
 
-    Falls back to the dense reference when Pallas is unavailable, the
-    sequence does not tile evenly, or Sq != Sk. ``interpret=True`` runs
-    the kernels in the Pallas interpreter (CPU testing); default
-    auto-detects TPU. Block sizes default to _auto_block(S); pass
+    Falls back to the dense reference when the sequence does not tile
+    evenly or Sq != Sk (announced by a warning on a TPU, see
+    ``_use_kernel``). ``interpret=True`` runs the kernels in the Pallas
+    interpreter (CPU testing); the default compiles them on a TPU and
+    runs dense elsewhere. Block sizes default to _auto_block(S); pass
     explicit values to override.
 
     Training memory is O(S) per head row (out + lse residuals) instead
@@ -530,23 +513,39 @@ def flash_attention(
 
 
 def _use_kernel(q, k, block_q, block_k, interpret):
-    if not HAS_PALLAS:
+    """Whether the Pallas kernels serve this call. ``interpret=None``
+    decides from the platform: compiled on a TPU, the dense reference
+    elsewhere. A TPU call the kernel cannot serve says so at trace time
+    — a dense S x S score tensor where the caller asked for the kernel
+    must not arrive unannounced."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None and not on_tpu:
         return False
     s = q.shape[2]
-    if s != k.shape[2]:  # kernel assumes Sq == Sk; dense handles the rest
-        return False
-    if s % block_q or s % block_k:
-        return False
-    if not interpret and (block_q % 128 or block_k % 128):
-        # on real hardware Mosaic requires lane blocks in multiples of
-        # 128: the lse lane dimension is blocked by block_q, and the
-        # streamed variant slices the lane (S) dim of the transposed
-        # K/V in block_k chunks (the interpreter is laxer — tests
-        # exercise smaller geometries there)
-        return False
-    if interpret is None:
-        return jax.default_backend() == "tpu"
-    return True
+    reason = None
+    if s != k.shape[2]:
+        reason = f"Sq {s} != Sk {k.shape[2]} (the kernel assumes Sq == Sk)"
+    elif s % block_q or s % block_k:
+        reason = f"S {s} does not tile by blocks ({block_q}, {block_k})"
+    elif not interpret and (block_q % 128 or block_k % 128):
+        # Mosaic requires lane blocks in multiples of 128: the lse lane
+        # dimension is blocked by block_q, and the streamed variant
+        # slices the lane (S) dim of the transposed K/V in block_k
+        # chunks (the interpreter is laxer — tests exercise smaller
+        # geometries there)
+        reason = (
+            f"blocks ({block_q}, {block_k}) are not multiples of the "
+            "128-lane tile"
+        )
+    if reason is None:
+        return True
+    if on_tpu and not interpret:
+        warnings.warn(
+            f"flash_attention runs the DENSE reference on this TPU: {reason}",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+    return False
 
 
 def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
@@ -591,8 +590,8 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
         grid=(bh, s // block_q),
         in_specs=[
             qblk,
-            pl.BlockSpec(memory_space=_HBM),  # K^T stays in HBM
-            pl.BlockSpec(memory_space=_HBM),  # V^T stays in HBM
+            pl.BlockSpec(memory_space=pltpu.HBM),  # K^T stays in HBM
+            pl.BlockSpec(memory_space=pltpu.HBM),  # V^T stays in HBM
         ],
         out_specs=[qblk, lse_blk],
         out_shape=out_shape,
@@ -635,7 +634,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     )[:, None, :]
     qspec = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))
-    hbm = pl.BlockSpec(memory_space=_HBM)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     lse_blk = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))
     if _variant(s, d, k.dtype) == "staged":
         args = (flat(q), flat(k), flat(v), flat(g), lse, delta)
@@ -725,12 +724,11 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def auto_attention(q, k, v, *, causal=False, n_devices=1):
     """Pick dense vs the Pallas kernel by score-tensor footprint.
 
-    Measured on TPU v5e (BASELINE.md r3): XLA's fused dense attention
-    beats the kernel at every size where the S x S score tensor
-    comfortably fits HBM, so the kernel's job is the long-context
-    regime where dense would blow memory. The footprint estimate is
-    per device (fwd+bwd fp32 scores / ``n_devices`` — pass the mesh
-    size when batch/seq dims are sharded); the threshold is
+    XLA's fused dense attention serves every size where the S x S
+    score tensor comfortably fits HBM; the kernel's job is the
+    long-context regime where dense would blow memory. The footprint
+    estimate is per device (fwd+bwd fp32 scores / ``n_devices`` — pass
+    the mesh size when batch/seq dims are sharded); the threshold is
     SINGA_TPU_DENSE_ATTN_MB (default 512).
     """
     import os

@@ -2,9 +2,8 @@
 
 Why this exists (round-4 perf work): autodiff through
 ``jnp.mean``/``jnp.var`` plus fp32 casts generated 4-6 extra
-full-activation passes per BatchNorm; on ResNet-50 at batch 128 the 53
-BN layers owned 18 ms of a 50.9 ms train step (measured by layer
-ablation on a v5e chip, BASELINE.md r4). This implementation does the
+full-activation passes per BatchNorm, and ResNet-50 has 53 of them on
+activation-sized tensors. This implementation does the
 information-theoretic minimum of HBM traffic:
 
   fwd:  one fused read of x for both moments (sum and sum-of-squares

@@ -54,16 +54,17 @@ fetch index map so blocks past a sequence's live range re-fetch the
 previous block id — Pallas skips the DMA when consecutive grid steps
 map to the same block — and ``pl.when`` skips their compute.
 
-``interpret=True`` (the default, and what CPU CI runs) executes the
-kernel through the Pallas interpreter — plain XLA ops, so the
-masking/online-softmax logic is tested on every run and the kernel
-composes with GSPMD sharding (``serving_kv_shardings`` lays the pool's
-heads over the model axis; the grid's ``S*H`` dimension partitions
-with it). ``interpret=False`` compiles through Mosaic for a real TPU
-and constrains the geometry (``fusable``): the K/V block tile must
-align to the (8, 128) float32 register tile, i.e. ``kv_block_len`` a
-multiple of 8 and ``head_dim`` a multiple of 128. netlint's KRN001 is
-the static mirror of that rejection.
+``interpret`` is decided from the platform (``_call``): on a TPU the
+kernel compiles through Mosaic, elsewhere it runs through the Pallas
+interpreter — plain XLA ops, so the masking/online-softmax logic is
+tested on every CPU run and the kernel composes with GSPMD sharding
+(``serving_kv_shardings`` lays the pool's heads over the model axis;
+the grid's ``S*H`` dimension partitions with it). Tests pass an
+explicit ``True``. Every operand's block has its last two dims EQUAL
+to the array's, so Mosaic takes any ``kv_block_len`` / ``head_dim`` /
+query count (``fusable``; tests/test_chip_compile.py asks the v5e
+compiler) — tiles off the (8, 128) register tile are padded, not
+refused.
 """
 
 from __future__ import annotations
@@ -74,47 +75,22 @@ import math
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from .attention import NEG_INF
 
-try:  # soft import, like ops/attention: CPU wheels ship pallas too,
-    # but a missing extra must degrade to a loud config error, not an
-    # import-time crash of the whole serve package
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
-
-
-#: hardware tile floor for the compiled (interpret=False) kernel: the
-#: K/V block tile is (block_len, head_dim) float32 — sublanes of 8,
-#: lanes of 128 (pallas_guide "Tiling Constraints")
-_SUBLANE, _LANE = 8, 128
-
-
-def fusable(block_len: int, head_dim: int, interpret: bool = True):
+def fusable(block_len: int):
     """None if the kernel can serve this geometry, else the reason it
-    cannot — the ONE tiling predicate the engine's runtime rejection
+    cannot — the ONE geometry predicate the engine's runtime rejection
     and netlint's KRN001 both consult (a static mirror must never
-    drift from the thing it mirrors)."""
-    if not HAS_PALLAS:
-        return "jax.experimental.pallas is unavailable in this environment"
+    drift from the thing it mirrors). The interpreter and the v5e
+    compiler both take every block length and head dim (each block's
+    last two dims equal its array's), so a pool block holding at least
+    one position is the whole condition."""
     if block_len < 1:
         return f"kv_block_len {block_len} < 1"
-    if interpret:
-        return None  # the interpreter tiles anything
-    if block_len % _SUBLANE:
-        return (
-            f"kv_block_len {block_len} not a multiple of {_SUBLANE} "
-            f"(the fp32 sublane tile): the compiled kernel cannot tile "
-            "the pool's block dimension"
-        )
-    if head_dim % _LANE:
-        return (
-            f"head_dim {head_dim} not a multiple of {_LANE} (the lane "
-            "tile): the compiled kernel cannot tile the head dimension"
-        )
     return None
 
 
@@ -143,7 +119,7 @@ def _kernel(
     b = pl.program_id(1)
     s = pl.program_id(0) // n_heads
     q = q_ref[0, 0].astype(jnp.float32)            # (Q, D)
-    pos = pos_ref[0]                               # (Q,) int32
+    pos = pos_ref[0, 0]                            # (Q,) int32
     scale = 1.0 / math.sqrt(q.shape[-1])
 
     def fold(scores, mask, values):
@@ -183,7 +159,7 @@ def _kernel(
         if has_chunk:
             ck = ck_ref[0, 0].astype(jnp.float32)  # (Q, D)
             cv = cv_ref[0, 0].astype(jnp.float32)
-            vld = valid_ref[0] != 0
+            vld = valid_ref[0, 0] != 0
             # column jj holds the entry AT position pos[jj]: causal
             # within the chunk, padding/rejected columns masked out
             mask = (pos[None, :] <= pos[:, None]) & vld[None, :]
@@ -207,9 +183,10 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
     s, h, nq, d = q.shape
     _, _, bl, _ = k_pool.shape
     mb = tables.shape[1]
-    reason = fusable(bl, d, interpret=bool(interpret))
-    if reason is not None:
-        raise ValueError(f"paged_attention cannot run: {reason}")
+    if interpret is None:
+        # THE place the fused serving kernel picks its form: compiled
+        # through Mosaic on a TPU, the Pallas interpreter anywhere else
+        interpret = jax.default_backend() != "tpu"
     if chunk is None:
         # write-then-read: blocks must cover every query position
         live_to = jnp.max(positions, axis=1)
@@ -230,14 +207,18 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
     qspec = pl.BlockSpec(
         (1, 1, nq, d), lambda i, b, t, n: (i // h, i % h, 0, 0)
     )
-    rowspec = pl.BlockSpec((1, nq), lambda i, b, t, n: (i // h, 0))
+    # per-sequence rows ride as (S, 1, Q) with a (1, 1, Q) block: the
+    # block's last two dims then EQUAL the array's, which Mosaic takes
+    # at any Q — a (1, Q) block of an (S, Q) array is refused unless
+    # S == 1 (sublane dim neither 8-divisible nor the array's)
+    rowspec = pl.BlockSpec((1, 1, nq), lambda i, b, t, n: (i // h, 0, 0))
     kvspec = pl.BlockSpec((1, 1, bl, d), kmap)
     in_specs = [qspec, kvspec, kvspec, rowspec]
-    args = [q, k_pool, v_pool, positions.astype(jnp.int32)]
+    args = [q, k_pool, v_pool, positions.astype(jnp.int32)[:, None, :]]
     if chunk is not None:
         ck, cv, valid = chunk
         in_specs += [qspec, qspec, rowspec]
-        args += [ck, cv, valid.astype(jnp.int32)]
+        args += [ck, cv, valid.astype(jnp.int32)[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s * h, mb),
@@ -290,7 +271,7 @@ def modeled_bytes(
 
 
 def paged_attention(
-    q, k_pool, v_pool, tables, positions, *, interpret=True
+    q, k_pool, v_pool, tables, positions, *, interpret=None
 ):
     """Masked paged attention, write-then-read form.
 
@@ -307,7 +288,7 @@ def paged_attention(
 
 def paged_attention_overlay(
     q, k_pool, v_pool, tables, positions, chunk_k, chunk_v, chunk_valid,
-    *, interpret=True,
+    *, interpret=None,
 ):
     """Masked paged attention with the fresh chunk OVERLAID — the
     verify tick's no-pool-write form (KV rewind by construction).

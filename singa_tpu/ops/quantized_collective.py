@@ -101,13 +101,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 exports shard_map at the top level; this image's
-    # 0.4.x ships it under experimental — parallel/{ring,moe,pipeline}
-    # import this shim too, so every shard_map call site resolves the
-    # rename in one place
-    from jax.experimental.shard_map import shard_map
-except Exception:  # pragma: no cover - newer jax
-    shard_map = jax.shard_map
+from jax.extend import core as jcore
 
 #: int8 symmetric range: q in [-127, 127], scale = max|e| / 127 (shared
 #: with parallel/collectives.py's reference quantized path — ONE
@@ -338,7 +332,15 @@ def hier_ring_geometry(widths, ring, *, data_axis: str = "data"):
 # ---------------------------------------------------------------------------
 
 
-def _quant_acc_kernel(q_ref, s_ref, x_ref, o_ref):
+#: rows of the (rows, 128) chunk view one grid step of ``quant_acc``
+#: holds in VMEM: a multiple of the int8 (32, 128) tile, and 1024 rows
+#: x (1 + 4 + 4) bytes double-buffered stays near 2.4 MB — far inside
+#: the 16 MB scoped-VMEM limit a single whole-chunk block overran at
+#: ~1.2M elements
+_ACC_BLOCK_ROWS = 1024
+
+
+def _quant_acc_kernel(s_ref, q_ref, x_ref, o_ref):
     o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0] + x_ref[...]
 
 
@@ -349,22 +351,35 @@ def quant_acc(
     """``dequantize_int8(q, scale) + local`` as ONE fused Pallas kernel
     — the per-hop accumulation's memory traffic is one read of the int8
     chunk, one read of the local f32 partial, one write, with no f32
-    dequantized intermediate ever hitting HBM. ``interpret=True`` runs
-    it through the Pallas interpreter (plain XLA ops — the unit test
-    pins it to the jnp form within 1 ulp; the interpreter may contract
-    the multiply-add into an fma); ``interpret=False`` compiles
-    through Mosaic and needs ``ring_fusable`` geometry."""
+    dequantized intermediate ever hitting HBM. The chunk is viewed as
+    (rows, 128) and walked ``_ACC_BLOCK_ROWS`` rows per grid step, so
+    VMEM holds one block whatever the chunk's size. ``interpret=True``
+    runs it through the Pallas interpreter (plain XLA ops — the unit
+    test pins it to the jnp form within 1 ulp; the interpreter may
+    contract the multiply-add into an fma); ``interpret=False``
+    compiles through Mosaic and needs ``ring_fusable`` geometry (a
+    chunk that is no multiple of 128 rides as one (1, n) block, which
+    only the interpreter takes)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     n = local.size
     cols = _LANE if n % _LANE == 0 else n
-    qf = q.reshape(n // cols, cols)
-    xf = local.astype(jnp.float32).reshape(n // cols, cols)
+    rows = n // cols
+    block_rows = min(rows, _ACC_BLOCK_ROWS)
+    block = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
     out = pl.pallas_call(
         _quant_acc_kernel,
-        out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        grid=(pl.cdiv(rows, block_rows),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), block, block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
         interpret=bool(interpret),
-    )(qf, scale.reshape(1, 1), xf)
+    )(
+        scale.reshape(1, 1).astype(jnp.float32),
+        q.reshape(rows, cols),
+        local.astype(jnp.float32).reshape(rows, cols),
+    )
     return out.reshape(local.shape)
 
 
@@ -822,6 +837,18 @@ def ring_reduce_gradients(
                 ).reshape(
                     _shard_shape(gs[nm].shape, d, n)
                 ).astype(gs[nm].dtype)
+        for nm in bucket:
+            # materialize the reduced gradient before anything consumes
+            # it. A 2-wide ring's one-trip allgather scan is inlined, and
+            # XLA then fuses the owner's local dequantize and the
+            # received chunks' dequantize — two code paths — into the
+            # optimizer update, where the backend may contract each
+            # path's multiply-add differently (seen on XLA:CPU): the
+            # owner's copy of its own chunk lands an ulp off the copies
+            # it broadcast, "replicated" params drift apart across
+            # shards, and a resumed run (whose restore re-equalizes
+            # them) stops matching the uninterrupted one
+            out[nm] = jax.lax.optimization_barrier(out[nm])
         if overlapped:
             token = out[bucket[0]]
     return out, new_res
@@ -929,8 +956,6 @@ def ppermute_wire_bytes_levels(
     within-group hop keeps ``src//K == dst//K``, the cross-group hop
     keeps ``src%K == dst%K`` (disjoint for K, M > 1; a perm matching
     neither — e.g. a flat ring's — raises, misuse is loud)."""
-    import jax.core as jcore
-
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     K = max(1, int(intra_degree))
     out = {"intra": 0, "inter": 0}
@@ -1005,8 +1030,6 @@ def ppermute_wire_bytes(jaxpr) -> int:
     sub-jaxprs — the measured half of the wire-bytes gate: counted from
     the program the step actually traces, not from the model. Accepts a
     ClosedJaxpr (``jax.make_jaxpr(...)(...)``) or a raw Jaxpr."""
-    import jax.core as jcore
-
     inner = getattr(jaxpr, "jaxpr", jaxpr)
 
     def walk(jx, mult: int) -> int:

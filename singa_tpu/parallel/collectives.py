@@ -91,8 +91,10 @@ class GradCommSpec:
     #: or "q8_hier" (the hierarchical two-level ring: f32 intra-slice,
     #: int8 inter-slice — geometry from the ``ring {}`` fields below)
     wire_impl: str = "reference"
-    #: pure-XLA ppermute form (True, the CPU-CI path) vs the fused
-    #: Pallas per-hop quantize+accumulate kernel (False, real hardware)
+    #: the ring hop's form — NOT the Pallas interpreter: True is the
+    #: pure-XLA ppermute hop (a real compiled program on the chip, and
+    #: the default on every platform), False the Pallas ``quant_acc``
+    #: hop, whose (8, 128)-aligned-chunk gate no shipped conf passes
     interpret: bool = True
     #: ``ring {}`` geometry for q8_hier (hier_ring_geometry resolves
     #: these against the mesh): named axes, or the factored data-axis
@@ -146,7 +148,12 @@ class GradCommSpec:
         impl = (
             kernels.grad_allreduce if kernels is not None else "reference"
         )
-        interpret = bool(kernels.interpret) if kernels is not None else True
+        # unset keeps the plain-XLA hop on every platform (the serving
+        # kernel, not the ring, follows the platform when unset)
+        interpret = (
+            True if kernels is None or kernels.interpret is None
+            else bool(kernels.interpret)
+        )
         if impl in ("quantized_ring", "q8_hier") and (
             cfg is None or cfg.mode != "quantized"
         ):

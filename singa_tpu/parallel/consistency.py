@@ -119,8 +119,7 @@ def random_sync(replicas, snapshots, center, indices, full_coverage=False):
     new value is c0[x] + sum_{j<=i, x in idx_j} delta_j[x] and the final
     center is c0 + the full sum — an associative prefix over the replica
     axis. This computes it with one batched scatter + jnp.cumsum instead
-    of the r3 lax.scan whose serial gather/scatter rounds cost 3.1x the
-    sync engine at 8 replicas (BASELINE.md r3 replica table). The
+    of a lax.scan of serial gather/scatter rounds, one per replica. The
     arrival order is fixed at 0..R-1 — the reference's order was
     whatever ZMQ delivered, so this is as valid an execution as any, and
     it matches the previous scan's order exactly (differences vs the

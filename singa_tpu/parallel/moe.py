@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.quantized_collective import shard_map
 from .mesh import axis_pair_mesh
 
 EXPERT_AXIS = "expert"
@@ -137,7 +136,7 @@ def moe_ffn(
         # shape (1,) so the data axis can stack shards' values
         return y.reshape(b, s, d).astype(x.dtype), aux.reshape(1)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -175,7 +174,7 @@ def moe_ffn_a2a(
     E-fold more tokens and the combine all-reduces a FULL (n, d)
     activation: ~2 * n * d comm per device plus E-fold redundant
     routing/dispatch compute. At E experts the all-to-all form does
-    O(1/E) of both. (measured: BASELINE.md r4.)
+    O(1/E) of both.
 
     **Semantics vs moe_ffn/moe_ffn_dense**: the capacity limit is per
     (source shard, expert) — cf * n_local / E slots — the standard
@@ -236,7 +235,7 @@ def moe_ffn_a2a(
         return y.reshape(b, s, d).astype(x.dtype), aux.reshape(1)
 
     token_spec = P(token_axes if data else axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.quantized_collective import shard_map
 from .mesh import axis_pair_mesh
 
 PIPE_AXIS = "pipe"
@@ -93,14 +92,8 @@ def pipeline_apply(
             return send, (out, valid & is_last, t - stage)
 
         # the carry must already wear the vma of its steady state: derive
-        # from xm (data axis) and mark pipe-varying (send crosses hops).
-        # pcast/vma only exists on newer jax; the experimental shard_map
-        # this image ships tracks replication itself, so the bare zero
-        # is already correct there
-        pcast = getattr(jax.lax, "pcast", None)
-        zero = xm[0] * 0.0
-        if pcast is not None:
-            zero = pcast(zero, (axis,), to="varying")
+        # from xm (data axis) and mark pipe-varying (send crosses hops)
+        zero = jax.lax.pcast(xm[0] * 0.0, (axis,), to="varying")
         _, (outs, valids, idxs) = jax.lax.scan(
             tick, zero, jnp.arange(nmicro + nstages - 1)
         )
@@ -113,7 +106,7 @@ def pipeline_apply(
         )
         return jax.lax.psum(buf, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

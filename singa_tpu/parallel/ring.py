@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.quantized_collective import shard_map
 
 from ..ops.attention import (
     block_attn_finish,
@@ -105,7 +104,7 @@ def ring_attention(
         return attention(q, k, v, causal=causal)
     data = "data" if "data" in mesh.shape else None
     spec = P(data, None, axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attn_local, axis_name=axis, causal=causal
         ),

@@ -57,11 +57,10 @@ keeps the bitwise-pinned gather -> ``cache_attend`` path above;
 table, no dense ``(S, H, cache_len, D)`` materialization per layer.
 Fused output is allclose to the reference (online softmax reorders the
 reduction — the PR 9 cross-shape caveat at kernel granularity); greedy
-token STREAMS are pinned identical in tests. ``kernels { interpret }``
-(default true) runs the kernel through the Pallas interpreter — plain
-XLA ops, CPU-safe and GSPMD-shardable — set false on a real TPU to
-compile through Mosaic (geometry-gated: see paged_attention.fusable,
-statically mirrored by netlint KRN001).
+token STREAMS are pinned identical in tests. The kernel's form follows
+the platform (ops/paged_attention._call): compiled through Mosaic on a
+TPU, the Pallas interpreter — plain XLA ops, CPU-safe and
+GSPMD-shardable — elsewhere; ``kernels { interpret }`` pins either.
 """
 
 from __future__ import annotations
@@ -124,11 +123,11 @@ class EngineConfig:
     #: = the Pallas kernel reading K/V blocks in place via the block
     #: table (ops/paged_attention.py)
     attend_impl: str = "reference"
-    #: ``kernels { interpret }``: run the fused kernel through the
-    #: Pallas interpreter (plain XLA ops — CPU-safe, GSPMD-shardable;
-    #: what CI exercises). False compiles through Mosaic on a real TPU
-    #: and constrains the geometry (paged_attention.fusable / KRN001).
-    interpret: bool = True
+    #: ``kernels { interpret }``: None (the conf left it unset) lets
+    #: the platform decide — Mosaic-compiled on a TPU, the Pallas
+    #: interpreter (plain XLA ops — CPU-safe, GSPMD-shardable; what CI
+    #: exercises) elsewhere. True/False pin the form.
+    interpret: bool | None = None
 
     @classmethod
     def from_conf(cls, serving, kernels=None) -> "EngineConfig":
@@ -208,10 +207,7 @@ class Engine:
         if self._fused:
             from ..ops.paged_attention import fusable
 
-            reason = fusable(
-                self.serving.kv_block_len, cfg.head_dim,
-                interpret=self.serving.interpret,
-            )
+            reason = fusable(self.serving.kv_block_len)
             if reason is not None:
                 # the runtime rejection KRN001 statically mirrors
                 raise ValueError(

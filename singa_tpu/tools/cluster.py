@@ -33,6 +33,12 @@ job is
 (init_distributed sees the pod environment and calls
 jax.distributed.initialize() with no arguments). This module is for
 reference-style CPU/GPU fleets and local multi-process runs.
+
+One process per chip: this launcher never initializes a JAX backend, but
+several LOCAL ranks would contend for the host's accelerator, so
+``start`` refuses more than one local rank unless ``JAX_PLATFORMS=cpu``
+(parallel/launch.refuse_local_ranks_on_a_chip) — local gangs are a CPU
+rehearsal.
 """
 
 from __future__ import annotations
@@ -46,7 +52,7 @@ import socket
 import subprocess
 import sys
 
-from ..parallel.launch import read_hostfile
+from ..parallel.launch import read_hostfile, refuse_local_ranks_on_a_chip
 
 SSH_OPTS = [
     "-oStrictHostKeyChecking=no",
@@ -84,6 +90,7 @@ def start(args) -> int:
             "lines", file=sys.stderr,
         )
         return 2
+    refuse_local_ranks_on_a_chip(sum(_is_local(h) for h in hosts[:n]))
     pdir = _proc_dir(args.workspace)
     hostfile = os.path.abspath(args.hostfile)
     if n < len(hosts):

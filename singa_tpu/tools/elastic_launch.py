@@ -27,6 +27,11 @@ exits have happened — the elastic drill: the reshard-on-restore path
 (resilience/reshard.py) re-slices the drained checkpoint onto the new
 world size, so shrinking a preempted 8-host gang to whatever capacity
 is left is one flag, not a migration project.
+
+One process per chip: every rank is forked onto THIS host, so a gang
+wider than one rank is a ``JAX_PLATFORMS=cpu`` rehearsal and is refused
+otherwise (parallel/launch.refuse_local_ranks_on_a_chip). The launcher
+itself never initializes a JAX backend.
 """
 
 from __future__ import annotations
@@ -38,6 +43,7 @@ import subprocess
 import sys
 
 from ..config import load_model_config
+from ..parallel.launch import refuse_local_ranks_on_a_chip
 from ..resilience.launcher import RestartBudget, supervise_gang
 
 
@@ -78,6 +84,7 @@ def parse_args(argv=None):
 
 def run_gang_once(args, nprocs: int, *, log=print) -> list[int]:
     """One gang attempt: spawn nprocs ranks, wait, return exit codes."""
+    refuse_local_ranks_on_a_chip(nprocs)
     workdir = os.path.dirname(os.path.abspath(args.model_conf)) or "."
     hostfile = _write_hostfile(workdir, nprocs) if nprocs > 1 else None
     procs = []

@@ -1,60 +1,84 @@
 """Persistent XLA compilation cache: warm-start repeat runs.
 
-BENCH_r05 measured 60-135 ms of fixed per-run startup overhead, mostly
-XLA recompilation of programs that are bit-identical across runs (the
-train step, the eval chunks, the snapshot copy). jax ships a persistent
-compilation cache keyed on the lowered computation; pointing it at a
-directory makes every run after the first skip those compiles entirely.
+Most of a run's fixed start-up cost is XLA recompilation of programs
+that are bit-identical across runs (the train step, the eval chunks,
+the engine's decode/prefill/verify programs). jax ships a persistent
+compilation cache keyed on the lowered computation — and on the cache
+directory's own path, so a directory that moves never hits.
 
-Resolution order for the cache directory (first hit wins):
-
-  1. ``SINGA_TPU_COMPILE_CACHE`` env var — operators override per run
-     (the values ``0``/``off``/``none`` disable the cache)
-  2. ``ClusterConfig.compile_cache_dir`` — the cluster conf pins a
-     shared location (same ``off`` spellings disable)
-  3. ``<workspace>/compile_cache`` — the default for any job with a
-     workspace; jobs without one run uncached (nowhere durable to put it)
-
-``bench.py`` measures the realized warm-start delta (cold vs warm first
-step) and reports it as ``compile_warm_start`` in its output.
+The directory is placed from OUTSIDE the program: when
+``JAX_COMPILATION_CACHE_DIR`` is set jax has already taken it from the
+environment and nothing here (or anywhere in the repo) sets another.
+When it is not, every entry point — training jobs, serving hosts,
+``bench.py``, ``chip_smoke.py`` — shares ONE fixed, git-ignored
+directory inside the checkout (``DEFAULT_CACHE_DIR``), so two
+consecutive runs of the same checkout hit each other's entries.
+``JAX_ENABLE_COMPILATION_CACHE=false`` (jax's own switch) turns it off.
 """
 
 from __future__ import annotations
 
 import os
 
-_OFF = ("", "0", "off", "none", "false")
+#: <checkout>/.compile_cache — fixed, so the path-keyed cache hits
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".compile_cache")
 
 
-def resolve_cache_dir(cluster_cfg=None) -> str | None:
-    """The persistent-cache directory the resolution order picks, or
-    None when caching is disabled / unconfigured."""
-    path = os.environ.get("SINGA_TPU_COMPILE_CACHE")
-    if path is None and cluster_cfg is not None:
-        if cluster_cfg.compile_cache_dir:
-            path = cluster_cfg.compile_cache_dir
-        elif cluster_cfg.workspace:
-            path = os.path.join(cluster_cfg.workspace, "compile_cache")
-    if path is None or path.strip().lower() in _OFF:
-        return None
+def setup_compile_cache(log=print) -> str:
+    """Turn the persistent cache on for this process and return its
+    directory. The min-time/min-size gates are zeroed: singa-tpu jobs
+    compile a handful of large programs, so every entry is worth
+    keeping."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    log(f"persistent compile cache: {path}")
     return path
 
 
-def enable_compile_cache(path: str, log=print) -> bool:
-    """Point jax's persistent compilation cache at ``path``. The
-    min-time/min-size gates are zeroed: singa-tpu jobs compile a handful
-    of large programs, so every entry is worth keeping. Returns False
-    (and keeps running uncached) on jax builds without the knobs."""
-    import jax
+class CacheCounter:
+    """Counts this process's persistent-cache hits and misses, and the
+    seconds spent obtaining executables (compiled or read back), from
+    jax's own monitoring events while the ``with`` block is open — how
+    a run says whether its compiles were served from the cache."""
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - version-dependent
-        log(f"persistent compile cache unavailable ({e}); running uncached")
-        return False
-    return True
+    def __init__(self) -> None:
+        self.hits = 0
+        self.misses = 0
+        self.compile_s = 0.0
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def _on_duration(self, event: str, secs: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compile_s += secs
+
+    def __enter__(self) -> "CacheCounter":
+        import jax
+
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration
+        )
+        return self
+
+    def __exit__(self, *exc) -> None:
+        import jax
+
+        jax.monitoring.unregister_event_listener(self._on_event)
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
 
 
 def disable_compile_cache(log=print) -> None:
@@ -69,24 +93,9 @@ def disable_compile_cache(log=print) -> None:
     is untouched."""
     import jax
 
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            jax.config.update("jax_compilation_cache_dir", None)
-            log(
-                "persistent compile cache: disabled for restart attempts "
-                "(in-process re-read of fresh entries is not crash-safe)"
-            )
-    except Exception:  # pragma: no cover - version-dependent
-        pass
-
-
-def setup_compile_cache(cluster_cfg=None, log=print) -> str | None:
-    """Resolve + enable in one call (main.py's entry). Returns the
-    active cache dir, or None when disabled."""
-    path = resolve_cache_dir(cluster_cfg)
-    if path is None:
-        return None
-    if not enable_compile_cache(path, log=log):
-        return None
-    log(f"persistent compile cache: {path}")
-    return path
+    if jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", None)
+        log(
+            "persistent compile cache: disabled for restart attempts "
+            "(in-process re-read of fresh entries is not crash-safe)"
+        )

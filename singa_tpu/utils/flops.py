@@ -19,7 +19,6 @@ walk. Causal attention scores count at half density — the flash kernel
 from __future__ import annotations
 
 import math
-import os
 
 
 def layer_fwd_flops(layer, src_shapes: list[tuple]) -> float:
@@ -109,19 +108,22 @@ _PEAKS = (
 
 
 def device_peak_flops(device=None) -> float | None:
-    """bf16 peak FLOP/s of one chip, or None when unknown (e.g. CPU).
-
-    Override with SINGA_TPU_PEAK_TFLOPS for hardware not in the table.
-    """
-    env = os.environ.get("SINGA_TPU_PEAK_TFLOPS")
-    if env:
-        return float(env) * 1e12
+    """bf16 peak FLOP/s of one chip; None on the CPU platform (no
+    utilization is ever quoted against it). An accelerator whose
+    ``device_kind`` is not in the table is an error, not a default: a
+    utilization against a guessed peak is worse than none."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind.lower()
     for key, peak in _PEAKS:
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no bf16 peak on record for {device.platform} device_kind "
+        f"{device.device_kind!r}: add it to utils/flops._PEAKS with its "
+        "source before quoting a utilization"
+    )

@@ -158,15 +158,39 @@ def test_spec_inert_and_active_forms():
 def test_overlap_buckets_leave_values_bitwise(shard):
     """``buckets: N`` with mode exact only chains the reductions in
     reverse-topo order (optimization_barrier is a value identity): the
-    run stays bitwise-identical to the unbucketized one."""
+    reduced GRADIENTS stay bitwise-identical to the unbucketized run.
+
+    The trajectory after the optimizer update is pinned to 2 ulps of
+    each tensor's magnitude, not bitwise: the update ``0.9*h + lr*g``
+    holds two multiplies feeding one add, XLA:CPU lets LLVM contract
+    one of them into an fma, and which one depends on the emission
+    order inside the fused update — which the barrier shifts (the
+    gradient's transpose lands before the lr multiply instead of after
+    it). Same all-reduce, same operands, one rounding moved."""
     t_none = _mk(_cfg(shard))
     t_ovl = _mk(_cfg(shard, extra="grad_comm { mode: exact buckets: 3 }"))
     assert t_ovl._comm is not None and t_ovl._comm.overlapped
-    assert _loss_trace(t_none, 10) == _loss_trace(t_ovl, 10)
+    ln, lo = _loss_trace(t_none, 10), _loss_trace(t_ovl, 10)
+    eps = float(np.finfo(np.float32).eps)
+    for a, b in zip(ln, lo):
+        assert abs(a - b) <= 2 * eps * abs(a), (ln, lo)
     for name in t_none.params:
+        want = np.asarray(t_none.params[name])
+        np.testing.assert_allclose(
+            np.asarray(t_ovl.params[name]), want, rtol=0,
+            atol=2 * eps * float(np.abs(want).max()), err_msg=name,
+        )
+    # the value identity itself, bitwise: one step whose "update" hands
+    # the reduced gradients straight back
+    g_none = _mk(_cfg(shard))
+    g_ovl = _mk(_cfg(shard, extra="grad_comm { mode: exact buckets: 3 }"))
+    for t in (g_none, g_ovl):
+        t._apply_update = lambda step, params, grads, state: (grads, state)
+        t.train_one_batch(0)
+    for name in g_none.params:
         np.testing.assert_array_equal(
-            np.asarray(t_none.params[name]),
-            np.asarray(t_ovl.params[name]), err_msg=name,
+            np.asarray(g_none.params[name]),
+            np.asarray(g_ovl.params[name]), err_msg=name,
         )
 
 

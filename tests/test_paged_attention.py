@@ -194,14 +194,15 @@ def test_overlay_matches_dense_overlay_oracle():
 
 
 def test_fusable_predicate_and_modeled_bytes():
-    """Interpret mode tiles anything; the compiled kernel demands the
-    (8, 128) fp32 tile; the bytes model counts q/o + live block tiles
-    (+ the overlay chunk)."""
-    assert fusable(3, 7, interpret=True) is None
-    assert fusable(16, 128, interpret=False) is None
-    assert "kv_block_len" in fusable(12, 128, interpret=False)
-    assert "head_dim" in fusable(16, 96, interpret=False)
-    assert fusable(0, 128, interpret=True) is not None
+    """Interpreter and compiler both tile any block (the (8, 128)
+    demand was the repo's, not Mosaic's — tests/test_chip_compile.py
+    asks the v5e compiler about 12 and 96); an empty block is refused;
+    the bytes model counts q/o + live block tiles (+ the overlay
+    chunk)."""
+    assert fusable(3) is None
+    assert fusable(16) is None
+    assert fusable(12) is None
+    assert "kv_block_len" in fusable(0)
     base = modeled_bytes(2, 2, 1, 8, 4, 6)
     assert base == 2 * 2 * 2 * 1 * 8 * 4 + 2 * 6 * 2 * 4 * 8 * 4
     assert modeled_bytes(2, 2, 1, 8, 4, 6, overlay=True) > base
@@ -413,19 +414,20 @@ def test_default_config_jaxpr_identical_to_explicit_reference(lm):
 
 
 def test_engine_rejects_untileable_fused_geometry(lm):
-    """The runtime rejection KRN001 statically mirrors: fused with
-    interpret off and a geometry Mosaic cannot tile raises at
-    construction; interpret on tiles anything; junk impl names raise
-    loudly."""
+    """The runtime rejection KRN001 statically mirrors: a pool block
+    with no positions raises at construction under fused; a head_dim
+    off the 128-lane tile (16 here) builds in every form — Mosaic pads
+    it, the v5e ran it — and junk impl names raise loudly."""
     cfg, params = lm  # head_dim 16: not a multiple of 128
-    with pytest.raises(ValueError, match="head_dim"):
+    with pytest.raises(ValueError, match="kv_block_len"):
+        Engine(params, cfg, EngineConfig(
+            slots=2, kv_block_len=0, attend_impl="fused",
+        ))
+    for interpret in (None, True, False):
         Engine(params, cfg, EngineConfig(
             slots=2, kv_block_len=8, attend_impl="fused",
-            interpret=False,
+            interpret=interpret,
         ))
-    Engine(params, cfg, EngineConfig(
-        slots=2, kv_block_len=8, attend_impl="fused", interpret=True,
-    ))
     with pytest.raises(ValueError, match="reference"):
         Engine(params, cfg, EngineConfig(slots=2, attend_impl="fusedx"))
 
@@ -505,22 +507,22 @@ def test_kernels_conf_lint_did_you_mean(kernels_conf):
 
 
 def test_krn001_untileable_fused_geometry_lint(kernels_conf):
-    """KRN001: `fused` with interpret off and an untileable
-    kv_block_len or head_dim is a lint ERROR (the static mirror of the
-    engine's construction-time rejection); interpret on, reference
-    impl, or a tileable geometry stays clean — and both bad dims
-    report independently."""
-    assert not _diags(kernels_conf, "KRN001")  # 16 % 8, 256/2 % 128: ok
-    bad_bl = kernels_conf.replace("kv_block_len: 16", "kv_block_len: 12")
+    """KRN001: `fused` with a geometry the engine would refuse at
+    construction is a lint ERROR (the static mirror of
+    paged_attention.fusable) whatever `interpret` says; the reference
+    impl stays clean — and the geometries the old (8, 128) demand
+    refused (block 12, head_dim 96) are clean, as the compiler is."""
+    assert not _diags(kernels_conf, "KRN001")
+    off_tile = kernels_conf.replace(
+        "kv_block_len: 16", "kv_block_len: 12"
+    ).replace("embedding_dim: 256", "embedding_dim: 192")
+    assert not _diags(off_tile, "KRN001")
+    bad_bl = kernels_conf.replace("kv_block_len: 16", "kv_block_len: 0")
     assert len(_diags(bad_bl, "KRN001")) == 1
-    bad_hd = kernels_conf.replace("embedding_dim: 256",
-                                  "embedding_dim: 192")
-    assert len(_diags(bad_hd, "KRN001")) == 1
-    both = bad_bl.replace("embedding_dim: 256", "embedding_dim: 192")
-    assert len(_diags(both, "KRN001")) == 2
-    assert not _diags(
-        bad_bl.replace("interpret: false", "interpret: true"), "KRN001"
-    )
+    for pin in ("interpret: true", ""):
+        assert len(_diags(
+            bad_bl.replace("interpret: false", pin), "KRN001"
+        )) == 1
     assert not _diags(
         bad_bl.replace("paged_attention: fused",
                        "paged_attention: reference"),
@@ -532,7 +534,11 @@ def test_engine_config_from_conf_reads_kernels_block():
     from singa_tpu.config.schema import KernelsConfig, ServingConfig
 
     ec = EngineConfig.from_conf(None, None)
-    assert ec.attend_impl == "reference" and ec.interpret is True
+    # unset: the platform decides (ops/paged_attention._call)
+    assert ec.attend_impl == "reference" and ec.interpret is None
+    assert EngineConfig.from_conf(
+        None, KernelsConfig.from_fields({"paged_attention": ["fused"]})
+    ).interpret is None
     kern = KernelsConfig.from_fields(
         {"paged_attention": ["fused"], "interpret": [False]}
     )

@@ -205,12 +205,18 @@ def test_eos_mid_accepted_run_retires_at_the_right_token():
     discarded, never delivered (sequential decode would have stopped
     there)."""
     cfg = tiny_cfg()
-    params = tiny_params(cfg)
+    # seed 5: a free run whose first five tokens are all distinct (the
+    # seed-0 model's run is one constant token under this XLA, which
+    # would put the EOS at token 0 and void the scenario)
+    params = tiny_params(cfg, seed=5)
     prompt = np.asarray([1, 2, 3], np.int32)
     free_run = np.asarray(
         generate(params, jnp.asarray(prompt)[None], cfg, 12)
     )[0, 3:]
     eos = int(free_run[4])
+    # the scenario itself: the EOS first appears at index 4, INSIDE the
+    # first accepted run (4 scripted drafts + the bonus token)
+    assert list(free_run).index(eos) == 4, free_run
     want = list(free_run[:5])  # sequential stops at the EOS hit
     # script the TRUE continuation as the draft: the run containing the
     # EOS is accepted whole, the scheduler must still cut at EOS

@@ -149,13 +149,91 @@ def test_coordinator_address():
         coordinator_address([])
 
 
-def test_init_distributed_single_host_noop(tmp_path):
+def test_init_distributed_single_host_noop(tmp_path, monkeypatch):
+    import jax
+
+    def never(*a, **k):
+        raise AssertionError("one host must not go looking for a rendezvous")
+
+    monkeypatch.setattr(jax.distributed, "initialize", never)
+    for var in ("COORDINATOR_ADDRESS", "MEGASCALE_COORDINATOR_ADDRESS",
+                "TPU_WORKER_HOSTNAMES"):
+        monkeypatch.delenv(var, raising=False)
     # no hostfile, no pod env -> no-op
+    assert init_distributed(0, None) is False
+    # a single-host TPU VM names itself alone: still one process
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     assert init_distributed(0, None) is False
     # one-line hostfile -> still single process
     p = tmp_path / "hosts"
     p.write_text("localhost\n")
     assert init_distributed(0, str(p)) is False
+
+
+def test_init_distributed_failed_pod_rendezvous_raises(monkeypatch):
+    """A pod-shaped environment whose rendezvous fails must not carry
+    on as N independent same-seed trainers."""
+    import jax
+
+    def refuse(*a, **k):
+        raise RuntimeError("coordinator unreachable")
+
+    monkeypatch.setattr(jax.distributed, "initialize", refuse)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "host-a,host-b")
+    with pytest.raises(RuntimeError, match="coordinator unreachable"):
+        init_distributed(0, None)
+
+
+def test_local_gangs_are_a_cpu_rehearsal(monkeypatch):
+    """One process per chip: the launchers refuse several LOCAL ranks
+    unless the platform is pinned to the CPU."""
+    from singa_tpu.parallel.launch import refuse_local_ranks_on_a_chip
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    refuse_local_ranks_on_a_chip(4)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    refuse_local_ranks_on_a_chip(1)
+    with pytest.raises(SystemExit, match="one process per chip"):
+        refuse_local_ranks_on_a_chip(2)
+
+
+def test_device_peak_flops_knows_the_chip_or_raises():
+    """The CPU has no peak (no utilization is quoted against it); a
+    chip in the table answers; a chip that is not is an error, never a
+    default."""
+    from types import SimpleNamespace as Dev
+
+    from singa_tpu.utils.flops import device_peak_flops
+
+    assert device_peak_flops(Dev(platform="cpu", device_kind="cpu")) is None
+    assert device_peak_flops(
+        Dev(platform="tpu", device_kind="TPU v5 lite")
+    ) == 197e12
+    with pytest.raises(ValueError, match="no bf16 peak on record"):
+        device_peak_flops(Dev(platform="tpu", device_kind="TPU v9"))
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: no directory is set in code.
+    Unset: one fixed directory inside the checkout."""
+    import jax
+
+    from singa_tpu.utils import compile_cache
+
+    updates = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.setup_compile_cache(
+        log=lambda s: None
+    ) == "/somewhere/else"
+    assert "jax_compilation_cache_dir" not in updates
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.setup_compile_cache(log=lambda s: None)
+    assert updates["jax_compilation_cache_dir"] == path
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".compile_cache")
 
 
 def test_init_distributed_bad_rank(tmp_path):
