@@ -227,19 +227,26 @@ class Net:
                     inputs.append(val)
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
             out = None
-            if layer_hook is not None:
-                out = layer_hook(layer, resolved, inputs, lrng)
-            if out is None:
-                if layer.has_buffers:
-                    out, updates = layer.apply_stateful(
-                        resolved, buffers, inputs,
-                        training=training, rng=lrng,
-                    )
-                    new_buffers.update(updates)
-                else:
-                    out = layer.apply(
-                        resolved, inputs, training=training, rng=lrng
-                    )
+            # the ONE place a conf layer's operations get their name in
+            # a trace: ``kBatchNorm.s1b2_c_bn`` in every ``op_name``,
+            # the backward's inside ``transpose(jvp(...))``. No ``/``:
+            # it separates the scopes of a path.
+            with jax.named_scope(
+                f"{layer.TYPE}.{layer.name}".replace("/", "_")
+            ):
+                if layer_hook is not None:
+                    out = layer_hook(layer, resolved, inputs, lrng)
+                if out is None:
+                    if layer.has_buffers:
+                        out, updates = layer.apply_stateful(
+                            resolved, buffers, inputs,
+                            training=training, rng=lrng,
+                        )
+                        new_buffers.update(updates)
+                    else:
+                        out = layer.apply(
+                            resolved, inputs, training=training, rng=lrng
+                        )
             if layer.is_losslayer:
                 loss, m = out
                 total_loss = total_loss + loss

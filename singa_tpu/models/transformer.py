@@ -167,38 +167,58 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     prefill, and the KV-cache decode step all run this body, so the
     train->decode bit-exact parity cannot silently diverge.
     ``moe_capacity_factor`` overrides the MoE capacity (decode passes E
-    so routing is drop-free; None keeps the training default)."""
-    b, s, _ = x.shape
-    h = _layernorm(x, params[f"{p}/ln1/scale"], params[f"{p}/ln1/bias"])
-    qkv = h @ params[f"{p}/attn/qkv"]
-    qkv = qkv.reshape(b, s, 3, cfg.n_heads, cfg.head_dim)
-    q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
-    o, extra = attend(q, k, v)
-    o = jnp.moveaxis(o, 1, 2).reshape(b, s, cfg.d_model)
-    x = x + o @ params[f"{p}/attn/out"]
-    h = _layernorm(x, params[f"{p}/ln2/scale"], params[f"{p}/ln2/bias"])
-    aux = jnp.float32(0.0)
-    if cfg.moe_experts:
-        from ..parallel.moe import moe_ffn, moe_ffn_dense
+    so routing is drop-free; None keeps the training default).
 
-        moe_params = {
-            k2: params[f"{p}/moe/{k2}"] for k2 in ("gate", "up", "down")
-        }
-        if mesh is not None and "expert" in getattr(mesh, "shape", {}):
-            y, aux = moe_ffn(h, moe_params, mesh)
-        elif moe_capacity_factor is not None:
-            y, aux = moe_ffn_dense(
-                h, moe_params, capacity_factor=moe_capacity_factor
+    Every operation is named: the block's ``p`` (``blk3``) and inside it
+    ``ln1``, ``qkv``, ``attend`` (whatever implements it), ``attn_out``,
+    ``ln2``, ``mlp`` or ``moe``. A trace is read by these names."""
+    b, s, _ = x.shape
+    scope = jax.named_scope
+    with scope(p):
+        with scope("ln1"):
+            h = _layernorm(
+                x, params[f"{p}/ln1/scale"], params[f"{p}/ln1/bias"]
             )
+        with scope("qkv"):
+            qkv = h @ params[f"{p}/attn/qkv"]
+            qkv = qkv.reshape(b, s, 3, cfg.n_heads, cfg.head_dim)
+            q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
+        with scope("attend"):
+            o, extra = attend(q, k, v)
+        with scope("attn_out"):
+            o = jnp.moveaxis(o, 1, 2).reshape(b, s, cfg.d_model)
+            x = x + o @ params[f"{p}/attn/out"]
+        with scope("ln2"):
+            h = _layernorm(
+                x, params[f"{p}/ln2/scale"], params[f"{p}/ln2/bias"]
+            )
+        aux = jnp.float32(0.0)
+        if cfg.moe_experts:
+            from ..parallel.moe import moe_ffn, moe_ffn_dense
+
+            moe_params = {
+                k2: params[f"{p}/moe/{k2}"] for k2 in ("gate", "up", "down")
+            }
+            with scope("moe"):
+                if mesh is not None and "expert" in getattr(
+                    mesh, "shape", {}
+                ):
+                    y, aux = moe_ffn(h, moe_params, mesh)
+                elif moe_capacity_factor is not None:
+                    y, aux = moe_ffn_dense(
+                        h, moe_params, capacity_factor=moe_capacity_factor
+                    )
+                else:
+                    y, aux = moe_ffn_dense(h, moe_params)
+                x = x + y
         else:
-            y, aux = moe_ffn_dense(h, moe_params)
-        x = x + y
-    else:
-        h = jax.nn.gelu(h @ params[f"{p}/mlp/up"])
-        x = x + h @ params[f"{p}/mlp/down"]
+            with scope("mlp"):
+                h = jax.nn.gelu(h @ params[f"{p}/mlp/up"])
+                x = x + h @ params[f"{p}/mlp/down"]
     return x, aux, extra
 
 
+@jax.named_scope("lm_head")
 def lm_head(params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Final layernorm + tied-embedding projection — the ONE LM head
     every forward shares (lm_apply, generate()'s prefill and decode
@@ -224,7 +244,8 @@ def lm_apply(
     With ``return_aux`` also returns the summed MoE load-balancing loss
     (0.0 for dense-FFN configs)."""
     b, s = tokens.shape
-    x = params["embed/tok"][tokens] + params["embed/pos"][:s]
+    with jax.named_scope("embed"):
+        x = params["embed/tok"][tokens] + params["embed/pos"][:s]
     aux_total = jnp.float32(0.0)
     attend = lambda q, k, v: (_attend(q, k, v, cfg, mesh), None)  # noqa: E731
     for i in range(cfg.n_layers):
@@ -236,6 +257,7 @@ def lm_apply(
     return logits
 
 
+@jax.named_scope("cache_attend")
 def cache_attend(q, k_cache, v_cache, positions):
     """Masked attention of Q queries against a FULL cache — the single
     attention body every serving path shares (generate()'s prefill and

@@ -36,6 +36,8 @@ import os
 import threading
 import time
 
+from .span import span
+
 
 def config_hash(model_cfg) -> str:
     """Deterministic 12-hex digest of a ModelConfig — the run identity
@@ -133,19 +135,18 @@ class FlightRecorder:
     def span(self, name: str, *, track: str = "phases",
              steps: int | None = None):
         """Context-manager form of ``record_span`` (feeder/stager/writer
-        threads wrap their unit of work in one)."""
+        threads wrap their unit of work in one), through ``obs.span``:
+        in a profiler trace the work shows as ``singa/<track>.<name>``
+        on its thread's line."""
         if not self.trace_spans:
             yield
             return
-        t0w = time.time()
-        t0 = time.perf_counter()
+        sp = span(f"{track}.{name}")
         try:
-            yield
+            with sp:
+                yield
         finally:
-            self.record_span(
-                name, t0w, time.perf_counter() - t0,
-                track=track, steps=steps,
-            )
+            sp.record(self, name, track=track, steps=steps)
 
     def phase_span(
         self, name: str, t0_wall: float, dur: float, steps: int | None = None

@@ -580,6 +580,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
             out_specs=[qblk, lse_blk],
             out_shape=out_shape,
             interpret=bool(interpret),
+            name="flash_fwd",
         )(qf, kf, vf)
         return out.reshape(b, h, s, d), lse
     # streamed: K/V stay in HBM in transposed (BH, D, S) layout (see
@@ -602,6 +603,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=bool(interpret),
+        name="flash_fwd",
     )(qf, jnp.swapaxes(kf, 1, 2), jnp.swapaxes(vf, 1, 2))
     return out.reshape(b, h, s, d), lse
 
@@ -650,6 +652,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             interpret=bool(interpret),
+            name="flash_bwd_dq",
         )(*args)
         dk, dv = pl.pallas_call(
             functools.partial(
@@ -664,6 +667,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
                 jax.ShapeDtypeStruct((bh, s, d), v.dtype),
             ],
             interpret=bool(interpret),
+            name="flash_bwd_dkv",
         )(*args)
         unflat = lambda x: x.reshape(b, h, s, d)  # noqa: E731
         return unflat(dq), unflat(dk), unflat(dv)
@@ -685,6 +689,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=bool(interpret),
+        name="flash_bwd_dq",
     )(flat(q), flat(g), lse, delta, kt, vt)
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -709,6 +714,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=bool(interpret),
+        name="flash_bwd_dkv",
     )(
         flat(k), flat(v),
         jnp.swapaxes(flat(q), 1, 2), jnp.swapaxes(flat(g), 1, 2),

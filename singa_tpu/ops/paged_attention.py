@@ -240,6 +240,7 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=bool(interpret),
+        name="paged_attention",
     )(tflat, nlive, *args)
 
 

@@ -320,12 +320,23 @@ class Engine:
         s, h = g.shape[0], g.shape[1]
         return g.reshape(s, h, self.pool.cache_len, g.shape[-1])
 
+    @staticmethod
+    @jax.named_scope("kv_write")
+    def _kv_write(pool_arr, bid, off, fresh):
+        """One pool with ``fresh`` scattered to offsets ``off`` of
+        blocks ``bid`` — the ONE write every program shares; in a
+        trace its operations are ``kv_write``."""
+        return pool_arr.at[bid, :, off].set(fresh)
+
+    @jax.named_scope("gather_kv")
     def _gather_kv(self, kp, vp, tables):
         """Both dense views of one layer's K and V pools — the ONE
         helper the reference attends share (decode/prefill/verify each
-        used to spell the pair out)."""
+        used to spell the pair out). In a trace its operations are
+        ``gather_kv``: what the fused kernel exists to delete."""
         return self._gather(kp, tables), self._gather(vp, tables)
 
+    @jax.named_scope("paged_attention")
     def _paged_attend(self, q, kp, vp, tables, positions):
         """The fused path's write-then-read attend (decode + prefill):
         the fresh K/V were already scattered into ``kp``/``vp``, the
@@ -363,10 +374,11 @@ class Engine:
         cfg = self.pool
         tokens, pos, live = state["tokens"], state["pos"], state["live"]
         mcfg = self.cfg
-        x = (
-            params["embed/tok"][tokens][:, None, :]
-            + params["embed/pos"][pos][:, None, :]
-        )
+        with jax.named_scope("embed"):
+            x = (
+                params["embed/tok"][tokens][:, None, :]
+                + params["embed/pos"][pos][:, None, :]
+            )
         # each slot's write target: its current block, current offset.
         # Dead lanes route to the trash block explicitly — a slot that
         # is admitted-but-still-prefilling has a REAL table whose first
@@ -380,8 +392,8 @@ class Engine:
 
         def mk_attend(i):
             def attend(q, k, v):
-                kp = state["k"][i].at[bid, :, off].set(k[:, :, 0, :])
-                vp = state["v"][i].at[bid, :, off].set(v[:, :, 0, :])
+                kp = self._kv_write(state["k"][i], bid, off, k[:, :, 0, :])
+                vp = self._kv_write(state["v"][i], bid, off, v[:, :, 0, :])
                 if self._fused:
                     o = self._paged_attend(
                         q, kp, vp, state["tables"], pos[:, None]
@@ -403,8 +415,9 @@ class Engine:
             new_k.append(kp)
             new_v.append(vp)
         logits = lm_head(params, x)[:, 0]
-        new_rng, keys = self._split_keys(state)
-        nxt = self._sample(logits, keys, state["temp"], live, tokens)
+        with jax.named_scope("sample"):
+            new_rng, keys = self._split_keys(state)
+            nxt = self._sample(logits, keys, state["temp"], live, tokens)
         new_state = {
             **state,
             "tokens": nxt,
@@ -428,10 +441,11 @@ class Engine:
         # clip the embedding/table lookups for padding positions; their
         # values are masked, only their indices must stay in range
         p_safe = jnp.minimum(p, mcfg.max_len - 1)
-        x = (
-            params["embed/tok"][chunk]
-            + params["embed/pos"][p_safe]
-        )[None]
+        with jax.named_scope("embed"):
+            x = (
+                params["embed/tok"][chunk]
+                + params["embed/pos"][p_safe]
+            )[None]
         row = state["tables"][slot]
         bid = jnp.where(
             valid,
@@ -443,11 +457,11 @@ class Engine:
 
         def mk_attend(i):
             def attend(q, k, v):
-                kp = state["k"][i].at[bid, :, off].set(
-                    jnp.moveaxis(k[0], 1, 0)
+                kp = self._kv_write(
+                    state["k"][i], bid, off, jnp.moveaxis(k[0], 1, 0)
                 )
-                vp = state["v"][i].at[bid, :, off].set(
-                    jnp.moveaxis(v[0], 1, 0)
+                vp = self._kv_write(
+                    state["v"][i], bid, off, jnp.moveaxis(v[0], 1, 0)
                 )
                 if self._fused:
                     o = self._paged_attend(q, kp, vp, row[None], p[None])
@@ -513,7 +527,8 @@ class Engine:
         p = pos[:, None] + j                                     # (S, Q)
         valid = live[:, None] & (j <= n_draft[:, None])
         p_safe = jnp.minimum(p, mcfg.max_len - 1)
-        x = params["embed/tok"][seq] + params["embed/pos"][p_safe]
+        with jax.named_scope("embed"):
+            x = params["embed/tok"][seq] + params["embed/pos"][p_safe]
         row_idx = jnp.minimum(
             p_safe // cfg.block_len, state["tables"].shape[1] - 1
         )
@@ -530,10 +545,11 @@ class Engine:
             stay untouched); entries beyond a slot's n_draft are
             garbage no valid query's causal mask can reach (query j
             attends positions <= pos + j only)."""
-            dense = self._gather(pool_arr, state["tables"])
-            return dense.at[s_idx, :, p_safe].set(
-                jnp.moveaxis(new_shqd, 1, 2)
-            )
+            with jax.named_scope("gather_kv"):
+                dense = self._gather(pool_arr, state["tables"])
+                return dense.at[s_idx, :, p_safe].set(
+                    jnp.moveaxis(new_shqd, 1, 2)
+                )
 
         def mk_attend(i):
             def attend(qh, kh, vh):
@@ -546,11 +562,12 @@ class Engine:
                         paged_attention_overlay,
                     )
 
-                    o = paged_attention_overlay(
-                        qh, state["k"][i], state["v"][i],
-                        state["tables"], p, kh, vh, valid,
-                        interpret=self.serving.interpret,
-                    )
+                    with jax.named_scope("paged_attention"):
+                        o = paged_attention_overlay(
+                            qh, state["k"][i], state["v"][i],
+                            state["tables"], p, kh, vh, valid,
+                            interpret=self.serving.interpret,
+                        )
                     return o, (kh, vh)
                 if kd == 0:
                     # zero draft width: rewind is definitionally inert
@@ -560,11 +577,11 @@ class Engine:
                     # IS serve_bench's isolated-machinery probe, and
                     # the write targets (bid routes dead lanes to
                     # trash) equal the post-acceptance routing below
-                    kp = state["k"][i].at[bid, :, off].set(
-                        jnp.moveaxis(kh, 1, 2)
+                    kp = self._kv_write(
+                        state["k"][i], bid, off, jnp.moveaxis(kh, 1, 2)
                     )
-                    vp = state["v"][i].at[bid, :, off].set(
-                        jnp.moveaxis(vh, 1, 2)
+                    vp = self._kv_write(
+                        state["v"][i], bid, off, jnp.moveaxis(vh, 1, 2)
                     )
                     o = cache_attend(
                         qh,
@@ -588,13 +605,16 @@ class Engine:
             )
             fresh.append(extras)
         logits = lm_head(params, x)                              # (S, Q, V)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        new_rng, keys = self._split_keys(state)
         # position 0 samples through the temperature lane (temperature
         # slots ride the verify tick with n_draft == 0: their one
         # emitted token per tick is this sample); positions >= 1 are
         # greedy-only — temperature slots never accept drafts
-        first = self._sample(logits[:, 0], keys, state["temp"], live, tokens)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            new_rng, keys = self._split_keys(state)
+            first = self._sample(
+                logits[:, 0], keys, state["temp"], live, tokens
+            )
         g = jnp.concatenate([first[:, None], greedy[:, 1:]], axis=1)
         match = (draft == g[:, :kd]) & (
             jnp.arange(kd)[None, :] < n_draft[:, None]
@@ -616,17 +636,14 @@ class Engine:
         else:
             bid_keep = jnp.where(emit_mask, bid, 0)
             new_k, new_v = [], []
-            for (kh, vh) in fresh:
-                new_k.append(
-                    state["k"][len(new_k)].at[bid_keep, :, off].set(
-                        jnp.moveaxis(kh, 1, 2)
-                    )
-                )
-                new_v.append(
-                    state["v"][len(new_v)].at[bid_keep, :, off].set(
-                        jnp.moveaxis(vh, 1, 2)
-                    )
-                )
+            for i, (kh, vh) in enumerate(fresh):
+                with jax.named_scope(f"blk{i}"):
+                    new_k.append(self._kv_write(
+                        state["k"][i], bid_keep, off, jnp.moveaxis(kh, 1, 2)
+                    ))
+                    new_v.append(self._kv_write(
+                        state["v"][i], bid_keep, off, jnp.moveaxis(vh, 1, 2)
+                    ))
         new_state = {
             **state,
             "tokens": jnp.where(live, last_tok, tokens),

@@ -46,6 +46,7 @@ import time
 import numpy as np
 
 from ...comm.wire import WireError
+from ...obs import span
 from ...resilience.faults import InjectedCrash
 from ...resilience.preemption import EXIT_RESUMABLE
 from ..engine import Engine, EngineConfig
@@ -516,7 +517,7 @@ class FleetHost:
             # clock domain; a cross-host import re-stamps at arrival
             req.enqueue_mono = mseq.enqueue_mono or now
             req.admit_mono = req.enqueue_mono
-            req.admit_wall = time.time()
+            req._life = span("sched.request", nested=False).start()
             req.first_token_mono = now
             self.sched._slot_req[slot] = req
             self.migrate_in += 1

@@ -44,6 +44,13 @@ Lifecycle events (``request_admit`` / ``prefill`` / ``decode_tick`` /
 spans flow into the PR 6 flight recorder, so
 ``tools/trace.py --summarize`` reports serving p50/p99 and tokens/sec
 with no serving-specific plumbing.
+
+Every phase of a tick is an ``obs.span`` (``singa/sched.tick`` and,
+inside it, ``admit`` / ``prefill`` / ``decode`` holding ``draft`` /
+``dispatch`` / ``pull`` / ``emit``), so a profiler trace lays the
+scheduler's work beside the device's operations. Each carries
+``tick=``; the spans of one request carry its ``rid=``. ``sched.pull``
+is the one place a tick waits for the device.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ import time
 
 import numpy as np
 
+from ..obs import span
 from .engine import Engine
 from .kv_pool import PoolExhausted
 from .speculate import make_drafter
@@ -75,11 +83,12 @@ class Request:
     slot: int | None = None
     tokens: list = dataclasses.field(default_factory=list)
     enqueue_mono: float = 0.0
-    admit_wall: float = 0.0
     admit_mono: float = 0.0
     first_token_mono: float = 0.0
     finish_mono: float = 0.0
     _prefilled: int = 0
+    #: admission to retirement, for the recorder's ``requests`` track
+    _life: object = None
 
     @property
     def latency_s(self) -> float:
@@ -221,67 +230,14 @@ class Scheduler:
         stalled = False
         while self._queue and free:
             req = self._queue[0]
-            try:
-                adm = self.engine.admit(
-                    free[0], len(req.prompt) + req.max_new_tokens,
-                    prompt=req.prompt,
-                )
-            except PoolExhausted:
-                stalled = True
-                break
-            self._queue.popleft()
-            slot = free.pop(0)
-            self._slot_req[slot] = req
-            req.slot = slot
-            req.status = "prefill"
-            # prefill starts at the first token the prefix cache did
-            # not cover (lane positions are seeded by pos0 each chunk,
-            # so a hit just skips the covered chunks)
-            req._prefilled = adm.prefill_from
-            # a handed-back (drained) request restarts from scratch on
-            # re-admission: its partial output was delivered at evict
-            # time, regeneration must not append to it
-            req.tokens = []
-            req.admit_wall = time.time()
-            req.admit_mono = time.perf_counter()
-            self._event(
-                "request_admit", rid=req.rid, slot=slot,
-                prompt_len=int(len(req.prompt)), blocks=len(adm.blocks),
-                queued_s=round(req.admit_mono - req.enqueue_mono, 6),
-            )
-            if self.engine.allocator.cache is not None:
-                self.prefix_lookups += 1
-            if adm.cached_tokens:
-                c = self.engine.serving.max_prefill_chunk
-                saved = (
-                    -(-len(req.prompt) // c)
-                    - -(-(len(req.prompt) - adm.prefill_from) // c)
-                )
-                self.prefix_hits += 1
-                # blocks this sequence reads through another owner's
-                # bytes (a COW'd tail block became private)
-                shared = (
-                    adm.cached_tokens // self.engine.pool.block_len
-                    - (1 if adm.cow_copied else 0)
-                )
-                self.blocks_shared += shared
-                self.prefill_chunks_saved += saved
-                self._event(
-                    "prefix_hit", rid=req.rid, slot=slot,
-                    cached_tokens=int(adm.cached_tokens),
-                    blocks_shared=int(shared), chunks_saved=int(saved),
-                )
-            if adm.tail_tokens:
-                self.partial_hits += 1
-                self.tail_tokens_shared += adm.tail_tokens
-                self._event(
-                    "partial_hit", rid=req.rid, slot=slot,
-                    cached_tokens=int(adm.cached_tokens),
-                    tail_tokens=int(adm.tail_tokens),
-                )
-            if adm.cow_copied:
-                self.cow_copies += 1
-                self._event("cow_copy", rid=req.rid, slot=slot)
+            with span(
+                "sched.admit", tick=self.ticks, rid=req.rid, slot=free[0]
+            ) as admitting:
+                stalled = not self._admit(req, free[0])
+                if stalled:
+                    admitting.note(stalled=1)
+                    break
+            free.pop(0)
         if stalled:
             self.backpressure_ticks += 1
             self._event(
@@ -289,6 +245,71 @@ class Scheduler:
                 queued=len(self._queue),
                 free_blocks=self.engine.allocator.free_blocks,
             )
+
+    def _admit(self, req: Request, slot: int) -> bool:
+        """The queue's head into ``slot``: blocks from the allocator,
+        the slot's table on the device. -> False where the pool cannot
+        cover the request now (it stays queued)."""
+        try:
+            adm = self.engine.admit(
+                slot, len(req.prompt) + req.max_new_tokens,
+                prompt=req.prompt,
+            )
+        except PoolExhausted:
+            return False
+        self._queue.popleft()
+        self._slot_req[slot] = req
+        req.slot = slot
+        req.status = "prefill"
+        # prefill starts at the first token the prefix cache did
+        # not cover (lane positions are seeded by pos0 each chunk,
+        # so a hit just skips the covered chunks)
+        req._prefilled = adm.prefill_from
+        # a handed-back (drained) request restarts from scratch on
+        # re-admission: its partial output was delivered at evict
+        # time, regeneration must not append to it
+        req.tokens = []
+        req._life = span("sched.request", nested=False).start()
+        req.admit_mono = req._life.t0
+        self._event(
+            "request_admit", rid=req.rid, slot=slot,
+            prompt_len=int(len(req.prompt)), blocks=len(adm.blocks),
+            queued_s=round(req.admit_mono - req.enqueue_mono, 6),
+        )
+        if self.engine.allocator.cache is not None:
+            self.prefix_lookups += 1
+        if adm.cached_tokens:
+            c = self.engine.serving.max_prefill_chunk
+            saved = (
+                -(-len(req.prompt) // c)
+                - -(-(len(req.prompt) - adm.prefill_from) // c)
+            )
+            self.prefix_hits += 1
+            # blocks this sequence reads through another owner's
+            # bytes (a COW'd tail block became private)
+            shared = (
+                adm.cached_tokens // self.engine.pool.block_len
+                - (1 if adm.cow_copied else 0)
+            )
+            self.blocks_shared += shared
+            self.prefill_chunks_saved += saved
+            self._event(
+                "prefix_hit", rid=req.rid, slot=slot,
+                cached_tokens=int(adm.cached_tokens),
+                blocks_shared=int(shared), chunks_saved=int(saved),
+            )
+        if adm.tail_tokens:
+            self.partial_hits += 1
+            self.tail_tokens_shared += adm.tail_tokens
+            self._event(
+                "partial_hit", rid=req.rid, slot=slot,
+                cached_tokens=int(adm.cached_tokens),
+                tail_tokens=int(adm.tail_tokens),
+            )
+        if adm.cow_copied:
+            self.cow_copies += 1
+            self._event("cow_copy", rid=req.rid, slot=slot)
+        return True
 
     def _prefill_some(self) -> None:
         # one chunk per prefilling request per tick: decode never waits
@@ -301,28 +322,37 @@ class Scheduler:
                 self.engine.serving.max_prefill_chunk,
                 len(req.prompt) - req._prefilled,
             )
-            last = self.engine.prefill_chunk(
-                slot, req.prompt[req._prefilled:req._prefilled + n],
-                req._prefilled,
+            with span(
+                "sched.prefill", tick=self.ticks, rid=req.rid, slot=slot,
+                tokens=int(n),
+            ):
+                self._prefill(slot, req, n)
+
+    def _prefill(self, slot: int, req: Request, n: int) -> None:
+        """One chunk of ``n`` prompt tokens; after the last, the
+        request's first token (``engine.activate`` pulls it)."""
+        last = self.engine.prefill_chunk(
+            slot, req.prompt[req._prefilled:req._prefilled + n],
+            req._prefilled,
+        )
+        req._prefilled += n
+        self.prefill_chunks += 1
+        self._event(
+            "prefill", rid=req.rid, slot=slot, tokens=int(n),
+            done=int(req._prefilled), of=int(len(req.prompt)),
+        )
+        if req._prefilled >= len(req.prompt):
+            # every prompt position is now prefill-written: index
+            # the fully-covered blocks for future prefix hits
+            self.engine.register_prefix(slot, req.prompt)
+            first = self.engine.activate(
+                slot, last, len(req.prompt), req.seed,
+                temperature=req.temperature,
             )
-            req._prefilled += n
-            self.prefill_chunks += 1
-            self._event(
-                "prefill", rid=req.rid, slot=slot, tokens=int(n),
-                done=int(req._prefilled), of=int(len(req.prompt)),
-            )
-            if req._prefilled >= len(req.prompt):
-                # every prompt position is now prefill-written: index
-                # the fully-covered blocks for future prefix hits
-                self.engine.register_prefix(slot, req.prompt)
-                first = self.engine.activate(
-                    slot, last, len(req.prompt), req.seed,
-                    temperature=req.temperature,
-                )
-                req.tokens.append(first)
-                req.status = "decoding"
-                req.first_token_mono = time.perf_counter()
-                self._check_done(slot, req, first)
+            req.tokens.append(first)
+            req.status = "decoding"
+            req.first_token_mono = time.perf_counter()
+            self._check_done(slot, req, first)
 
     def _check_done(self, slot: int, req: Request, tok: int) -> bool:
         if (req.eos is not None and tok == req.eos) or (
@@ -366,10 +396,11 @@ class Scheduler:
             tokens=int(len(req.tokens)),
             latency_s=round(req.latency_s, 6),
         )
-        if self.recorder is not None:
-            self.recorder.record_span(
-                "request", req.admit_wall, req.latency_s,
-                track="requests", steps=len(req.tokens),
+        if req._life is not None:
+            req._life.stop()
+            req._life.record(
+                self.recorder, "request", track="requests",
+                steps=len(req.tokens),
             )
 
     def _draft_for(self, req: Request) -> list[int]:
@@ -395,9 +426,10 @@ class Scheduler:
         or up to spec_k + 1 through the verify program when speculation
         is on (skipped entirely on a prefill-role fleet host,
         ``decode_enabled`` False). -> tokens emitted."""
-        self._admit_some()
-        self._prefill_some()
-        emitted_n = self._decode_some() if self.decode_enabled else 0
+        with span("sched.tick", tick=self.ticks):
+            self._admit_some()
+            self._prefill_some()
+            emitted_n = self._decode_some() if self.decode_enabled else 0
         self.ticks += 1
         return emitted_n
 
@@ -410,30 +442,39 @@ class Scheduler:
         decoding = {
             s: r for s, r in self._slot_req.items() if r.status == "decoding"
         }
-        emitted_n = 0
-        if decoding:
-            accepted_n = 0
-            t0w, t0 = time.time(), time.perf_counter()
+        if not decoding:
+            return 0
+        tick, accepted_n, emitted_n = self.ticks, 0, 0
+        # draft, dispatch and pull: the recorder's ``decode_tick`` and
+        # ``full_tick_s`` (the fan-out below is not in them)
+        with span("sched.decode", tick=tick) as whole:
             if self.spec_k > 0:
                 slots = self.engine.serving.slots
                 drafts = np.zeros((slots, self.spec_k), np.int32)
                 nd = np.zeros((slots,), np.int32)
-                for slot, req in decoding.items():
-                    d = self._draft_for(req)
-                    drafts[slot, :len(d)] = d
-                    nd[slot] = len(d)
-                drafted_n = int(nd.sum())
+                with span("sched.draft", tick=tick) as drafting:
+                    for slot, req in decoding.items():
+                        d = self._draft_for(req)
+                        drafts[slot, :len(d)] = d
+                        nd[slot] = len(d)
+                    drafted_n = int(nd.sum())
+                    drafting.note(drafted=drafted_n)
                 self.spec_drafted += drafted_n
                 self._event(
                     "spec_draft", drafted=drafted_n, live=len(decoding),
                 )
-                emitted_dev, accepted_dev = self.engine.verify(drafts, nd)
-                emitted = np.asarray(emitted_dev)
-                accepted_n = int(np.asarray(accepted_dev).sum())
+                with span("sched.dispatch", tick=tick, live=len(decoding)):
+                    emitted_dev, accepted_dev = self.engine.verify(drafts, nd)
+                with span("sched.pull", tick=tick):
+                    emitted = np.asarray(emitted_dev)
+                    accepted_n = int(np.asarray(accepted_dev).sum())
                 self.spec_accepted += accepted_n
             else:
-                emitted = np.asarray(self.engine.decode())[:, None]
-            dur = time.perf_counter() - t0
+                with span("sched.dispatch", tick=tick, live=len(decoding)):
+                    emitted_dev = self.engine.decode()
+                with span("sched.pull", tick=tick):
+                    emitted = np.asarray(emitted_dev)[:, None]
+        with span("sched.emit", tick=tick) as fan_out:
             for slot, req in sorted(decoding.items()):
                 # fan the slot's accepted run out token by token: EOS
                 # or budget INSIDE the run stops exactly where
@@ -445,26 +486,25 @@ class Scheduler:
                     emitted_n += 1
                     if self._check_done(slot, req, int(tok)):
                         break
-            self._live_ticks += len(decoding)
-            self.decode_ticks += 1
-            self.tokens_emitted += emitted_n
-            if len(decoding) == self.engine.serving.slots:
-                self.full_tick_s += dur
-                self.full_tick_tokens += emitted_n
-            if self.recorder is not None:
-                self.recorder.record_span(
-                    "decode_tick", t0w, dur,
-                    track="serving", steps=emitted_n,
-                )
-            if self.spec_k > 0:
-                self._event(
-                    "spec_accept", accepted=accepted_n, emitted=emitted_n,
-                    drafted=drafted_n,
-                )
+            fan_out.note(emitted=emitted_n)
+        self._live_ticks += len(decoding)
+        self.decode_ticks += 1
+        self.tokens_emitted += emitted_n
+        if len(decoding) == self.engine.serving.slots:
+            self.full_tick_s += whole.dur
+            self.full_tick_tokens += emitted_n
+        whole.record(
+            self.recorder, "decode_tick", track="serving", steps=emitted_n
+        )
+        if self.spec_k > 0:
             self._event(
-                "decode_tick", live=len(decoding), emitted=emitted_n,
-                blocks_used=self.engine.allocator.used_blocks,
+                "spec_accept", accepted=accepted_n, emitted=emitted_n,
+                drafted=drafted_n,
             )
+        self._event(
+            "decode_tick", live=len(decoding), emitted=emitted_n,
+            blocks_used=self.engine.allocator.used_blocks,
+        )
         return emitted_n
 
     # -- loops ----------------------------------------------------------

@@ -1374,8 +1374,10 @@ class Trainer:
         except Exception as e:  # pragma: no cover - defensive
             self.log(f"TELEMETRY: comm probe failed: {e}")
 
+    @jax.named_scope("update")
     def _apply_update(self, step, params: dict, grads: dict, state: dict):
-        """Updater.apply under the configured ``update_mode``.
+        """Updater.apply under the configured ``update_mode``; in a
+        trace its operations carry the scope ``update``.
 
         ``replicated``: every rank runs the full elementwise update.
         ``zero``: params are viewed through the update layout (a slice

@@ -26,6 +26,16 @@ _CHECKOUT = os.path.dirname(
 )
 DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".compile_cache")
 
+#: hashed into every key (jax's own hook for it). jax leaves a program's
+#: names (``jax.named_scope`` paths, source lines) out of the key, so an
+#: executable cached by code that named its operations otherwise, or not
+#: at all, would be served with ITS names — and a trace is read by them
+#: (benchmark/program_trace.py). The names themselves stay out of the
+#: key: programs that differ only in them (one helper jitted at two
+#: call sites) keep sharing an entry, as jax intends. Count this up in
+#: a change that renames a scope and leaves the operations as they are.
+NAMES = "singa-names-1"
+
 
 def setup_compile_cache(log=print) -> str:
     """Turn the persistent cache on for this process and return its
@@ -38,6 +48,9 @@ def setup_compile_cache(log=print) -> str:
     if not path:
         path = DEFAULT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
+    from jax._src import cache_key
+
+    cache_key.custom_hook = lambda: NAMES
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     log(f"persistent compile cache: {path}")

@@ -16,7 +16,8 @@ counters are the always-on cheap layer, like the reference's.
 from __future__ import annotations
 
 import contextlib
-import time
+
+from ..obs.span import span
 
 
 class Timers:
@@ -39,19 +40,19 @@ class Timers:
         """Time one occurrence of ``name``. ``steps`` is how many train
         steps the occurrence covers (chunked dispatch windows pass the
         window length) — feeds the per-STEP means and the span export;
-        accumulators are otherwise unchanged."""
-        sink = self.span_sink
-        t0w = time.time() if sink is not None else 0.0
-        t0 = time.perf_counter()
+        accumulators are otherwise unchanged. Each occurrence is an
+        ``obs.span``: ``singa/trainer.<name>`` in a profiler trace."""
+        sp = span("trainer." + name, steps=steps)
         try:
-            yield
+            with sp:
+                yield
         finally:
-            dt = time.perf_counter() - t0
+            dt = sp.dur
             self._acc[name] = self._acc.get(name, 0.0) + dt
             self._n[name] = self._n.get(name, 0) + 1
             self._steps[name] = self._steps.get(name, 0) + max(1, steps)
-            if sink is not None:
-                sink(name, t0w, dt, steps)
+            if self.span_sink is not None:
+                self.span_sink(name, sp.t0_wall, dt, steps)
 
     def total(self, name: str) -> float:
         return self._acc.get(name, 0.0)

@@ -1,0 +1,439 @@
+"""The program's own names: ``obs.span`` host spans on the profiler's
+clock, ``jax.named_scope`` paths on device operations, and the names of
+the compiled programs. Counts and structure only — a CPU run gives no
+time worth asserting.
+
+- a tiny ``Scheduler`` ticked under ``jax.profiler.trace`` and read back
+  with ``ProfileData``: every span of the tick is there with its
+  attributes, nested in its tick, and carries the ``rid`` it served;
+- ``obs.span`` leaves the flight recorder alone unless one is attached,
+  and feeds it the records the scheduler used to hand-roll;
+- a tiny conf net (conv + BatchNorm + loss) and the tiny engine, lowered
+  and compiled: the operations carry their layer's scope, forward and
+  backward;
+- the programs' names, which key the ``XLA Modules`` line of a trace.
+"""
+
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from singa_tpu import obs
+from singa_tpu.config import parse_model_config
+from singa_tpu.data.loader import synthetic_arrays, write_records
+from singa_tpu.graph.builder import build_net
+from singa_tpu.models.transformer import TransformerConfig, init_lm
+from singa_tpu.params import init_params
+from singa_tpu.serve import Engine, EngineConfig, Request, Scheduler
+from singa_tpu.utils import Timers
+
+SCHED_SPANS = {
+    "sched.tick": {"tick"},
+    "sched.admit": {"tick", "rid", "slot"},
+    "sched.prefill": {"tick", "rid", "slot", "tokens"},
+    "sched.decode": {"tick"},
+    "sched.draft": {"tick", "drafted"},
+    "sched.dispatch": {"tick", "live"},
+    "sched.pull": {"tick"},
+    "sched.emit": {"tick", "emitted"},
+}
+
+
+class Recorder:
+    """The recorder's two entry points, kept as lists."""
+
+    def __init__(self):
+        self.events, self.spans = [], []
+
+    def event(self, kind, **payload):
+        self.events.append((kind, payload))
+
+    def record_span(self, name, t0_wall, dur, *, track="phases", steps=None):
+        self.spans.append((name, track, steps))
+
+
+def tiny_engine(spec_k=0):
+    cfg = TransformerConfig(
+        vocab=32, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_len=32
+    )
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    return Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, max_prefill_chunk=4, spec_k=spec_k,
+    ))
+
+
+def serve_some(sched, n=3):
+    rs = np.random.RandomState(0)
+    for rid in range(n):
+        sched.submit(Request(
+            rid=rid, prompt=rs.randint(0, 32, size=(6 + rid,)),
+            max_new_tokens=4,
+        ))
+    sched.serve()
+
+
+def read_spans(trace_dir):
+    """-> [(name, start, end, attrs)] of the ``singa/`` events."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("singa/"):
+                    attrs = {
+                        k: v for k, v in dict(e.stats).items()
+                        if not k.startswith("_")
+                    }
+                    out.append((
+                        e.name[len("singa/"):], e.start_ns,
+                        e.start_ns + e.duration_ns, attrs,
+                    ))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Three requests through a speculating scheduler (so that
+    ``sched.draft`` runs too) under the profiler, and the trainer's
+    phases beside them."""
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    rec = Recorder()
+    sched = Scheduler(tiny_engine(spec_k=2), recorder=rec)
+    timers = Timers()
+    with jax.profiler.trace(trace_dir):
+        serve_some(sched)
+        with timers.phase("train", steps=8):
+            pass
+    return read_spans(trace_dir), rec, sched
+
+
+@pytest.mark.parametrize("name", sorted(SCHED_SPANS))
+def test_scheduler_span_is_there_with_its_attributes(traced, name):
+    spans, _, sched = traced
+    mine = [s for s in spans if s[0] == name]
+    assert mine, name
+    for _, _, _, attrs in mine:
+        assert set(attrs) >= SCHED_SPANS[name], (name, attrs)
+        assert 0 <= attrs["tick"] < sched.ticks
+    if name == "sched.tick":
+        assert [s[3]["tick"] for s in mine] == list(range(sched.ticks))
+
+
+def test_spans_nest_in_their_tick(traced):
+    spans, _, _ = traced
+    ticks = {s[3]["tick"]: s for s in spans if s[0] == "sched.tick"}
+    for name, start, end, attrs in spans:
+        if name.startswith("sched.") and name != "sched.tick":
+            _, t0, t1, _ = ticks[attrs["tick"]]
+            assert t0 <= start and end <= t1, (name, attrs)
+    # draft, dispatch and pull lie in the tick's ``sched.decode``
+    decodes = [s for s in spans if s[0] == "sched.decode"]
+    for name, start, end, attrs in spans:
+        if name in ("sched.draft", "sched.dispatch", "sched.pull"):
+            assert any(
+                d[1] <= start and end <= d[2]
+                and d[3]["tick"] == attrs["tick"] for d in decodes
+            ), (name, attrs)
+
+
+@pytest.mark.parametrize("span,event", [
+    ("sched.admit", "request_admit"), ("sched.prefill", "prefill"),
+])
+def test_request_spans_carry_the_rid_they_served(traced, span, event):
+    spans, rec, _ = traced
+    served = sorted(
+        (p["rid"], p["slot"]) for kind, p in rec.events if kind == event
+    )
+    assert served
+    got = sorted(
+        (a["rid"], a["slot"]) for n, _, _, a in spans
+        if n == span and "stalled" not in a
+    )
+    assert got == served
+
+
+def test_trainer_phase_is_on_the_profilers_clock(traced):
+    spans, _, _ = traced
+    (phase,) = [s for s in spans if s[0] == "trainer.train"]
+    assert phase[3]["steps"] == 8
+
+
+def test_recorder_gets_the_schedulers_spans_through_obs_span(traced):
+    _, rec, sched = traced
+    ticks = [s for s in rec.spans if s[0] == "decode_tick"]
+    assert len(ticks) == sched.decode_ticks
+    assert all(track == "serving" for _, track, _ in ticks)
+    assert sum(steps for _, _, steps in ticks) == sched.tokens_emitted
+    requests = [s for s in rec.spans if s[0] == "request"]
+    assert sorted(steps for _, _, steps in requests) == [4, 4, 4]
+    assert all(track == "requests" for _, track, _ in requests)
+    assert sched.full_tick_s > 0
+
+
+def test_no_recorder_no_record_and_no_trace_needed():
+    """Outside a profiler session, with no recorder attached, a span is
+    two clock reads: the scheduler runs as before and nothing is kept."""
+    sched = Scheduler(tiny_engine())
+    serve_some(sched)
+    assert len(sched.finished) == 3 and sched.full_tick_s > 0
+    with obs.span("anything", tick=1) as sp:
+        pass
+    assert sp.dur >= 0 and sp.t0_wall > 0
+    sp.record(None, "anything")  # no recorder: no-op
+    rec = Recorder()
+    sp.record(rec, "anything", track="t", steps=3)
+    assert rec.spans == [("anything", "t", 3)]
+
+
+def test_timers_phase_still_feeds_its_sink():
+    got = []
+    t = Timers(span_sink=lambda name, t0, dur, steps: got.append(
+        (name, steps, t0 > 0, dur >= 0)
+    ))
+    with t.phase("train", steps=8):
+        pass
+    with t.phase("data"):
+        pass
+    assert got == [("train", 8, True, True), ("data", 1, True, True)]
+    assert t.steps("train") == 8 and t.total("train") >= 0
+
+
+def test_flight_recorder_span_goes_through_obs_span(tmp_path):
+    trace_dir = str(tmp_path / "trace")
+    rec = obs.FlightRecorder(str(tmp_path / "events"))
+    with jax.profiler.trace(trace_dir):
+        with rec.span("assemble_batch", track="feeder"):
+            pass
+    assert rec.recorded == 1
+    assert [s[0] for s in read_spans(trace_dir)] == ["feeder.assemble_batch"]
+
+
+# ---------------------------------------------------------------------
+# device names
+# ---------------------------------------------------------------------
+
+
+def instructions(text):
+    """-> [(opcode, op_name)] of a compiled module's text."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= \S+ ([a-z\-]+)\(", line)
+        n = re.search(r'op_name="([^"]+)"', line)
+        if m and n:
+            out.append((m.group(1), n.group(1)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def conv_net_text(tmp_path_factory):
+    """A conv + BatchNorm + loss conf net, forward and backward in one
+    compiled program."""
+    shard = str(tmp_path_factory.mktemp("shard") / "shard")
+    write_records(shard, *synthetic_arrays(16, seed=4))
+    net = build_net(parse_model_config(f"""
+name: "names"
+train_steps: 1
+updater {{ base_learning_rate: 0.1 param_type: "Param" }}
+neuralnet {{
+  layer {{ name: "data" type: "kShardData"
+          data_param {{ path: "{shard}" batchsize: 8 }} }}
+  layer {{ name: "mnist" type: "kMnistImage" srclayers: "data"
+          mnist_param {{ norm_a: 255 norm_b: 0 }} }}
+  layer {{ name: "label" type: "kLabel" srclayers: "data" }}
+  layer {{ name: "c1" type: "kConvolution" srclayers: "mnist"
+          convolution_param {{ num_filters: 4 kernel: 3 stride: 1 }}
+          param {{ name: "weight" init_method: "kUniformSqrtFanIn" }}
+          param {{ name: "bias" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "bn1" type: "kBatchNorm" srclayers: "c1"
+          param {{ name: "gamma" init_method: "kConstant" value: 1 }}
+          param {{ name: "beta" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "relu1" type: "kReLU" srclayers: "bn1" }}
+  layer {{ name: "c2" type: "kConvolution" srclayers: "relu1"
+          convolution_param {{ num_filters: 4 kernel: 3 stride: 2 }}
+          param {{ name: "weight" init_method: "kUniformSqrtFanIn" }}
+          param {{ name: "bias" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "bn2" type: "kBatchNorm" srclayers: "c2"
+          param {{ name: "gamma" init_method: "kConstant" value: 1 }}
+          param {{ name: "beta" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "fc" type: "kInnerProduct" srclayers: "bn2"
+          inner_product_param {{ num_output: 10 }}
+          param {{ name: "w" init_method: "kUniformSqrtFanIn" }}
+          param {{ name: "b" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "loss" type: "kSoftmaxLoss" srclayers: "fc" srclayers: "label"
+          softmaxloss_param {{ topk: 1 }} }}
+}}
+"""), "kTrain")
+    params = init_params(jax.random.PRNGKey(0), net.param_specs())
+    (dl,) = net.datalayers
+    batch = {"data": {"image": jnp.asarray(dl.images[:8]),
+                      "label": jnp.asarray(dl.labels[:8])}}
+
+    def loss(p):
+        return net.forward(p, batch, training=True)[0]
+
+    return jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+
+
+def test_every_convolution_carries_its_layers_scope(conv_net_text):
+    # by the primitive at the path's end: XLA:CPU rewrites a backward
+    # convolution into other opcodes, its ``op_name`` stays
+    convs = {
+        n for _, n in instructions(conv_net_text)
+        if n.endswith("/conv_general_dilated")
+    }
+    assert any(op == "convolution" for op, _ in instructions(conv_net_text))
+    for name in convs:
+        assert re.search(r"kConvolution\.c[12]\)*/", name), name
+    for layer in ("c1", "c2"):
+        assert f"jit(loss)/jvp(kConvolution.{layer})/conv_general_dilated" in convs
+        assert (
+            f"jit(loss)/transpose(jvp(kConvolution.{layer}))"
+            "/conv_general_dilated"
+        ) in convs
+
+
+@pytest.mark.parametrize("layer", ["bn1", "bn2"])
+def test_batchnorm_reductions_carry_its_scope_both_ways(conv_net_text, layer):
+    """BatchNorm is a ``custom_vjp``: its hand-written backward must
+    come out under ``transpose(jvp(kBatchNorm.<layer>))`` like any
+    other layer's."""
+    reduces = [
+        n for op, n in instructions(conv_net_text)
+        if op == "reduce" and f"kBatchNorm.{layer}" in n
+    ]
+    assert any(f"/jvp(kBatchNorm.{layer})/" in n for n in reduces), reduces
+    assert any(
+        f"/transpose(jvp(kBatchNorm.{layer}))/" in n for n in reduces
+    ), reduces
+
+
+def test_every_reduction_lies_in_some_layers_scope(conv_net_text):
+    for op, name in instructions(conv_net_text):
+        if op == "reduce":
+            assert re.search(r"k[A-Z]\w+\.\w+", name), name
+
+
+@pytest.fixture(scope="module")
+def engine_texts():
+    eng = tiny_engine(spec_k=2)
+    slot, chunk = jnp.int32(0), jnp.zeros((4,), jnp.int32)
+    draft = jnp.zeros((2, 2), jnp.int32)
+    lowered = {
+        "_decode": eng._decode_jit.lower(eng.params, eng.state),
+        "_prefill": eng._prefill_jit.lower(
+            eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(4)
+        ),
+        "_verify": eng._verify_jit.lower(
+            eng.params, eng.state, draft, jnp.zeros((2,), jnp.int32)
+        ),
+    }
+    return {k: v.compile().as_text() for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("scope", [
+    "blk0/attend/gather_kv", "blk0/attend/kv_write",
+    "blk0/attend/cache_attend", "blk1/attend/gather_kv", "blk0/qkv",
+    "blk0/mlp", "blk0/ln1", "blk0/attn_out", "embed", "lm_head", "sample",
+])
+def test_decode_program_names_its_operations(engine_texts, scope):
+    names = {n for _, n in instructions(engine_texts["_decode"])}
+    assert any(f"jit(_decode)/{scope}/" in n for n in names), scope
+
+
+@pytest.mark.parametrize("program", ["_prefill", "_verify"])
+def test_prefill_and_verify_share_the_decode_names(engine_texts, program):
+    names = {n for _, n in instructions(engine_texts[program])}
+    for scope in ("blk0/attend/gather_kv", "blk0/attend/cache_attend",
+                  "kv_write", "lm_head"):
+        assert any(f"/{scope}/" in n for n in names), (program, scope)
+    assert not any("/" in s for s in ("gather_kv", "kv_write", "sample"))
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill", "_verify"])
+def test_engine_program_names(engine_texts, program):
+    # ``XLA Modules`` events of a trace are keyed by these
+    assert f"HloModule jit_{program}," in engine_texts[program]
+
+
+def test_trainer_chunk_program_name(tmp_path):
+    from singa_tpu.trainer import Trainer
+
+    shard = str(tmp_path / "shard")
+    write_records(shard, *synthetic_arrays(16, seed=4))
+    trainer = Trainer(parse_model_config(f"""
+name: "chunk-name"
+train_steps: 4
+updater {{ base_learning_rate: 0.1 param_type: "Param" }}
+neuralnet {{
+  layer {{ name: "data" type: "kShardData"
+          data_param {{ path: "{shard}" batchsize: 8 }} }}
+  layer {{ name: "mnist" type: "kMnistImage" srclayers: "data"
+          mnist_param {{ norm_a: 255 norm_b: 0 }} }}
+  layer {{ name: "label" type: "kLabel" srclayers: "data" }}
+  layer {{ name: "fc" type: "kInnerProduct" srclayers: "mnist"
+          inner_product_param {{ num_output: 10 }}
+          param {{ name: "w" init_method: "kUniformSqrtFanIn" }}
+          param {{ name: "b" init_method: "kConstant" value: 0 }} }}
+  layer {{ name: "loss" type: "kSoftmaxLoss" srclayers: "fc" srclayers: "label"
+          softmaxloss_param {{ topk: 1 }} }}
+}}
+"""), seed=0, log=lambda s: None, prefetch=False)
+    fn = trainer._make_chunk_fn(2)
+    pipes = trainer._pipelines[id(trainer.train_net)]
+    text = fn.lower(
+        trainer.params, trainer.state, trainer.buffers, jnp.int32(0),
+        {name: jnp.int32(0) for name in pipes},
+        trainer._dev_data[id(trainer.train_net)],
+    ).compile().as_text()
+    assert "HloModule jit_chunk_fn," in text
+    names = {n for _, n in instructions(text)}
+    # inside the scan: the layers' scopes and the update's
+    assert any("/update/" in n for n in names)
+    assert any("transpose(jvp(kInnerProduct.fc))" in n for n in names)
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attention",
+])
+def test_pallas_kernels_are_named(kernel):
+    from singa_tpu.ops.attention import flash_attention
+    from singa_tpu.ops.paged_attention import paged_attention
+
+    if kernel == "paged_attention":
+        q = jnp.zeros((2, 2, 1, 8))
+        pool = jnp.zeros((5, 2, 8, 8))
+        jaxpr = jax.make_jaxpr(lambda q, k, v: paged_attention(
+            q, k, v, jnp.zeros((2, 4), jnp.int32),
+            jnp.zeros((2, 1), jnp.int32), interpret=True,
+        ))(q, pool, pool)
+    else:
+        x = jnp.zeros((1, 2, 16, 8))
+
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, block_q=8, block_k=8, interpret=True
+            ).sum()
+
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    assert f"name={kernel}" in str(jaxpr)
+
+
+def test_compile_cache_key_is_salted_by_the_names_version(monkeypatch):
+    """JAX leaves names out of the persistent cache's key: an executable
+    cached by code that named its operations otherwise would be served
+    with its old names, and a trace is read by them. The key holds a
+    constant that a renaming change counts up."""
+    from jax._src import cache_key
+
+    from singa_tpu.utils import compile_cache
+
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "")
+    monkeypatch.setattr(jax.config, "update", lambda k, v: None)
+    compile_cache.setup_compile_cache(log=lambda s: None)
+    assert cache_key.custom_hook() == compile_cache.NAMES
