@@ -389,10 +389,11 @@ def _serve_streams(params, cfg, impl: str, spec_k: int, requests):
     return engine, {r.rid: list(r.tokens) for r in sched.finished}
 
 
-def _kernel_vs_gather(seed: int, cfg) -> float:
+def _kernel_vs_gather(seed: int, h: int, d: int, max_len: int) -> float:
     """The paged kernel against ``cache_attend`` over the gathered pool
-    on the same seed-made inputs, at the engine's three call shapes
-    (decode, prefill chunk, verify overlay). -> max |difference|."""
+    on the same seed-made inputs, ``h`` heads of ``d``, at the engine's
+    three call shapes (decode, prefill chunk, verify overlay).
+    -> max |difference|."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -403,13 +404,18 @@ def _kernel_vs_gather(seed: int, cfg) -> float:
     )
 
     rs = np.random.RandomState(seed)
-    h, d, bl = cfg.n_heads, cfg.head_dim, 16
-    mb = cfg.max_len // bl
+    bl = 16
+    mb = max_len // bl
     worst = 0.0
 
     def gather(pool, tables):
         g = jnp.moveaxis(pool[tables], 2, 1)
         return g.reshape(g.shape[0], h, mb * bl, d)
+
+    def stored(pool):
+        # the oracle reads (NB, H, BL, D); the engine stores, and the
+        # kernel takes, (NB, BL, H * D)
+        return jnp.moveaxis(pool, 1, 2).reshape(pool.shape[0], bl, h * d)
 
     for s, q_len, overlay in ((8, 1, False), (1, 16, False), (8, 5, True)):
         nb = s * mb + 1
@@ -426,7 +432,7 @@ def _kernel_vs_gather(seed: int, cfg) -> float:
             ck = jnp.asarray(rs.randn(s, h, q_len, d), jnp.float32)
             cv = jnp.asarray(rs.randn(s, h, q_len, d), jnp.float32)
             got = paged_attention_overlay(
-                q, kp, vp, tables, pos, ck, cv,
+                q, stored(kp), stored(vp), tables, pos, ck, cv,
                 jnp.ones((s, q_len), jnp.int32),
             )
             rows = jnp.arange(s)[:, None]
@@ -438,7 +444,7 @@ def _kernel_vs_gather(seed: int, cfg) -> float:
             )
             want = cache_attend(q, gk, gv, pos)
         else:
-            got = paged_attention(q, kp, vp, tables, pos)
+            got = paged_attention(q, stored(kp), stored(vp), tables, pos)
             want = cache_attend(
                 q, gather(kp, tables), gather(vp, tables), pos
             )
@@ -510,7 +516,13 @@ def serve(devices, work: str, seed: int, sizes: Sizes) -> dict:
                         "tpu_custom_call" in lowered.as_text(),
                         f"fused {name} program holds no Mosaic kernel",
                     )
-    worst = _kernel_vs_gather(seed, cfg)
+    # the model's own heads, and GPT-2's 16 of 64: the kernel walks the
+    # heads as column slices of a pool row, and a 64-wide head's slice
+    # starts off the 128-lane boundary
+    worst = max(
+        _kernel_vs_gather(seed, h, d, cfg.max_len)
+        for h, d in ((cfg.n_heads, cfg.head_dim), (16, 64))
+    )
     # greedy streams against the reference's plain decode. Every other
     # run is a different compiled program (another attend, or the
     # verify shape), and on the chip their f32 matmuls run as bf16

@@ -239,9 +239,9 @@ def kv_pool_bytes(
     model_cfg: ModelConfig, widths: dict[str, int], notes: list[str]
 ) -> int:
     """Per-device bytes of the serving engine's paged KV pools: K and V
-    per attention layer, each ``(n_blocks, heads, block_len, head_dim)``
-    f32 (serve/engine.py), heads sharded over the model axis when it
-    divides (serving_kv_shardings). 0 when the conf declares no serving
+    per attention layer, each ``(n_blocks, block_len, heads * head_dim)``
+    f32 (serve/kv_pool.py), whole heads sharded over the model axis when
+    it divides (serving_kv_shardings). 0 when the conf declares no serving
     block or the geometry is not statically decidable."""
     srv = model_cfg.serving
     if srv is None:

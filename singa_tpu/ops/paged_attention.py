@@ -15,6 +15,15 @@ across grid steps in VMEM scratch — flash-attention tiling over
 block-granular K/V, the PagedAttention idea from vLLM-style serving.
 No dense ``(S, H, C, D)`` intermediate ever exists.
 
+WHAT THE KERNEL IS HANDED: the pools as the engine stores them,
+``(n_blocks, block_len, H * D)`` (serve/kv_pool.py: one row a token,
+the heads side by side in it), with no view or relayout in between.
+One grid step fetches one whole block for ALL heads — a
+``(1, block_len, H * D)`` tile, its minor dimension the model's width
+— and the heads are walked inside the kernel with static column
+slices, so the grid is ``(S, max_blocks)`` and the statistics are kept
+per head.
+
 Two entry points cover the engine's three call shapes:
 
 ``paged_attention``
@@ -58,8 +67,8 @@ map to the same block — and ``pl.when`` skips their compute.
 kernel compiles through Mosaic, elsewhere it runs through the Pallas
 interpreter — plain XLA ops, so the masking/online-softmax logic is
 tested on every CPU run and the kernel composes with GSPMD sharding
-(``serving_kv_shardings`` lays the pool's heads over the model axis;
-the grid's ``S*H`` dimension partitions with it). Tests pass an
+(``serving_kv_shardings`` lays the pool's last dimension, whole heads
+a shard, over the model axis). Tests pass an
 explicit ``True``. Every operand's block has its last two dims EQUAL
 to the array's, so Mosaic takes any ``kv_block_len`` / ``head_dim`` /
 query count (``fusable``; tests/test_chip_compile.py asks the v5e
@@ -97,13 +106,18 @@ def fusable(block_len: int):
 def _kernel(
     tab_ref, nlive_ref,
     q_ref, k_ref, v_ref, pos_ref, *rest,
-    block_len, n_heads, mb, per_query_pool_mask, has_chunk,
+    block_len, mb, per_query_pool_mask, has_chunk,
 ):
-    """One (sequence*head, pool-block) program.
+    """One (sequence, pool-block) program over ALL heads.
+
+    A pool block is ``(block_len, H * D)``: one row a token, the heads
+    side by side in it (serve/kv_pool.py), so the block is fetched
+    whole and head ``h`` is columns ``[h * D, (h + 1) * D)`` of it,
+    walked here with static slices.
 
     Grid iterates the block dimension innermost and sequentially, so
     the flash (acc, m, l) statistics live in VMEM scratch across steps
-    of the same (s, h) row: initialized at b == 0, folded per live
+    of the same sequence: initialized at b == 0, folded per live
     block, normalized at b == mb - 1 (where the overlay chunk, if any,
     is folded last — online softmax is order-free).
 
@@ -117,21 +131,24 @@ def _kernel(
     else:
         o_ref, acc, m, l = rest
     b = pl.program_id(1)
-    s = pl.program_id(0) // n_heads
-    q = q_ref[0, 0].astype(jnp.float32)            # (Q, D)
+    s = pl.program_id(0)
+    n_heads, nq, d = q_ref.shape[1:]
     pos = pos_ref[0, 0]                            # (Q,) int32
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = 1.0 / math.sqrt(d)
 
-    def fold(scores, mask, values):
-        """One online-softmax update of the running (acc, m, l)."""
+    def fold(h, scores, mask, values):
+        """One online-softmax update of head h's running (acc, m, l)."""
         scores = jnp.where(mask, scores, NEG_INF)
-        m_prev = m[0]
+        m_prev = m[h]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(mask, jnp.exp(scores - m_new[:, None]), 0.0)
-        acc[...] = acc[...] * alpha[:, None] + p @ values
-        l[0] = l[0] * alpha + jnp.sum(p, axis=-1)
-        m[0] = m_new
+        acc[h] = acc[h] * alpha[:, None] + p @ values
+        l[h] = l[h] * alpha + jnp.sum(p, axis=-1)
+        m[h] = m_new
+
+    def query(h):
+        return q_ref[0, h].astype(jnp.float32)     # (Q, D)
 
     @pl.when(b == 0)
     def _init():
@@ -141,8 +158,6 @@ def _kernel(
 
     @pl.when(b < nlive_ref[s])
     def _pool_block():
-        k = k_ref[0, 0].astype(jnp.float32)        # (BL, D)
-        v = v_ref[0, 0].astype(jnp.float32)
         kpos = b * block_len + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_len), 1
         )[0]
@@ -150,22 +165,28 @@ def _kernel(
             mask = kpos[None, :] <= pos[:, None]   # (Q, BL)
         else:
             mask = jnp.broadcast_to(
-                kpos[None, :] < pos[0], (q.shape[0], block_len)
+                kpos[None, :] < pos[0], (nq, block_len)
             )
-        fold((q @ k.T) * scale, mask, v)
+        for h in range(n_heads):
+            cols = pl.ds(h * d, d)
+            k = k_ref[0, :, cols].astype(jnp.float32)   # (BL, D)
+            v = v_ref[0, :, cols].astype(jnp.float32)
+            fold(h, (query(h) @ k.T) * scale, mask, v)
 
     @pl.when(b == mb - 1)
     def _finish():
         if has_chunk:
-            ck = ck_ref[0, 0].astype(jnp.float32)  # (Q, D)
-            cv = cv_ref[0, 0].astype(jnp.float32)
             vld = valid_ref[0, 0] != 0
             # column jj holds the entry AT position pos[jj]: causal
             # within the chunk, padding/rejected columns masked out
             mask = (pos[None, :] <= pos[:, None]) & vld[None, :]
-            fold((q @ ck.T) * scale, mask, cv)
-        safe = jnp.where(l[0] == 0.0, 1.0, l[0])
-        o_ref[0, 0] = (acc[...] / safe[:, None]).astype(o_ref.dtype)
+        for h in range(n_heads):
+            if has_chunk:
+                ck = ck_ref[0, h].astype(jnp.float32)   # (Q, D)
+                cv = cv_ref[0, h].astype(jnp.float32)
+                fold(h, (query(h) @ ck.T) * scale, mask, cv)
+            safe = jnp.where(l[h] == 0.0, 1.0, l[h])
+            o_ref[0, h] = (acc[h] / safe[:, None]).astype(o_ref.dtype)
 
 
 def live_blocks(last_position, block_len, max_blocks):
@@ -181,7 +202,12 @@ def live_blocks(last_position, block_len, max_blocks):
 
 def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
     s, h, nq, d = q.shape
-    _, _, bl, _ = k_pool.shape
+    _, bl, hd = k_pool.shape
+    if hd != h * d:
+        raise ValueError(
+            f"pool rows are {hd} wide, the queries' {h} heads of {d} "
+            f"need {h * d}: the pool is (n_blocks, block_len, H * D)"
+        )
     mb = tables.shape[1]
     if interpret is None:
         # THE place the fused serving kernel picks its form: compiled
@@ -200,19 +226,18 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         # clamp dead iterations at the last live block: the repeated
         # index lets the grid pipeline skip the re-fetch, pl.when
         # skips the compute — bytes saved, not just masked
-        row = i // h
-        bb = jnp.minimum(b, jnp.maximum(nref[row] - 1, 0))
-        return (tref[row * mb + bb], i % h, 0, 0)
+        bb = jnp.minimum(b, jnp.maximum(nref[i] - 1, 0))
+        return (tref[i * mb + bb], 0, 0)
 
-    qspec = pl.BlockSpec(
-        (1, 1, nq, d), lambda i, b, t, n: (i // h, i % h, 0, 0)
-    )
-    # per-sequence rows ride as (S, 1, Q) with a (1, 1, Q) block: the
-    # block's last two dims then EQUAL the array's, which Mosaic takes
-    # at any Q — a (1, Q) block of an (S, Q) array is refused unless
-    # S == 1 (sublane dim neither 8-divisible nor the array's)
-    rowspec = pl.BlockSpec((1, 1, nq), lambda i, b, t, n: (i // h, 0, 0))
-    kvspec = pl.BlockSpec((1, 1, bl, d), kmap)
+    # every operand's block has its last two dims EQUAL to the array's,
+    # which Mosaic takes at any size: a sequence's queries over all its
+    # heads, a whole pool block, and per-sequence rows as (S, 1, Q)
+    # with a (1, 1, Q) block — a (1, Q) block of an (S, Q) array is
+    # refused unless S == 1 (sublane dim neither 8-divisible nor the
+    # array's)
+    qspec = pl.BlockSpec((1, h, nq, d), lambda i, b, t, n: (i, 0, 0, 0))
+    rowspec = pl.BlockSpec((1, 1, nq), lambda i, b, t, n: (i, 0, 0))
+    kvspec = pl.BlockSpec((1, bl, hd), kmap)
     in_specs = [qspec, kvspec, kvspec, rowspec]
     args = [q, k_pool, v_pool, positions.astype(jnp.int32)[:, None, :]]
     if chunk is not None:
@@ -221,19 +246,19 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         args += [ck, cv, valid.astype(jnp.int32)[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s * h, mb),
+        grid=(s, mb),
         in_specs=in_specs,
         out_specs=qspec,
         scratch_shapes=[
-            pltpu.VMEM((nq, d), jnp.float32),      # acc
-            pltpu.VMEM((1, nq), jnp.float32),      # m (running rowmax)
-            pltpu.VMEM((1, nq), jnp.float32),      # l (running rowsum)
+            pltpu.VMEM((h, nq, d), jnp.float32),   # acc
+            pltpu.VMEM((h, nq), jnp.float32),      # m (running rowmax)
+            pltpu.VMEM((h, nq), jnp.float32),      # l (running rowsum)
         ],
     )
     return pl.pallas_call(
         functools.partial(
             _kernel,
-            block_len=bl, n_heads=h, mb=mb,
+            block_len=bl, mb=mb,
             per_query_pool_mask=chunk is None,
             has_chunk=chunk is not None,
         ),
@@ -277,7 +302,7 @@ def paged_attention(
     """Masked paged attention, write-then-read form.
 
     ``q`` (S, H, Q, D) queries at absolute ``positions`` (S, Q);
-    ``k_pool``/``v_pool`` (n_blocks, H, block_len, D) pools already
+    ``k_pool``/``v_pool`` (n_blocks, block_len, H * D) pools already
     holding every attended entry (the fresh chunk was scattered in,
     padding to the trash block); ``tables`` (S, max_blocks) block ids.
     -> (S, H, Q, D), allclose to

@@ -209,16 +209,17 @@ def serving_kv_shardings(
     """-> (pool_sharding, state_sharding) for the serving engine's paged
     KV state (serve/engine.py).
 
-    The pools are ``(n_blocks, heads, block_len, head_dim)``: the heads
-    dim shards over the ``model`` axis when it divides evenly — the
-    serving analog of kLayerPartition (each model shard holds its
-    heads' K/V, attention contracts locally, GSPMD reassembles the
-    output exactly as it does for the TP projections) — else the pool
-    replicates, announced like every other indivisible-dim fallback.
-    The block dim NEVER shards: block ids are a global namespace the
-    host allocator hands out, and a table must be resolvable on every
-    shard. Slot-lane state (tokens/pos/live/rng/tables) is tiny and
-    always replicates."""
+    The pools are ``(n_blocks, block_len, heads * head_dim)``, heads
+    major within the last dim (serve/kv_pool.py): that dim shards over
+    the ``model`` axis when the axis divides ``n_heads``, so every
+    shard holds WHOLE heads — the serving analog of kLayerPartition
+    (each model shard holds its heads' K/V, attention contracts
+    locally, GSPMD reassembles the output exactly as it does for the
+    TP projections) — else the pool replicates, announced like every
+    other indivisible-dim fallback. The block dim NEVER shards: block
+    ids are a global namespace the host allocator hands out, and a
+    table must be resolvable on every shard. Slot-lane state
+    (tokens/pos/live/rng/tables) is tiny and always replicates."""
     repl = replicated(mesh)
     nmodel = dict(mesh.shape).get(MODEL_AXIS, 1)
     if nmodel <= 1:
@@ -231,7 +232,7 @@ def serving_kv_shardings(
                 stacklevel=2,
             )
         return repl, repl
-    return NamedSharding(mesh, P(None, MODEL_AXIS, None, None)), repl
+    return NamedSharding(mesh, P(None, None, MODEL_AXIS)), repl
 
 
 def state_shardings(

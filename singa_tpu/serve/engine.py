@@ -44,9 +44,10 @@ greedy-only per slot: a temperature > 0 slot rides the verify tick
 with zero drafts (it emits its one sampled token per tick; its key
 discipline — one split per emitted token — is identical either way).
 
-Sharding: pass a mesh and the pools lay their heads dim out over the
-``model`` axis (parallel/shardings.serving_kv_shardings) — the serving
-analog of kLayerPartition; everything else replicates.
+Sharding: pass a mesh and the pools lay their last dim (H * D, heads
+major: serve/kv_pool.py) out over the ``model`` axis, whole heads a
+shard (parallel/shardings.serving_kv_shardings) — the serving analog
+of kLayerPartition; everything else replicates.
 
 ATTENTION IMPLEMENTATION is a per-engine knob (the ``kernels {
 paged_attention }`` model-conf block): ``reference`` (the default)
@@ -238,10 +239,7 @@ class Engine:
         #: into the prefix index is gated on the version still being live
         self._slot_version: dict[int, int] = {}
         s, mb = self.serving.slots, self.pool.max_blocks_per_seq
-        shape = (
-            self.pool.n_blocks, cfg.n_heads,
-            self.pool.block_len, cfg.head_dim,
-        )
+        shape = self.pool.array_shape(cfg.n_heads, cfg.head_dim)
         pool_sh = state_sh = None
         if mesh is not None:
             from ..parallel.shardings import serving_kv_shardings
@@ -308,25 +306,44 @@ class Engine:
     # ------------------------------------------------------------------
 
     def _gather(self, pool_arr, tables):
-        """(NB, H, BL, D) pool + (S', MB) tables -> (S', H, CL, D) dense
-        per-sequence cache views (CL = MB * BL = the dense cache_len).
+        """(NB, BL, H*D) pool (the stored shape, serve/kv_pool.py) +
+        (S', MB) tables -> (S', H, CL, D) dense per-sequence cache views
+        (CL = MB * BL = the dense cache_len): a sequence's blocks are
+        consecutive rows of CL tokens, each H*D wide, and the heads come
+        out of the row.
 
         Gather indices are promised in bounds: every table entry is an
         allocator-issued block id (rows beyond a sequence's allocation
         hold the trash block, 0), so XLA's per-index clamp — work whose
         only effect the attend mask would zero anyway — is skipped."""
         g = pool_arr.at[tables].get(mode="promise_in_bounds")
-        g = jnp.moveaxis(g, 2, 1)                 # (S', H, MB, BL, D)
-        s, h = g.shape[0], g.shape[1]
-        return g.reshape(s, h, self.pool.cache_len, g.shape[-1])
+        g = g.reshape(                            # (S', CL, H, D)
+            g.shape[0], self.pool.cache_len, self.cfg.n_heads, -1
+        )
+        return jnp.moveaxis(g, 2, 1)
 
     @staticmethod
     @jax.named_scope("kv_write")
     def _kv_write(pool_arr, bid, off, fresh):
-        """One pool with ``fresh`` scattered to offsets ``off`` of
-        blocks ``bid`` — the ONE write every program shares; in a
-        trace its operations are ``kv_write``."""
-        return pool_arr.at[bid, :, off].set(fresh)
+        """One (NB, BL, H*D) pool with ``fresh`` (..., H, D), one entry
+        a token of ``bid`` / ``off``, scattered to offsets ``off`` of
+        blocks ``bid`` as whole H*D-wide rows — the ONE write every
+        program shares; in a trace its operations are ``kv_write``."""
+        return pool_arr.at[bid, off].set(fresh.reshape(*bid.shape, -1))
+
+    def _blocks_out(self, pool_arr, row):
+        """Blocks ``row`` of one pool in the shape that leaves the
+        engine: (n, H, BL, D), the fleet's wire format."""
+        g = pool_arr[row]
+        g = g.reshape(*g.shape[:2], self.cfg.n_heads, -1)
+        return jnp.moveaxis(g, 2, 1)
+
+    @staticmethod
+    def _blocks_in(pool_arr, row, blocks):
+        """One pool with (n, H, BL, D) ``blocks`` from the wire written
+        to blocks ``row``: ``_blocks_out``'s inverse."""
+        b = jnp.moveaxis(blocks, 1, 2)
+        return pool_arr.at[row].set(b.reshape(*b.shape[:2], -1))
 
     @jax.named_scope("gather_kv")
     def _gather_kv(self, kp, vp, tables):
@@ -698,8 +715,8 @@ class Engine:
         the sequence's allocation gather the trash block; the host side
         trims them before serialization."""
         row = state["tables"][slot]
-        k = jnp.stack([kp[row] for kp in state["k"]])
-        v = jnp.stack([vp[row] for vp in state["v"]])
+        k = jnp.stack([self._blocks_out(kp, row) for kp in state["k"]])
+        v = jnp.stack([self._blocks_out(vp, row) for vp in state["v"]])
         return (
             k, v, state["tokens"][slot], state["pos"][slot],
             state["temp"][slot], state["rng"][slot],
@@ -717,11 +734,11 @@ class Engine:
         so duplicate trash writes can only disagree about garbage the
         attend mask zeroes exactly."""
         new_k = tuple(
-            kp.at[scatter_row].set(kblk[i])
+            self._blocks_in(kp, scatter_row, kblk[i])
             for i, kp in enumerate(state["k"])
         )
         new_v = tuple(
-            vp.at[scatter_row].set(vblk[i])
+            self._blocks_in(vp, scatter_row, vblk[i])
             for i, vp in enumerate(state["v"])
         )
         return {
@@ -741,8 +758,8 @@ class Engine:
         (L, MB, H, BL, D) bulk value — the device half of serving a
         ``cache_fetch`` (pad rows gather the trash block; the host
         trims them before the ship frame is serialized)."""
-        k = jnp.stack([kp[row] for kp in state["k"]])
-        v = jnp.stack([vp[row] for vp in state["v"]])
+        k = jnp.stack([self._blocks_out(kp, row) for kp in state["k"]])
+        v = jnp.stack([self._blocks_out(vp, row) for vp in state["v"]])
         return k, v
 
     def _install_prog(self, state, scatter_row, kblk, vblk):
@@ -754,11 +771,11 @@ class Engine:
         return {
             **state,
             "k": tuple(
-                kp.at[scatter_row].set(kblk[i])
+                self._blocks_in(kp, scatter_row, kblk[i])
                 for i, kp in enumerate(state["k"])
             ),
             "v": tuple(
-                vp.at[scatter_row].set(vblk[i])
+                self._blocks_in(vp, scatter_row, vblk[i])
                 for i, vp in enumerate(state["v"])
             ),
         }

@@ -4,13 +4,33 @@ A dense serving cache reserves ``slots * max_len`` K/V positions per
 layer no matter how long each stream actually is; at thousands of
 concurrent streams that reservation — not compute — caps concurrency.
 Here device memory holds ONE pool of fixed-size blocks per layer,
-shaped ``(n_blocks, heads, block_len, head_dim)``, and each sequence
-owns an ordered list of block ids (its block table). Admission
+shaped ``(n_blocks, block_len, heads * head_dim)`` (below), and each
+sequence owns an ordered list of block ids (its block table). Admission
 allocates exactly the blocks a request's ``prompt + budget`` needs;
 retirement returns them; a stream's cache view is a gather of its
 table. Blocks are uniform, so the allocator is a free list with zero
 external fragmentation — "fragmentation" can only mean internal slack
 inside a sequence's last block, bounded by ``block_len - 1`` positions.
+
+THE STORED SHAPE is ``KVPool.array_shape``: ``(n_blocks, block_len,
+heads * head_dim)`` — a block is ``block_len`` token rows, a row holds
+one token's K (or V) for every head side by side, head ``h`` in
+columns ``[h * head_dim, (h + 1) * head_dim)``. It is one shape for
+every model, from the model's own numbers, and it is the shape the
+serving programs USE: a write is one whole row a token
+(``Engine._kv_write``), a gather is whole blocks (``Engine._gather``,
+which still hands its callers the ``(S, H, cache_len, D)`` view), and
+the two minor dimensions (``block_len``, ``d_model``) fill the chip's
+(8, 128) tiles at every published width. With the heads in a dimension
+of their own — ``(n_blocks, heads, block_len, head_dim)`` — a 64-wide
+head half-fills a 128-lane tile, the runtime stores such an argument
+with the BLOCK index minor-most instead, and every program that
+touches the pools copies all of them into the scatter's layout on the
+way in and back on the way out: 61 of a decode tick's 114 ms and 62 of
+a prefill chunk's 64 ms on a v5e (PERF.md, PR 24). ``tests/test_chip_compile.py`` pins that no compiled serving
+program holds such a copy. What LEAVES an engine (``export_slot``,
+``export_blocks``) keeps the fleet's ``(L, n, H, BL, D)`` wire format:
+the engine transposes a sequence's few blocks at that boundary.
 
 Block id 0 is reserved as the TRASH block: it is never allocated, table
 rows are initialized to it, and fixed-shape prefill chunks route their
@@ -90,6 +110,12 @@ class KVPool:
     def cache_len(self) -> int:
         """Gathered per-sequence cache length (= padded max_len)."""
         return self.max_blocks_per_seq * self.block_len
+
+    def array_shape(self, n_heads: int, head_dim: int) -> tuple:
+        """Shape of ONE layer's K (or V) pool array: a block is
+        ``block_len`` token rows of ``n_heads * head_dim`` values, heads
+        major within a row (the module header says why)."""
+        return (self.n_blocks, self.block_len, n_heads * head_dim)
 
     @classmethod
     def for_model(cls, max_len: int, block_len: int, n_blocks: int = 0,

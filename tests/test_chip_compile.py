@@ -16,11 +16,15 @@ persistent cache is off around them (a described-device entry cannot be
 read back and would warn). One file, so one worker holds the library.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from singa_tpu.models.transformer import TransformerConfig, init_lm
 from singa_tpu.ops.attention import flash_attention
 from singa_tpu.ops.paged_attention import (
     fusable,
@@ -28,6 +32,7 @@ from singa_tpu.ops.paged_attention import (
     paged_attention_overlay,
 )
 from singa_tpu.ops.quantized_collective import quant_acc
+from singa_tpu.serve import Engine, EngineConfig
 
 
 @pytest.fixture(scope="module")
@@ -96,7 +101,7 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     q = sds((s, h, q_len, d), dtype)
-    pool = sds((s * mb + 1, h, bl, d), dtype)
+    pool = sds((s * mb + 1, bl, h * d), dtype)
     tables = sds((s, mb), jnp.int32)
     rows = sds((s, q_len), jnp.int32)
     assert fusable(bl) is None
@@ -111,6 +116,102 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
             q, pool, pool, tables, rows,
         )
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def serve_cell(one_chip):
+    """The engine of ``gpt2_medium_serve_closed`` (32 slots, blocks of
+    16, 128-token chunks; GPT-2 medium's width) cut to 2 layers, and
+    its arguments as shapes on the described chip: the pools are
+    ``f32[2049, 16, 1024]``, 134 MB each, 4 of them."""
+    cfg = TransformerConfig(
+        vocab=50257, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+        max_len=1024,
+    )
+    params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+    eng = Engine(params, cfg, EngineConfig(
+        slots=32, kv_block_len=16, max_prefill_chunk=128, spec_k=4,
+    ))
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return arg(jnp.int32, *shape)
+
+    def sds(tree):
+        return jax.tree.map(lambda a: arg(a.dtype, *a.shape), tree)
+
+    # shapes from here on: the engine's own zeros (0.5 GB) are let go
+    eng.state = state = sds(eng.state)
+    head = (sds(params), state)
+    mb = eng.pool.max_blocks_per_seq
+    # a slot's blocks in the fleet's wire format, (L, MB, H, BL, D)
+    wire = arg(jnp.float32, 2, mb, 16, 16, 64)
+    lanes = (i32(), i32(), arg(jnp.float32), arg(jnp.uint32, 2))
+    # name -> (function, arguments, which argument holds the pools and
+    # is donated, whether the pools come back out)
+    return eng, {
+        "decode": (eng._decode, head, 1, True),
+        "prefill": (
+            eng._prefill, head + (i32(), i32(128), i32(), i32()), 1, True,
+        ),
+        "verify": (eng._verify, head + (i32(32, 4), i32(32)), 1, True),
+        "cow": (eng._cow_prog, (state, i32(), i32()), 0, True),
+        "import": (
+            eng._import_prog,
+            (state, i32(), i32(mb), i32(mb), wire, wire) + lanes, 0, True,
+        ),
+        "install": (
+            eng._install_prog, (state, i32(mb), wire, wire), 0, True,
+        ),
+        "export": (eng._export_prog, (state, i32()), 0, False),
+        "export_blocks": (
+            eng._export_blocks_prog, (state, i32(mb)), 0, False,
+        ),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "decode", "prefill", "verify", "cow", "import", "install", "export",
+    "export_blocks",
+])
+def test_serving_programs_relayout_no_pool(serve_cell, program):
+    """The pools are stored in the layout the programs' scatter and
+    gather use (serve/kv_pool.py): no compiled program copies a whole
+    pool on its way in or out (two such copies a pool cost 61 of a
+    decode tick's 114 ms and 62 of a prefill chunk's 64 on the chip
+    before), and every pool leaves in the layout it arrived in, so the
+    donated buffer is written in place. The admission path's programs
+    (copy-on-write, migration, prefix shipping) are held to the same:
+    they transpose a slot's blocks at the wire, never a pool."""
+    eng, programs = serve_cell
+    fn, args, pools_at, pools_out = programs[program]
+    donate = (pools_at,) if pools_out else ()
+    text = (
+        jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+    )
+    pool = eng.state["k"][0]
+    n_pools = 2 * eng.cfg.n_layers
+    copies = [
+        m.group(0)
+        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+        if math.prod(map(int, m.group(1).split(","))) == pool.size
+    ]
+    assert copies == []
+    header = text[:text.index("\n")]
+    layouts = re.search(
+        r"entry_computation_layout=\{\((.*)\)->(.*)\}", header
+    )
+    dims = ",".join(map(str, pool.shape))
+    arrive, leave = (
+        re.findall(rf"f32\[{dims}\](\{{[^}}]*\}})", side)
+        for side in layouts.groups()
+    )
+    assert len(arrive) == n_pools and len(set(arrive)) == 1
+    if pools_out:
+        assert len(leave) == n_pools and set(leave) == set(arrive)
+        assert header.count("-alias)") >= n_pools
 
 
 @pytest.mark.parametrize(

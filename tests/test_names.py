@@ -407,7 +407,7 @@ def test_pallas_kernels_are_named(kernel):
 
     if kernel == "paged_attention":
         q = jnp.zeros((2, 2, 1, 8))
-        pool = jnp.zeros((5, 2, 8, 8))
+        pool = jnp.zeros((5, 8, 2 * 8))
         jaxpr = jax.make_jaxpr(lambda q, k, v: paged_attention(
             q, k, v, jnp.zeros((2, 4), jnp.int32),
             jnp.zeros((2, 1), jnp.int32), interpret=True,

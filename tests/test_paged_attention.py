@@ -79,6 +79,13 @@ def oracle_gather(pool_arr, tables, cache_len):
     return g.reshape(g.shape[0], g.shape[1], cache_len, g.shape[-1])
 
 
+def stored(pool_arr):
+    """A (NB, H, BL, D) pool, the axes the oracle above reads, in the
+    shape the engine stores and the kernel takes: (NB, BL, H * D)."""
+    p = jnp.moveaxis(jnp.asarray(pool_arr), 1, 2)
+    return p.reshape(*p.shape[:2], -1)
+
+
 # ---------------------------------------------------------------------------
 # kernel vs oracle
 # ---------------------------------------------------------------------------
@@ -113,7 +120,9 @@ def test_kernel_matches_gather_oracle(block_len, head_dim, fill):
     pos = jnp.asarray(
         rs.randint(0, fill + 1, size=(s, q)), jnp.int32
     )
-    got = paged_attention(qh, kp, vp, tables, pos, interpret=True)
+    got = paged_attention(
+        qh, stored(kp), stored(vp), tables, pos, interpret=True
+    )
     want = cache_attend(
         qh,
         oracle_gather(kp, tables, mb * block_len),
@@ -138,7 +147,7 @@ def test_trash_block_garbage_never_moves_the_output():
     tables = jnp.asarray(1 + np.arange(s * mb).reshape(s, mb), jnp.int32)
     pos = jnp.asarray([[5], [9]], jnp.int32)
     base = paged_attention(
-        q, jnp.asarray(kp), jnp.asarray(vp), tables, pos, interpret=True
+        q, stored(kp), stored(vp), tables, pos, interpret=True
     )
     kp2, vp2 = kp.copy(), vp.copy()
     kp2[0], vp2[0] = 1e9, -1e9              # the trash block
@@ -149,7 +158,7 @@ def test_trash_block_garbage_never_moves_the_output():
             kp2[1 + row * mb + b, :, lo:] = 7e8
             vp2[1 + row * mb + b, :, lo:] = -7e8
     poisoned = paged_attention(
-        q, jnp.asarray(kp2), jnp.asarray(vp2), tables, pos, interpret=True
+        q, stored(kp2), stored(vp2), tables, pos, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(base), np.asarray(poisoned))
 
@@ -174,7 +183,8 @@ def test_overlay_matches_dense_overlay_oracle():
         [[1, 1, 1, 0], [1, 1, 1, 1], [1, 0, 0, 0]], bool
     )
     got = paged_attention_overlay(
-        qh, kp, vp, tables, pos, ck, cv, valid, interpret=True
+        qh, stored(kp), stored(vp), tables, pos, ck, cv, valid,
+        interpret=True,
     )
     sidx = jnp.arange(s)[:, None]
     gk = oracle_gather(kp, tables, mb * bl).at[sidx, :, pos].set(

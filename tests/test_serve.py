@@ -418,6 +418,53 @@ def test_engine_under_tensor_parallel_matches_single_device():
     assert tp == plain
 
 
+@pytest.mark.parametrize("boundary", ["export_slot", "export_blocks"])
+def test_boundary_formats_keep_the_wire_shape(boundary):
+    """The pools are stored (n_blocks, block_len, H * D); what LEAVES
+    an engine keeps the fleet's wire format, (L, n, H, BL, D), holding
+    the values the gathered view holds — and a slot that left one
+    engine in that format decodes the same tokens in another."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompt = np.asarray([3, 1, 4, 1, 5, 9, 2, 6, 5], np.int32)
+    ec = EngineConfig(slots=3, kv_block_len=8, max_prefill_chunk=4)
+    ea = Engine(params, cfg, ec)
+    h, d, bl = cfg.n_heads, cfg.head_dim, ec.kv_block_len
+    assert (
+        ea.state["k"][0].shape
+        == ea.pool.array_shape(h, d)
+        == (ea.pool.n_blocks, bl, h * d)
+    )
+    ea.admit(1, len(prompt) + 10)
+    for c0 in range(0, len(prompt), 4):
+        last = ea.prefill_chunk(1, prompt[c0:c0 + 4], c0)
+    ea.activate(1, last, len(prompt), seed=0)
+    for _ in range(3):
+        ea.decode()
+    blocks = ea._slot_blocks[1]
+    payload = ea.export_slot(1)
+    if boundary == "export_blocks":
+        k, v = ea.export_blocks(blocks)
+        payload = {**payload, "k": k, "v": v}
+    n = len(blocks)
+    assert n == 3
+    assert payload["k"].shape == (cfg.n_layers, n, h, bl, d)
+    assert payload["v"].shape == (cfg.n_layers, n, h, bl, d)
+    for i in range(cfg.n_layers):
+        for name in ("k", "v"):
+            view = np.asarray(ea._gather(
+                ea.state[name][i], ea.state["tables"][1:2]
+            )[0])                                   # (H, CL, D)
+            want = np.moveaxis(view.reshape(h, -1, bl, d), 1, 0)[:n]
+            np.testing.assert_array_equal(payload[name][i], want)
+    ref = [int(np.asarray(ea.decode())[1]) for _ in range(5)]
+    eb = Engine(params, cfg, ec)
+    eb.admit(0, 16)  # occupy: the import's block ids must differ
+    eb.import_slot(2, payload)
+    got = [int(np.asarray(eb.decode())[2]) for _ in range(5)]
+    assert got == ref
+
+
 def test_serving_kv_shardings_fallback():
     from jax.sharding import Mesh
 
