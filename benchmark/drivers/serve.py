@@ -66,7 +66,15 @@ class Driver:
 
     # -- set-up ---------------------------------------------------------
 
-    def build(self):
+    # The served model is the three methods that follow (the reference
+    # counts as one): a second served LM is a subclass in a file of its
+    # own that overrides them, and the closed loop below stays as it is.
+
+    def make_engine(self) -> None:
+        """Configuration and traffic -> the program's model configuration
+        (``self.mcfg``: the loop reads its ``vocab`` and ``max_len``),
+        the parameters, ONE ``Engine`` (``self.engine``) and its
+        ``Scheduler`` (``self.sched``)."""
         from singa_tpu.models.transformer import TransformerConfig
         from singa_tpu.serve import Engine, EngineConfig, Scheduler
 
@@ -75,16 +83,38 @@ class Driver:
             vocab=c["vocab_size"], d_model=c["n_embd"], n_heads=c["n_head"],
             n_layers=c["n_layer"], d_ff=c["n_inner"], max_len=c["n_positions"],
         )
-        params = weights.make(ref_lm.lm_specs(c), self.seed)
+        params = weights.make(self.reference_specs(), self.seed)
         self.engine = Engine(params, self.mcfg, EngineConfig(
             slots=t["slots"], kv_block_len=t["kv_block_len"],
             kv_blocks=t["kv_blocks"], max_prefill_chunk=t["max_prefill_chunk"],
         ))
         self.sched = Scheduler(self.engine)
+
+    def token_fwd_flops(self, position: int) -> float:
+        """Forward FLOPs of one token that reads ``position`` cached
+        positions (its own among them)."""
+        return flops.lm_token_fwd_flops(self.config, position)
+
+    def reference_specs(self) -> dict:
+        """The parameters' names, shapes and how they are drawn: what
+        ``weights.make`` turns into the served weights, and again into
+        the reference's."""
+        return ref_lm.lm_specs(self.config)
+
+    def reference_forward(self, params, seq, arith: str = "float32"):
+        """The plain reference: tokens (S,) -> logits (S, vocab), row t
+        scoring the token at t + 1. ``arith`` below float32 is the
+        control."""
+        return ref_lm.forward(params, seq, self.config, arith)
+
+    def build(self):
+        self.make_engine()
         self.tick_attrs: dict = {}
         if self.traced:
             self._wrap_engine()
-        self.requests = traffic_gen.requests(t, c["vocab_size"], self.seed)
+        self.requests = traffic_gen.requests(
+            self.traffic, self.mcfg.vocab, self.seed
+        )
         self.next_request = 0
         #: rid -> [tokens seen, stamp of the last one]
         self.seen: dict[int, list] = {}
@@ -110,8 +140,7 @@ class Driver:
                 n = len(tokens)
                 # positions pos0+1 .. pos0+n are read by the chunk's rows
                 self.flops_done += sum(
-                    flops.lm_token_fwd_flops(self.config, pos0 + i + 1)
-                    for i in range(n)
+                    self.token_fwd_flops(pos0 + i + 1) for i in range(n)
                 )
             return out
 
@@ -156,8 +185,8 @@ class Driver:
                     # each decoded token read the cache up to its own
                     # position
                     for i in range(max(seen[0], 1), len(req.tokens)):
-                        self.flops_done += flops.lm_token_fwd_flops(
-                            self.config, len(req.prompt) + i
+                        self.flops_done += self.token_fwd_flops(
+                            len(req.prompt) + i
                         )
             seen[0], seen[1] = len(req.tokens), now
         for req in sched.finished[n_done:]:
@@ -248,16 +277,15 @@ class Driver:
         import jax
         import jax.numpy as jnp
 
-        cfg = self.config
-        params = weights.make(ref_lm.lm_specs(cfg), self.seed)
-        size = cfg["n_positions"]
+        params = weights.make(self.reference_specs(), self.seed)
+        size = self.mcfg.max_len
 
         @jax.jit
         def gaps(params, seq, served):
-            logits = ref_lm.forward(params, seq, cfg)
+            logits = self.reference_forward(params, seq)
             if arith is not None:
                 served = jnp.argmax(
-                    ref_lm.forward(params, seq, cfg, arith), axis=-1
+                    self.reference_forward(params, seq, arith), axis=-1
                 )
             best = jnp.max(logits, axis=-1)
             got = jnp.take_along_axis(logits, served[:, None], axis=-1)[:, 0]

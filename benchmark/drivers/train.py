@@ -146,6 +146,13 @@ class Driver:
         from singa_tpu.data.loader import write_records
         from singa_tpu.trainer import Trainer
 
+        if "generator" not in self.config:
+            raise RuntimeError(
+                f"driver train: configuration {self.config.get('name')!r} "
+                f"names no \"generator\" (benchmark/models/<generator>.py, "
+                f"which builds its layer list); a configuration that is "
+                f"only served needs none"
+            )
         shard = os.path.join(self.work, "shard")
         self.records = make_records(self.config, self.traffic, self.seed)
         write_records(shard, *self.records)

@@ -42,6 +42,7 @@ if ROOT not in sys.path:
 BENCH_FILE = os.path.join(ROOT, "BENCHMARK.json")
 TRAFFIC_DIR = os.path.join(HERE, "traffic")
 LIMITS_DIR = os.path.join(HERE, "limits")
+METRICS_DIR = os.path.join(HERE, "metrics")
 
 #: seconds of the window that a ``--trace 1`` run keeps under the
 #: profiler unless the traffic file says otherwise
@@ -93,24 +94,30 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
     return bench, cell, config, traffic
 
 
-def metrics_of(bench: dict, kind: str, cell: str, reported: set) -> list:
+def metrics_of(bench: dict, kind: str, cell: str) -> list:
     """The ``kind`` ("end_to_end" / "per_layer") entries this cell
-    reports: those that list it, and those that list no cells and whose
-    end-to-end metric (``moves``) the cell reports."""
+    reports: those that list it. An end-to-end metric that lists no
+    cells (``setup_s``) is every cell's; a per-layer metric always names
+    its cells."""
     out = []
     for m in bench[kind]:
         if "workloads" in m:
             if cell in m["workloads"]:
                 out.append(m)
-        elif kind == "end_to_end" or m["moves"] in reported:
+        elif kind == "end_to_end":
             out.append(m)
+        else:
+            raise SystemExit(
+                f"benchmark: per-layer metric {m['name']!r} lists no "
+                f"workloads in BENCHMARK.json"
+            )
     return out
 
 
 def load_reader(name: str):
     """``metrics/<name>.py`` -> its ``read(run)``. Names hold dots, so
     the file is loaded by path."""
-    path = os.path.join(HERE, "metrics", f"{name}.py")
+    path = os.path.join(METRICS_DIR, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
         "benchmark_metric_" + name.replace(".", "_").replace("-", "_"), path
     )
@@ -261,7 +268,7 @@ def run(argv=None) -> dict:
         "driver": driver,
     }
     out_metrics = {}
-    for m in metrics_of(bench, kind, cell["name"], set(end_to_end)):
+    for m in metrics_of(bench, kind, cell["name"]):
         value = (
             load_reader(m["name"])(run_view) if args.trace
             else end_to_end[m["name"]]

@@ -8,8 +8,9 @@
 - percentile, step-time and MFU arithmetic on hand-made inputs;
 - the trace reduction on a hand-made trace and on a cut of a trace
   recorded on the chip (``data/trace_resnet_v5e.json``);
-- ``run.py`` end to end for both drivers with the device gate steered
-  from here, both ``--trace`` modes;
+- ``run.py`` end to end for every cell of BENCHMARK.json with the device
+  gate steered from ``conftest.py``, both ``--trace`` modes, at the tiny
+  sizes kept as data under ``tiny/`` (one cell built a test);
 - the controls: the output check FAILS for the reference computed in a
   lower precision, and for each fault planted under the timed path (a
   step that returns its state unchanged, half of the batch left out, a
@@ -105,9 +106,12 @@ def test_every_name_finds_its_file(bench):
         assert c["file"].startswith("benchmark/")
         cfg = load(ROOT, c["file"])
         assert cfg["name"] == c["name"]
-        assert os.path.exists(
-            os.path.join(BENCH, "models", cfg["generator"] + ".py")
-        )
+        # a configuration that trains through the conf trainer names the
+        # generator of its layer list; one that is only served has none
+        if "generator" in cfg:
+            assert os.path.exists(
+                os.path.join(BENCH, "models", cfg["generator"] + ".py")
+            )
     configs = {c["name"] for c in bench["configs"]}
     used = set()
     for cell in bench["workloads"]:
@@ -136,11 +140,10 @@ def test_each_cell_reports_what_it_must(bench):
         assert set(m.get("workloads", ())) <= cells
     for cell in cells:
         mine = {
-            m["name"]
-            for m in harness.metrics_of(bench, "end_to_end", cell, set())
+            m["name"] for m in harness.metrics_of(bench, "end_to_end", cell)
         }
         assert "setup_s" in mine and len(mine) >= 2
-        layer = harness.metrics_of(bench, "per_layer", cell, mine)
+        layer = harness.metrics_of(bench, "per_layer", cell)
         assert layer
         for m in layer:
             # a per-layer metric moves an end-to-end metric of this cell
@@ -148,6 +151,23 @@ def test_each_cell_reports_what_it_must(bench):
         assert any("mfu" in m["name"].split(".")[0].split("_") for m in layer)
     four = sum(c["chips"] == 4 for c in bench["workloads"])
     assert four <= max(1, len(cells) // 4)
+
+
+def test_every_per_layer_metric_names_its_cells(bench):
+    """No per-layer metric is handed to a cell by what it ``moves``: a
+    cell's driver feeds the readers it is listed for and no others, so
+    the rehearsal's set of printed names stays an equality when a cell
+    is added."""
+    from benchmark import run as harness
+
+    for m in bench["per_layer"]:
+        assert m.get("workloads"), m["name"]
+    unlisted = json.loads(json.dumps(bench))
+    del unlisted["per_layer"][0]["workloads"]
+    with pytest.raises(SystemExit, match="lists no workloads"):
+        harness.metrics_of(
+            unlisted, "per_layer", bench["workloads"][0]["name"]
+        )
 
 
 # ---------------------------------------------------------------------
@@ -402,132 +422,14 @@ def test_trace_reduction_recorded():
 # run.py end to end, tiny, gate steered from here
 # ---------------------------------------------------------------------
 
-TINY_RESNET = dict(blocks=[1], widths=[8], stem_width=8, classes=10, crop=32)
-TINY_GPT2 = dict(n_embd=32, n_layer=1, n_head=2, n_inner=64, vocab_size=300,
-                 initializer_range=0.3,
-                 n_positions=64)
-TINY_TRAFFIC = {
-    # fewer records than the checked steps read: the rows go round, as
-    # the cell's own nine steps go round its four batches
-    "imagenet_b256": {"driver": "train", "batch": 8, "records": 16,
-                      "chunk_steps": 2, "trace_seconds": 0.3},
-    "tokens_b4_s1024": {"driver": "train", "batch": 2, "seq_len": 16,
-                        "records": 4, "chunk_steps": 2, "trace_seconds": 0.3},
-    "closed_c32": {
-        "driver": "serve", "callers": 4, "slots": 4,
-        "kv_block_len": 8, "kv_blocks": 0, "max_prefill_chunk": 16,
-        "prompt_len": {"median": 12, "sigma": 0.7, "min": 4, "max": 40},
-        "output_len": {"median": 8, "sigma": 0.7, "min": 2, "max": 20},
-        "pool": 16, "shape_seed": 1, "greedy": True, "check_requests": 3,
-        "trace_seconds": 0.3,
-    },
-}
-#: float32 compute on the CPU follows the reference to rounding; the
-#: limits here stand well above that and well below any fault
-TINY_LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-4, "change_gap": 1e-4,
-               "grad_gap_median": 1e-4, "change_gap_median": 1e-4,
-               "grad_gap_matrices": 1e-4, "change_gap_matrices": 1e-4,
-               "logit_gap": 1e-4}
-
-
-@pytest.fixture(scope="module")
-def compile_cache(tmp_path_factory):
-    """One compile cache for the module: the tiny programs compile once."""
-    return str(tmp_path_factory.mktemp("cc"))
-
-
-@pytest.fixture()
-def tiny(tmp_path, monkeypatch, bench, compile_cache):
-    """A tiny copy of the benchmark's data files, the harness pointed
-    at it, and the look for a chip steered to the CPU."""
-    import jax
-
-    from benchmark import flops
-    from benchmark import run as harness
-    from benchmark import trace_reduce
-
-    # the rehearsal's device is a CPU, which has no peak on record (and
-    # must have none): the share it prints here is plumbing, not a number
-    monkeypatch.setattr(flops, "peak_flops", lambda kind: 197e12)
-    tiny_bench = json.loads(json.dumps(bench))
-    for entry in tiny_bench["configs"]:
-        cfg = load(ROOT, entry["file"])
-        cfg.update(TINY_RESNET if cfg["kind"] == "image" else TINY_GPT2)
-        cfg["compute_dtype"] = "float32"
-        path = tmp_path / f"{entry['name']}.json"
-        path.write_text(json.dumps(cfg))
-        entry["file"] = str(path)
-    (tmp_path / "traffic").mkdir()
-    (tmp_path / "limits").mkdir()
-    for cell in tiny_bench["workloads"]:
-        (tmp_path / "traffic" / f"{cell['traffic']}.json").write_text(
-            json.dumps(TINY_TRAFFIC[cell["traffic"]])
-        )
-        (tmp_path / "limits" / f"{cell['name']}.json").write_text(
-            json.dumps({
-                k: TINY_LIMITS[k]
-                for k in load(BENCH, "limits", cell["name"] + ".json")
-            })
-        )
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(tiny_bench))
-    monkeypatch.setattr(harness, "BENCH_FILE", str(tmp_path / "BENCHMARK.json"))
-    monkeypatch.setattr(harness, "TRAFFIC_DIR", str(tmp_path / "traffic"))
-    monkeypatch.setattr(harness, "LIMITS_DIR", str(tmp_path / "limits"))
-    monkeypatch.setattr(harness, "ROOT", str(tmp_path))
-    monkeypatch.setattr(
-        harness, "require_devices", lambda chips: jax.devices()[:chips]
-    )
-    # the CPU has no device plane: the reduction is fed the recorded cut
-    recorded = load(HERE, "data", "trace_resnet_v5e.json")
-    monkeypatch.setattr(trace_reduce, "load_xplane", lambda path: recorded)
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", compile_cache)
-    return harness, tiny_bench
-
-
 def cell_names():
     return [c["name"] for c in load(ROOT, "BENCHMARK.json")["workloads"]]
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("cell", cell_names())
-def test_run_end_to_end(tiny, cell, trace, capsys):
-    harness, tiny_bench = tiny
-    rc = harness.main([
-        "--workload", cell, "--seed", str(2**31 + 11), "--seconds", "0.6",
-        "--trace", str(trace),
-    ])
-    assert rc == 0
-    out = capsys.readouterr()
-    last = json.loads(out.out.strip().splitlines()[-1])
-    assert list(last)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
-    assert list(last)[-1] == "compared"
-    assert last["correct"] is True, last["compared"]
-    assert last["attempted"] > 0 and last["failed"] == 0
-    assert last["counters"]["window_compiles"] == 0
-    assert set(last["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
-    e2e = {
-        m["name"]
-        for m in harness.metrics_of(tiny_bench, "end_to_end", cell, set())
-    }
-    if trace:
-        want = {
-            m["name"]
-            for m in harness.metrics_of(tiny_bench, "per_layer", cell, e2e)
-        }
-        assert set(last["device"]) >= {"busy_s", "window_s"}
-        assert last["device"]["busy_s"] > 0
-        assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        want = e2e
-    assert set(last["metrics"]) == want
-    units = {
-        m["name"]: m["unit"]
-        for m in tiny_bench["end_to_end"] + tiny_bench["per_layer"]
-    }
-    for name, m in last["metrics"].items():
-        assert m["unit"] == units[name] and m["value"] > 0, name
-    for name, c in last["compared"].items():
-        assert f"compared {name}: value" in out.err
+def test_run_end_to_end(rehearse, cell, trace):
+    rehearse(cell, trace)
 
 
 def test_no_tpu_no_result(capsys):
@@ -545,36 +447,33 @@ def test_no_tpu_no_result(capsys):
 # ---------------------------------------------------------------------
 
 
-def train_driver(tmp_path, kind="tokens"):
-    """The train driver at a tiny size, comparing the numbers that the
-    shipped training cell compares (``limits/resnet50_train.json``)."""
+def train_driver(tiny_files, tmp_path, config="gpt2_medium",
+                 traffic="tokens_b4_s1024"):
+    """The train driver at the rehearsal's tiny sizes, comparing the
+    numbers that the shipped training cell compares
+    (``limits/resnet50_train.json``)."""
     import jax
 
     from benchmark import run as harness
     from benchmark.drivers import train
 
-    if kind == "tokens":
-        cfg = load(BENCH, "configs", "gpt2_medium.json") | TINY_GPT2
-        traffic = TINY_TRAFFIC["tokens_b4_s1024"]
-    else:
-        cfg = load(BENCH, "configs", "resnet50.json") | TINY_RESNET
-        traffic = TINY_TRAFFIC["imagenet_b256"]
-    cfg["compute_dtype"] = "float32"
-    limits = {
-        k: TINY_LIMITS[k] for k in load(BENCH, "limits", "resnet50_train.json")
-    }
+    files = tiny_files()
     return train.Driver(
-        config=cfg, traffic=traffic, limits=limits, seed=7,
+        config=files.config(config), traffic=files.traffic(traffic),
+        limits=files.limits("resnet50_train"), seed=7,
         devices=jax.devices()[:1], work=str(tmp_path),
         spans=harness.Spans(False),
     )
 
 
-@pytest.mark.parametrize("kind,ariths", [
-    ("tokens", ("bfloat16", "float8")), ("image", ("float8", "bfloat16_all")),
+@pytest.mark.parametrize("config,traffic,ariths", [
+    pytest.param("gpt2_medium", "tokens_b4_s1024", ("bfloat16", "float8"),
+                 id="tokens-ariths0"),
+    pytest.param("resnet50", "imagenet_b256", ("float8", "bfloat16_all"),
+                 id="image-ariths1"),
 ])
 def test_training_check_passes_then_fails_lower_precision(
-    tmp_path, kind, ariths
+    tiny_files, tmp_path, config, traffic, ariths
 ):
     """The program passes the check as a run makes it; the reference in
     a lower precision, put in its place, fails it on the shipped keys,
@@ -582,7 +481,7 @@ def test_training_check_passes_then_fails_lower_precision(
     from benchmark import run as harness
     from benchmark.drivers import train
 
-    d = train_driver(tmp_path, kind)
+    d = train_driver(tiny_files, tmp_path, config, traffic)
     d.program = d.first_steps(d.build())
     d.release()
     assert harness.passes(d.check())
@@ -603,7 +502,9 @@ def test_training_check_passes_then_fails_lower_precision(
 @pytest.mark.parametrize(
     "fault", ["state_unchanged", "later_steps_unchanged", "half_batch"]
 )
-def test_training_fault_under_the_timed_path(tmp_path, monkeypatch, fault):
+def test_training_fault_under_the_timed_path(
+    tiny_files, tmp_path, monkeypatch, fault
+):
     """The rest of a run with the timed path broken underneath.
     ``later_steps_unchanged`` exists only in the window's chunk program:
     step 0 (the one-step program) and the chunk's first step are sound,
@@ -635,7 +536,7 @@ def test_training_fault_under_the_timed_path(tmp_path, monkeypatch, fault):
             )
             return *kept, new[3]
     monkeypatch.setattr(Trainer, name, broken)
-    d = train_driver(tmp_path)
+    d = train_driver(tiny_files, tmp_path)
     d.setup()
     d.window(0.3)
     assert d.attempted_failed()[1] == 0  # the window itself sees nothing
@@ -645,33 +546,35 @@ def test_training_fault_under_the_timed_path(tmp_path, monkeypatch, fault):
     assert all(math.isfinite(c["value"]) for c in compared.values())
 
 
-def serve_driver(tmp_path):
+def serve_driver(tiny_files, tmp_path):
     import jax
 
     from benchmark import run as harness
     from benchmark.drivers import serve
 
-    cfg = load(BENCH, "configs", "gpt2_medium.json") | TINY_GPT2
+    files = tiny_files()
     return serve.Driver(
-        config=cfg, traffic=TINY_TRAFFIC["closed_c32"],
-        limits={"logit_gap": TINY_LIMITS["logit_gap"]}, seed=9, devices=jax.devices()[:1], work=str(tmp_path),
+        config=files.config("gpt2_medium"),
+        traffic=files.traffic("closed_c32"),
+        limits=files.limits("gpt2_medium_serve_closed"), seed=9,
+        devices=jax.devices()[:1], work=str(tmp_path),
         spans=harness.Spans(False),
     )
 
 
-def test_serving_check_passes_then_fails_lower_precision(tmp_path):
+def test_serving_check_passes_then_fails_lower_precision(tiny_files, tmp_path):
     from benchmark import run as harness
 
-    d = serve_driver(tmp_path)
+    d = serve_driver(tiny_files, tmp_path)
     d.setup()
     d.window(0.6)
     d.release()
     assert sum(len(t) for _, t in d.sample) >= 10
     assert harness.passes(d.check())
-    assert d.logit_gaps(d.sample, "float8") > TINY_LIMITS["logit_gap"]
+    assert d.logit_gaps(d.sample, "float8") > d.limits["logit_gap"]
 
 
-def test_serving_fault_token_altered(tmp_path, monkeypatch):
+def test_serving_fault_token_altered(tiny_files, tmp_path, monkeypatch):
     """A token altered where it is produced: the decode program's
     output shifted by one id on every live slot."""
     import jax.numpy as jnp
@@ -686,7 +589,7 @@ def test_serving_fault_token_altered(tmp_path, monkeypatch):
         return jnp.where(out >= 0, (out + 1) % self.cfg.vocab, out)
 
     monkeypatch.setattr(Engine, "decode", altered)
-    d = serve_driver(tmp_path)
+    d = serve_driver(tiny_files, tmp_path)
     d.setup()
     d.window(0.6)
     d.release()

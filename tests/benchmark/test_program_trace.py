@@ -5,7 +5,7 @@
   counted; a gap is booked to the innermost span; a span's self time
   leaves out what its children cover;
 - the same reductions on two cuts recorded on the chip by PR 24
-  (``data/scopes_serve_v5e.json``, ``data/scopes_resnet_v5e.json``);
+  (``data/scopes_<cell>.json``, found by the cell's name);
 - each reader on a hand-made run and on an empty one (None, never 0).
 """
 
@@ -202,10 +202,18 @@ def recorded(name):
 
 @pytest.mark.parametrize("name,program,scopes,unscoped_share", [
     # serving: the relayout copies of the whole pools at the programs'
-    # entry and exit carry an argument's name or none (PERF.md section 5)
-    ("scopes_serve_v5e.json", "jit__decode",
-     {"gather_kv", "kv_write", "cache_attend", "qkv", "mlp"}, 0.75),
-    ("scopes_resnet_v5e.json", "jit_chunk_fn", set(), 0.05),
+    # entry and exit carry an argument's name or none (PERF.md section 5).
+    # The cuts were renamed to their cells' names, bytes unchanged (PR
+    # 26); the ids are the ones the tests have had since PR 24
+    pytest.param(
+        "scopes_gpt2_medium_serve_closed.json", "jit__decode",
+        {"gather_kv", "kv_write", "cache_attend", "qkv", "mlp"}, 0.75,
+        id="scopes_serve_v5e.json-jit__decode-scopes0-0.75",
+    ),
+    pytest.param(
+        "scopes_resnet50_train.json", "jit_chunk_fn", set(), 0.05,
+        id="scopes_resnet_v5e.json-jit_chunk_fn-scopes1-0.05",
+    ),
 ])
 def test_reductions_on_recorded_cuts(name, program, scopes, unscoped_share):
     trace = recorded(name)
