@@ -46,38 +46,115 @@ class TransformerConfig:
     #: expert parallelism. The load-balancing aux joins lm_loss.
     moe_experts: int = 0
     moe_aux_weight: float = 0.01
+    # -- the block's vocabulary beyond GPT-2's. Every default leaves the
+    # model above bit for bit; ONE ``_block_apply`` lowers them all.
+    #: "layernorm" (scale and bias) | "rmsnorm" (scale alone, float32)
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    #: "learned" (an ``embed/pos`` table) | "rope" (rotate-half on q and
+    #: k at each token's own position; no table, so no length to size)
+    pos: str = "learned"
+    rope_theta: float = 10000.0
+    #: K/V heads; 0 = as many as query heads. Query head j reads K/V
+    #: head ``j // (n_heads // n_kv_heads)``.
+    n_kv_heads: int = 0
+    #: width of a head; 0 = ``d_model // n_heads``
+    head_dim: int = 0
+    #: RMSNorm over each head's ``head_dim`` on q and k, before rotation
+    qk_norm: bool = False
+    #: False = a head of its own (``head/out``) instead of ``embed/tok.T``
+    tied_head: bool = True
+    #: > 0: every token goes to its ``moe_top_k`` best experts of a
+    #: softmax over all ``moe_experts``, weights renormalised, SwiGLU
+    #: experts ``moe_d_ff`` wide, no capacity and no dropped token
+    #: (parallel/moe.py ``moe_topk_ffn``). 0 = the Switch top-1 layer.
+    moe_top_k: int = 0
+    moe_d_ff: int = 0
+    #: > 0: generation by diffusion over blocks of this many positions
+    #: (serve/engine.py ``_block_step``): a query sees every position up
+    #: to the END of its own block, and ``mask_id`` stands where a
+    #: position is still masked.
+    diffusion_block: int = 0
+    mask_id: int = 0
+
+    def __post_init__(self):
+        if not self.head_dim:
+            assert self.d_model % self.n_heads == 0
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if not self.n_kv_heads:
+            object.__setattr__(self, "n_kv_heads", self.n_heads)
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_kv_heads {self.n_kv_heads} does not divide n_heads "
+                f"{self.n_heads}"
+            )
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm {self.norm!r}: layernorm or rmsnorm")
+        if self.pos not in ("learned", "rope"):
+            raise ValueError(f"pos {self.pos!r}: learned or rope")
+        if self.moe_top_k and not (
+            0 < self.moe_top_k <= self.moe_experts and self.moe_d_ff > 0
+        ):
+            raise ValueError(
+                f"moe_top_k {self.moe_top_k} needs moe_experts >= it "
+                f"({self.moe_experts}) and a moe_d_ff ({self.moe_d_ff})"
+            )
 
     @property
-    def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
-        return self.d_model // self.n_heads
+    def qkv_width(self) -> int:
+        """Columns of the packed ``attn/qkv``: q's heads, then k's, v's."""
+        return (self.n_heads + 2 * self.n_kv_heads) * self.head_dim
+
+    @property
+    def gqa(self) -> bool:
+        return self.n_kv_heads != self.n_heads
 
 
 def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
-    """Flat name-keyed param pytree; scaled-normal init."""
+    """Flat name-keyed param pytree; scaled-normal init. The names
+    follow the config's fields: no ``embed/pos`` under rotary positions,
+    no norm bias under RMSNorm, ``attn/q_norm`` / ``attn/k_norm`` with
+    QK-norm, ``head/out`` for an untied head, and the expert layer's own
+    tree (parallel/moe.py) under ``moe/``."""
     params: dict[str, jnp.ndarray] = {}
+    bias = cfg.norm == "layernorm"
 
     def norm(key, shape, scale):
         return scale * jax.random.normal(key, shape, dtype=jnp.float32)
 
+    def norm_params(name, width):
+        params[f"{name}/scale"] = jnp.ones((width,))
+        if bias:
+            params[f"{name}/bias"] = jnp.zeros((width,))
+
     keys = iter(jax.random.split(rng, 2 + 4 * cfg.n_layers))
     params["embed/tok"] = norm(next(keys), (cfg.vocab, cfg.d_model), 0.02)
-    params["embed/pos"] = norm(next(keys), (cfg.max_len, cfg.d_model), 0.02)
+    pos_key = next(keys)
+    if cfg.pos == "learned":
+        params["embed/pos"] = norm(pos_key, (cfg.max_len, cfg.d_model), 0.02)
     for i in range(cfg.n_layers):
         p = f"blk{i}"
         d, f = cfg.d_model, cfg.d_ff
-        params[f"{p}/ln1/scale"] = jnp.ones((d,))
-        params[f"{p}/ln1/bias"] = jnp.zeros((d,))
-        params[f"{p}/attn/qkv"] = norm(next(keys), (d, 3 * d), 1 / math.sqrt(d))
-        params[f"{p}/attn/out"] = norm(
-            next(keys), (d, d), 1 / math.sqrt(d * 2 * cfg.n_layers)
+        norm_params(f"{p}/ln1", d)
+        params[f"{p}/attn/qkv"] = norm(
+            next(keys), (d, cfg.qkv_width), 1 / math.sqrt(d)
         )
-        params[f"{p}/ln2/scale"] = jnp.ones((d,))
-        params[f"{p}/ln2/bias"] = jnp.zeros((d,))
+        params[f"{p}/attn/out"] = norm(
+            next(keys), (cfg.n_heads * cfg.head_dim, d),
+            1 / math.sqrt(d * 2 * cfg.n_layers),
+        )
+        if cfg.qk_norm:
+            params[f"{p}/attn/q_norm"] = jnp.ones((cfg.head_dim,))
+            params[f"{p}/attn/k_norm"] = jnp.ones((cfg.head_dim,))
+        norm_params(f"{p}/ln2", d)
         if cfg.moe_experts:
-            from ..parallel.moe import init_moe
+            from ..parallel.moe import init_moe, init_moe_topk
 
-            moe = init_moe(next(keys), d, f, cfg.moe_experts)
+            moe = (
+                init_moe_topk(next(keys), d, cfg.moe_d_ff, cfg.moe_experts)
+                if cfg.moe_top_k
+                else init_moe(next(keys), d, f, cfg.moe_experts)
+            )
             for k, v in moe.items():
                 params[f"{p}/moe/{k}"] = v
         else:
@@ -87,8 +164,11 @@ def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
             params[f"{p}/mlp/down"] = norm(
                 next(keys), (f, d), 1 / math.sqrt(f * 2 * cfg.n_layers)
             )
-    params["ln_f/scale"] = jnp.ones((cfg.d_model,))
-    params["ln_f/bias"] = jnp.zeros((cfg.d_model,))
+    norm_params("ln_f", cfg.d_model)
+    if not cfg.tied_head:
+        params["head/out"] = norm(
+            jax.random.fold_in(rng, 1), (cfg.d_model, cfg.vocab), 0.02
+        )
     return params
 
 
@@ -139,6 +219,59 @@ def _layernorm(x, scale, bias, eps=1e-5):
     return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
+def _rmsnorm(x, scale, eps):
+    """x / rms(x) * scale over the last axis, worked in float32 whatever
+    ``x`` is stored in, and handed back in ``x``'s type."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
+def _norm(params, name, x, cfg):
+    """The config's norm over the model width: ``<name>/scale`` (and
+    ``<name>/bias`` for LayerNorm)."""
+    if cfg.norm == "rmsnorm":
+        return _rmsnorm(x, params[f"{name}/scale"], cfg.norm_eps)
+    return _layernorm(
+        x, params[f"{name}/scale"], params[f"{name}/bias"], cfg.norm_eps
+    )
+
+
+@jax.named_scope("rope")
+def _rope(x, positions, theta):
+    """Rotate-half rotary embedding: ``x`` (B, H, S, D) at ``positions``
+    (B, S). Pair (i, i + D/2) turns by ``pos * theta ** (-2i / D)``;
+    angles, sines and the rotation are float32, the result ``x``'s type."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None, :, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def embed(params, tokens, positions, cfg):
+    """Token embedding, plus the learned position rows where the config
+    has a table (rotary positions enter in the block, on q and k)."""
+    x = params["embed/tok"][tokens]
+    if cfg.pos == "learned":
+        x = x + params["embed/pos"][positions]
+    return x
+
+
+def block_limits(positions, cfg):
+    """The last position each query may see: its own for a causal
+    model; the end of its block under diffusion over blocks (M[i, j] = 0
+    iff ``j // B <= i // B``). Every attention body masks by
+    ``arange(C) <= limit``, so this one number a query is the whole
+    block-causal mask."""
+    b = cfg.diffusion_block
+    return positions // b * b + (b - 1) if b else positions
+
+
 def _attend(q, k, v, cfg: TransformerConfig, mesh):
     if cfg.attn == "ring":
         if mesh is None:
@@ -158,42 +291,77 @@ def _attend(q, k, v, cfg: TransformerConfig, mesh):
 
 
 def _block_apply(params, p, x, attend, cfg, mesh=None,
-                 moe_capacity_factor=None):
+                 moe_capacity_factor=None, positions=None, valid=None):
     """One transformer block with a pluggable attention implementation.
 
-    ``attend(q, k, v) -> (o, extra)`` receives/returns (B, H, S, D);
-    ``extra`` passes through (K/V caches for decode, None otherwise).
+    ``attend(q, k, v) -> (o, extra)`` receives q (B, H, S, D) and k, v
+    (B, n_kv_heads, S, D) and returns (B, H, S, D); ``extra`` passes
+    through (K/V caches for decode, None otherwise).
     The SINGLE definition of block semantics — lm_apply, generate()'s
     prefill, and the KV-cache decode step all run this body, so the
-    train->decode bit-exact parity cannot silently diverge.
-    ``moe_capacity_factor`` overrides the MoE capacity (decode passes E
-    so routing is drop-free; None keeps the training default).
+    train->decode bit-exact parity cannot silently diverge. The config's
+    fields choose among its operations (LayerNorm or RMSNorm, QK-norm,
+    rotary positions, fewer K/V heads, GELU MLP, Switch or top-k
+    experts); there is no second body.
+    ``moe_capacity_factor`` overrides the Switch MoE's capacity (decode
+    passes E so routing is drop-free; None keeps the training default).
+    ``positions`` (B, S) are the tokens' own positions, read by rotary
+    embedding alone. ``valid`` (B, S) marks the tokens that count in the
+    top-k expert layer's two counters (None = all).
+
+    -> (x, aux, extra): ``aux`` is the Switch layer's load-balancing
+    loss (0.0 for a dense FFN), or for the top-k layer its counters,
+    int32 ``[experts hit, most tokens one expert took]``.
 
     Every operation is named: the block's ``p`` (``blk3``) and inside it
-    ``ln1``, ``qkv``, ``attend`` (whatever implements it), ``attn_out``,
-    ``ln2``, ``mlp`` or ``moe``. A trace is read by these names."""
+    ``ln1``, ``qkv`` (holding ``qk_norm`` and ``rope``), ``attend``
+    (whatever implements it), ``attn_out``, ``ln2``, ``mlp`` or ``moe``
+    (the top-k layer: ``route``, ``experts``, ``combine``). A trace is
+    read by these names."""
     b, s, _ = x.shape
     scope = jax.named_scope
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with scope(p):
         with scope("ln1"):
-            h = _layernorm(
-                x, params[f"{p}/ln1/scale"], params[f"{p}/ln1/bias"]
-            )
+            h = _norm(params, f"{p}/ln1", x, cfg)
         with scope("qkv"):
             qkv = h @ params[f"{p}/attn/qkv"]
-            qkv = qkv.reshape(b, s, 3, cfg.n_heads, cfg.head_dim)
-            q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
+            if cfg.gqa:
+                q, k, v = (
+                    jnp.moveaxis(part.reshape(b, s, -1, hd), 2, 1)
+                    for part in jnp.split(
+                        qkv, [hq * hd, (hq + hkv) * hd], axis=-1
+                    )
+                )
+            else:
+                qkv = qkv.reshape(b, s, 3, hq, hd)
+                q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
+            if cfg.qk_norm:
+                with scope("qk_norm"):
+                    q = _rmsnorm(q, params[f"{p}/attn/q_norm"], cfg.norm_eps)
+                    k = _rmsnorm(k, params[f"{p}/attn/k_norm"], cfg.norm_eps)
+            if cfg.pos == "rope":
+                q = _rope(q, positions, cfg.rope_theta)
+                k = _rope(k, positions, cfg.rope_theta)
         with scope("attend"):
             o, extra = attend(q, k, v)
         with scope("attn_out"):
-            o = jnp.moveaxis(o, 1, 2).reshape(b, s, cfg.d_model)
+            o = jnp.moveaxis(o, 1, 2).reshape(b, s, hq * hd)
             x = x + o @ params[f"{p}/attn/out"]
         with scope("ln2"):
-            h = _layernorm(
-                x, params[f"{p}/ln2/scale"], params[f"{p}/ln2/bias"]
-            )
+            h = _norm(params, f"{p}/ln2", x, cfg)
         aux = jnp.float32(0.0)
-        if cfg.moe_experts:
+        if cfg.moe_top_k:
+            from ..parallel.moe import MOE_TOPK_PARAMS, moe_topk_ffn
+
+            with scope("moe"):
+                y, aux = moe_topk_ffn(
+                    h,
+                    {k2: params[f"{p}/moe/{k2}"] for k2 in MOE_TOPK_PARAMS},
+                    cfg.moe_top_k, valid=valid,
+                )
+                x = x + y
+        elif cfg.moe_experts:
             from ..parallel.moe import moe_ffn, moe_ffn_dense
 
             moe_params = {
@@ -219,16 +387,19 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
 
 
 @jax.named_scope("lm_head")
-def lm_head(params: dict, x: jnp.ndarray) -> jnp.ndarray:
-    """Final layernorm + tied-embedding projection — the ONE LM head
-    every forward shares (lm_apply, generate()'s prefill and decode
-    scan, and the serving engine's decode/prefill/verify programs in
-    serve/engine.py). Shared for the same reason ``_block_apply`` is:
-    the speculative verify step's per-position logits must be the SAME
-    head math as the one-token decode tick, so acceptance decisions
-    cannot drift from what sequential decode would have emitted."""
-    xf = _layernorm(x, params["ln_f/scale"], params["ln_f/bias"])
-    return xf @ params["embed/tok"].T
+def lm_head(params: dict, x: jnp.ndarray, cfg) -> jnp.ndarray:
+    """Final norm + projection — the ONE LM head every forward shares
+    (lm_apply, generate()'s prefill and decode scan, and the serving
+    engine's programs in serve/engine.py). Shared for the same reason
+    ``_block_apply`` is: the speculative verify step's per-position
+    logits must be the SAME head math as the one-token decode tick, so
+    acceptance decisions cannot drift from what sequential decode would
+    have emitted. The config's norm; the embedding transposed, or the
+    model's own ``head/out`` where the head is untied; logits are
+    accumulated and returned in float32 whatever the weights' type."""
+    xf = _norm(params, "ln_f", x, cfg)
+    w = params["embed/tok"].T if cfg.tied_head else params["head/out"]
+    return jnp.matmul(xf, w, preferred_element_type=jnp.float32)
 
 
 def lm_apply(
@@ -239,19 +410,31 @@ def lm_apply(
     *,
     return_aux: bool = False,
 ):
-    """tokens (B, S) int32 -> logits (B, S, vocab); causal.
+    """tokens (B, S) int32 -> logits (B, S, vocab); causal, or
+    block-causal where the config has a ``diffusion_block`` (row t then
+    scores the token AT t: feed ``mask_id`` where a position is masked).
 
     With ``return_aux`` also returns the summed MoE load-balancing loss
-    (0.0 for dense-FFN configs)."""
+    (0.0 for dense-FFN and top-k configs)."""
     b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     with jax.named_scope("embed"):
-        x = params["embed/tok"][tokens] + params["embed/pos"][:s]
+        x = embed(params, tokens, slice(0, s), cfg)
     aux_total = jnp.float32(0.0)
-    attend = lambda q, k, v: (_attend(q, k, v, cfg, mesh), None)  # noqa: E731
+    if cfg.gqa or cfg.diffusion_block:
+        # the cache-free side of the serving parity tests: the sequence's
+        # own K and V are the whole cache, each query's limit its mask
+        limits = block_limits(positions, cfg)
+        attend = lambda q, k, v: (cache_attend(q, k, v, limits), None)  # noqa: E731
+    else:
+        attend = lambda q, k, v: (_attend(q, k, v, cfg, mesh), None)  # noqa: E731
     for i in range(cfg.n_layers):
-        x, aux, _ = _block_apply(params, f"blk{i}", x, attend, cfg, mesh)
-        aux_total = aux_total + aux
-    logits = lm_head(params, x)
+        x, aux, _ = _block_apply(
+            params, f"blk{i}", x, attend, cfg, mesh, positions=positions
+        )
+        if not cfg.moe_top_k:
+            aux_total = aux_total + aux
+    logits = lm_head(params, x, cfg)
     if return_aux:
         return logits, aux_total
     return logits
@@ -264,22 +447,43 @@ def cache_attend(q, k_cache, v_cache, positions):
     decode scan here, the paged-KV engine's gathered blocks in
     serve/engine.py, the conf-net decode in serve/conf_decode.py).
 
-    ``q`` (B, H, Q, D) holds queries whose absolute sequence positions
-    are ``positions`` (B, Q); ``k_cache``/``v_cache`` (B, H, C, D) hold
+    ``q`` (B, H, Q, D) holds queries that may see the cache up to and
+    including ``positions`` (B, Q) — a query's own position in a causal
+    model, the end of its block under diffusion over blocks
+    (``block_limits``); ``k_cache``/``v_cache`` (B, Hkv, C, D) hold
     the whole (zero-padded) cache. Cache entries beyond a query's
-    position score -1e30, so their softmax weight underflows to exactly
+    limit score -1e30, so their softmax weight underflows to exactly
     0.0 — the cache tail (and any garbage a paged pool gathers there)
     never moves a bit of the output. Because the math is shared, "paged
     KV == dense cache" parity is bitwise by construction, not tested
-    luck."""
+    luck.
+
+    With fewer K/V heads than query heads, query head j reads K/V head
+    ``j // (H // Hkv)``: the queries are grouped over the K/V heads in
+    the products themselves and the cache is never repeated in memory.
+    Scores and the softmax are float32 whatever the cache's type."""
     scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache) * scale
+    b, h, nq, d = q.shape
+    hkv = k_cache.shape[1]
     mask = (
         jnp.arange(k_cache.shape[2])[None, None, None, :]
         <= positions[:, None, :, None]
     )
-    s = jnp.where(mask, s, -1e30)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v_cache)
+    if h == hkv:
+        s = jnp.einsum(
+            "bhqd,bhkd->bhqk", q, k_cache,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        s = jnp.where(mask, s, -1e30)
+        w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", w, v_cache)
+    qg = q.reshape(b, hkv, h // hkv, nq, d)
+    s = jnp.einsum(
+        "bhgqd,bhkd->bhgqk", qg, k_cache, preferred_element_type=jnp.float32
+    ) * scale
+    s = jnp.where(mask[:, :, None], s, -1e30)
+    w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
+    return jnp.einsum("bhgqk,bhkd->bhgqd", w, v_cache).reshape(b, h, nq, d)
 
 
 def _block_step(params, p, x, k_cache, v_cache, pos, cfg):
@@ -298,9 +502,11 @@ def _block_step(params, p, x, k_cache, v_cache, pos, cfg):
         )
         return cache_attend(q, nk, nv, positions), (nk, nv)
 
+    b, nq, _ = x.shape
     x, _, (nk, nv) = _block_apply(
         params, p, x, attend, cfg,
         moe_capacity_factor=float(max(cfg.moe_experts, 1)),
+        positions=jnp.broadcast_to(pos + jnp.arange(nq)[None, :], (b, nq)),
     )
     return x, nk, nv
 
@@ -367,23 +573,20 @@ def generate(
     # score footprint for long prompts — the serving tier's chunked
     # prefill — and is bitwise chunk-split-invariant: each query attends
     # the full masked cache regardless of which chunk computed it.
-    shape = (b, cfg.n_heads, cfg.max_len, cfg.head_dim)
+    shape = (b, cfg.n_kv_heads, cfg.max_len, cfg.head_dim)
     k_caches = [jnp.zeros(shape) for _ in range(cfg.n_layers)]
     v_caches = [jnp.zeros(shape) for _ in range(cfg.n_layers)]
     x_last = None
     for c0 in range(0, plen, prefill_chunk):
         n = min(prefill_chunk, plen - c0)
-        x = (
-            params["embed/tok"][prompt[:, c0:c0 + n]]
-            + params["embed/pos"][c0:c0 + n]
-        )
+        x = embed(params, prompt[:, c0:c0 + n], jnp.arange(c0, c0 + n), cfg)
         for i in range(cfg.n_layers):
             x, k_caches[i], v_caches[i] = _block_step(
                 params, f"blk{i}", x, k_caches[i], v_caches[i],
                 jnp.int32(c0), cfg,
             )
         x_last = x
-    last_logits = lm_head(params, x_last)[:, -1]
+    last_logits = lm_head(params, x_last, cfg)[:, -1]
 
     def sample(logits, key):
         if temperature <= 0.0:
@@ -398,10 +601,7 @@ def generate(
     # ---- decode: scan over single-token steps ----
     def step(carry, key):
         token, pos, ks, vs = carry
-        x = (
-            params["embed/tok"][token][:, None, :]
-            + params["embed/pos"][pos][None, None, :]
-        )
+        x = embed(params, token, pos, cfg)[:, None, :]
         new_ks, new_vs = [], []
         for i in range(cfg.n_layers):
             x, nk, nv = _block_step(
@@ -409,7 +609,7 @@ def generate(
             )
             new_ks.append(nk)
             new_vs.append(nv)
-        logits = lm_head(params, x)[:, 0]
+        logits = lm_head(params, x, cfg)[:, 0]
         nxt = sample(logits, key)
         return (nxt, pos + 1, new_ks, new_vs), token
 

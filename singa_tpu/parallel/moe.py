@@ -14,6 +14,11 @@ combine is one psum over that axis. Under shard_map each device:
 
 Dropped tokens (over capacity) pass through on the residual path, like
 Switch Transformer. Routing/combine math stays fp32 under bf16 compute.
+
+Beside it, for serving, ``moe_topk_ffn``: top-k of a softmax over all
+experts, renormalised, SwiGLU experts, NO capacity and no dropped token
+(the layer of the Qwen3-MoE lineage). It is one device's layer: it holds
+every expert it routes over.
 """
 
 from __future__ import annotations
@@ -45,6 +50,89 @@ def init_moe(
         "down": (1.0 / np.sqrt(d_ff))
         * jax.random.normal(kd, (n_experts, d_ff, d_model)),
     }
+
+
+#: the top-k layer's parameters: router ``gate`` (D, E); per expert the
+#: SwiGLU gate and up projections ``w_gate`` and ``w_up`` (E, D, F) and
+#: ``w_down`` (E, F, D). Expert-major, as the TPU compiler lays the
+#: three products out: stored (D, E, F) it copies 0.4 GB a matrix into
+#: this order in every pass (read off the compiled text, PERF.md PR 28)
+MOE_TOPK_PARAMS = ("gate", "w_gate", "w_up", "w_down")
+
+
+def init_moe_topk(
+    rng: jax.Array, d_model: int, d_ff: int, n_experts: int
+) -> dict:
+    """Param pytree of ``moe_topk_ffn`` (names: ``MOE_TOPK_PARAMS``)."""
+    kr, kg, ku, kd = jax.random.split(rng, 4)
+    s = 1.0 / np.sqrt(d_model)
+    return {
+        "gate": s * jax.random.normal(kr, (d_model, n_experts)),
+        "w_gate": s * jax.random.normal(kg, (n_experts, d_model, d_ff)),
+        "w_up": s * jax.random.normal(ku, (n_experts, d_model, d_ff)),
+        "w_down": (1.0 / np.sqrt(d_ff))
+        * jax.random.normal(kd, (n_experts, d_ff, d_model)),
+    }
+
+
+def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None):
+    """Drop-free top-k SwiGLU experts: x (B, S, D) ->
+    (y (B, S, D), int32 [experts hit, most tokens one expert took]).
+
+        p = softmax_f32(x Wr) over ALL experts;  T = top-k(p)
+        g_e = p_e / sum_{T} p  for e in T, else 0
+        y = sum_e g_e * ((silu(x Wg_e) * (x Wu_e)) Wd_e)
+
+    HOW: every expert runs on every token and ``g`` (zero outside T)
+    weights the sum — two products (N, D) x (E, D, F) batched over the
+    experts and one contraction over (E, F) jointly, so the (N, E, D)
+    per-expert outputs are never formed. There is no capacity, no sort and no dispatch
+    buffer, so no routing pattern can drop a token or leave a term out,
+    and a skewed router costs what a flat one does. It is the form for
+    a serving pass of a few hundred tokens over many narrow experts:
+    there every expert's weights are read whatever is done (256 tokens x
+    top-8 over 128 experts leave no expert idle), and at N tokens the
+    products cost N FLOPs a weight byte — at N = 256 about the v5e's
+    own ratio of FLOPs to bytes (240), so the idle products ride on the
+    weight reads: 1.80 ms a layer at the published size against 1.64 ms
+    for reading the layer's experts and doing nothing (PERF.md, PR 28).
+    At thousands of tokens a pass a sorted, grouped product is the form
+    to write instead.
+
+    Router, softmax, gates and the SwiGLU are float32; the products take
+    ``x`` and the weights as stored, accumulate in float32 and come out
+    in ``x``'s type. ``valid``
+    (B, S) marks the tokens the two counters count (None = all)."""
+    b, s, d = x.shape
+    n = b * s
+    x2d = x.reshape(n, d)
+    e = params["gate"].shape[1]
+    with jax.named_scope("route"):
+        logits = jnp.matmul(
+            x2d.astype(jnp.float32), params["gate"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)                   # (N, E)
+        top_p, top_e = jax.lax.top_k(probs, top_k)
+        chosen = jnp.zeros((n, e), bool).at[
+            jnp.arange(n)[:, None], top_e
+        ].set(True)
+        gates = jnp.where(chosen, probs, 0.0) / jnp.sum(
+            top_p, axis=-1, keepdims=True
+        )
+        counted = chosen if valid is None else (
+            chosen & valid.reshape(n, 1)
+        )
+        load = jnp.sum(counted, axis=0, dtype=jnp.int32)          # (E,)
+        stats = jnp.stack([jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+    with jax.named_scope("experts"):
+        f32 = jnp.float32
+        a = jnp.einsum("nd,edf->enf", x2d, params["w_gate"]).astype(f32)
+        u = jnp.einsum("nd,edf->enf", x2d, params["w_up"]).astype(f32)
+        h = (jax.nn.silu(a) * u * gates.T[:, :, None]).astype(x.dtype)
+    with jax.named_scope("combine"):
+        y = jnp.einsum("enf,efd->nd", h, params["w_down"])
+    return y.reshape(b, s, d), stats
 
 
 def _route(x2d: jnp.ndarray, gate_w: jnp.ndarray, capacity: int):

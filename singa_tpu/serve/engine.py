@@ -30,6 +30,22 @@ it:
            decode would have written — the pool after any accept/
            reject pattern is bitwise what one-token ticks leave.
 
+  block_step  generation by diffusion over blocks (a model with a
+           ``diffusion_block`` B): ONE donated fixed-shape pass over
+           every live slot's CURRENT block of B positions, ``mask_id``
+           fed where a position is still masked, each query seeing the
+           cache and the whole block (``block_limits``). A slot whose
+           block has masked positions DENOISES: the most confident of
+           them take their greedy tokens for good. A slot whose block
+           has none COMMITS: the block's K and V go to the pool and the
+           slot moves on to a fresh masked block. Verify's discipline:
+           attention reads the gathered views with the block's fresh
+           K/V overlaid, and the pool takes one masked scatter after
+           the forward — to real blocks for committing slots, to the
+           trash block for the rest — so nothing is written before
+           commit. Prefill of such a model is block-causal (the same
+           limits) and yields no token.
+
 All programs run the SAME ``_block_apply``/``cache_attend``/``lm_head``
 body as models/transformer.generate — paged-vs-dense parity AND
 speculative-vs-sequential parity are shared code, not a tolerance.
@@ -75,7 +91,9 @@ import numpy as np
 from ..models.transformer import (
     TransformerConfig,
     _block_apply,
+    block_limits,
     cache_attend,
+    embed,
     lm_head,
 )
 from .kv_pool import BlockAllocator, KVPool, PoolExhausted
@@ -129,6 +147,11 @@ class EngineConfig:
     #: interpreter (plain XLA ops — CPU-safe, GSPMD-shardable; what CI
     #: exercises) elsewhere. True/False pin the form.
     interpret: bool | None = None
+    #: denoising passes a block for a model generated by diffusion over
+    #: blocks (``TransformerConfig.diffusion_block`` B): each pass fixes
+    #: the ``B // block_steps`` most confident masked positions
+    #: (``low_confidence_static``). 0 = B passes, one token each.
+    block_steps: int = 0
 
     @classmethod
     def from_conf(cls, serving, kernels=None) -> "EngineConfig":
@@ -214,6 +237,12 @@ class Engine:
                 raise ValueError(
                     f"kernels {{ paged_attention: fused }}: {reason}"
                 )
+        self._refuse_what_cannot_run(cfg, self.serving, mesh)
+        #: tokens a denoising pass fixes in a block (0: no block steps)
+        self.block_fix = 0
+        if cfg.diffusion_block:
+            steps = self.serving.block_steps or cfg.diffusion_block
+            self.block_fix = cfg.diffusion_block // steps
         self.pool = KVPool.for_model(
             cfg.max_len, self.serving.kv_block_len,
             self.serving.kv_blocks, self.serving.slots,
@@ -239,7 +268,10 @@ class Engine:
         #: into the prefix index is gated on the version still being live
         self._slot_version: dict[int, int] = {}
         s, mb = self.serving.slots, self.pool.max_blocks_per_seq
-        shape = self.pool.array_shape(cfg.n_heads, cfg.head_dim)
+        # a pool row is one token's K (or V) for the K/V heads alone,
+        # in the parameters' own type (float32 parameters: float32 pools)
+        shape = self.pool.array_shape(cfg.n_kv_heads, cfg.head_dim)
+        pool_dtype = params["embed/tok"].dtype
         pool_sh = state_sh = None
         if mesh is not None:
             from ..parallel.shardings import serving_kv_shardings
@@ -259,12 +291,20 @@ class Engine:
             ),
             "tables": put(jnp.zeros((s, mb), jnp.int32), state_sh),
             "k": tuple(
-                put(jnp.zeros(shape), pool_sh) for _ in range(cfg.n_layers)
+                put(jnp.zeros(shape, pool_dtype), pool_sh)
+                for _ in range(cfg.n_layers)
             ),
             "v": tuple(
-                put(jnp.zeros(shape), pool_sh) for _ in range(cfg.n_layers)
+                put(jnp.zeros(shape, pool_dtype), pool_sh)
+                for _ in range(cfg.n_layers)
             ),
         }
+        if cfg.diffusion_block:
+            # per-slot block lanes: the current block's tokens and which
+            # of its positions are still masked (``pos`` is its start)
+            lanes = (s, cfg.diffusion_block)
+            self.state["blk_tok"] = jnp.zeros(lanes, jnp.int32)
+            self.state["blk_masked"] = jnp.ones(lanes, bool)
         #: blocks owned per slot, freed at retire
         self._slot_blocks: dict[int, list[int]] = {}
         #: the admission-time digest chain per slot (register_prefix
@@ -273,6 +313,12 @@ class Engine:
         self._decode_jit = jax.jit(self._decode, donate_argnums=(1,))
         self._prefill_jit = jax.jit(self._prefill, donate_argnums=(1,))
         self._verify_jit = jax.jit(self._verify, donate_argnums=(1,))
+        self._block_step_jit = jax.jit(
+            self._block_step, donate_argnums=(1,)
+        )
+        self._activate_block_jit = jax.jit(
+            self._activate_block_prog, donate_argnums=(0,)
+        )
         # admission-path lane updates fused into one dispatch each —
         # a request admission must not stall live slots' ticks behind a
         # storm of single-element device ops
@@ -301,13 +347,75 @@ class Engine:
             self._install_prog, donate_argnums=(0,)
         )
 
+    @staticmethod
+    def _refuse_what_cannot_run(cfg, serving, mesh) -> None:
+        """What no program here computes for a model with fewer K/V
+        heads than query heads or generated by diffusion over blocks is
+        refused by the field's name, not run wrongly (ROADMAP Queue 2
+        keeps the list)."""
+        why = Engine._beyond_gpt2(cfg)
+        if why is None:
+            return
+        refused = {
+            "speculate (spec_k)": serving.spec_k > 0,
+            "prefix_cache": serving.prefix_cache,
+            "kernels.paged_attention = fused (attend_impl)":
+                serving.attend_impl == "fused",
+            "a tensor-parallel mesh": mesh is not None,
+        }
+        for what, asked in refused.items():
+            if asked:
+                raise ValueError(
+                    f"serving: {what} cannot run for a model with {why}"
+                )
+        b = cfg.diffusion_block
+        if not b:
+            return
+        steps = serving.block_steps or b
+        for name, value in (
+            ("kv_block_len", serving.kv_block_len),
+            ("max_prefill_chunk", serving.max_prefill_chunk),
+            ("max_len", cfg.max_len),
+        ):
+            if value % b:
+                raise ValueError(
+                    f"serving: {name} = {value} must be a multiple of "
+                    f"diffusion_block = {b}"
+                )
+        if b % steps:
+            raise ValueError(
+                f"serving: block_steps = {steps} must divide "
+                f"diffusion_block = {b}"
+            )
+
+    def _refuse_for_slot_state(self, what: str) -> None:
+        """Slot export/import and prefix shipping move pool bytes in the
+        fleet's (L, n, H, BL, D) wire format and lanes that know nothing
+        of a block in flight: refused for the same models."""
+        why = self._beyond_gpt2(self.cfg)
+        if why is not None:
+            raise ValueError(
+                f"serving: {what} cannot run for a model with {why}"
+            )
+
+    @staticmethod
+    def _beyond_gpt2(cfg) -> str | None:
+        """The field (with its value) for which the refusals above
+        hold, None for a model that every path here serves."""
+        if cfg.diffusion_block:
+            return f"diffusion_block = {cfg.diffusion_block}"
+        if cfg.gqa:
+            return f"n_kv_heads = {cfg.n_kv_heads} != n_heads = {cfg.n_heads}"
+        return None
+
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
 
     def _gather(self, pool_arr, tables):
-        """(NB, BL, H*D) pool (the stored shape, serve/kv_pool.py) +
-        (S', MB) tables -> (S', H, CL, D) dense per-sequence cache views
+        """(NB, BL, H*D) pool (the stored shape, serve/kv_pool.py; H the
+        K/V heads) + (S', MB) tables -> (S', H, CL, D) dense
+        per-sequence cache views
         (CL = MB * BL = the dense cache_len): a sequence's blocks are
         consecutive rows of CL tokens, each H*D wide, and the heads come
         out of the row.
@@ -318,7 +426,7 @@ class Engine:
         only effect the attend mask would zero anyway — is skipped."""
         g = pool_arr.at[tables].get(mode="promise_in_bounds")
         g = g.reshape(                            # (S', CL, H, D)
-            g.shape[0], self.pool.cache_len, self.cfg.n_heads, -1
+            g.shape[0], self.pool.cache_len, self.cfg.n_kv_heads, -1
         )
         return jnp.moveaxis(g, 2, 1)
 
@@ -335,7 +443,7 @@ class Engine:
         """Blocks ``row`` of one pool in the shape that leaves the
         engine: (n, H, BL, D), the fleet's wire format."""
         g = pool_arr[row]
-        g = g.reshape(*g.shape[:2], self.cfg.n_heads, -1)
+        g = g.reshape(*g.shape[:2], self.cfg.n_kv_heads, -1)
         return jnp.moveaxis(g, 2, 1)
 
     @staticmethod
@@ -352,6 +460,28 @@ class Engine:
         used to spell the pair out). In a trace its operations are
         ``gather_kv``: what the fused kernel exists to delete."""
         return self._gather(kp, tables), self._gather(vp, tables)
+
+    def _write_targets(self, tables, p_safe, valid):
+        """(S, Q) positions -> each one's (block id, offset) through
+        its slot's table; where ``valid`` is False the block is the
+        trash block."""
+        row_idx = jnp.minimum(
+            p_safe // self.pool.block_len, tables.shape[1] - 1
+        )
+        bid = jnp.take_along_axis(tables, row_idx, axis=1)
+        return jnp.where(valid, bid, 0), p_safe % self.pool.block_len
+
+    @jax.named_scope("gather_kv")
+    def _overlay(self, pool_arr, tables, p_safe, new_shqd):
+        """(S, H, C, D) gathered view of one pool with fresh K (or V)
+        ``new_shqd`` (S, H, Q, D) laid over each slot's columns
+        ``p_safe`` (S, Q) — the pool itself is NOT written (verify's
+        rejected positions and a denoising block's must stay
+        untouched); a query sees what is laid over only as far as its
+        limit lets it."""
+        dense = self._gather(pool_arr, tables)
+        s_idx = jnp.arange(p_safe.shape[0])[:, None]
+        return dense.at[s_idx, :, p_safe].set(jnp.moveaxis(new_shqd, 1, 2))
 
     @jax.named_scope("paged_attention")
     def _paged_attend(self, q, kp, vp, tables, positions):
@@ -392,10 +522,7 @@ class Engine:
         tokens, pos, live = state["tokens"], state["pos"], state["live"]
         mcfg = self.cfg
         with jax.named_scope("embed"):
-            x = (
-                params["embed/tok"][tokens][:, None, :]
-                + params["embed/pos"][pos][:, None, :]
-            )
+            x = embed(params, tokens, pos, mcfg)[:, None, :]
         # each slot's write target: its current block, current offset.
         # Dead lanes route to the trash block explicitly — a slot that
         # is admitted-but-still-prefilling has a REAL table whose first
@@ -428,10 +555,11 @@ class Engine:
             x, _, (kp, vp) = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
+                positions=pos[:, None],
             )
             new_k.append(kp)
             new_v.append(vp)
-        logits = lm_head(params, x)[:, 0]
+        logits = lm_head(params, x, mcfg)[:, 0]
         with jax.named_scope("sample"):
             new_rng, keys = self._split_keys(state)
             nxt = self._sample(logits, keys, state["temp"], live, tokens)
@@ -450,7 +578,11 @@ class Engine:
         [pos0, pos0 + C): writes the chunk's K/V into the slot's blocks
         (padding positions to the trash block) and returns the logits
         at the last VALID position — garbage only where the mask
-        already guarantees it cannot matter."""
+        already guarantees it cannot matter. Each query sees the cache
+        up to ``block_limits`` of its position: itself, or under
+        diffusion over blocks the end of its block — whole blocks a
+        chunk, so the keys it may see are written — and such a model's
+        prefill yields no token (no head is run; 0 comes back)."""
         cfg, mcfg = self.pool, self.cfg
         c = chunk.shape[0]
         p = pos0 + jnp.arange(c)
@@ -459,10 +591,8 @@ class Engine:
         # values are masked, only their indices must stay in range
         p_safe = jnp.minimum(p, mcfg.max_len - 1)
         with jax.named_scope("embed"):
-            x = (
-                params["embed/tok"][chunk]
-                + params["embed/pos"][p_safe]
-            )[None]
+            x = embed(params, chunk, p_safe, mcfg)[None]
+        limits = block_limits(p, mcfg)
         row = state["tables"][slot]
         bid = jnp.where(
             valid,
@@ -486,7 +616,7 @@ class Engine:
                     o = cache_attend(
                         q,
                         *self._gather_kv(kp, vp, row[None]),
-                        p[None],
+                        limits[None],
                     )
                 return o, (kp, vp)
             return attend
@@ -495,12 +625,16 @@ class Engine:
             x, _, (kp, vp) = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
+                positions=p[None], valid=valid[None],
             )
             new_k.append(kp)
             new_v.append(vp)
-        logits = lm_head(params, x)[0]
+        new_state = {**state, "k": tuple(new_k), "v": tuple(new_v)}
+        if mcfg.diffusion_block:
+            return new_state, jnp.float32(0.0)
+        logits = lm_head(params, x, mcfg)[0]
         last = jnp.take(logits, jnp.maximum(n_valid - 1, 0), axis=0)
-        return {**state, "k": tuple(new_k), "v": tuple(new_v)}, last
+        return new_state, last
 
     def _verify(self, params, state, draft, n_draft):
         """The speculative tick: score every live slot's current token
@@ -545,28 +679,12 @@ class Engine:
         valid = live[:, None] & (j <= n_draft[:, None])
         p_safe = jnp.minimum(p, mcfg.max_len - 1)
         with jax.named_scope("embed"):
-            x = params["embed/tok"][seq] + params["embed/pos"][p_safe]
-        row_idx = jnp.minimum(
-            p_safe // cfg.block_len, state["tables"].shape[1] - 1
-        )
-        bid = jnp.take_along_axis(state["tables"], row_idx, axis=1)
-        bid = jnp.where(valid, bid, 0)
-        off = p_safe % cfg.block_len
-        s_idx = jnp.arange(draft.shape[0])[:, None]  # (S, 1)
+            x = embed(params, seq, p_safe, mcfg)
+        bid, off = self._write_targets(state["tables"], p_safe, valid)
         fresh = []
 
         def overlay(pool_arr, new_shqd):
-            """(S, H, C, D) gathered view with the fresh chunk K/V
-            scattered over each slot's [pos, pos+kd] columns — the
-            pool itself is NOT written here (rejected positions must
-            stay untouched); entries beyond a slot's n_draft are
-            garbage no valid query's causal mask can reach (query j
-            attends positions <= pos + j only)."""
-            with jax.named_scope("gather_kv"):
-                dense = self._gather(pool_arr, state["tables"])
-                return dense.at[s_idx, :, p_safe].set(
-                    jnp.moveaxis(new_shqd, 1, 2)
-                )
+            return self._overlay(pool_arr, state["tables"], p_safe, new_shqd)
 
         def mk_attend(i):
             def attend(qh, kh, vh):
@@ -619,9 +737,10 @@ class Engine:
             x, _, extras = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
+                positions=p,
             )
             fresh.append(extras)
-        logits = lm_head(params, x)                              # (S, Q, V)
+        logits = lm_head(params, x, mcfg)                        # (S, Q, V)
         # position 0 samples through the temperature lane (temperature
         # slots ride the verify tick with n_draft == 0: their one
         # emitted token per tick is this sample); positions >= 1 are
@@ -670,6 +789,123 @@ class Engine:
             "v": tuple(new_v),
         }
         return new_state, emitted, jnp.where(live, acc, 0)
+
+    def _block_step(self, params, state):
+        """One pass of generation by diffusion over blocks, for every
+        live slot at once: the slot's current block — B positions from
+        ``pos``, ``mask_id`` where ``blk_masked`` — through the model
+        against the cache and each other (every query's limit is the
+        block's end).
+
+        What follows the forward depends on the slot's phase, in one
+        fixed shape. A slot with NO masked position commits: the
+        block's K and V (those of the fully unmasked block, which later
+        blocks read) go to its real pool blocks, ``pos`` moves on B and
+        the lanes hold a fresh, all-masked block. A slot with masked
+        positions denoises: per masked position the greedy token and
+        its confidence (the softmax probability of that token), and the
+        ``block_fix`` most confident — all of them if fewer are left —
+        take their tokens for good (``low_confidence_static``); its K/V
+        go to the trash block. Nothing reaches the pool before commit:
+        ``_verify``'s overlay-then-masked-write, with its helpers.
+
+        -> (state', one int32 vector: the (S, B) tokens this pass
+        fixed, by position in the block, -1 elsewhere, flattened; then
+        the pass's expert counters, experts hit summed over layers and
+        the most tokens one expert of one layer took — one pull)."""
+        mcfg = self.cfg
+        bl = mcfg.diffusion_block
+        pos, live, masked = state["pos"], state["live"], state["blk_masked"]
+        n_slots = pos.shape[0]
+        p = pos[:, None] + jnp.arange(bl)[None, :]               # (S, B)
+        p_safe = jnp.minimum(p, mcfg.max_len - 1)
+        seq = jnp.where(masked, jnp.int32(mcfg.mask_id), state["blk_tok"])
+        commit = live & ~jnp.any(masked, axis=1)
+        valid = jnp.broadcast_to(live[:, None], p.shape)
+        with jax.named_scope("embed"):
+            x = embed(params, seq, p_safe, mcfg)
+        limits = block_limits(p, mcfg)
+        fresh, stats = [], []
+
+        def mk_attend(i):
+            def attend(qh, kh, vh):
+                o = cache_attend(
+                    qh,
+                    self._overlay(state["k"][i], state["tables"], p_safe, kh),
+                    self._overlay(state["v"][i], state["tables"], p_safe, vh),
+                    limits,
+                )
+                return o, (kh, vh)
+            return attend
+
+        for i in range(mcfg.n_layers):
+            x, aux, extras = _block_apply(
+                params, f"blk{i}", x, mk_attend(i), mcfg,
+                moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
+                positions=p, valid=valid,
+            )
+            fresh.append(extras)
+            if mcfg.moe_top_k:
+                stats.append(aux)
+        logits = lm_head(params, x, mcfg)                        # (S, B, V)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            top = jnp.max(logits, axis=-1)
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            _, best = jax.lax.top_k(
+                jnp.where(masked, conf, -1.0), self.block_fix
+            )
+            fix = jnp.zeros(masked.shape, bool).at[
+                jnp.arange(n_slots)[:, None], best
+            ].set(True) & masked & live[:, None]
+        bid, off = self._write_targets(
+            state["tables"], p_safe, jnp.broadcast_to(commit[:, None], p.shape)
+        )
+        new_k, new_v = [], []
+        for i, (kh, vh) in enumerate(fresh):
+            with jax.named_scope(f"blk{i}"):
+                new_k.append(self._kv_write(
+                    state["k"][i], bid, off, jnp.moveaxis(kh, 1, 2)
+                ))
+                new_v.append(self._kv_write(
+                    state["v"][i], bid, off, jnp.moveaxis(vh, 1, 2)
+                ))
+        moved = commit[:, None]
+        new_state = {
+            **state,
+            "pos": pos + jnp.where(commit, bl, 0),
+            "blk_tok": jnp.where(
+                moved, 0, jnp.where(fix, greedy, state["blk_tok"])
+            ),
+            "blk_masked": moved | (masked & ~fix),
+            "k": tuple(new_k),
+            "v": tuple(new_v),
+        }
+        if stats:
+            st = jnp.stack(stats)                                # (L, 2)
+            counters = jnp.stack([jnp.sum(st[:, 0]), jnp.max(st[:, 1])])
+        else:
+            counters = jnp.zeros((2,), jnp.int32)
+        out = jnp.concatenate([
+            jnp.where(fix, greedy, jnp.int32(-1)).reshape(-1), counters,
+        ])
+        return new_state, out
+
+    def _activate_block_prog(self, state, slot, pos0, tail, n_tail):
+        """A slot whose prompt's whole blocks are prefilled goes live at
+        block start ``pos0`` with the prompt's ``n_tail`` last tokens in
+        place in its block lanes and the rest masked. No token is
+        sampled: a model generated by diffusion has none to give yet."""
+        return {
+            **state,
+            "pos": state["pos"].at[slot].set(pos0),
+            "live": state["live"].at[slot].set(True),
+            "temp": state["temp"].at[slot].set(0.0),
+            "blk_tok": state["blk_tok"].at[slot].set(tail),
+            "blk_masked": state["blk_masked"].at[slot].set(
+                jnp.arange(tail.shape[0]) >= n_tail
+            ),
+        }
 
     def _admit_prog(self, state, slot, row):
         return {
@@ -1006,6 +1242,27 @@ class Engine:
         )
         return int(first)
 
+    def activate_block(self, slot: int, prompt) -> None:
+        """Flip ``slot`` live for block steps once the whole blocks of
+        its prompt are prefilled (``len(prompt) // B * B`` tokens): the
+        prompt's tail starts its first block, the rest of it masked."""
+        b = self.cfg.diffusion_block
+        n_tail = len(prompt) % b
+        tail = np.zeros((b,), np.int32)
+        tail[:n_tail] = prompt[len(prompt) - n_tail:]
+        self.state = self._activate_block_jit(
+            self.state, jnp.int32(slot), jnp.int32(len(prompt) - n_tail),
+            jnp.asarray(tail), jnp.int32(n_tail),
+        )
+
+    def block_step(self):
+        """One pass over every live slot's current block (a model with a
+        ``diffusion_block``). -> one int32 device vector: the
+        (slots, B) tokens the pass fixed, -1 elsewhere, flattened, then
+        the pass's two expert counters (``_block_step``)."""
+        self.state, out = self._block_step_jit(self.params, self.state)
+        return out
+
     def decode(self):
         """One tick: every live slot advances one token. -> emitted
         (slots,) int32 device array, -1 on dead slots."""
@@ -1038,6 +1295,7 @@ class Engine:
         digest chain (so the importer can re-register prefix-cached
         blocks without re-hashing). The slot itself is untouched — the
         caller retires it once the bytes are safely on the wire."""
+        self._refuse_for_slot_state("slot export")
         blocks = self._slot_blocks.get(slot)
         if not blocks:
             raise ValueError(f"slot {slot} owns no blocks (not admitted?)")
@@ -1070,6 +1328,7 @@ class Engine:
         sequences may migrate: the chain's registration contract needs
         every prompt position already written. -> {"blocks", "shared",
         "registered"}."""
+        self._refuse_for_slot_state("slot import")
         alloc = self.allocator
         n = int(payload["k"].shape[1])
         chain = list(payload.get("chain") or ())
@@ -1130,6 +1389,7 @@ class Engine:
         a ``cache_ship`` reply. The caller retains the blocks across
         the gather (an unlucky concurrent admission could otherwise
         LRU-reclaim them mid-read)."""
+        self._refuse_for_slot_state("prefix shipping (export_blocks)")
         n = len(blocks)
         mb = self.pool.max_blocks_per_seq
         if n > mb:
@@ -1154,6 +1414,7 @@ class Engine:
         (the fleet host degrades the request to plain prefill). ->
         {"installed", "shared"} block counts. Idempotent: re-delivering
         the same ship installs nothing."""
+        self._refuse_for_slot_state("prefix shipping (install_prefix)")
         alloc = self.allocator
         if alloc.cache is None or not alloc.lru_enabled:
             # without LRU parking a refcount-0 block cannot outlive the

@@ -33,6 +33,24 @@ scheduler answers every client's generation request from one engine:
     discarded, never delivered). Temperature slots ride the same tick
     with zero drafts. Token streams are identical to one-token ticks
     by construction; only tick count changes;
+  - for a model generated by diffusion over blocks
+    (``TransformerConfig.diffusion_block``) a tick dispatches the
+    engine's BLOCK STEP instead: a pass over every live slot's current
+    block that fixes 0..B of its tokens, out of order. A token is
+    DELIVERED — joins ``Request.tokens``, is counted and stamped — when
+    it joins the contiguous unmasked prefix of its request, which is
+    what a caller can read; ``Request.unmask_pass`` keeps, per
+    delivered token, the pass of its block that fixed it. Such a
+    request's prefill covers the whole blocks of its prompt and yields
+    no token; a budget that ends inside a block stops delivery there.
+    Block steps run ONE PASS AHEAD of the host: a tick dispatches its
+    pass and then reads the pass dispatched the tick before, so
+    delivery, retirement, admission and the next chunk's hand-over
+    happen while the device works (a block step is a quarter of a
+    one-token tick of the dense model here, and the host's share of it
+    would otherwise idle the device an eighth of the time). The state is
+    on the device and a pass needs nothing from the host, so the tokens
+    are the same; a request that finished rides one more pass, unread;
   - a SIGTERM'd serving host drains via the resilience plane: the
     serve loop observes ``PreemptionHandler.requested`` at a tick
     boundary, hands every in-flight sequence back (recorded, with its
@@ -82,6 +100,9 @@ class Request:
     status: str = "queued"        # queued|prefill|decoding|done|evicted
     slot: int | None = None
     tokens: list = dataclasses.field(default_factory=list)
+    #: block steps only: per delivered token, which denoising pass of
+    #: its block (0-based) fixed it
+    unmask_pass: list = dataclasses.field(default_factory=list)
     enqueue_mono: float = 0.0
     admit_mono: float = 0.0
     first_token_mono: float = 0.0
@@ -89,6 +110,14 @@ class Request:
     _prefilled: int = 0
     #: admission to retirement, for the recorder's ``requests`` track
     _life: object = None
+    #: block steps only: the block in flight, as the host follows it —
+    #: per position the pass that fixed its token (None while masked,
+    #: -1 for a prompt token), the tokens, how many positions of it are
+    #: spoken for (prompt tail or delivered), and passes made on it
+    _blk_pass: list = dataclasses.field(default_factory=list)
+    _blk_tok: list = dataclasses.field(default_factory=list)
+    _blk_read: int = 0
+    _blk_passes: int = 0
 
     @property
     def latency_s(self) -> float:
@@ -110,6 +139,22 @@ class Scheduler:
         #: for tests/probes — e.g. speculate.NullDrafter forces zero
         #: acceptance while keeping the whole verify path hot)
         self.spec_k = engine.serving.spec_k
+        #: block length of a model generated by diffusion over blocks
+        #: (0: one-token ticks): decode ticks dispatch ``block_step``
+        self.block_len = engine.cfg.diffusion_block
+        #: block-step accounting (all zero for one-token ticks):
+        #: slot-passes (one a live slot a block step, commits included),
+        #: commits, tokens delivered, experts hit summed over passes and
+        #: layers, and the most tokens one expert took in any pass
+        self.block_passes = 0
+        self.block_commits = 0
+        self.tokens_delivered = 0
+        self.experts_hit = 0
+        self.expert_max_load = 0
+        #: block steps run ONE PASS AHEAD of the host: (the device
+        #: result of the pass dispatched last tick, the requests it
+        #: served by slot), read this tick after the next is dispatched
+        self._in_flight = None
         if drafter is not None:
             self.drafter = drafter
         else:
@@ -181,6 +226,9 @@ class Scheduler:
         self.partial_hits = self.tail_tokens_shared = 0
         self.decode_blocks_registered = 0
         self.prefill_chunks = self.prefill_chunks_saved = 0
+        self.block_passes = self.block_commits = 0
+        self.tokens_delivered = 0
+        self.experts_hit = self.expert_max_load = 0
         self.engine.allocator.reset_stats()
         self._live_ticks = 0
         self.backpressure_ticks = 0
@@ -199,6 +247,12 @@ class Scheduler:
                 f"request {req.rid}: prompt {len(req.prompt)} + budget "
                 f"{req.max_new_tokens} exceeds max_len "
                 f"{self.engine.cfg.max_len}"
+            )
+        if self.block_len and req.temperature > 0.0:
+            raise ValueError(
+                f"request {req.rid}: temperature {req.temperature} cannot "
+                f"run for a model with diffusion_block = {self.block_len} "
+                "(block steps unmask greedily by confidence)"
             )
         req.prompt = np.asarray(req.prompt, np.int32)
         # a request that crossed the fleet's front door (or a drain
@@ -268,7 +322,7 @@ class Scheduler:
         # a handed-back (drained) request restarts from scratch on
         # re-admission: its partial output was delivered at evict
         # time, regeneration must not append to it
-        req.tokens = []
+        req.tokens, req.unmask_pass = [], []
         req._life = span("sched.request", nested=False).start()
         req.admit_mono = req._life.t0
         self._event(
@@ -320,7 +374,7 @@ class Scheduler:
                 continue
             n = min(
                 self.engine.serving.max_prefill_chunk,
-                len(req.prompt) - req._prefilled,
+                self._prefill_len(req) - req._prefilled,
             )
             with span(
                 "sched.prefill", tick=self.ticks, rid=req.rid, slot=slot,
@@ -328,19 +382,35 @@ class Scheduler:
             ):
                 self._prefill(slot, req, n)
 
+    def _prefill_len(self, req: Request) -> int:
+        """Prompt tokens that prefill writes: all of them, or under
+        block steps the prompt's whole blocks (its tail starts the
+        first block that is denoised)."""
+        n = len(req.prompt)
+        return n // self.block_len * self.block_len if self.block_len else n
+
     def _prefill(self, slot: int, req: Request, n: int) -> None:
         """One chunk of ``n`` prompt tokens; after the last, the
-        request's first token (``engine.activate`` pulls it)."""
-        last = self.engine.prefill_chunk(
-            slot, req.prompt[req._prefilled:req._prefilled + n],
-            req._prefilled,
-        )
-        req._prefilled += n
-        self.prefill_chunks += 1
-        self._event(
-            "prefill", rid=req.rid, slot=slot, tokens=int(n),
-            done=int(req._prefilled), of=int(len(req.prompt)),
-        )
+        request's first token (``engine.activate`` pulls it) — or, under
+        block steps, no token: the slot goes live on its first block."""
+        last = None
+        if n:
+            last = self.engine.prefill_chunk(
+                slot, req.prompt[req._prefilled:req._prefilled + n],
+                req._prefilled,
+            )
+            req._prefilled += n
+            self.prefill_chunks += 1
+            self._event(
+                "prefill", rid=req.rid, slot=slot, tokens=int(n),
+                done=int(req._prefilled), of=int(len(req.prompt)),
+            )
+        if self.block_len:
+            if req._prefilled >= self._prefill_len(req):
+                self.engine.activate_block(slot, req.prompt)
+                self._fresh_block(req, list(req.prompt[req._prefilled:]))
+                req.status = "decoding"
+            return
         if req._prefilled >= len(req.prompt):
             # every prompt position is now prefill-written: index
             # the fully-covered blocks for future prefix hits
@@ -403,6 +473,44 @@ class Scheduler:
                 steps=len(req.tokens),
             )
 
+    def _fresh_block(self, req: Request, tail=()) -> None:
+        """The host's copy of a slot's block lanes: ``tail`` prompt
+        tokens in place (spoken for, never delivered), the rest masked."""
+        rest = self.block_len - len(tail)
+        req._blk_tok = [int(t) for t in tail] + [0] * rest
+        req._blk_pass = [-1] * len(tail) + [None] * rest
+        req._blk_read = len(tail)
+        req._blk_passes = 0
+
+    def _deliverable(self, req: Request, fixed) -> list[int]:
+        """Follow one block step on the host's copy of the slot's block
+        (``fixed``: the (B,) tokens the pass fixed, -1 elsewhere) and
+        return the tokens that JOINED THE CONTIGUOUS UNMASKED PREFIX of
+        the request by it, in order — what its caller can now read.
+        Their passes go to ``Request.unmask_pass`` as the emit loop
+        appends the tokens (it stops at an EOS and trims the passes of
+        what it drops; the budget is kept to here). A pass over a
+        block with nothing masked was its commit: the next block starts."""
+        if None not in req._blk_pass:
+            self.block_commits += 1
+            self._fresh_block(req)
+            return []
+        for j, tok in enumerate(fixed):
+            if tok >= 0:
+                req._blk_tok[j] = int(tok)
+                req._blk_pass[j] = req._blk_passes
+        req._blk_passes += 1
+        room = req.max_new_tokens - len(req.tokens)
+        out = []
+        while (
+            req._blk_read < self.block_len and len(out) < room
+            and req._blk_pass[req._blk_read] is not None
+        ):
+            out.append(req._blk_tok[req._blk_read])
+            req.unmask_pass.append(req._blk_pass[req._blk_read])
+            req._blk_read += 1
+        return out
+
     def _draft_for(self, req: Request) -> list[int]:
         """Draft tokens for one decoding request: greedy slots only
         (speculation is greedy-only per slot — a temperature slot's
@@ -442,7 +550,8 @@ class Scheduler:
         decoding = {
             s: r for s, r in self._slot_req.items() if r.status == "decoding"
         }
-        if not decoding:
+        in_flight, self._in_flight = self._in_flight, None
+        if not decoding and in_flight is None:
             return 0
         tick, accepted_n, emitted_n = self.ticks, 0, 0
         # draft, dispatch and pull: the recorder's ``decode_tick`` and
@@ -469,6 +578,35 @@ class Scheduler:
                     emitted = np.asarray(emitted_dev)
                     accepted_n = int(np.asarray(accepted_dev).sum())
                 self.spec_accepted += accepted_n
+            elif self.block_len:
+                # one pass ahead: this tick's pass goes to the device
+                # first, then the pass before it is read and followed
+                if decoding:
+                    with span(
+                        "sched.dispatch", tick=tick, live=len(decoding)
+                    ):
+                        self._in_flight = (self.engine.block_step(), decoding)
+                if in_flight is None:
+                    return 0
+                out_dev, decoding = in_flight
+                with span("sched.pull", tick=tick):
+                    out = np.asarray(out_dev)
+                # plain lists: the walk below is a few hundred scalar
+                # reads a tick, ten times dearer on numpy scalars
+                fixed = out[:-2].reshape(-1, self.block_len).tolist()
+                self.block_passes += len(decoding)
+                self.experts_hit += int(out[-2])
+                self.expert_max_load = max(
+                    self.expert_max_load, int(out[-1])
+                )
+                # what each slot can now READ of what the pass fixed
+                # (a request that finished since its pass was dispatched
+                # rode it unread)
+                emitted = [
+                    self._deliverable(decoding[s], row)
+                    if s in decoding and decoding[s].status == "decoding"
+                    else () for s, row in enumerate(fixed)
+                ]
             else:
                 with span("sched.dispatch", tick=tick, live=len(decoding)):
                     emitted_dev = self.engine.decode()
@@ -484,12 +622,22 @@ class Scheduler:
                         break
                     req.tokens.append(int(tok))
                     emitted_n += 1
+                    if self.block_len and len(req.tokens) == 1:
+                        req.first_token_mono = time.perf_counter()
                     if self._check_done(slot, req, int(tok)):
                         break
+                # an EOS inside a run drops the run's tail, passes too
+                del req.unmask_pass[len(req.tokens):]
             fan_out.note(emitted=emitted_n)
+        if self._in_flight is not None and not self._slot_req:
+            # the last request finished with one more pass on its way:
+            # nobody is left to read it
+            self._in_flight = None
         self._live_ticks += len(decoding)
         self.decode_ticks += 1
         self.tokens_emitted += emitted_n
+        if self.block_len:
+            self.tokens_delivered += emitted_n
         if len(decoding) == self.engine.serving.slots:
             self.full_tick_s += whole.dur
             self.full_tick_tokens += emitted_n
@@ -532,6 +680,7 @@ class Scheduler:
             "drain", reason=reason,
             in_flight=len(self._slot_req), queued=len(self._queue),
         )
+        self._in_flight = None
         handed_back = []
         for slot in sorted(self._slot_req):
             req = self._slot_req[slot]

@@ -437,3 +437,71 @@ def test_compile_cache_key_is_salted_by_the_names_version(monkeypatch):
     monkeypatch.setattr(jax.config, "update", lambda k, v: None)
     compile_cache.setup_compile_cache(log=lambda s: None)
     assert cache_key.custom_hook() == compile_cache.NAMES
+
+
+# ---------------------------------------------------------------------
+# block steps: a model generated by diffusion over blocks
+# ---------------------------------------------------------------------
+
+
+def tiny_block_engine():
+    cfg = TransformerConfig(
+        vocab=40, d_model=32, n_heads=4, n_layers=2, max_len=32,
+        norm="rmsnorm", pos="rope", n_kv_heads=2, head_dim=8, qk_norm=True,
+        tied_head=False, moe_experts=4, moe_top_k=2, moe_d_ff=16,
+        diffusion_block=4, mask_id=39,
+    )
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    return Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, max_prefill_chunk=4, block_steps=2,
+    ))
+
+
+@pytest.fixture(scope="module")
+def block_step_text():
+    eng = tiny_block_engine()
+    return eng._block_step_jit.lower(eng.params, eng.state).compile().as_text()
+
+
+def test_block_step_program_name(block_step_text):
+    # ``jit__block_step`` on the ``XLA Modules`` line of a trace
+    assert "HloModule jit__block_step," in block_step_text
+
+
+@pytest.mark.parametrize("scope", [
+    "blk0/qkv", "blk0/qkv/qk_norm", "blk0/qkv/rope",
+    "blk0/attend/gather_kv", "blk0/attend/cache_attend", "blk1/kv_write",
+    "blk0/moe/route", "blk0/moe/experts", "blk0/moe/combine",
+    "blk1/moe/experts", "blk0/ln1", "blk0/attn_out", "embed", "lm_head",
+    "sample",
+])
+def test_block_step_program_names_its_operations(block_step_text, scope):
+    names = {n for _, n in instructions(block_step_text)}
+    assert any(f"jit(_block_step)/{scope}/" in n for n in names), scope
+
+
+def test_block_step_tick_has_the_unchanged_span_set(tmp_path):
+    """A tick that dispatches a block step is read by the same spans as
+    a one-token tick: no ``sched.draft``, nothing new."""
+    trace_dir = str(tmp_path / "trace")
+    sched = Scheduler(tiny_block_engine())
+    rs = np.random.RandomState(0)
+    with jax.profiler.trace(trace_dir):
+        for rid in range(3):
+            sched.submit(Request(
+                rid=rid, prompt=rs.randint(0, 39, size=(6 + rid,)),
+                max_new_tokens=6,
+            ))
+        sched.serve()
+    spans = read_spans(trace_dir)
+    assert {s[0] for s in spans if s[0].startswith("sched.")} == (
+        set(SCHED_SPANS) - {"sched.draft"}
+    )
+    for name, _, _, attrs in spans:
+        if name in SCHED_SPANS:
+            assert set(attrs) >= SCHED_SPANS[name], (name, attrs)
+    # one pass ahead: the last pass dispatched is never read
+    dispatches = [s for s in spans if s[0] == "sched.dispatch"]
+    assert len(dispatches) == sched.decode_ticks + 1
+    emitted = sum(s[3]["emitted"] for s in spans if s[0] == "sched.emit")
+    assert emitted == sched.tokens_delivered == 18
