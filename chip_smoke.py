@@ -474,7 +474,8 @@ def _top2_gap(params, cfg, prompt, tokens) -> float:
 def serve(devices, work: str, seed: int, sizes: Sizes) -> dict:
     """The lm_d128_serve shape answers six requests four times: with the
     ``reference`` attend and with ``fused`` — which on a TPU compiles
-    through Mosaic with no conf change — each as plain decode and as
+    through Mosaic with no conf change, and is what the engine picks
+    there when nothing is pinned — each as plain decode and as
     speculative verify (spec_k 4)."""
     import jax
     import jax.numpy as jnp
@@ -512,10 +513,27 @@ def serve(devices, work: str, seed: int, sizes: Sizes) -> dict:
                         engine.params, engine.state
                     )
                 for name, lowered in programs.items():
+                    # the decode tick and the verify pass run the
+                    # kernel; a prefill chunk keeps the one-slot gather
+                    # whatever the engine runs (serve/engine.py)
                     _check(
-                        "tpu_custom_call" in lowered.as_text(),
-                        f"fused {name} program holds no Mosaic kernel",
+                        ("tpu_custom_call" in lowered.as_text())
+                        == (name != "prefill"),
+                        f"fused {name} program: Mosaic kernel "
+                        f"{'missing' if name != 'prefill' else 'present'}",
                     )
+    # left to itself the engine picks the kernel on the chip for this
+    # model (causal, as many K/V heads as query heads, no mesh)
+    from singa_tpu.serve.engine import choose_attend, EngineConfig
+
+    chosen = choose_attend(
+        cfg, EngineConfig(kv_block_len=16), None, devices[0].platform
+    )
+    _check(
+        chosen == ("fused" if on_chip else f"reference: platform = "
+                   f"{devices[0].platform}"),
+        f"the engine chose {chosen!r} on {devices[0].platform}",
+    )
     # the model's own heads, and GPT-2's 16 of 64: the kernel walks the
     # heads as column slices of a pool row, and a 64-wide head's slice
     # starts off the 128-lane boundary

@@ -1016,16 +1016,18 @@ class KernelsConfig(Message):
     (the Pallas hot-path seam, singa_tpu/ops/paged_attention.py +
     singa_tpu/ops/quantized_collective.py).
 
-    ``paged_attention: fused`` swaps the serving engine's attention —
-    every decode tick, prefill chunk, and speculative verify pass —
-    from the reference gather -> ``cache_attend`` path onto a Pallas
+    ``paged_attention: fused`` pins the serving engine's attention —
+    the decode tick and the speculative verify pass — onto a Pallas
     kernel that reads K/V blocks IN PLACE through the block table
     (flash-attention online-softmax tiling over block-granular K/V, no
     dense ``(slots, heads, cache_len, head_dim)`` materialization per
-    layer). Output is allclose to the reference (online softmax
-    reorders the reduction); greedy token streams are identical.
-    ``reference`` (default, = no block) keeps the bitwise-pinned
-    oracle path untouched. Left unset, ``interpret`` follows the
+    layer) in place of the reference gather -> ``cache_attend`` path.
+    Output is allclose to the reference (online softmax reorders the
+    reduction); greedy token streams are identical. ``reference`` pins
+    the bitwise-pinned oracle path. Left unset (also: no block), the
+    engine chooses (serve/engine.py ``choose_attend``): the kernel on
+    a TPU with no mesh for a model it knows, the oracle path
+    everywhere else. Left unset, ``interpret`` follows the
     platform for the serving kernel: compiled through Mosaic on a
     TPU, the Pallas interpreter (plain XLA ops, CPU-safe and
     GSPMD-shardable, what CI exercises) elsewhere; true/false pin it.
@@ -1060,8 +1062,9 @@ class KernelsConfig(Message):
 
     FIELDS = {
         # serving-tier attention: "reference" gather + cache_attend
-        # oracle, "fused" Pallas paged-attention kernel
-        "paged_attention": Field("enum", "reference", enum=KERNEL_IMPLS),
+        # oracle, "fused" Pallas paged-attention kernel; unset = the
+        # engine chooses (serve/engine.py choose_attend)
+        "paged_attention": Field("enum", enum=KERNEL_IMPLS),
         # training-tier gradient collective: "reference" = grad_comm's
         # quantize-around-the-psum oracle (fp32 on the wire),
         # "quantized_ring" = int8-on-the-wire ppermute ring
@@ -1169,8 +1172,9 @@ class ModelConfig(Message):
         # serving defaults (tools/serve_bench.py, tools/generate.py) ---
         "serving": Field("message", message=ServingConfig),
         # --- singa-tpu extension: per-site kernel selection (Pallas
-        # hot paths, singa_tpu/ops/paged_attention.py). Absent = every
-        # site runs its reference oracle path ---
+        # hot paths, singa_tpu/ops/paged_attention.py). Absent = the
+        # gradient collective runs its reference oracle path and the
+        # serving engine chooses its attention itself ---
         "kernels": Field("message", message=KernelsConfig),
         # --- singa-tpu extension: disaggregated serving fleet
         # (singa_tpu/serve/fleet/) — presence dispatches main.py to a

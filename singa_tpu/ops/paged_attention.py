@@ -18,17 +18,37 @@ No dense ``(S, H, C, D)`` intermediate ever exists.
 WHAT THE KERNEL IS HANDED: the pools as the engine stores them,
 ``(n_blocks, block_len, H * D)`` (serve/kv_pool.py: one row a token,
 the heads side by side in it), with no view or relayout in between.
-One grid step fetches one whole block for ALL heads — a
-``(1, block_len, H * D)`` tile, its minor dimension the model's width
-— and the heads are walked inside the kernel with static column
-slices, so the grid is ``(S, max_blocks)`` and the statistics are kept
-per head.
 
-Two entry points cover the engine's three call shapes:
+TWO FORMS behind the two entry points, chosen in ``_call`` from the
+call's shape:
+
+the grid form (``_kernel``)
+    any number of queries a sequence, with or without an overlaid
+    chunk. One grid step fetches one whole block for ALL heads — a
+    ``(1, block_len, H * D)`` tile, its minor dimension the model's
+    width — and the heads are walked inside the kernel with static
+    column slices, so the grid is ``(S, max_blocks)`` and the
+    statistics are kept per head. A step past a sequence's live range
+    skips its fetch and its compute, but not the step.
+
+the one-query form (``_one_query_kernel``)
+    the decode tick: ONE query a sequence, nothing overlaid. At a
+    serving cell's shape the grid form is all steps and small
+    products — 32 slots x 64 table entries x 24 layers are 49,152
+    steps a tick, under a third of them live, each walking 16 heads of
+    ``(1, 64) x (64, 16)`` — so this form has no grid: one program
+    walks a flat list of the LIVE chunks of every sequence, copies a
+    chunk's blocks from HBM by hand (the next chunk's while this one
+    is folded) and folds all heads at once. On a v5e it reads the
+    live blocks of GPT-2 medium's pools at 0.95 of what a plain sum
+    over a pool reads them at (PERF.md §6, PR 29).
+
+Two entry points cover the engine's call shapes:
 
 ``paged_attention``
-    write-then-read — the decode tick ``(slots, 1)`` and chunked
-    prefill ``(1, chunk)`` pattern: the fresh K/V were already
+    write-then-read — the decode tick's ``(slots, 1)`` pattern (and a
+    chunk of queries, ``(1, chunk)``, which the engine's prefill no
+    longer asks for): the fresh K/V were already
     scattered into the pool (padding/dead lanes to the trash block),
     so every attended entry lives behind the table and the mask is
     ``cache_attend``'s exactly: pool position <= query position.
@@ -58,10 +78,11 @@ own re-tiled GEMM accumulation. Greedy token STREAMS are pinned
 identical in tests — argmax decisions survive reduction-order ulps on
 every workload the suite drives.
 
-Bytes skipped, not just bytes reorganized: the causal bound clamps the
-fetch index map so blocks past a sequence's live range re-fetch the
-previous block id — Pallas skips the DMA when consecutive grid steps
-map to the same block — and ``pl.when`` skips their compute.
+Bytes skipped, not just bytes reorganized: in the grid form the causal
+bound clamps the fetch index map so blocks past a sequence's live
+range re-fetch the previous block id — Pallas skips the DMA when
+consecutive grid steps map to the same block — and ``pl.when`` skips
+their compute; the one-query form never lists them.
 
 ``interpret`` is decided from the platform (``_call``): on a TPU the
 kernel compiles through Mosaic, elsewhere it runs through the Pallas
@@ -73,7 +94,9 @@ explicit ``True``. Every operand's block has its last two dims EQUAL
 to the array's, so Mosaic takes any ``kv_block_len`` / ``head_dim`` /
 query count (``fusable``; tests/test_chip_compile.py asks the v5e
 compiler) — tiles off the (8, 128) register tile are padded, not
-refused.
+refused. The one-query form copies blocks by hand onto whole register
+tiles, so it takes a ``kv_block_len`` that is a multiple of the pool
+dtype's tile rows and leaves every other to the grid form.
 """
 
 from __future__ import annotations
@@ -189,6 +212,194 @@ def _kernel(
             o_ref[0, h] = (acc[h] / safe[:, None]).astype(o_ref.dtype)
 
 
+#: pool positions one step of the one-query kernel fetches and folds
+#: (whole blocks: ``_CHUNK_POSITIONS // block_len`` of them, at least 1)
+_CHUNK_POSITIONS = 128
+
+
+def _spread(x, heads_to_columns):
+    """(R, H) float32 -> (R, H * D), head h's value in each of its D
+    columns, to the last bit: a product with a 0/1 matrix in float32
+    (a few rows of running statistics, not a hot product)."""
+    return jnp.dot(
+        x, heads_to_columns, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _one_query_kernel(
+    tab_ref, nlive_ref, seq_ref, chunk_ref, n_ref, pos_ref,
+    q_ref, fold_ref, spread_ref, k_hbm, v_hbm, o_ref,
+    kbuf, vbuf, sem, acc, m, l,
+    *, block_len, mb, group,
+):
+    """The decode tick's shape — ONE query a sequence — as one program
+    over a flat list of LIVE chunks: item ``i`` is chunk
+    ``chunk_ref[i]`` (``group`` consecutive table entries) of sequence
+    ``seq_ref[i]``, and ``n_ref[0]`` items are walked, so a block past
+    a sequence's live range costs no step at all.
+
+    The pools stay in HBM: a chunk's live blocks are copied by hand
+    into one of two ``(group * block_len, H * D)`` buffers, the next
+    item's while this one is folded, across sequence boundaries too.
+
+    The heads are folded together instead of walked: a pool row holds
+    all H heads side by side, so ``K * q`` (the query as ONE H * D-wide
+    row) is every head's products at once, and the sum within each
+    head's D columns is one product with the 0/1 matrix ``fold_ref``
+    (H * D, H): scores (positions, H). The running (m, l) are (1, H);
+    the probabilities go back to H * D columns through ``spread_ref``
+    (H, H * D) to meet V. Both are products at the platform's default
+    precision, as the reference path's are (on a TPU one bfloat16 pass
+    of ``K * q`` and of the probabilities, accumulated in float32; on
+    a CPU float32 throughout); ``K * q``, ``p * V`` and every statistic
+    are float32 everywhere."""
+    gbl = group * block_len
+    n = n_ref[0]
+    scale = 1.0 / math.sqrt(q_ref.shape[-1] // m.shape[-1])
+
+    def copies(i, buf):
+        """Item i's (is it live?, its K copy, its V copy), a block each."""
+        seq = seq_ref[i]
+        for g in range(group):
+            b = chunk_ref[i] * group + g
+            bid = tab_ref[seq * mb + jnp.minimum(b, mb - 1)]
+            rows = pl.ds(g * block_len, block_len)
+            yield b < nlive_ref[seq], (
+                pltpu.make_async_copy(
+                    k_hbm.at[bid], kbuf.at[buf, rows], sem.at[0, buf]
+                ),
+                pltpu.make_async_copy(
+                    v_hbm.at[bid], vbuf.at[buf, rows], sem.at[1, buf]
+                ),
+            )
+
+    def each_copy(i, buf, do):
+        for live, pair in copies(i, buf):
+            @pl.when(live)
+            def _():
+                for copy in pair:
+                    do(copy)
+
+    # rows a part-filled chunk leaves stale are masked, but 0 * NaN is
+    # NaN: the buffers start as zeros, and hold pool bytes ever after
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _first():
+        each_copy(0, 0, lambda copy: copy.start())
+
+    def fold_item(i, carry):
+        buf = i % 2
+
+        @pl.when(i + 1 < n)
+        def _next():
+            each_copy(i + 1, 1 - buf, lambda copy: copy.start())
+
+        each_copy(i, buf, lambda copy: copy.wait())
+        seq, c = seq_ref[i], chunk_ref[i]
+        spread = spread_ref[...]
+
+        @pl.when(c == 0)
+        def _init():
+            acc[...] = jnp.zeros_like(acc)
+            m[...] = jnp.full_like(m, NEG_INF)
+            l[...] = jnp.zeros_like(l)
+
+        q = q_ref[seq].astype(jnp.float32) * scale               # (1, HD)
+        products = kbuf[buf].astype(jnp.float32) * q             # (GBL, HD)
+        scores = jnp.dot(
+            products, fold_ref[...], preferred_element_type=jnp.float32
+        )                                                        # (GBL, H)
+        kpos = c * gbl + jax.lax.broadcasted_iota(jnp.int32, (gbl, 1), 0)
+        mask = kpos <= pos_ref[seq]
+        scores = jnp.where(mask, scores, NEG_INF)
+        m_prev = m[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)                          # (1, H)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)        # (GBL, H)
+        l[...] = l[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        m[...] = m_new
+        p_wide = jnp.dot(
+            p, spread, preferred_element_type=jnp.float32
+        )                                                        # (GBL, HD)
+        acc[...] = acc[...] * _spread(alpha, spread) + jnp.sum(
+            p_wide * vbuf[buf].astype(jnp.float32), axis=0, keepdims=True
+        )
+
+        @pl.when((c + 1) * group >= nlive_ref[seq])
+        def _finish():
+            lw = _spread(l[...], spread)
+            o_ref[seq] = (
+                acc[...] / jnp.where(lw == 0.0, 1.0, lw)
+            ).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, n, fold_item, 0)
+
+
+def _call_one_query(q, k_pool, v_pool, tables, positions, interpret):
+    """``_one_query_kernel`` on (S, H, 1, D) queries: the list of live
+    chunks, the two 0/1 matrices and the queries as H * D-wide rows
+    are made here, in plain XLA (a tick's 24 layers ask for the same
+    list, which the compiler computes once)."""
+    s, h, _, d = q.shape
+    _, bl, hd = k_pool.shape
+    mb = tables.shape[1]
+    group = max(1, min(_CHUNK_POSITIONS // bl, mb))
+    pos = positions[:, 0].astype(jnp.int32)
+    nlive = live_blocks(pos, bl, mb).astype(jnp.int32)
+    chunks = (nlive + group - 1) // group                # (S,) a sequence
+    ends = jnp.cumsum(chunks)
+    item = jnp.arange(s * -(-mb // group), dtype=jnp.int32)
+    seq = jnp.minimum(
+        jnp.searchsorted(ends, item, side="right", method="compare_all"),
+        s - 1,
+    ).astype(jnp.int32)
+    chunk = item - (ends - chunks)[seq]
+    fold = (
+        jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]
+    ).astype(jnp.float32)                                # (HD, H)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(
+            _one_query_kernel, block_len=bl, mb=mb, group=group
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(1,),
+            in_specs=[
+                whole(s, 1, hd), whole(hd, h), whole(h, hd), in_hbm, in_hbm,
+            ],
+            out_specs=whole(s, 1, hd),
+            scratch_shapes=[
+                pltpu.VMEM((2, group * bl, hd), k_pool.dtype),
+                pltpu.VMEM((2, group * bl, hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),       # (K or V, buffer)
+                pltpu.VMEM((1, hd), jnp.float32),      # acc
+                pltpu.VMEM((1, h), jnp.float32),       # m (running max)
+                pltpu.VMEM((1, h), jnp.float32),       # l (running sum)
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
+        interpret=interpret,
+        name="paged_attention",
+    )(
+        tables.reshape(-1).astype(jnp.int32), nlive, seq,
+        chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32), pos,
+        jnp.moveaxis(q, 1, 2).reshape(s, 1, hd), fold, fold.T,
+        k_pool, v_pool,
+    )
+    return jnp.moveaxis(out.reshape(s, 1, h, d), 2, 1)
+
+
 def live_blocks(last_position, block_len, max_blocks):
     """Blocks the kernel's clamped grid actually fetches for one
     sequence whose last attended POOL position is ``last_position``
@@ -198,6 +409,12 @@ def live_blocks(last_position, block_len, max_blocks):
     gates on — keeping the gated model in lockstep with what the
     kernel fetches. Works on scalars and arrays."""
     return jnp.clip((last_position + block_len) // block_len, 0, max_blocks)
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one register tile of ``dtype``: 8 of float32, 16 of
+    bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
 
 
 def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
@@ -213,6 +430,11 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         # THE place the fused serving kernel picks its form: compiled
         # through Mosaic on a TPU, the Pallas interpreter anywhere else
         interpret = jax.default_backend() != "tpu"
+    if chunk is None and nq == 1 and bl % _sublanes(k_pool.dtype) == 0:
+        # the decode tick: blocks copied by hand land on whole tiles
+        return _call_one_query(
+            q, k_pool, v_pool, tables, positions, bool(interpret)
+        )
     if chunk is None:
         # write-then-read: blocks must cover every query position
         live_to = jnp.max(positions, axis=1)

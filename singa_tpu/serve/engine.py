@@ -65,13 +65,19 @@ major: serve/kv_pool.py) out over the ``model`` axis, whole heads a
 shard (parallel/shardings.serving_kv_shardings) — the serving analog
 of kLayerPartition; everything else replicates.
 
-ATTENTION IMPLEMENTATION is a per-engine knob (the ``kernels {
-paged_attention }`` model-conf block): ``reference`` (the default)
-keeps the bitwise-pinned gather -> ``cache_attend`` path above;
-``fused`` swaps the Pallas paged-attention kernel
+ATTENTION IMPLEMENTATION is the engine's to choose (``choose_attend``):
+``reference`` keeps the bitwise-pinned gather -> ``cache_attend`` path
+above; ``fused`` swaps the Pallas paged-attention kernel
 (ops/paged_attention.py) in at the ``attend`` closure seam of
 ``_block_apply`` — K/V blocks are read IN PLACE through the block
 table, no dense ``(S, H, cache_len, D)`` materialization per layer.
+Left unset (no ``kernels { paged_attention }`` in the model conf), the
+kernel runs where it compiles and knows the model — on a TPU, with no
+mesh, one K/V head a query head, one token a tick — and the gather
+path everywhere else; the scheduler's ``kernel_select`` event says
+which and why. Naming either in the conf pins it. The choice covers
+the decode tick and the verify pass; a prefill chunk always takes the
+one-slot gather, where the kernel's many-query shape does not win.
 Fused output is allclose to the reference (online softmax reorders the
 reduction — the PR 9 cross-shape caveat at kernel granularity); greedy
 token STREAMS are pinned identical in tests. The kernel's form follows
@@ -137,11 +143,13 @@ class EngineConfig:
     #: awaiting a peer's cache_ship this long before degrading to plain
     #: prefill (serve/fleet/host.py)
     prefix_fetch_timeout_s: float = 2.0
-    #: ``kernels { paged_attention }``: "reference" = the gather +
-    #: cache_attend oracle path (bitwise-pinned, the default); "fused"
-    #: = the Pallas kernel reading K/V blocks in place via the block
-    #: table (ops/paged_attention.py)
-    attend_impl: str = "reference"
+    #: ``kernels { paged_attention }``: None (the conf left it unset)
+    #: lets the engine choose (``choose_attend``: the kernel on a TPU
+    #: for a model it knows, the gather path elsewhere). "reference"
+    #: pins the gather + cache_attend oracle path (bitwise-pinned),
+    #: "fused" the Pallas kernel reading K/V blocks in place via the
+    #: block table (ops/paged_attention.py)
+    attend_impl: str | None = None
     #: ``kernels { interpret }``: None (the conf left it unset) lets
     #: the platform decide — Mosaic-compiled on a TPU, the Pallas
     #: interpreter (plain XLA ops — CPU-safe, GSPMD-shardable; what CI
@@ -187,6 +195,31 @@ class EngineConfig:
         )
 
 
+def choose_attend(cfg, serving, mesh, platform: str) -> str:
+    """Which attention the engine's programs run, from what the engine
+    can see: ``"fused"`` or ``"reference"``, the latter followed by
+    ``": <why>"`` where the engine chose it — the string the
+    scheduler's ``kernel_select`` event carries. A pure function, so a
+    CPU test can ask it about a TPU.
+
+    A pinned ``serving.attend_impl`` is returned as it is. Unset, the
+    kernel runs where it compiles through Mosaic and knows the model:
+    it walks one K/V head a query head and has no block step
+    (``Engine._beyond_gpt2``), GSPMD cannot partition a Mosaic call
+    (a mesh), and off a TPU it would run through the Pallas
+    interpreter, a grid step at a time."""
+    if serving.attend_impl is not None:
+        return serving.attend_impl
+    from ..ops.paged_attention import fusable
+
+    why = Engine._beyond_gpt2(cfg) or fusable(serving.kv_block_len)
+    if why is None and mesh is not None:
+        why = "a tensor-parallel mesh"
+    if why is None and platform != "tpu":
+        why = f"platform = {platform}"
+    return "fused" if why is None else f"reference: {why}"
+
+
 @dataclasses.dataclass(frozen=True)
 class Admission:
     """What admit() did for one request: the sequence's full block list
@@ -222,12 +255,16 @@ class Engine:
         self.cfg = cfg
         self.serving = serving or EngineConfig()
         self.temperature = float(temperature)
-        if self.serving.attend_impl not in ("reference", "fused"):
+        if self.serving.attend_impl not in (None, "reference", "fused"):
             raise ValueError(
                 f"kernels.paged_attention must be 'reference' or "
                 f"'fused', got {self.serving.attend_impl!r}"
             )
-        self._fused = self.serving.attend_impl == "fused"
+        #: what ``choose_attend`` said, reason included
+        self.attend_choice = choose_attend(
+            cfg, self.serving, mesh, jax.default_backend()
+        )
+        self._fused = self.attend_choice == "fused"
         if self._fused:
             from ..ops.paged_attention import fusable
 
@@ -485,7 +522,7 @@ class Engine:
 
     @jax.named_scope("paged_attention")
     def _paged_attend(self, q, kp, vp, tables, positions):
-        """The fused path's write-then-read attend (decode + prefill):
+        """The fused path's write-then-read attend (the decode tick):
         the fresh K/V were already scattered into ``kp``/``vp``, the
         kernel reads blocks in place through ``tables``."""
         from ..ops.paged_attention import paged_attention
@@ -610,14 +647,14 @@ class Engine:
                 vp = self._kv_write(
                     state["v"][i], bid, off, jnp.moveaxis(v[0], 1, 0)
                 )
-                if self._fused:
-                    o = self._paged_attend(q, kp, vp, row[None], p[None])
-                else:
-                    o = cache_attend(
-                        q,
-                        *self._gather_kv(kp, vp, row[None]),
-                        limits[None],
-                    )
+                # the one-slot gather whatever the decode tick runs: a
+                # (1, cache_len) view a layer, which the kernel's
+                # many-query shape does not beat (PERF.md §6, PR 29)
+                o = cache_attend(
+                    q,
+                    *self._gather_kv(kp, vp, row[None]),
+                    limits[None],
+                )
                 return o, (kp, vp)
             return attend
 

@@ -203,12 +203,12 @@ class Scheduler:
         self.full_tick_tokens = 0
         self.finished: list[Request] = []
         # run-start provenance: which implementation the attend seam
-        # runs (kernels { paged_attention }), so an incident report can
-        # say which path this run took (trace.py --summarize
-        # serving.attend_impl)
+        # runs and, where the engine chose it, why (engine.py
+        # choose_attend), so an incident report can say which path this
+        # run took (trace.py --summarize serving.attend_impl)
         self._event(
             "kernel_select", site="serve.paged_attention",
-            impl=engine.serving.attend_impl,
+            impl=engine.attend_choice,
         )
 
     def reset_counters(self) -> None:

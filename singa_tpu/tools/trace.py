@@ -260,8 +260,9 @@ def summarize(records: list[dict]) -> dict:
     )
 
     # kernel selection: the run-start kernel_select event says which
-    # attend implementation the serving engine ran (reference | fused)
-    # — incident reports must say which path a run took
+    # attend implementation the serving engine ran (fused | reference,
+    # followed by ": <why>" where the engine chose it itself) —
+    # incident reports must say which path a run took
     attend_impl = next(
         (
             r["data"].get("impl")

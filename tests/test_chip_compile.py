@@ -118,8 +118,7 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
     assert "tpu_custom_call" in text
 
 
-@pytest.fixture(scope="module")
-def serve_cell(one_chip):
+def _serve_cell(one_chip):
     """The engine of ``gpt2_medium_serve_closed`` (32 slots, blocks of
     16, 128-token chunks; GPT-2 medium's width) cut to 2 layers, and
     its arguments as shapes on the described chip: the pools are
@@ -172,6 +171,24 @@ def serve_cell(one_chip):
     }
 
 
+@pytest.fixture(scope="module")
+def serve_cell(one_chip):
+    """The cell's engine as this process builds it: on the CPU the
+    engine chooses the gather path."""
+    return _serve_cell(one_chip)
+
+
+@pytest.fixture(scope="module")
+def serve_cell_on_tpu(one_chip):
+    """The cell's engine as the chip builds it, ``attend_impl`` unset:
+    ``jax.default_backend()`` still says cpu here (the chip is
+    described, not attached), so the answer is steered while the
+    engine is built, and again by the test while the kernel lowers."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return _serve_cell(one_chip)
+
+
 @pytest.mark.parametrize("program", [
     "decode", "prefill", "verify", "cow", "import", "install", "export",
     "export_blocks",
@@ -186,11 +203,20 @@ def test_serving_programs_relayout_no_pool(serve_cell, program):
     (copy-on-write, migration, prefix shipping) are held to the same:
     they transpose a slot's blocks at the wire, never a pool."""
     eng, programs = serve_cell
-    fn, args, pools_at, pools_out = programs[program]
+    assert eng.attend_choice == "reference: platform = cpu"
+    _no_pool_is_relaid_out(eng, *_compiled_program(programs[program]))
+
+
+def _compiled_program(program):
+    fn, args, pools_at, pools_out = program
     donate = (pools_at,) if pools_out else ()
     text = (
         jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
     )
+    return text, pools_out
+
+
+def _no_pool_is_relaid_out(eng, text, pools_out):
     pool = eng.state["k"][0]
     n_pools = 2 * eng.cfg.n_layers
     copies = [
@@ -212,6 +238,34 @@ def test_serving_programs_relayout_no_pool(serve_cell, program):
     if pools_out:
         assert len(leave) == n_pools and set(leave) == set(arrive)
         assert header.count("-alias)") >= n_pools
+
+
+@pytest.mark.parametrize("program,kernel", [
+    ("decode", True), ("verify", True), ("prefill", False),
+])
+def test_engine_on_a_tpu_reads_the_pool_in_place(
+    serve_cell_on_tpu, program, kernel, monkeypatch
+):
+    """Left to itself on a TPU, the engine's decode tick (and the
+    verify pass that follows the same choice) holds the Mosaic kernel
+    and no dense ``(slots, cache_len, heads, head_dim)`` view of a
+    pool in any order of its dimensions — and still relays out no
+    pool. The prefill chunk keeps its one-slot gather whatever the
+    choice: the program decides that, not the platform."""
+    eng, programs = serve_cell_on_tpu
+    assert eng.attend_choice == "fused"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, pools_out = _compiled_program(programs[program])
+    assert ("tpu_custom_call" in text) == kernel
+    s, cl = eng.serving.slots, eng.pool.cache_len
+    h, d = eng.cfg.n_heads, eng.cfg.head_dim
+    dense = {(s, cl, h, d), (s, h, cl, d), (s, cl, h * d)}
+    shapes = {
+        tuple(map(int, m.group(1).split(",")))
+        for m in re.finditer(r"f32\[([\d,]+)\]", text)
+    }
+    assert not dense & shapes
+    _no_pool_is_relaid_out(eng, text, pools_out)
 
 
 @pytest.mark.parametrize(

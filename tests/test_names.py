@@ -56,13 +56,13 @@ class Recorder:
         self.spans.append((name, track, steps))
 
 
-def tiny_engine(spec_k=0):
+def tiny_engine(**serving):
     cfg = TransformerConfig(
         vocab=32, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_len=32
     )
     params = init_lm(jax.random.PRNGKey(0), cfg)
     return Engine(params, cfg, EngineConfig(
-        slots=2, kv_block_len=8, max_prefill_chunk=4, spec_k=spec_k,
+        slots=2, kv_block_len=8, max_prefill_chunk=4, **serving,
     ))
 
 
@@ -344,6 +344,33 @@ def engine_texts():
 def test_decode_program_names_its_operations(engine_texts, scope):
     names = {n for _, n in instructions(engine_texts["_decode"])}
     assert any(f"jit(_decode)/{scope}/" in n for n in names), scope
+
+
+@pytest.fixture(scope="module")
+def kernel_decode_names():
+    """``jit__decode`` of an engine on the paged kernel (interpreted
+    here; on a TPU the engine chooses it itself)."""
+    eng = tiny_engine(attend_impl="fused")
+    text = eng._decode_jit.lower(eng.params, eng.state).compile().as_text()
+    return {n for _, n in instructions(text)}
+
+
+@pytest.mark.parametrize("scope", [
+    "blk0/attend/paged_attention", "blk1/attend/paged_attention",
+    "blk0/attend/kv_write",
+])
+def test_decode_under_the_kernel_names_it_inside_attend(
+    kernel_decode_names, scope
+):
+    assert any(
+        f"jit(_decode)/{scope}/" in n for n in kernel_decode_names
+    ), scope
+
+
+@pytest.mark.parametrize("scope", ["gather_kv", "cache_attend"])
+def test_decode_under_the_kernel_has_no_gather(kernel_decode_names, scope):
+    # ``kv_gather_ms_per_tick`` reads the scope and falls silent
+    assert not any(f"/{scope}/" in n for n in kernel_decode_names)
 
 
 @pytest.mark.parametrize("program", ["_prefill", "_verify"])

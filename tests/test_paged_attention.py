@@ -34,6 +34,7 @@ from singa_tpu.ops.paged_attention import (
     paged_attention_overlay,
 )
 from singa_tpu.serve import Engine, EngineConfig, Request, Scheduler
+from singa_tpu.serve.engine import choose_attend
 
 
 def tiny_cfg(**kw):
@@ -131,6 +132,49 @@ def test_kernel_matches_gather_oracle(block_len, head_dim, fill):
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize("chunk_positions", [16, 128, 4096])
+def test_one_query_kernel_walks_the_live_chunks(monkeypatch, chunk_positions):
+    """The decode tick's form (one query a sequence, blocks copied by
+    hand a chunk at a time over a flat list of live chunks) at
+    sequences of very different lengths: one position, a chunk's last
+    and first, a block's edge, the whole table; a chunk of two blocks,
+    of sixteen, and wider than the table. Each against the oracle and
+    against the grid form, which the same call takes for a block
+    length off the register tile."""
+    from singa_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_CHUNK_POSITIONS", chunk_positions)
+    rs = np.random.RandomState(chunk_positions)
+    h, d, bl, mb = 3, 8, 8, 48
+    positions = [0, 7, 8, 127, 128, 200, mb * bl - 1]
+    s = len(positions)
+    nb = s * mb + 1
+    kp = jnp.asarray(rs.randn(nb, h, bl, d), jnp.float32)
+    vp = jnp.asarray(rs.randn(nb, h, bl, d), jnp.float32)
+    qh = jnp.asarray(rs.randn(s, h, 1, d), jnp.float32)
+    tables = jnp.asarray(
+        1 + rs.permutation(s * mb).reshape(s, mb), jnp.int32
+    )
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    got = paged_attention(
+        qh, stored(kp), stored(vp), tables, pos, interpret=True
+    )
+    want = cache_attend(
+        qh, oracle_gather(kp, tables, mb * bl),
+        oracle_gather(vp, tables, mb * bl), pos,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5
+    )
+    monkeypatch.setattr(pa, "_sublanes", lambda dtype: bl + 1)
+    grid = paged_attention(
+        qh, stored(kp), stored(vp), tables, pos, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(grid), atol=1e-5, rtol=1e-5
     )
 
 
@@ -443,6 +487,118 @@ def test_engine_rejects_untileable_fused_geometry(lm):
 
 
 # ---------------------------------------------------------------------------
+# the engine chooses
+# ---------------------------------------------------------------------------
+
+
+def sdar_shaped_cfg(**kw):
+    """Fewer K/V heads than query heads, generated by diffusion over
+    blocks: the model the kernel does not know."""
+    base = dict(
+        vocab=40, d_model=32, n_heads=4, n_layers=2, max_len=32,
+        norm="rmsnorm", pos="rope", n_kv_heads=2, head_dim=8, qk_norm=True,
+        tied_head=False, moe_experts=4, moe_top_k=2, moe_d_ff=16,
+        diffusion_block=4, mask_id=39,
+    )
+    base.update(kw)
+    return TransformerConfig(**base)
+
+
+@pytest.mark.parametrize("cfg,serving,mesh,platform,choice", [
+    (tiny_cfg(), {}, None, "tpu", "fused"),
+    (tiny_cfg(), {}, None, "cpu", "reference: platform = cpu"),
+    (tiny_cfg(), {}, None, "gpu", "reference: platform = gpu"),
+    (tiny_cfg(), {}, "a mesh", "tpu", "reference: a tensor-parallel mesh"),
+    (tiny_cfg(n_heads=4, n_kv_heads=2), {}, None, "tpu",
+     "reference: n_kv_heads = 2 != n_heads = 4"),
+    (sdar_shaped_cfg(n_kv_heads=4), {}, None, "tpu",
+     "reference: diffusion_block = 4"),
+    (sdar_shaped_cfg(), {}, "a mesh", "tpu",
+     "reference: diffusion_block = 4"),
+    # a pin wins over everything the choice looks at
+    (tiny_cfg(), {"attend_impl": "reference"}, None, "tpu", "reference"),
+    (tiny_cfg(), {"attend_impl": "fused"}, None, "cpu", "fused"),
+    (tiny_cfg(), {"attend_impl": "fused"}, "a mesh", "cpu", "fused"),
+], ids=[
+    "tpu_mha_no_mesh", "cpu", "gpu", "mesh", "fewer_kv_heads",
+    "diffusion_block", "the_model_is_named_first", "pinned_reference",
+    "pinned_fused", "pinned_fused_under_a_mesh",
+])
+def test_choose_attend(cfg, serving, mesh, platform, choice):
+    """The kernel where it compiles and knows the model, the gather
+    path everywhere else, each with the reason the ``kernel_select``
+    event carries; asked by hand, so the CPU can ask about a TPU."""
+    assert choose_attend(
+        cfg, EngineConfig(kv_block_len=8, **serving), mesh, platform
+    ) == choice
+
+
+def test_unset_engine_on_the_cpu_takes_the_gather_path_and_says_why(lm):
+    cfg, params = lm
+    eng = Engine(params, cfg, EngineConfig(slots=2, kv_block_len=8))
+    assert eng.attend_choice == "reference: platform = cpu"
+    assert not eng._fused
+    events = []
+
+    class Recorder:
+        def event(self, kind, **payload):
+            events.append((kind, payload))
+
+    Scheduler(eng, recorder=Recorder())
+    assert ("kernel_select", {
+        "step": 0, "site": "serve.paged_attention",
+        "impl": "reference: platform = cpu",
+    }) in events
+
+
+def test_unset_engine_on_a_tpu_runs_the_kernel(lm, monkeypatch):
+    """What the CPU cannot be asked by construction is asked with the
+    platform steered from the test: the engine's decode holds the
+    kernel, its pinned twin's does not."""
+    cfg, params = lm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, interpret=True,
+    ))
+    assert eng.attend_choice == "fused" and eng._fused
+    jaxpr = str(jax.make_jaxpr(eng._decode)(params, eng.state))
+    assert "name=paged_attention" in jaxpr
+    pinned = Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, attend_impl="reference",
+    ))
+    assert "pallas_call" not in str(
+        jax.make_jaxpr(pinned._decode)(params, pinned.state)
+    )
+
+
+def test_sdar_shaped_engine_lowers_the_same_block_step_unset_or_pinned(
+    monkeypatch,
+):
+    """A model the kernel does not know takes the gather path on a TPU
+    too: ``_block_step`` and ``_prefill`` lower to the text they lower
+    to under an explicit ``reference``."""
+    cfg = sdar_shaped_cfg()
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def texts(**kw):
+        eng = Engine(params, cfg, EngineConfig(
+            slots=2, kv_block_len=8, max_prefill_chunk=4, block_steps=2, **kw,
+        ))
+        return eng, (
+            eng._block_step_jit.lower(eng.params, eng.state).as_text(),
+            eng._prefill_jit.lower(
+                eng.params, eng.state, jnp.int32(0),
+                jnp.zeros((4,), jnp.int32), jnp.int32(0), jnp.int32(4),
+            ).as_text(),
+        )
+
+    unset, unset_texts = texts()
+    assert unset.attend_choice == "reference: diffusion_block = 4"
+    assert unset_texts == texts(attend_impl="reference")[1]
+
+
+# ---------------------------------------------------------------------------
 # conf / lint
 # ---------------------------------------------------------------------------
 
@@ -544,8 +700,13 @@ def test_engine_config_from_conf_reads_kernels_block():
     from singa_tpu.config.schema import KernelsConfig, ServingConfig
 
     ec = EngineConfig.from_conf(None, None)
-    # unset: the platform decides (ops/paged_attention._call)
-    assert ec.attend_impl == "reference" and ec.interpret is None
+    # unset: the engine chooses the path (choose_attend) and the
+    # platform the kernel's form (ops/paged_attention._call)
+    assert ec.attend_impl is None and ec.interpret is None
+    # a kernels block that names no paged_attention leaves it unset too
+    assert EngineConfig.from_conf(
+        None, KernelsConfig.from_fields({"interpret": [True]})
+    ).attend_impl is None
     assert EngineConfig.from_conf(
         None, KernelsConfig.from_fields({"paged_attention": ["fused"]})
     ).interpret is None
