@@ -66,7 +66,7 @@ class Sizes:
     the CPU rehearsal passes others."""
 
     resnet_conf: str = "examples/imagenet/resnet50.conf"
-    resnet_batch: int = 128       # bench.py's one-chip batch
+    resnet_batch: int = 128       # one chip's batch
     resnet_image: int = 256       # stored record edge (the conf crops)
     lm_conf: str = "examples/lm/tinylm_d128.conf"
     lm_seq: int = 8192            # the standing long-context shape

@@ -395,7 +395,7 @@ class RBMConfig(Message):
     The reference declares the contrastive-divergence algorithm
     (GradCalcAlg.kContrastiveDivergence, model.proto:40-44) but ships no CD
     worker or RBM layer; this message parameterizes the greenfield kRBM
-    layer that fills that hole (BASELINE config 4)."""
+    layer that fills that hole (examples/mnist/rbm.conf)."""
 
     FIELDS = {
         "num_hidden": Field("int"),

@@ -72,8 +72,8 @@ def registered_types() -> list[str]:
 
 
 # the reference's 18 built-ins (neuralnet.cc:13-33) + extensions:
-# kSigmoid, kRBM + kEuclideanLoss (the CD/autoencoder path, BASELINE #4),
-# kBatchNorm/kAdd/kGlobalPooling (the ResNet vocabulary, BASELINE #5),
+# kSigmoid, kRBM + kEuclideanLoss (the CD/autoencoder path),
+# kBatchNorm/kAdd/kGlobalPooling (the ResNet vocabulary),
 # kSequenceData/kEmbedding/kLayerNorm/kAttention/kDense/kLMLoss/kMoE (the
 # transformer-LM vocabulary — long-context + MoE as config citizens)
 for _cls in (

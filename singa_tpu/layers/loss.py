@@ -54,8 +54,8 @@ class EuclideanLossLayer(Layer):
     """kEuclideanLoss: 0.5 * mean squared reconstruction error.
 
     singa-tpu extension (no counterpart in this reference snapshot): the
-    regression/autoencoder loss needed by BASELINE config 4's deep
-    autoencoder, where the target srclayer is the input image itself.
+    regression/autoencoder loss needed by the deep autoencoder
+    (examples/mnist/autoencoder.conf), where the target srclayer is the input image itself.
     Takes (prediction, target) srclayers; both are flattened to
     (batch, -1). loss = 0.5/batch * sum((pred - target)^2).
     """

@@ -2,8 +2,8 @@
 
 The reference predates batch normalization and residual networks (its
 layer registry tops out at LRN, src/worker/neuralnet.cc:13-33); these
-layers extend the same config surface so BASELINE.json's stretch target —
-ImageNet ResNet-50 (config 5) — is expressible as a plain job file.
+layers extend the same config surface so ImageNet ResNet-50 is
+expressible as a plain job file.
 
 kBatchNorm's running statistics are the framework's first *buffers*:
 non-trainable state updated by the layer inside the jitted step and

@@ -3,8 +3,7 @@
 The reference *declares* CD training — GradCalcAlg.kContrastiveDivergence
 (src/proto/model.proto:40-44) and the TrainOneBatch comment naming a
 "CD worker" (include/worker/base_layer.h:96-97) — but this snapshot ships
-no RBM layer or CD worker; BASELINE config 4 ("RBM / deep autoencoder on
-MNIST") makes it a target anyway. This layer is that greenfield fill,
+no RBM layer or CD worker. This layer is that greenfield fill,
 designed TPU-first: the whole CD-k Gibbs chain is a fixed-length
 `lax.scan`-free unroll of sigmoid+matmul ops inside the jitted step, so
 the MXU sees (B,V)x(V,H) matmuls and XLA fuses the sampling elementwise.

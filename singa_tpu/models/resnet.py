@@ -1,4 +1,4 @@
-"""ResNet job-config generator (BASELINE.json stretch config 5).
+"""ResNet job-config generator (ImageNet ResNet-50 and its kin).
 
 The reference expresses every model as a text-proto layer list
 (examples/mnist/*.conf); ResNets keep that contract — this module *emits

@@ -18,7 +18,7 @@ from autodiff of the naive formula.
 The reference has no batch normalization (its registry tops out at LRN,
 /root/reference/src/worker/neuralnet.cc:13-33); this op backs the
 kBatchNorm extension layer (singa_tpu/layers/norm.py) that the ResNet
-configs (BASELINE stretch config 5) are built from.
+configs are built from.
 
 ``batch_norm_train`` returns (y, mean, var). The y-cotangent math is
 the standard BN backward:
@@ -178,11 +178,10 @@ def batch_norm_train_sampled(x, gamma, beta, eps, stride, shift=None):
         estimate — large batches tolerate this the way ghost/virtual BN
         does);
       * the mean/var gradient paths are dropped (straight-through).
-    Why it exists: measured on ResNet-50 @128/v5e, exact BN's marginal
-    cost is 14.6 ms of a 46.6 ms step, and the irreducible same-math
-    term (the stats read, 2.71 GB) caps a perfect conv-epilogue kernel
-    at ~3.3 ms back = 34.7% MFU (bench/ablations/bn_roofline.py). This
-    knob removes (stride-1)/stride of the stats read AND lets XLA fuse
+    Why it exists: the irreducible same-math term of exact BN is the
+    stats read (2.71 GB on ResNet-50 at batch 128, counted from the
+    shapes), which no conv-epilogue kernel can remove. This knob
+    removes (stride-1)/stride of the stats read AND lets XLA fuse
     the whole backward into one (dy, x) read since dx no longer waits
     on the reductions. Exposed as batchnorm_param.stats_sample_stride
     (default 1 = exact op); convergence consequences are the user's

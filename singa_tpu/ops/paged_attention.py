@@ -404,10 +404,8 @@ def live_blocks(last_position, block_len, max_blocks):
     """Blocks the kernel's clamped grid actually fetches for one
     sequence whose last attended POOL position is ``last_position``
     (= ceil((last_position + 1) / block_len), clipped to the table
-    width; -1 = no pool blocks). The ONE formula shared by the kernel
-    (``_call``'s nlive) and the bytes model tools/attend_stall.py
-    gates on — keeping the gated model in lockstep with what the
-    kernel fetches. Works on scalars and arrays."""
+    width; -1 = no pool blocks): the ``nlive`` of both kernel forms.
+    Works on scalars and arrays."""
     return jnp.clip((last_position + block_len) // block_len, 0, max_blocks)
 
 
@@ -489,33 +487,6 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         interpret=bool(interpret),
         name="paged_attention",
     )(tflat, nlive, *args)
-
-
-def modeled_bytes(
-    n_seqs: int, n_heads: int, n_queries: int, head_dim: int,
-    block_len: int, live_blocks_total: int, *, overlay: bool = False,
-    itemsize: int = 4,
-) -> int:
-    """The kernel's modeled bytes accessed for one invocation — what a
-    ``pl.CostEstimate`` declares on hardware: Q in, the LIVE K/V block
-    tiles the clamped grid actually fetches (dead iterations re-fetch
-    the previous block and Pallas skips the DMA), the overlay chunk if
-    any, and O out. ``live_blocks_total`` is the sum over sequences of
-    each one's live-block count (what ``_call`` computes as ``nlive``).
-
-    This is the deterministic arm of tools/attend_stall.py's or-gate:
-    the XLA cost analysis of the INTERPRETED kernel models the
-    emulation's bookkeeping (whole-buffer loop carries), not the
-    kernel's memory traffic, so the comparison against the reference
-    path's dense gather uses this model instead — block-tile reads vs
-    the ``(slots, H, cache_len, D)`` materialization."""
-    qo = 2 * n_seqs * n_heads * n_queries * head_dim * itemsize
-    kv = 2 * live_blocks_total * n_heads * block_len * head_dim * itemsize
-    chunk = (
-        2 * n_seqs * n_heads * n_queries * head_dim * itemsize
-        if overlay else 0
-    )
-    return qo + kv + chunk
 
 
 def paged_attention(

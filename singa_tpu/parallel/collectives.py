@@ -27,7 +27,7 @@ the sharding constraint is applied to the quantized tensor, and the
 residuals live shard-local), which is where an XLA with EQuARX-style
 quantized collectives picks the wire format up. The convergence harness
 (tools/convergence.py ``--grad_comm q8``) validates the numerics end to
-end; tools/collective_stall.py gates the machinery's step-time cost.
+end.
 
 **Comm/compute overlap** (the async parameter-server heritage, made
 synchronous): ``buckets: N`` partitions the params into N groups in

@@ -1,9 +1,8 @@
 """Speculative multi-token decode: model-free drafting for the engine.
 
 The serving engine's one-token tick is weight-streaming-bound: every
-tick streams the full weights to emit one token per live slot
-(tools/serve_bench.py measured it; the lm_d128_serve bench row notes
-it). Speculative decoding amortizes that stream: draft ``k`` candidate
+tick streams the full weights to emit one token per live slot.
+Speculative decoding amortizes that stream: draft ``k`` candidate
 tokens per slot cheaply, score all ``(slots, k+1)`` positions in ONE
 batched verify forward (serve/engine.py ``Engine.verify``), and emit
 every accepted token — up to k+1 tokens for the cost of one weight

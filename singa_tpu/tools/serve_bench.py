@@ -29,7 +29,7 @@ speculation machinery — the verify program at zero draft width, i.e.
 the one-token tick plus draft lanes, acceptance cumprod, and the KV
 rewind's save/restore, acceptance forced to zero by having nothing to
 accept — must cost <= 5% over the plain decode tick (interleaved
-best-of-trials, the collective_stall pattern). Token streams must be
+best-of-trials). Token streams must be
 IDENTICAL to the one-token run either way — speculation may only
 change *when* tokens appear, never *which*.
 
@@ -362,8 +362,8 @@ def run_continuous(params, cfg, prompts, args, slots, recorder=None,
 
 
 def measure_spec_machinery(params, cfg, args, trials=3, ticks=10):
-    """Isolated speculation-machinery cost (the collective_stall
-    "isolated machinery" or-gate arm): the verify program at ZERO draft
+    """Isolated speculation-machinery cost (the "isolated machinery"
+    or-gate arm): the verify program at ZERO draft
     width — the one-token tick plus everything speculation bolts on
     (draft lanes, acceptance cumprod, the rewind's masked write
     routing), with acceptance forced to zero by having nothing to
@@ -377,8 +377,8 @@ def measure_spec_machinery(params, cfg, args, trials=3, ticks=10):
     bytes accessed + transcendentals of the two programs) — on this
     repo's 2-core CI hosts, wall-clock A/B of near-identical compiled
     programs swings 0.8-1.25x from scheduling/compile-layout variance
-    (collective_stall documented the same; its slope-fit answer does
-    not apply to a single fused program), while the cost model
+    (a slope fit over window sizes does not apply to a single fused
+    program), while the cost model
     resolves the actual <1% machinery delta deterministically.
     Interleaved best-of-trials wall times ride the JSON un-gated for
     transparency. -> dict(cost_ratio, time_ratio, decode_ms,
@@ -1211,7 +1211,7 @@ def _fleet_main(args, params, cfg, prompts) -> int:
         if out["single_tokens_per_s"] else None
     )
     has_decode = any(h.role == "decode" for h in hosts)
-    # or-gate (the stall tools' pattern): the end-to-end speedup
+    # or-gate: the end-to-end speedup
     # carries on accelerator hosts, where N fleet hosts ARE N chips'
     # worth of decode bandwidth; on CPU CI every "host" shares the
     # same cores, so the deterministic arm carries — the role split
@@ -1376,7 +1376,7 @@ def main(argv=None) -> int:
         out["decode_tick_ms"] = _r(probe["decode_ms"])
         out["verify_k0_tick_ms"] = _r(probe["verify_k0_ms"])
         out["spec_threshold"] = args.spec_threshold
-        # or-gate (the stall tools' pattern): the end-to-end speedup
+        # or-gate: the end-to-end speedup
         # carries where drafting lands (the accelerator bar — one
         # weight stream buys up to k+1 tokens; on a CPU host decode is
         # compute-bound, so the (k+1)-wide verify pays ~(k+1)x compute
@@ -1411,7 +1411,7 @@ def main(argv=None) -> int:
         # prefill work, never move a token
         out["token_mismatches"] = _token_mismatches(cold_sched, sched)
         out["prefix_threshold"] = args.prefix_threshold
-        # or-gate (the stall tools' pattern): end-to-end warm/cold
+        # or-gate: end-to-end warm/cold
         # tokens/sec carries where prefill dominates the workload (the
         # production bar); the prefill-chunks-EXECUTED drop is the
         # deterministic, host-independent arm — a counter, not a
@@ -1479,7 +1479,7 @@ def main(argv=None) -> int:
         )
         out["token_mismatches"] = mismatches
         out["threshold"] = args.threshold
-        # or-gate (ckpt/input/collective_stall's pattern): the END-TO-END
+        # or-gate: the END-TO-END
         # speedup carries where the workload is long enough to amortize
         # admission; the STEADY-STATE ratio is the honest capacity
         # measurement on short CI workloads and noisy shared runners.

@@ -4,8 +4,8 @@ batch.sh reruns a job over nworkers in {1,2,4,8,16}, rewriting
 cluster.conf each time and logging to log1k/NwMsTt
 (examples/mnist/batch.sh:3-17). Here each sweep point runs the job for a
 fixed step count on an nworkers-device mesh and reports samples/sec plus
-scaling efficiency vs the smallest point — the BASELINE.json ">=70% from
-8 to 64 chips" bar, rehearsed ahead of hardware on a virtual CPU mesh.
+scaling efficiency vs the smallest point; ``--virtual`` rehearses the
+meshes on virtual CPU devices, where the rates are not device numbers.
 
 Each point runs in a fresh subprocess because the XLA device-count flag
 must be set before jax import (and real multi-host runs are one process
@@ -64,25 +64,18 @@ def _child(model_conf: str, nworkers: int, steps: int,
         "nworkers": nworkers,
         "batch": trainer.train_net.batchsize,
         "samples_per_sec": (steps - warmup) * trainer.train_net.batchsize / dt,
-        # which input path and update layout fed the point (bench.py's
-        # feeder/update_mode row fields) — a scaling knee stays
-        # attributable to the data path or the update sharding
+        # which input path and update layout fed the point — a scaling
+        # knee stays attributable to the data path or the update
+        # sharding
         "feeder": trainer.feeder_mode,
         "update_mode": trainer.update_mode,
         "opt_state_bytes_per_device": trainer.opt_state_bytes_per_device(),
         # how gradients crossed the data axis at this point (exact /
-        # quantized + wire dtype) and the machinery's isolated marginal
-        # ms — a scaling knee stays attributable to the collective
+        # quantized + wire dtype) — a scaling knee stays attributable
+        # to the collective
         "comm_mode": trainer.comm_mode,
         "comm_dtype": trainer.comm_dtype,
-        "comm_ms": round(_comm_ms(trainer), 3),
     }))
-
-
-def _comm_ms(trainer) -> float:
-    from .collective_stall import measure_comm_ms
-
-    return measure_comm_ms(trainer, i1=2, i2=6, trials=1)
 
 
 def run_sweep(
@@ -156,7 +149,7 @@ def main(argv: list[str] | None = None) -> int:
                         grad_comm=args.grad_comm)
     print(
         f"{'nworkers':>8} {'batch':>6} {'samples/s':>12} {'efficiency':>10} "
-        f"{'update':>10} {'opt-B/dev':>10} {'comm':>14} {'comm-ms':>8}"
+        f"{'update':>10} {'opt-B/dev':>10} {'comm':>14}"
     )
     for r in results:
         comm = r["comm_mode"] + (f":{r['comm_dtype']}" if r["comm_dtype"]
@@ -165,7 +158,7 @@ def main(argv: list[str] | None = None) -> int:
             f"{r['nworkers']:>8} {r['batch']:>6} "
             f"{r['samples_per_sec']:>12.0f} {r['efficiency']:>10.2f} "
             f"{r['update_mode']:>10} {r['opt_state_bytes_per_device']:>10} "
-            f"{comm:>14} {r['comm_ms']:>8.3f}"
+            f"{comm:>14}"
         )
     if args.json:
         with open(args.json, "w") as f:

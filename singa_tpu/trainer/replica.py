@@ -377,9 +377,7 @@ class ReplicaTrainer(Trainer):
         # chunk program; window-aligned chunks additionally stack
         # MULTIPLE windows into one program (outer lax.scan over
         # windows, round between inner scans) — one dispatch where the
-        # split engine paid 2 per window. Measured on chip: the replica
-        # bench row went 0.828 (split) -> 0.675 (single-window fused)
-        # -> see BASELINE r5 for the multi-window number.
+        # split engine paid 2 per window.
         fusable = fires and self._device_pure_sync()
         if not fusable:
             super().train_chunk(step0, nsteps)

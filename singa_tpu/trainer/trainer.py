@@ -19,6 +19,7 @@ the train step of the step they trigger on (worker.cc:190-200).
 from __future__ import annotations
 
 import os
+import time
 from typing import Callable
 
 import jax
@@ -1193,9 +1194,9 @@ class Trainer:
         return params, state, new_buffers, metrics, ok
 
     def _ring_reduce_probe(self, grads: dict, res: dict):
-        """The ring reduction in isolation (no forward) for the stall
-        tools' machinery probes: each shard treats the replicated input
-        as its local partial, so the program exercises exactly the
+        """The ring reduction in isolation (no forward) for the comm
+        probe (``_record_comm_probe``): each shard treats the replicated
+        input as its local partial, so the program exercises exactly the
         step's quantize/ppermute/accumulate work."""
         from jax.sharding import PartitionSpec as P
 
@@ -1243,13 +1244,13 @@ class Trainer:
         the reference fp32 ring all-reduce (reduce-scatter alone under
         zero_update) vs the quantized ring's ppermute payloads. The one
         place the model's trainer plumbing (sizes, buckets, gather map,
-        zero sharding) lives: the ``kernel_select`` event,
-        tools/collective_stall.py's gated arm, and bench.py's
-        ``wire_bytes_ratio`` row all consult it. ``ndata`` defaults to
-        the mesh's real data-axis width; bench passes a nominal width
-        when the host's own axis is 1-wide (an empty wire) — a nominal
-        width the chunking could not actually divide is halved until
-        ``ring_reducible`` accepts it (never below the real width), so
+        zero sharding) lives: the ``kernel_select`` event and the
+        wire-bytes audit of tests/test_quantized_collective.py consult
+        it. ``ndata`` defaults to the mesh's real data-axis width; a
+        caller may price a nominal width when the host's own axis is
+        1-wide (an empty wire) — a nominal width the chunking could not
+        actually divide is halved until ``ring_reducible`` accepts it
+        (never below the real width), so
         the model's floor divisions stay exact and the priced geometry
         is one the ring could really run. Under ``q8_hier`` the dict
         additionally carries the per-level split — ``intra`` /
@@ -1368,11 +1369,70 @@ class Trainer:
             return
         self._comm_probe_done = True
         try:
-            from ..tools.collective_stall import record_comm_probe
-
-            record_comm_probe(self)
+            self._record_comm_probe()
         except Exception as e:  # pragma: no cover - defensive
             self.log(f"TELEMETRY: comm probe failed: {e}")
+
+    def _record_comm_probe(self) -> None:
+        """Run 16 chained reduction rounds (the constrain +
+        quantize + dequantize + residual-update machinery, nothing else)
+        ONCE under the ``comm`` phase, compile + warmup outside the
+        timed region, so the flight recorder gets a real measured span
+        whose dur/steps is the per-reduction cost, and emit a
+        ``comm_probe`` event carrying the host-side number. A
+        quantized_ring trainer's rounds run the real shard_map'd ring
+        (``_ring_reduce_probe`` — each round's ppermutes move the int8
+        chunks); every other mode rides ``_reduce_grads``."""
+        from ..parallel.collectives import is_residual_key
+
+        rounds = 16
+        spec = self._comm
+        reduce = (
+            self._ring_reduce_probe if spec.ring else self._reduce_grads
+        )
+
+        def prog(grads, res):
+            def body(carry, i):
+                g, r = carry
+                g2, r2 = reduce(g, r)
+                return (g2, {**r, **r2}), jnp.float32(0)
+
+            (g, _), _ = jax.lax.scan(
+                body, (grads, res), jnp.arange(rounds)
+            )
+            return g
+
+        # inputs are live-state-shaped (and the residuals ARE the live
+        # buffers) — never donate them
+        fn = jax.jit(prog)  # netlint: disable=JAX003
+        # ones in the live params' stored shapes (an all-zero gradient
+        # would pin the int8 scale to its floor — not the representative
+        # regime), plus the trainer's actual residual buffers
+        grads = jax.tree.map(jnp.ones_like, dict(self.params))
+        res = {
+            k: v for k, v in self.buffers.items() if is_residual_key(k)
+        }
+
+        def run() -> float:
+            g = fn(grads, res)
+            # a host pull of a reduction over the result: it cannot
+            # return before the work is done
+            return float(jnp.sum(jnp.abs(next(iter(g.values())))))
+
+        run()  # compile + warm, outside the span
+        t0 = time.perf_counter()
+        with self.timers.phase("comm", steps=rounds):
+            run()
+        ms = (time.perf_counter() - t0) / rounds * 1e3
+        self.telemetry.event(
+            "comm_probe",
+            step=self.start_step,
+            mode=self.comm_mode,
+            dtype=self.comm_dtype,
+            buckets=spec.buckets,
+            rounds=rounds,
+            comm_ms=round(ms, 4),
+        )
 
     @jax.named_scope("update")
     def _apply_update(self, step, params: dict, grads: dict, state: dict):

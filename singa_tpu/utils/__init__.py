@@ -1,7 +1,5 @@
-"""Observability utilities: metric averaging, phase timers, FLOPs/MFU
-accounting, graph viz."""
+"""Observability utilities: metric averaging, phase timers, graph viz."""
 
-from .flops import device_peak_flops, net_fwd_flops, train_step_flops
 from .metrics import Performance
 from .timers import Timers
 from .viz import dump_net_json
@@ -9,8 +7,5 @@ from .viz import dump_net_json
 __all__ = [
     "Performance",
     "Timers",
-    "device_peak_flops",
     "dump_net_json",
-    "net_fwd_flops",
-    "train_step_flops",
 ]

@@ -10,7 +10,7 @@ The directory is placed from OUTSIDE the program: when
 ``JAX_COMPILATION_CACHE_DIR`` is set jax has already taken it from the
 environment and nothing here (or anywhere in the repo) sets another.
 When it is not, every entry point — training jobs, serving hosts,
-``bench.py``, ``chip_smoke.py`` — shares ONE fixed, git-ignored
+``benchmark/run.py``, ``chip_smoke.py`` — shares ONE fixed, git-ignored
 directory inside the checkout (``DEFAULT_CACHE_DIR``), so two
 consecutive runs of the same checkout hit each other's entries.
 ``JAX_ENABLE_COMPILATION_CACHE=false`` (jax's own switch) turns it off.

@@ -84,7 +84,6 @@ SLOW_TESTS = {
     "test_trains_digits_to_reference_accuracy",
     "test_fused_streams_identical_under_speculation",
     "test_fused_verify_zero_draft_width_matches_reference",
-    "test_attend_stall_gate_smoke",
     "test_fused_under_tensor_parallel_matches_single_device",
     "test_fused_streams_identical_interleaved",
     "test_fused_streams_identical_prefix_warm",

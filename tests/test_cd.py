@@ -1,5 +1,5 @@
 """Contrastive-divergence path: kRBM layer, CDTrainer, kEuclideanLoss,
-and the unroll-to-autoencoder recipe (BASELINE config 4 — the reference
+and the unroll-to-autoencoder recipe (the reference
 declares alg kContrastiveDivergence, model.proto:40-44, but never built
 the worker; this is the greenfield fill)."""
 

@@ -1,4 +1,4 @@
-"""CIFAR-10 path (BASELINE config 3): cifar binary loader, meanfile,
+"""CIFAR-10 path: cifar binary loader, meanfile,
 RGB parser with mean subtraction, and the AlexNet-style example conf."""
 
 import os

@@ -398,7 +398,7 @@ class TestReplicaTrainer:
 
 
 class TestReplicaComposition:
-    """Replica protocols x kLayerPartition (VERDICT r4 #1a): the reference
+    """Replica protocols x kLayerPartition: the reference
     composes intra-group model partitioning with cross-group async sync
     freely (group_size>1 partitions the net, src/worker/neuralnet.cc:55-56,
     while Elastic/RandomSync reconcile the groups, src/utils/param.cc:
@@ -471,7 +471,7 @@ class TestReplicaComposition:
 
 class TestReplicaProductionEngine:
     """Round-3 promotion: device cache + scan chunks + buffers make the
-    ReplicaTrainer a first-class engine (VERDICT r2 weak #2)."""
+    ReplicaTrainer a first-class engine."""
 
     def test_chunked_run_matches_per_step_run(self, tmp_path):
         """run() (device-cached, sync-window chunks) reproduces the

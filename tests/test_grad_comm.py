@@ -522,18 +522,6 @@ def test_ordering_chain_only_when_bucketized(shard):
 # ---------------------------------------------------------------------------
 
 
-def test_measure_comm_ms_isolated_probe(shard):
-    """The comm-machinery probe bench.py/collective_stall share: a
-    finite non-negative marginal ms for the exact, quantized, and
-    bucketized modes."""
-    from singa_tpu.tools.collective_stall import measure_comm_ms
-
-    for extra in ("", Q8, Q8_BUCKETS):
-        t = _mk(_cfg(shard, extra=extra))
-        ms = measure_comm_ms(t, i1=2, i2=6, trials=1)
-        assert np.isfinite(ms) and ms >= 0.0
-
-
 def test_comm_probe_records_span_and_summarize(shard, tmp_path):
     """The flight-recorder satellite: a grad_comm run with telemetry
     attached records ONE comm calibration span + comm_probe event at

@@ -292,7 +292,7 @@ def test_pp_conf_trains_on_data_pipe_mesh(token_shard):
     strict=False,
 )
 def test_three_axis_dp_pp_tp_matches_single_device(token_shard):
-    """A COMPOSED 3-axis job (VERDICT r4 #1c): one cluster conf builds a
+    """A COMPOSED 3-axis job: one cluster conf builds a
     (data=2, pipe=2, model=2) mesh and one program runs batch sharding,
     locationid pipeline stages, AND kLayerPartition dense splits at once
     — the shape of a real pod job, where every prior oracle paired a

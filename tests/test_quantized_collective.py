@@ -106,6 +106,29 @@ def _step_jaxpr(t):
     )
 
 
+def _wire_bytes(t) -> dict:
+    """``t.wire_bytes_model()`` plus the same bytes re-counted from the
+    step jaxpr's actual ppermute operand bytes x trip counts
+    (``ring_jaxpr``; per level as ``ring_jaxpr_intra`` /
+    ``ring_jaxpr_inter`` for a ``q8_hier`` trainer): the audit that the
+    model prices what the program sends — counts, no clocks."""
+    assert t._comm is not None and t._comm.ring
+    out = t.wire_bytes_model()
+    jaxpr = _step_jaxpr(t)
+    if t._comm.hier and t._ring_hier is not None:
+        intra_ax, inter_ax, k, _ = t._ring_hier
+        levels = ppermute_wire_bytes_levels(
+            jaxpr, intra_axis=intra_ax, inter_axis=inter_ax,
+            intra_degree=k,
+        )
+        out["ring_jaxpr_intra"] = int(levels["intra"])
+        out["ring_jaxpr_inter"] = int(levels["inter"])
+        out["ring_jaxpr"] = int(levels["intra"] + levels["inter"])
+    else:
+        out["ring_jaxpr"] = int(ppermute_wire_bytes(jaxpr))
+    return out
+
+
 def _ppermute_dtypes(jaxpr):
     """Every dtype a ppermute anywhere in the program moves, with the
     operand's element count — the wire inventory."""
@@ -312,14 +335,12 @@ def test_ring_wire_value_is_int8(shard):
 
 
 def test_wire_bytes_model_matches_jaxpr_and_gates(shard):
-    """The deterministic stall arm: the analytic ppermute-payload model
-    equals the bytes the traced program actually moves (scan trip
-    counts included), and the int8 drop vs the reference fp32
-    collective clears the >= 3.5x CI gate (~3.9x modeled)."""
-    from singa_tpu.tools.collective_stall import measure_wire_bytes
-
+    """The analytic ppermute-payload model equals the bytes the traced
+    program actually moves (scan trip counts included), and the int8
+    drop vs the reference fp32 collective is >= 3.5x (~3.9x
+    modeled)."""
     t = _mk(_cfg(shard, extra=Q8B_RING))
-    wire = measure_wire_bytes(t)
+    wire = _wire_bytes(t)
     assert wire["quantized_ring"] == wire["ring_jaxpr"] > 0
     assert wire["reference"] / wire["quantized_ring"] >= 3.5
     # the trainer-facing model agrees (what kernel_select reports)
@@ -335,7 +356,7 @@ def test_wire_bytes_model_matches_jaxpr_and_gates(shard):
     )
     # a nominal width the chunking can't divide (fc2 bias is (10,):
     # 10 % 8, 10 % 4) falls back to a validated width instead of
-    # pricing floor-divided phantom geometry (bench's wire_ndata)
+    # pricing floor-divided phantom geometry
     model = t.wire_bytes_model(ndata=8)
     assert model["ndata"] == 2
     assert model == t.wire_bytes_model()
@@ -405,8 +426,8 @@ def test_ring_bucketized_keeps_barrier_chain(shard):
 
 
 def test_ring_probe_reduces_correctly(shard):
-    """The ring reduction in isolation (`_ring_reduce_probe`, the stall
-    tools' seam): replicated input g on every shard -> the reduced
+    """The ring reduction in isolation (`_ring_reduce_probe`, the comm
+    probe's seam): replicated input g on every shard -> the reduced
     value is g back within one quantization step, and the banked
     residual is EXACTLY the owner-side quantization error (acc - deq),
     which re-injection would cancel."""
@@ -493,13 +514,11 @@ def test_ring_composes_with_zero_update(shard):
     the allgather phase never traces (fewer wire bytes, pinned against
     the jaxpr), and the run is LOSS-IDENTICAL to the ring over the
     replicated update — the same bar zero_update itself holds."""
-    from singa_tpu.tools.collective_stall import measure_wire_bytes
-
     tz = _mk(_cfg(shard, extra=Q8_RING, zero=True))
     tr = _mk(_cfg(shard, extra=Q8_RING, zero=False))
     assert tz.update_mode == "zero" and tz._comm.ring
     assert any(not g for g in tz._ring_gather.values())
-    wz, wr = measure_wire_bytes(tz), measure_wire_bytes(tr)
+    wz, wr = _wire_bytes(tz), _wire_bytes(tr)
     assert wz["quantized_ring"] == wz["ring_jaxpr"]
     assert wz["quantized_ring"] < wr["quantized_ring"]
     assert _loss_trace(tz, 12) == _loss_trace(tr, 12)
@@ -1033,16 +1052,14 @@ def _mk_named(cfg, *, cl=None, seed=3, **kw):
 
 
 def test_hier_wire_bytes_per_level_parity_and_gate(shard):
-    """The deterministic stall arm, per level: the analytic intra/inter
+    """Per level: the analytic intra/inter
     split equals the jaxpr-counted ppermute attribution EXACTLY (an
     inter level that shipped f32 chunks would count 4x the model and
     fail loudly), and the scarce-hop gate holds — inter bytes x
     intra_degree <= the flat same-n ring's bytes (K(M-1) <= KM-1,
     exact integers)."""
-    from singa_tpu.tools.collective_stall import measure_wire_bytes
-
     t = _mk(_cfg12(shard, extra=Q8B_HIER), ndata=4)
-    wire = measure_wire_bytes(t)
+    wire = _wire_bytes(t)
     assert wire["intra"] == wire["ring_jaxpr_intra"] > 0
     assert wire["inter"] == wire["ring_jaxpr_inter"] > 0
     assert wire["ring_jaxpr"] == wire["quantized_ring"] == (

@@ -1,5 +1,5 @@
 """BatchNorm/Add/GlobalPooling layers, buffer plumbing, and the ResNet
-config generator (BASELINE.json config 5)."""
+config generator."""
 
 import jax
 import jax.numpy as jnp

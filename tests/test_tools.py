@@ -197,22 +197,6 @@ def test_local_gangs_are_a_cpu_rehearsal(monkeypatch):
         refuse_local_ranks_on_a_chip(2)
 
 
-def test_device_peak_flops_knows_the_chip_or_raises():
-    """The CPU has no peak (no utilization is quoted against it); a
-    chip in the table answers; a chip that is not is an error, never a
-    default."""
-    from types import SimpleNamespace as Dev
-
-    from singa_tpu.utils.flops import device_peak_flops
-
-    assert device_peak_flops(Dev(platform="cpu", device_kind="cpu")) is None
-    assert device_peak_flops(
-        Dev(platform="tpu", device_kind="TPU v5 lite")
-    ) == 197e12
-    with pytest.raises(ValueError, match="no bf16 peak on record"):
-        device_peak_flops(Dev(platform="tpu", device_kind="TPU v9"))
-
-
 def test_compile_cache_is_placed_from_outside(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR set: no directory is set in code.
     Unset: one fixed directory inside the checkout."""

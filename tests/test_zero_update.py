@@ -483,14 +483,3 @@ def test_zero_knob_lint_did_you_mean(shard):
         d.code == "CFG001" and "zero_update" in (d.fix_hint or "")
         for d in col.sorted()
     )
-
-
-def test_measure_update_ms_isolated_probe(shard):
-    """The update-phase probe bench.py/update_stall share: returns a
-    finite positive marginal ms for both update modes."""
-    from singa_tpu.tools.update_stall import measure_update_ms
-
-    for zero in (False, True):
-        t = _mk(_cfg(shard, zero=zero), device_cache=False)
-        ms = measure_update_ms(t, i1=2, i2=6, trials=1)
-        assert np.isfinite(ms) and ms >= 0.0
