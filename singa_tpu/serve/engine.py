@@ -1266,18 +1266,20 @@ class Engine:
         return last
 
     def activate(self, slot: int, last_logits, plen: int, seed: int,
-                 temperature: float | None = None) -> int:
+                 temperature: float | None = None):
         """Sample the first token from the final prefill chunk's logits
         (the same key discipline as generate(): k0 = first split of the
         request's key), install the slot's temperature lane, and flip
         it live. ``temperature`` None = the engine default. -> the
-        first token."""
+        first token as a device scalar: nothing is read here, so the
+        caller can dispatch its decode before it waits for the chunk
+        (``int()`` of it is that wait)."""
         temp = self.temperature if temperature is None else float(temperature)
         self.state, first = self._activate_jit(
             self.state, jnp.int32(slot), last_logits,
             jnp.int32(plen), jnp.int32(seed), jnp.float32(temp),
         )
-        return int(first)
+        return first
 
     def activate_block(self, slot: int, prompt) -> None:
         """Flip ``slot`` live for block steps once the whole blocks of

@@ -846,6 +846,10 @@ class FleetHost:
                      f"{msg.src!r}: {e}")
             return
         cmd = body.get("cmd")
+        if cmd in ("flip", "rollback"):
+            # the tick boundary, whole: the pass dispatched under the
+            # outgoing weights is read before they go
+            self.sched.settle()
         if cmd == "flip":
             try:
                 res = self.engine.flip_params()
@@ -947,7 +951,9 @@ class FleetHost:
     def _export_ready(self) -> None:
         """Ship every filled (decoding-status) sequence to a decode
         peer. With no peer reachable the sequence WAITS in its slot —
-        the decode gate keeps it frozen, nothing is lost."""
+        the decode gate keeps it frozen, nothing is lost. (A host that
+        exports dispatches no decode, so no pass is in flight here:
+        ``drain`` settles the one a decoding host holds.)"""
         for slot in sorted(self.sched._slot_req):
             req = self.sched._slot_req[slot]
             if req.status != "decoding":
@@ -1063,6 +1069,11 @@ class FleetHost:
         # message a peer sent before seeing the tombstone must re-enter
         # the fleet through the forwarding below, not rot unread
         self._recv()
+        # decode runs one pass ahead of the host: read the pass in
+        # flight first, so the lanes a peer imports are the tokens the
+        # request holds (what it finishes is reported like any other)
+        self.sched.settle()
+        self._flush_results()
         self._event(
             "drain", reason=reason,
             in_flight=len(self.sched._slot_req),

@@ -22,7 +22,14 @@ scheduler answers every client's generation request from one engine:
   - every live slot advances one token per tick through the engine's
     single fixed-shape decode program; EOS or an exhausted budget
     retires the slot (blocks freed, available to the next admit — the
-    continuous part of continuous batching);
+    continuous part of continuous batching). Decode runs ONE PASS AHEAD
+    of the host: a tick dispatches all it has (admissions, chunks with
+    their hand-overs, its decode) before it reads anything, and the
+    decode it reads is the one dispatched the tick before, so delivery,
+    retirement, admission and the next hand-over happen while the device
+    works. The last token lives on the device and a pass needs nothing
+    from the host, so the tokens are the same; a request that finished
+    rides one more pass, unread (``lanes_unread``);
   - with speculation on (``serving { speculate { k } }``), each tick
     instead drafts up to k tokens per live greedy slot (model-free
     n-gram lookup over the request's own prompt+output,
@@ -32,7 +39,8 @@ scheduler answers every client's generation request from one engine:
     sequential decode would have stopped at (the tail of the run is
     discarded, never delivered). Temperature slots ride the same tick
     with zero drafts. Token streams are identical to one-token ticks
-    by construction; only tick count changes;
+    by construction; only tick count changes. The drafter needs the
+    newest token, so such a tick reads its own pass: none is in flight;
   - for a model generated by diffusion over blocks
     (``TransformerConfig.diffusion_block``) a tick dispatches the
     engine's BLOCK STEP instead: a pass over every live slot's current
@@ -43,14 +51,7 @@ scheduler answers every client's generation request from one engine:
     delivered token, the pass of its block that fixed it. Such a
     request's prefill covers the whole blocks of its prompt and yields
     no token; a budget that ends inside a block stops delivery there.
-    Block steps run ONE PASS AHEAD of the host: a tick dispatches its
-    pass and then reads the pass dispatched the tick before, so
-    delivery, retirement, admission and the next chunk's hand-over
-    happen while the device works (a block step is a quarter of a
-    one-token tick of the dense model here, and the host's share of it
-    would otherwise idle the device an eighth of the time). The state is
-    on the device and a pass needs nothing from the host, so the tokens
-    are the same; a request that finished rides one more pass, unread;
+    Block steps run one pass ahead of the host as one-token ticks do;
   - a SIGTERM'd serving host drains via the resilience plane: the
     serve loop observes ``PreemptionHandler.requested`` at a tick
     boundary, hands every in-flight sequence back (recorded, with its
@@ -68,7 +69,8 @@ inside it, ``admit`` / ``prefill`` / ``decode`` holding ``draft`` /
 ``dispatch`` / ``pull`` / ``emit``), so a profiler trace lays the
 scheduler's work beside the device's operations. Each carries
 ``tick=``; the spans of one request carry its ``rid=``. ``sched.pull``
-is the one place a tick waits for the device.
+is the one place a tick waits for the device, and the one place it
+reads from it: after the tick's ``sched.dispatch``.
 """
 
 from __future__ import annotations
@@ -77,6 +79,7 @@ import collections
 import dataclasses
 import time
 
+import jax
 import numpy as np
 
 from ..obs import span
@@ -151,10 +154,14 @@ class Scheduler:
         self.tokens_delivered = 0
         self.experts_hit = 0
         self.expert_max_load = 0
-        #: block steps run ONE PASS AHEAD of the host: (the device
-        #: result of the pass dispatched last tick, the requests it
-        #: served by slot), read this tick after the next is dispatched
+        #: decode and block steps run ONE PASS AHEAD of the host: (the
+        #: device result of the pass dispatched last tick, the requests
+        #: it served by slot), read this tick after the next is
+        #: dispatched (None under speculation: it reads its own tick's)
         self._in_flight = None
+        #: what that costs: lanes of the passes read (``_live_ticks``)
+        #: whose request had finished by then, one a finished request
+        self.lanes_unread = 0
         if drafter is not None:
             self.drafter = drafter
         else:
@@ -230,7 +237,7 @@ class Scheduler:
         self.tokens_delivered = 0
         self.experts_hit = self.expert_max_load = 0
         self.engine.allocator.reset_stats()
-        self._live_ticks = 0
+        self._live_ticks = self.lanes_unread = 0
         self.backpressure_ticks = 0
         self.full_tick_s, self.full_tick_tokens = 0.0, 0
 
@@ -365,9 +372,11 @@ class Scheduler:
             self._event("cow_copy", rid=req.rid, slot=slot)
         return True
 
-    def _prefill_some(self) -> None:
-        # one chunk per prefilling request per tick: decode never waits
-        # behind more than slots * one chunk of prompt work
+    def _prefill_some(self) -> list:
+        """One chunk per prefilling request per tick: decode never waits
+        behind more than slots * one chunk of prompt work. -> (slot,
+        request, first token on the device) where it was the last."""
+        firsts = []
         for slot in sorted(self._slot_req):
             req = self._slot_req[slot]
             if req.status != "prefill":
@@ -380,7 +389,10 @@ class Scheduler:
                 "sched.prefill", tick=self.ticks, rid=req.rid, slot=slot,
                 tokens=int(n),
             ):
-                self._prefill(slot, req, n)
+                first = self._prefill(slot, req, n)
+            if first is not None:
+                firsts.append((slot, req, first))
+        return firsts
 
     def _prefill_len(self, req: Request) -> int:
         """Prompt tokens that prefill writes: all of them, or under
@@ -389,10 +401,11 @@ class Scheduler:
         n = len(req.prompt)
         return n // self.block_len * self.block_len if self.block_len else n
 
-    def _prefill(self, slot: int, req: Request, n: int) -> None:
-        """One chunk of ``n`` prompt tokens; after the last, the
-        request's first token (``engine.activate`` pulls it) — or, under
-        block steps, no token: the slot goes live on its first block."""
+    def _prefill(self, slot: int, req: Request, n: int):
+        """One chunk of ``n`` prompt tokens; after the last the slot goes
+        live. -> the request's first token, still on the device
+        (``_first_tokens`` reads it once the tick's decode is out), or
+        None: not the last chunk, or block steps, which yield no token."""
         last = None
         if n:
             last = self.engine.prefill_chunk(
@@ -405,24 +418,36 @@ class Scheduler:
                 "prefill", rid=req.rid, slot=slot, tokens=int(n),
                 done=int(req._prefilled), of=int(len(req.prompt)),
             )
+        if req._prefilled < self._prefill_len(req):
+            return None
+        req.status = "decoding"
         if self.block_len:
-            if req._prefilled >= self._prefill_len(req):
-                self.engine.activate_block(slot, req.prompt)
-                self._fresh_block(req, list(req.prompt[req._prefilled:]))
-                req.status = "decoding"
+            self.engine.activate_block(slot, req.prompt)
+            self._fresh_block(req, list(req.prompt[req._prefilled:]))
+            return None
+        # every prompt position is now prefill-written: index
+        # the fully-covered blocks for future prefix hits
+        self.engine.register_prefix(slot, req.prompt)
+        return self.engine.activate(
+            slot, last, len(req.prompt), req.seed,
+            temperature=req.temperature,
+        )
+
+    def _first_tokens(self, firsts, tick: int) -> None:
+        """Read the first tokens ``_prefill_some`` left on the device and
+        deliver them: each comes from a program queued before this tick's
+        decode, so the wait is for work already ahead of it. A request
+        its first token ends (budget of one, EOS) retires here and rides
+        that decode, as any finished request rides one."""
+        if not firsts:
             return
-        if req._prefilled >= len(req.prompt):
-            # every prompt position is now prefill-written: index
-            # the fully-covered blocks for future prefix hits
-            self.engine.register_prefix(slot, req.prompt)
-            first = self.engine.activate(
-                slot, last, len(req.prompt), req.seed,
-                temperature=req.temperature,
-            )
-            req.tokens.append(first)
-            req.status = "decoding"
-            req.first_token_mono = time.perf_counter()
-            self._check_done(slot, req, first)
+        with span("sched.pull", tick=tick):
+            toks = jax.device_get([first for _, _, first in firsts])
+        now = time.perf_counter()
+        for (slot, req, _), tok in zip(firsts, toks):
+            req.tokens.append(int(tok))
+            req.first_token_mono = now
+            self._check_done(slot, req, int(tok))
 
     def _check_done(self, slot: int, req: Request, tok: int) -> bool:
         if (req.eos is not None and tok == req.eos) or (
@@ -531,38 +556,66 @@ class Scheduler:
         """One scheduling round: retire happens inline as tokens land,
         admit fills freed slots, prefill advances one chunk each, then
         every live slot decodes — one token through the decode program,
-        or up to spec_k + 1 through the verify program when speculation
-        is on (skipped entirely on a prefill-role fleet host,
-        ``decode_enabled`` False). -> tokens emitted."""
+        a pass over its block through the block step, or up to
+        spec_k + 1 tokens through the verify program when speculation is
+        on (none dispatched on a prefill-role fleet host,
+        ``decode_enabled`` False). Every dispatch of a tick goes out
+        before any read (``_decode_some``). -> tokens emitted (first
+        tokens, as ever, not among them)."""
         with span("sched.tick", tick=self.ticks):
             self._admit_some()
-            self._prefill_some()
-            emitted_n = self._decode_some() if self.decode_enabled else 0
+            firsts = self._prefill_some()
+            emitted_n = self._decode_some(firsts, self.decode_enabled)
+            if self._in_flight is not None and not self._slot_req:
+                # the last request finished with one more pass on its
+                # way: nobody is left to read it, and it is not counted
+                self._in_flight = None
         self.ticks += 1
         return emitted_n
 
-    def _decode_some(self) -> int:
-        """The decode phase of one tick: every decoding-status slot
-        advances through the decode (or speculative verify) program,
-        accepted runs fan out to their requests, EOS/budget retires
-        inline. Split out of ``tick`` so a fleet host can compose
-        role-gated rounds (serve/fleet/host.py). -> tokens emitted."""
+    def settle(self) -> int:
+        """Read the pass in flight and fan it out, dispatching no other:
+        afterwards ``Request.tokens`` and the device's lanes agree, which
+        whoever hands a live sequence on (``fleet/host.py``: drain to a
+        peer) or swaps the weights under it (the rollout's flip) needs
+        first. Requests may finish by it. -> tokens emitted."""
+        return self._decode_some(dispatch=False)
+
+    def _decode_some(self, firsts=(), dispatch: bool = True) -> int:
+        """The decode phase of one tick, ONE PASS AHEAD of the host:
+        this tick's pass over every decoding-status slot goes out first
+        (``dispatch``), then ``firsts`` and the pass dispatched the tick
+        before are read and fanned out to the requests that pass was
+        dispatched FOR; EOS/budget retires inline. ``decode()`` and
+        ``block_step()`` differ only in how the pulled array becomes
+        tokens a slot. A request that finished since its pass went out
+        rode it unread: the row it wrote lies inside the blocks it was
+        admitted with, and the device runs its ``retire`` and the next
+        ``admit`` behind it. Speculation alone reads its own tick's pass
+        (its drafter needs the newest tokens). -> tokens emitted."""
         decoding = {
             s: r for s, r in self._slot_req.items() if r.status == "decoding"
-        }
+        } if dispatch else {}
         in_flight, self._in_flight = self._in_flight, None
-        if not decoding and in_flight is None:
+        if not (decoding or in_flight or firsts):
             return 0
-        tick, accepted_n, emitted_n = self.ticks, 0, 0
+        tick, accepted_n, emitted_n, unread = self.ticks, 0, 0, 0
         # draft, dispatch and pull: the recorder's ``decode_tick`` and
         # ``full_tick_s`` (the fan-out below is not in them)
         with span("sched.decode", tick=tick) as whole:
             if self.spec_k > 0:
+                self._first_tokens(firsts, tick)
+                served = {
+                    s: r for s, r in decoding.items()
+                    if r.status == "decoding"
+                }
+                if not served:
+                    return 0
                 slots = self.engine.serving.slots
                 drafts = np.zeros((slots, self.spec_k), np.int32)
                 nd = np.zeros((slots,), np.int32)
                 with span("sched.draft", tick=tick) as drafting:
-                    for slot, req in decoding.items():
+                    for slot, req in served.items():
                         d = self._draft_for(req)
                         drafts[slot, :len(d)] = d
                         nd[slot] = len(d)
@@ -570,50 +623,52 @@ class Scheduler:
                     drafting.note(drafted=drafted_n)
                 self.spec_drafted += drafted_n
                 self._event(
-                    "spec_draft", drafted=drafted_n, live=len(decoding),
+                    "spec_draft", drafted=drafted_n, live=len(served),
                 )
-                with span("sched.dispatch", tick=tick, live=len(decoding)):
+                with span("sched.dispatch", tick=tick, live=len(served)):
                     emitted_dev, accepted_dev = self.engine.verify(drafts, nd)
                 with span("sched.pull", tick=tick):
                     emitted = np.asarray(emitted_dev)
                     accepted_n = int(np.asarray(accepted_dev).sum())
                 self.spec_accepted += accepted_n
-            elif self.block_len:
-                # one pass ahead: this tick's pass goes to the device
-                # first, then the pass before it is read and followed
+            else:
                 if decoding:
+                    step = (
+                        self.engine.block_step if self.block_len
+                        else self.engine.decode
+                    )
                     with span(
                         "sched.dispatch", tick=tick, live=len(decoding)
                     ):
-                        self._in_flight = (self.engine.block_step(), decoding)
+                        self._in_flight = (step(), decoding)
+                self._first_tokens(firsts, tick)
                 if in_flight is None:
                     return 0
-                out_dev, decoding = in_flight
+                out_dev, served = in_flight
                 with span("sched.pull", tick=tick):
                     out = np.asarray(out_dev)
-                # plain lists: the walk below is a few hundred scalar
-                # reads a tick, ten times dearer on numpy scalars
-                fixed = out[:-2].reshape(-1, self.block_len).tolist()
-                self.block_passes += len(decoding)
-                self.experts_hit += int(out[-2])
-                self.expert_max_load = max(
-                    self.expert_max_load, int(out[-1])
-                )
-                # what each slot can now READ of what the pass fixed
-                # (a request that finished since its pass was dispatched
-                # rode it unread)
-                emitted = [
-                    self._deliverable(decoding[s], row)
-                    if s in decoding and decoding[s].status == "decoding"
-                    else () for s, row in enumerate(fixed)
-                ]
-            else:
-                with span("sched.dispatch", tick=tick, live=len(decoding)):
-                    emitted_dev = self.engine.decode()
-                with span("sched.pull", tick=tick):
-                    emitted = np.asarray(emitted_dev)[:, None]
+                if self.block_len:
+                    # plain lists: the walk below is a few hundred scalar
+                    # reads a tick, ten times dearer on numpy scalars
+                    fixed = out[:-2].reshape(-1, self.block_len).tolist()
+                    self.block_passes += len(served)
+                    self.experts_hit += int(out[-2])
+                    self.expert_max_load = max(
+                        self.expert_max_load, int(out[-1])
+                    )
+                    # what each slot can now READ of what the pass fixed
+                    emitted = {
+                        s: self._deliverable(r, fixed[s])
+                        for s, r in served.items() if r.status == "decoding"
+                    }
+                else:
+                    emitted = out[:, None].tolist()
         with span("sched.emit", tick=tick) as fan_out:
-            for slot, req in sorted(decoding.items()):
+            for slot, req in sorted(served.items()):
+                if req.status != "decoding":
+                    # finished since its pass was dispatched
+                    unread += 1
+                    continue
                 # fan the slot's accepted run out token by token: EOS
                 # or budget INSIDE the run stops exactly where
                 # sequential decode would have — the tail is discarded
@@ -629,16 +684,13 @@ class Scheduler:
                 # an EOS inside a run drops the run's tail, passes too
                 del req.unmask_pass[len(req.tokens):]
             fan_out.note(emitted=emitted_n)
-        if self._in_flight is not None and not self._slot_req:
-            # the last request finished with one more pass on its way:
-            # nobody is left to read it
-            self._in_flight = None
-        self._live_ticks += len(decoding)
+        self._live_ticks += len(served)
+        self.lanes_unread += unread
         self.decode_ticks += 1
         self.tokens_emitted += emitted_n
         if self.block_len:
             self.tokens_delivered += emitted_n
-        if len(decoding) == self.engine.serving.slots:
+        if len(served) == self.engine.serving.slots:
             self.full_tick_s += whole.dur
             self.full_tick_tokens += emitted_n
         whole.record(
@@ -650,8 +702,8 @@ class Scheduler:
                 drafted=drafted_n,
             )
         self._event(
-            "decode_tick", live=len(decoding), emitted=emitted_n,
-            blocks_used=self.engine.allocator.used_blocks,
+            "decode_tick", live=len(served), emitted=emitted_n,
+            unread=unread, blocks_used=self.engine.allocator.used_blocks,
         )
         return emitted_n
 
@@ -680,6 +732,8 @@ class Scheduler:
             "drain", reason=reason,
             in_flight=len(self._slot_req), queued=len(self._queue),
         )
+        # a drained request restarts from scratch: the pass in flight
+        # is dropped with the tokens it would have given
         self._in_flight = None
         handed_back = []
         for slot in sorted(self._slot_req):
@@ -718,6 +772,7 @@ class Scheduler:
             "kv_blocks_peak": self.engine.allocator.peak_used,
             "kv_blocks_total": self.engine.pool.n_blocks - 1,
             "backpressure_ticks": self.backpressure_ticks,
+            "lanes_unread": self.lanes_unread,
             # instantaneous feedback the fleet router's least-loaded
             # placement keys on (serve/fleet/router.py): slots with no
             # live request, allocatable blocks (free + reclaimable LRU),

@@ -263,7 +263,9 @@ def test_a_slot_is_retired_and_taken_again_while_a_pass_is_in_flight(params):
     slot = next(s for s in range(2) if s not in sched._slot_req)
     late, served_by_slot = sched._in_flight
     assert served_by_slot[slot] is a and late is not None
+    unread = sched.lanes_unread
     sched.tick()               # b: admitted, prefilled, its first pass sent
+    assert sched.lanes_unread == unread + 1     # a's lane of the late pass
     assert sched._slot_req[slot] is b and b.status == "decoding"
     assert b.tokens == [] and sched._in_flight[1][slot] is b
     sched.tick()               # reads b's first pass, not a's late one
@@ -286,6 +288,7 @@ def test_no_pass_is_left_in_flight_when_the_server_runs_dry(params):
     sched.submit(Request(rid=0, prompt=prompt, max_new_tokens=8))
     sched.serve(max_ticks=200)
     assert not sched.busy and sched._in_flight is None
+    assert sched.lanes_unread == 0      # the dropped pass is not counted
     passes, ticks = sched.block_passes, sched.decode_ticks
     assert sched.tick() == 0
     assert (sched.block_passes, sched.decode_ticks) == (passes, ticks)

@@ -122,7 +122,7 @@ class TestMigrate:
         last = None
         for c0 in range(0, len(prompt), 4):
             last = eng.prefill_chunk(slot, prompt[c0:c0 + 4], c0)
-        first = eng.activate(slot, last, len(prompt), seed=0)
+        first = int(eng.activate(slot, last, len(prompt), seed=0))
         return eng, ec, [first]
 
     def test_migrated_continuation_bitwise(self):
@@ -454,7 +454,11 @@ class TestFleet:
             for h in hosts:
                 h.tick()
         victim = hosts[1]
+        # decode runs one pass ahead of the host: the drain finds one in
+        # flight, reads it, and exports lanes that agree with the tokens
+        assert victim.sched._in_flight is not None
         acct = victim.drain("test preemption")
+        assert victim.sched._in_flight is None
         assert acct["migrated"] or acct["forwarded"], \
             "nothing was in flight on the drained host?"
         assert all(
@@ -613,7 +617,7 @@ class TestFleet:
         for c0 in range(0, len(prompt), 8):
             last = ea.prefill_chunk(0, prompt[c0:c0 + 8], c0)
         ea.register_prefix(0, prompt)
-        first = ea.activate(0, last, len(prompt), seed=0)
+        first = int(ea.activate(0, last, len(prompt), seed=0))
         req = Request(rid=0, prompt=prompt, max_new_tokens=n)
         req.tokens = [first]
         mseq = migrate.deserialize(
@@ -711,7 +715,7 @@ def self_export_engine(params, cfg, ec, prompt, n):
     for c0 in range(0, len(prompt), c):
         last = e.prefill_chunk(0, prompt[c0:c0 + c], c0)
     e.register_prefix(0, prompt)
-    first = e.activate(0, last, len(prompt), seed=0)
+    first = int(e.activate(0, last, len(prompt), seed=0))
     req = Request(rid=99, prompt=prompt, max_new_tokens=n)
     req.tokens = [first]
     return e, req

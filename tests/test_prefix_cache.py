@@ -365,7 +365,7 @@ def test_warm_paged_cache_is_bitwise_the_cold_cache():
         last = None
         for c0 in range(adm.prefill_from, len(prompt), 4):
             last = eng.prefill_chunk(1, prompt[c0:c0 + 4], c0)
-        got = [eng.activate(1, last, len(prompt), seed=0)]
+        got = [int(eng.activate(1, last, len(prompt), seed=0))]
         for _ in range(n - 1):
             got.append(int(np.asarray(eng.decode())[1]))
         caches = [

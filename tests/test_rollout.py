@@ -511,6 +511,54 @@ class TestRolloutDrills:
         assert all(len(toks) > 0 for toks in got.values())
 
 
+    def test_a_flip_finds_no_pass_in_flight(self, monkeypatch):
+        """Decode runs one pass ahead of the host, and the flip is the
+        tick boundary WHOLE: the handler reads the pass dispatched
+        under the outgoing weights before they go, so at the swap every
+        live slot's device lane holds the token its request holds."""
+        cfg = tiny_cfg()
+        params = tiny_params(cfg)
+        ec = rollout_ec()
+        prompts, budgets = mixed_workload(cfg, n=4, seed=5)
+        hosts, t = build_unified2(params, cfg, ec)
+        router = Router(t)
+        for i, (p, m) in enumerate(zip(prompts, budgets)):
+            router.submit(Request(rid=i, prompt=p, max_new_tokens=16))
+        for _ in range(6):
+            for h in hosts:
+                h.tick()
+        serving = [h for h in hosts if h.sched._slot_req]
+        assert serving and all(
+            h.sched._in_flight is not None for h in serving
+        )
+        live_at_flip = []
+        real_flip = Engine.flip_params
+
+        def flip(engine):
+            (host,) = [h for h in hosts if h.engine is engine]
+            assert host.sched._in_flight is None
+            lanes = np.asarray(engine.state["tokens"])
+            for slot, req in host.sched._slot_req.items():
+                if req.status == "decoding":
+                    assert lanes[slot] == req.tokens[-1]
+                    live_at_flip.append(req.rid)
+            return real_flip(engine)
+
+        monkeypatch.setattr(Engine, "flip_params", flip)
+        t.register("ctl")
+        # a flip with streams live and a pass in flight: the
+        # controller's canary probes would let them finish first
+        for h in hosts:
+            h.engine.stage_params(tiny_params(cfg, seed=1), 1)
+            t.send(h.name, "rollout",
+                   json.dumps({"cmd": "flip"}).encode("utf-8"), src="ctl")
+            h.tick()
+        assert sorted(live_at_flip) == list(range(len(prompts)))
+        run_fleet_until_done(hosts, len(prompts))
+        got = fleet_streams(hosts)
+        assert all(len(got[i]) == 16 for i in range(len(prompts)))
+
+
 # ---------------------------------------------------------------------------
 # version skew: the mixed-version fleet is safe by construction
 # ---------------------------------------------------------------------------

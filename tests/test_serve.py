@@ -187,7 +187,7 @@ def test_paged_gather_is_bitwise_the_dense_cache():
         last = None
         for c0 in range(0, len(prompt), 4):
             last = eng.prefill_chunk(1, prompt[c0:c0 + 4], c0)
-        got = [eng.activate(1, last, len(prompt), seed=0)]
+        got = [int(eng.activate(1, last, len(prompt), seed=0))]
         for _ in range(n - 1):
             got.append(int(np.asarray(eng.decode())[1]))
         caches = [
@@ -235,7 +235,7 @@ def test_engine_tokens_match_block_step_oracle():
     last = None
     for c0 in range(0, len(prompt), 4):
         last = eng.prefill_chunk(1, prompt[c0:c0 + 4], c0)
-    got = [eng.activate(1, last, len(prompt), seed=0)]
+    got = [int(eng.activate(1, last, len(prompt), seed=0))]
     for _ in range(n - 1):
         got.append(int(np.asarray(eng.decode())[1]))
     assert got == want
@@ -267,6 +267,342 @@ def test_interleaved_streams_match_sequential_generate():
         np.testing.assert_array_equal(
             want, got, err_msg=f"stream {i} diverged under batching"
         )
+
+
+# ---------------------------------------------------------------------------
+# one pass ahead of the host (the twins of tests/test_block_serve.py's)
+# ---------------------------------------------------------------------------
+
+
+def by_hand(params, cfg, prompt, n, *, seed=0, temperature=0.0, eos=None):
+    """One request with the engine to itself, every token READ before
+    the next pass is dispatched: what a stream is, whatever discipline
+    the scheduler keeps."""
+    eng = Engine(params, cfg, EngineConfig(slots=1, kv_block_len=8))
+    eng.admit(0, len(prompt) + n)
+    last = eng.prefill_chunk(0, prompt, 0)
+    out = [int(eng.activate(0, last, len(prompt), seed,
+                            temperature=temperature))]
+    while len(out) < n and out[-1] != eos:
+        out.append(int(np.asarray(eng.decode())[0]))
+    return out
+
+
+def ahead_sched(params, cfg, slots=2, **kw):
+    return Scheduler(Engine(
+        params, cfg,
+        EngineConfig(slots=slots, kv_block_len=8, max_prefill_chunk=8),
+    ), **kw)
+
+
+def test_greedy_and_sampled_slots_sharing_ticks_give_their_own_streams():
+    """A greedy and a temperature slot side by side, requests ending
+    and their lanes riding a pass unread beside them: every stream is
+    what its request gets alone and read in the tick, token for token
+    (the key stream splits once a pass a slot ON THE DEVICE; a riding
+    lane's split dies with the slot)."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompts, budgets = mixed_workload(cfg, n=6, seed=21)
+    temps = [0.0, 0.8, 0.0, 1.1, 0.6, 0.0]
+    sched = ahead_sched(params, cfg)
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        sched.submit(Request(
+            rid=i, prompt=p, max_new_tokens=m, temperature=temps[i],
+            seed=70 + i,
+        ))
+    sched.serve()
+    assert sched.lanes_unread > 0 and sched._in_flight is None
+    for r in sched.finished:
+        p, m, t = prompts[r.rid], budgets[r.rid], temps[r.rid]
+        assert r.tokens == by_hand(
+            params, cfg, p, m, seed=70 + r.rid, temperature=t
+        ), f"stream {r.rid} (temperature {t}) moved"
+        if t == 0.0:
+            want = np.asarray(
+                generate(params, jnp.asarray(p)[None], cfg, m)
+            )[0, len(p):]
+            np.testing.assert_array_equal(want, r.tokens)
+    assert sched.engine.allocator.used_blocks == 0
+
+
+def test_a_slot_is_retired_and_taken_again_while_a_pass_is_in_flight():
+    """One-token ticks run one pass ahead of the host: when a request
+    ends, the pass dispatched after its last one is still on its way,
+    and the next request is admitted, prefilled and decoded in the same
+    slot before that pass is read. The late pass belongs to the request
+    that left — its one row lies inside the blocks that request was
+    admitted with — the newcomer reads none of it, and all three
+    streams are what they are alone."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    rs = np.random.RandomState(11)
+    first, second, third = (
+        rs.randint(0, cfg.vocab, size=(n,)).astype(np.int32)
+        for n in (5, 7, 4)
+    )
+    sched = ahead_sched(params, cfg)
+    eng = sched.engine
+    # a: 5 + 3 tokens fill ONE block of 8 to its last row
+    a = Request(rid=0, prompt=first, max_new_tokens=3)
+    c = Request(rid=2, prompt=third, max_new_tokens=20)  # keeps it live
+    b = Request(rid=1, prompt=second, max_new_tokens=6)
+    for req in (a, c, b):
+        sched.submit(req)
+    while a.status != "done":
+        sched.tick()
+    # a's slot is free, and the pass dispatched for it this tick rides on
+    slot = next(s for s in range(2) if s not in sched._slot_req)
+    late, served_by_slot = sched._in_flight
+    assert served_by_slot[slot] is a and late is not None
+    # the riding pass wrote row pos - 1: the last of a's one block
+    assert int(eng.state["pos"][slot]) == len(first) + 3 == 8
+    assert int(np.asarray(late)[slot]) >= 0        # a live lane, unread
+    unread = sched.lanes_unread
+    sched.tick()     # b: admitted, prefilled, first token, its first pass
+    assert sched.lanes_unread == unread + 1
+    assert sched._slot_req[slot] is b and b.status == "decoding"
+    assert len(b.tokens) == 1 and sched._in_flight[1][slot] is b
+    sched.tick()     # reads b's first pass, not a's late one
+    assert len(b.tokens) == 2
+    sched.serve()
+    for req, prompt in ((a, first), (b, second), (c, third)):
+        assert req.tokens == by_hand(
+            params, cfg, prompt, req.max_new_tokens
+        ), f"stream {req.rid} moved"
+    assert eng.allocator.used_blocks == 0
+
+
+def test_no_pass_is_left_in_flight_when_the_server_runs_dry():
+    """The pass dispatched after the last request's last one is dropped
+    with it: a server that ran dry holds no device result, counts no
+    pass nobody read, and serves the next request as a fresh one does."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompt = np.asarray([3, 1, 4, 1, 5, 9], np.int32)
+    sched = ahead_sched(params, cfg)
+    sched.submit(Request(rid=0, prompt=prompt, max_new_tokens=7))
+    sched.serve()
+    assert not sched.busy and sched._in_flight is None
+    counted = (sched.decode_ticks, sched._live_ticks, sched.lanes_unread)
+    assert counted == (6, 6, 0)       # six passes read; the seventh dropped
+    assert sched.tick() == 0
+    assert (
+        sched.decode_ticks, sched._live_ticks, sched.lanes_unread
+    ) == counted
+    again = Request(rid=1, prompt=prompt, max_new_tokens=7)
+    sched.submit(again)
+    sched.serve()
+    assert sched._in_flight is None
+    assert again.tokens == by_hand(params, cfg, prompt, 7)
+    assert sched.engine.allocator.used_blocks == 0
+
+
+@pytest.mark.parametrize("ends_by", ["budget_of_one", "eos_first"])
+def test_a_first_token_that_ends_its_request(ends_by):
+    """The first token is read in the tick's pull, behind the decode
+    the tick dispatched: a request it ends (a budget of one, an EOS)
+    has one token, frees its blocks, and rides that decode as any
+    finished request rides one — beside a neighbour whose stream does
+    not move."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompt = np.asarray([7, 2, 9, 4], np.int32)
+    other = np.asarray([5, 5, 1, 8, 3], np.int32)
+    (first,) = by_hand(params, cfg, prompt, 1)
+    short = (
+        Request(rid=0, prompt=prompt, max_new_tokens=1)
+        if ends_by == "budget_of_one"
+        else Request(rid=0, prompt=prompt, max_new_tokens=9, eos=first)
+    )
+    long = Request(rid=1, prompt=other, max_new_tokens=12)
+    sched = ahead_sched(params, cfg)
+    sched.submit(long)
+    sched.tick()
+    sched.submit(short)
+    sched.tick()      # admitted, prefilled, activated, read: done
+    assert short.status == "done" and short.tokens == [first]
+    assert short.first_token_mono >= short.admit_mono > 0
+    assert 1 not in sched._slot_req and sched._in_flight[1][1] is short
+    # its lane rode the decode of this tick: one row at len(prompt),
+    # inside the block it was admitted with
+    assert int(sched.engine.state["pos"][1]) == len(prompt) + 1
+    sched.serve()
+    assert sched.lanes_unread == 1    # short's; long's last pass dropped
+    assert long.tokens == by_hand(params, cfg, other, 12)
+    assert sched.engine.allocator.used_blocks == 0
+
+
+def test_lanes_unread_counts_one_a_finished_request(tmp_path):
+    """One lane a request that finished while the server stayed live,
+    beside ``_live_ticks``; in ``occupancy()``, on the recorder's
+    ``decode_tick`` event, and zeroed by ``reset_counters``."""
+    from singa_tpu.obs.recorder import FlightRecorder
+
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompts, budgets = mixed_workload(cfg, n=5, seed=2)
+    rec = FlightRecorder(str(tmp_path / "events"), rank=0, run_id="t")
+    sched = ahead_sched(params, cfg, slots=3, recorder=rec)
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=m))
+    # one that outlasts them all: every other request's late pass is read
+    sched.submit(Request(rid=9, prompt=prompts[0], max_new_tokens=26))
+    sched.serve()
+    assert [r.rid for r in sched.finished][-1] == 9
+    assert sched.lanes_unread == len(prompts)
+    # every lane read gave a token, but those
+    assert sched.tokens_emitted == sched._live_ticks - sched.lanes_unread
+    assert sched.occupancy()["lanes_unread"] == len(prompts)
+    rec.flush()
+    ticks = [
+        json.loads(l) for l in open(tmp_path / "events" / "rank_0.jsonl")
+    ]
+    ticks = [e["data"] for e in ticks if e["kind"] == "decode_tick"]
+    assert sum(e["unread"] for e in ticks) == len(prompts)
+    assert all(e["emitted"] == e["live"] - e["unread"] for e in ticks)
+    sched.reset_counters()
+    assert sched.lanes_unread == 0
+
+
+def test_settle_reads_the_pass_in_flight_and_dispatches_none():
+    """``settle()``: afterwards no pass is in flight and every live
+    slot's device lane holds the token its request holds at the
+    position that follows from it — what an export, a drain to a peer
+    or a flip of the weights needs — and the streams go on unmoved."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompts, budgets = mixed_workload(cfg, n=4, seed=8)
+    sched = ahead_sched(params, cfg, slots=3)
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=max(m, 6)))
+    for _ in range(3):
+        sched.tick()
+    assert sched._in_flight is not None
+    dispatched = sched.decode_ticks
+    held = {r.rid: len(r.tokens) for r in sched.in_flight}
+    assert sched.settle() == len(held)
+    assert sched._in_flight is None and sched.decode_ticks == dispatched + 1
+    toks = np.asarray(sched.engine.state["tokens"])
+    pos = np.asarray(sched.engine.state["pos"])
+    for slot, req in sched._slot_req.items():
+        assert len(req.tokens) == held[req.rid] + 1
+        assert toks[slot] == req.tokens[-1]
+        assert pos[slot] == len(req.prompt) + len(req.tokens) - 1
+    assert sched.settle() == 0        # nothing left to read
+    sched.serve()
+    for r in sched.finished:
+        assert r.tokens == by_hand(
+            params, cfg, prompts[r.rid], r.max_new_tokens
+        )
+
+
+class _Reads:
+    """Every device->host read the scheduler and the engine can make,
+    with the spans open at that moment: ``np.asarray`` / ``np.array``
+    of a device array as those two modules call it, and the scalar
+    conversions and ``jax.device_get`` of any code (``ArrayImpl._value``)."""
+
+    def __init__(self, monkeypatch):
+        from jax._src.array import ArrayImpl
+
+        from singa_tpu.serve import engine as engine_mod
+        from singa_tpu.serve import scheduler as sched_mod
+
+        self.log, self.open = [], []
+        reads = self
+
+        class Numpy:
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            @staticmethod
+            def asarray(a, *args, **kw):
+                if isinstance(a, jax.Array):
+                    reads.read()
+                return np.asarray(a, *args, **kw)
+
+            array = asarray
+
+        value = ArrayImpl._value
+
+        def spied(arr):
+            reads.read()
+            return value.fget(arr)
+
+        real_span = sched_mod.span
+
+        class Span:
+            def __init__(self, name, **attrs):
+                self.name, self.inner = name, real_span(name, **attrs)
+
+            def __enter__(self):
+                reads.open.append(self.name)
+                reads.log.append(("span", self.name))
+                return self.inner.__enter__()
+
+            def __exit__(self, *exc):
+                reads.open.pop()
+                return self.inner.__exit__(*exc)
+
+            def start(self):
+                return self.inner.start()
+
+        monkeypatch.setattr(sched_mod, "np", Numpy())
+        monkeypatch.setattr(engine_mod, "np", Numpy())
+        monkeypatch.setattr(ArrayImpl, "_value", property(spied))
+        monkeypatch.setattr(sched_mod, "span", Span)
+
+    def read(self):
+        self.log.append(("read", tuple(self.open)))
+
+
+def test_a_tick_reads_only_in_its_pull_after_its_dispatch(monkeypatch):
+    """A count, not a time: over a run with admissions, chunks, first
+    tokens and finishes, every device->host read of a tick falls inside
+    ``sched.pull``, after that tick's ``sched.dispatch`` — so admission,
+    a chunk's hand-over, the fan-out and retirement all run with the
+    tick's decode queued on the device."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    prompts, budgets = mixed_workload(cfg, n=7, seed=13)
+    sched = Scheduler(Engine(
+        params, cfg,
+        EngineConfig(slots=3, kv_block_len=8, max_prefill_chunk=4,
+                     prefix_cache=True, prefix_lru=True),
+    ))
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=m))
+    sched.tick()                      # compiles read nothing either, but
+    reads = _Reads(monkeypatch)       # the count starts on warm programs
+    sched.serve()
+    assert len(sched.finished) == len(prompts)
+    ticks, tick = [], None
+    for kind, what in reads.log:
+        if (kind, what) == ("span", "sched.tick"):
+            tick = []
+            ticks.append(tick)
+        tick.append((kind, what))
+    n_reads = 0
+    for tick in ticks:
+        names = [w for k, w in tick if k == "span"]
+        where = [w for k, w in tick if k == "read"]
+        n_reads += len(where)
+        assert all(w[-1:] == ("sched.pull",) for w in where), where
+        if where and "sched.dispatch" in names:
+            assert tick.index(("span", "sched.dispatch")) < min(
+                i for i, (k, _) in enumerate(tick) if k == "read"
+            )
+    # chunks, admissions and retirements were among those ticks
+    spans = {w for k, w in reads.log if k == "span"}
+    assert {"sched.admit", "sched.prefill", "sched.emit"} <= spans
+    # a read a pass and one a tick that activated a request, no more
+    assert sched.decode_ticks - 1 <= n_reads <= sched.decode_ticks + len(prompts)
+    for r in sched.finished:
+        want = np.asarray(generate(
+            params, jnp.asarray(prompts[r.rid])[None], cfg, budgets[r.rid]
+        ))[0, len(prompts[r.rid]):]
+        np.testing.assert_array_equal(want, r.tokens)
 
 
 def test_pool_exhaustion_backpressures_then_completes():
