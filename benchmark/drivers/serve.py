@@ -45,6 +45,59 @@ def percentile(values, q: float) -> float:
     return v[lo] + (v[hi] - v[lo]) * (pos - lo)
 
 
+#: a tick stalled when it took more than this many median ticks (the
+#: longest sound tick, two prefill chunks beside a decode, takes two)
+STALL_MEDIANS = 5.0
+
+
+def tick_stats(rows) -> dict:
+    """The windows' ``tick`` spans -> what a far-off run is read by. A
+    tick here is the whole turn of the loop, from its start to the next
+    one's (the last of a window: to the window's end), so the caller's
+    side counts. ``stall_s`` sums what each tick took beyond
+    ``STALL_MEDIANS`` medians and ``stall_ticks`` counts those ticks;
+    ``stalls`` places the ten longest of them: seconds into its window,
+    the turn's milliseconds, and those of it inside
+    ``Scheduler.tick()``. A few long waits read there, a slower host in
+    ``tick_mean_ms``, the mean over the ticks that did not stall (the
+    median sits on the edge between ticks with and without a prefill
+    chunk and moves with the mix). ``caller_s`` is what the windows
+    spent outside ``Scheduler.tick()``: the benchmark's own side of the
+    loop. Nothing where no window closed."""
+    ticks = sorted((r[1], r[2]) for r in rows if r[0] == "tick")
+    turns = []  # (seconds, into the window, inside the span)
+    for _, w0, w1, _ in (r for r in rows if r[0] == "window"):
+        mine = [(t0, t1) for t0, t1 in ticks if w0 <= t0 < w1]
+        starts = [t0 for t0, _ in mine] + [w1]
+        turns += [
+            (nxt - t0, t0 - w0, t1 - t0)
+            for (t0, t1), nxt in zip(mine, starts[1:])
+        ]
+    if not turns:
+        return {}
+    took = [t[0] for t in turns]
+    median = statistics.median(took)
+    stalled = sorted(
+        (t for t in turns if t[0] > STALL_MEDIANS * median), reverse=True
+    )
+    return {
+        "tick_p50_ms": 1000.0 * median,
+        # under half of the ticks can lie over the median: some did not stall
+        "tick_mean_ms": 1000.0 * (
+            sum(took) - sum(t[0] for t in stalled)
+        ) / (len(took) - len(stalled)),
+        "tick_p99_ms": 1000.0 * percentile(took, 99),
+        "tick_max_ms": 1000.0 * max(took),
+        "stall_s": sum(t[0] - STALL_MEDIANS * median for t in stalled),
+        "stall_ticks": len(stalled),
+        "stalls": [
+            [at, 1000.0 * turn, 1000.0 * inside]
+            for turn, at, inside in stalled[:10]
+        ],
+        "caller_s": sum(took) - sum(t[2] for t in turns),
+    }
+
+
 class Driver:
     def __init__(self, *, config, traffic, limits, seed, devices, work, spans):
         self.config, self.traffic, self.limits = config, traffic, limits
@@ -212,8 +265,12 @@ class Driver:
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
             self._tick()
-        self.window_s += time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self.window_s += t1 - t0
         self.in_window = False
+        # where the window's ticks end (``tick_stats``): a row, and no
+        # annotation in the trace
+        self.spans.rows.append(("window", t0, t1, {}))
 
     def end_to_end(self) -> dict:
         return {
@@ -237,6 +294,7 @@ class Driver:
             "ttft_max_ms": 1000.0 * max(self.ttfts),
             "itl_p50_ms": 1000.0 * statistics.median(self.gaps),
             "model_flops": self.flops_done,
+            **tick_stats(self.spans.rows),
         }
 
     def attempted_failed(self) -> tuple[int, int]:

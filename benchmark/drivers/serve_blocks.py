@@ -19,9 +19,12 @@ restated for blocks:
 - **the check replays ``Request.unmask_pass``**. For each sampled
   request and each pass index s, the reference scores the sequence as it
   stood at pass s (tokens fixed before s in place, the rest masked)
-  beside the clean sequence (``two_stream``). ``logit_gap``: over every
-  delivered token, the reference's best logit at its position in the
-  pass that fixed it, less the served token's. ``confidence_gap``: how
+  beside the clean sequence (``two_stream``). ``logit_gap_mean``: over
+  every delivered token, the reference's best logit at its position in
+  the pass that fixed it, less the served token's; the mean (the widest,
+  ``logit_gap``, is reported and no longer compared: it swings by its
+  nature and left no room under the control, PERF.md section 6, PR 33).
+  ``confidence_gap``: how
   far the ORDER of unmasking lies from the reference's, as a share of
   how far it could. Over every pass and block that had a choice (more
   positions masked than the rule fixes), in log-probability by the
@@ -116,8 +119,8 @@ def replay(request: tuple, size: int, block: int, mask_id: int,
     return clean, noisy, answer, fixed_at
 
 
-def pass_gaps(ref_logits, answer, fixed_at, s: int, block: int, n_fix: int,
-              judged_logits=None, served=None):
+def pass_numbers(ref_logits, answer, fixed_at, s: int, block: int,
+                 n_fix: int, judged_logits=None, served=None):
     """What pass ``s`` of one request adds to the two gaps, against the
     reference's ``ref_logits`` (size, vocab) of that pass.
 
@@ -125,11 +128,13 @@ def pass_gaps(ref_logits, answer, fixed_at, s: int, block: int, n_fix: int,
     and the positions it chose, those with ``fixed_at == s``. A control
     is judged by ``judged_logits`` of the same pass: its greedy tokens
     and the ``min(n_fix, masked)`` positions of each block it is most
-    confident of. -> (logit_gap, regret, room): the widest gap of a
-    token, and the two sums ``confidence_gap`` is the ratio of (module
-    docstring), over the blocks of this pass that had a choice. All
-    three are infinity where a block fixed another number of positions
-    than the rule states."""
+    confident of. -> (logit_gap, regret, room, gap_sum, judged): the
+    widest gap of a token, the two sums ``confidence_gap`` is the ratio
+    of (module docstring), over the blocks of this pass that had a
+    choice, and the gaps of the tokens this pass fixed summed, with how
+    many they were (``logit_gap_mean`` is the ratio of those two over
+    all passes). All but the count are infinity where a block fixed
+    another number of positions than the rule states."""
     import jax
     import jax.numpy as jnp
 
@@ -153,9 +158,8 @@ def pass_gaps(ref_logits, answer, fixed_at, s: int, block: int, n_fix: int,
         tokens = jnp.argmax(judged_logits, axis=-1)
         ruled = True
     got = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
-    logit_gap = jnp.max(
-        jnp.where(chosen.reshape(-1), best - got, 0.0)
-    )
+    gaps = jnp.where(chosen.reshape(-1), best - got, 0.0)
+    logit_gap = jnp.max(gaps)
     conf = best.reshape(-1, block)
     ranked = -jnp.sort(-jnp.where(masked, conf, -jnp.inf), axis=1)
     nth = jnp.take_along_axis(
@@ -167,7 +171,15 @@ def pass_gaps(ref_logits, answer, fixed_at, s: int, block: int, n_fix: int,
     regret = jnp.sum(jnp.where(choice, nth - least_chosen, 0.0))
     room = jnp.sum(jnp.where(choice, nth - least_masked, 0.0))
     bad = jnp.where(ruled, 0.0, jnp.inf)
-    return logit_gap + bad, regret + bad, room + bad
+    return (logit_gap + bad, regret + bad, room + bad, jnp.sum(gaps) + bad,
+            jnp.sum(chosen))
+
+
+def pass_gaps(*args):
+    """``pass_numbers``' first three, (logit_gap, regret, room), for the
+    program's own test of a served request (``tests/test_block_serve.py``
+    replays one through it)."""
+    return pass_numbers(*args)[:3]
 
 
 def confidence_gap(regret: float, room: float) -> float:
@@ -326,8 +338,9 @@ class Driver(serve.Driver):
 
     def gaps_of(self, sample, arith: str | None = None) -> dict:
         """``logit_gap`` (the widest over every pass of every request of
-        ``sample``) and ``confidence_gap`` (over all of them together).
-        With ``arith`` what is judged at each pass is what the reference
+        ``sample``), ``logit_gap_mean`` (over every token they deliver)
+        and ``confidence_gap`` (over all of them together). The limits
+        file says which of them a run compares. With ``arith`` what is judged at each pass is what the reference
         computed in that arithmetic serves and chooses there (the
         control)."""
         import jax.numpy as jnp
@@ -338,7 +351,7 @@ class Driver(serve.Driver):
         )
         mcfg, steps = self.mcfg, self.traffic["block_steps"]
         block = mcfg.diffusion_block
-        widest = regret = room = 0.0
+        widest = regret = room = gap_sum = tokens = 0.0
         for request in sample:
             clean, noisy, answer, fixed_at = replay(
                 request, mcfg.max_len, block, mcfg.mask_id, steps
@@ -350,20 +363,22 @@ class Driver(serve.Driver):
                 judged = None if arith is None else self.reference_forward(
                     params, jnp.asarray(clean), jnp.asarray(noisy[s]), arith
                 )
-                g, a, b = (float(x) for x in pass_gaps(
+                g, a, b, total, n = (float(x) for x in pass_numbers(
                     logits, jnp.asarray(answer), jnp.asarray(fixed_at), s,
                     block, block // steps, judged, jnp.asarray(clean),
                 ))
                 widest = max(widest, g if g == g else np.inf)
                 regret, room = regret + a, room + b
+                gap_sum, tokens = gap_sum + total, tokens + n
         return {"logit_gap": widest,
+                "logit_gap_mean": gap_sum / tokens if tokens else np.inf,
                 "confidence_gap": confidence_gap(regret, room)}
 
     def check(self) -> dict:
         got = self.gaps_of(self.sample) if self.sample else {}
         return {
             name: {"value": got.get(name), "limit": self.limits[name]}
-            for name in ("logit_gap", "confidence_gap")
+            for name in self.limits
         }
 
     def calibrate(self, controls=(), faults=(), seconds=8.0) -> dict:

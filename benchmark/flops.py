@@ -1,6 +1,7 @@
 """Model FLOPs of a step, worked out from shapes: the yardstick's own
-count (the arithmetic of ``singa_tpu/utils/flops.py``, copied so that a
-later change to the program cannot move it).
+count, and since PR 30 the repo's only one: the arithmetic came from
+``singa_tpu/utils/flops.py``, which that PR deleted, and lives here,
+where a later change to the program cannot move it.
 
 Conventions (the usual MFU accounting, e.g. the PaLM appendix): only
 matrix-product FLOPs count — convolutions, dense and inner-product

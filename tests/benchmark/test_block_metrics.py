@@ -169,7 +169,7 @@ def test_pass_gaps_on_hand_made_logits():
     ]))
     served = jnp.asarray([0, 0, 0, 0])
     room = np.log(0.6 / 0.4)      # its second best less its least sure
-    lg, regret, had = serve_blocks.pass_gaps(
+    lg, regret, had, total, n = serve_blocks.pass_numbers(
         logits, answer, fixed_at, 0, 4, 2, None, served)
     assert float(lg) == pytest.approx(0.0, abs=1e-6)
     assert float(regret) == pytest.approx(0.0, abs=1e-6)   # 0.7 and 0.6: its set
@@ -178,19 +178,23 @@ def test_pass_gaps_on_hand_made_logits():
     # second best is 0.6, and served token 1 at position 3
     fixed_at = jnp.asarray([0, 1, 1, 0])
     served = jnp.asarray([0, 0, 0, 1])
-    lg, regret, had = serve_blocks.pass_gaps(
+    lg, regret, had, total, n = serve_blocks.pass_numbers(
         logits, answer, fixed_at, 0, 4, 2, None, served)
     assert float(lg) == pytest.approx(np.log(0.5 / 0.3), abs=1e-5)
+    # of the two tokens the pass fixed one is the reference's: the sum
+    # that logit_gap_mean divides by the count is the other's gap
+    assert float(total) == pytest.approx(np.log(0.5 / 0.3), abs=1e-5)
+    assert int(n) == 2
     assert float(regret) == pytest.approx(np.log(0.6 / 0.5), abs=1e-5)
     assert float(had) == pytest.approx(room, abs=1e-5)
     assert serve_blocks.confidence_gap(float(regret), float(had)) == (
         pytest.approx(np.log(0.6 / 0.5) / room, abs=1e-5))
     # the least sure first: the whole of the room
-    lg, regret, had = serve_blocks.pass_gaps(
+    lg, regret, had, total, n = serve_blocks.pass_numbers(
         logits, answer, jnp.asarray([1, 0, 1, 0]), 0, 4, 2, None, served)
     assert float(regret) == pytest.approx(float(had)) == pytest.approx(room, abs=1e-5)
     # a pass with no choice (two masked, two to fix) adds to neither sum
-    lg, regret, had = serve_blocks.pass_gaps(
+    lg, regret, had, total, n = serve_blocks.pass_numbers(
         logits, answer, jnp.asarray([0, 1, 0, 1]), 1, 4, 2, None, served)
     assert float(regret) == float(had) == 0.0
     assert serve_blocks.confidence_gap(0.0, 0.0) == 0.0
@@ -198,15 +202,17 @@ def test_pass_gaps_on_hand_made_logits():
     theirs = jnp.log(jnp.asarray([
         [0.1, 0.8, 0.1], [0.3, 0.3, 0.4], [0.2, 0.2, 0.6], [0.1, 0.1, 0.8],
     ]))
-    lg, regret, had = serve_blocks.pass_gaps(
+    lg, regret, had, total, n = serve_blocks.pass_numbers(
         logits, answer, jnp.asarray([0, 0, 1, 1]), 0, 4, 2, theirs)
     # it chooses positions 0 and 3 (0.8 each) and serves tokens 1 and 2
     assert float(lg) == pytest.approx(np.log(0.7 / 0.2), abs=1e-5)
+    assert float(total) == pytest.approx(
+        np.log(0.7 / 0.2) + np.log(0.5 / 0.2), abs=1e-5)
     assert float(regret) == pytest.approx(np.log(0.6 / 0.5), abs=1e-5)
     # three fixed where the rule says two
-    bad = serve_blocks.pass_gaps(
+    bad = serve_blocks.pass_numbers(
         logits, answer, jnp.asarray([0, 0, 0, 1]), 0, 4, 2, None, served)
-    assert all(float(b) == np.inf for b in bad)
+    assert all(float(b) == np.inf for b in bad[:4]) and int(bad[4]) == 3
     assert serve_blocks.confidence_gap(float(bad[1]), float(bad[2])) == np.inf
 
 
@@ -356,7 +362,7 @@ def test_program_passes_its_limits(calibrated):
 
 
 @pytest.mark.parametrize("side,fails", [
-    ("float8", "logit_gap"), ("commit_skipped", "logit_gap"),
+    ("float8", "logit_gap_mean"), ("commit_skipped", "logit_gap_mean"),
     ("least_confident", "confidence_gap"),
 ])
 def test_control_and_faults_fail_a_limit(calibrated, side, fails):

@@ -75,6 +75,24 @@ SERVE = {
     }],
 }
 
+
+def under_the_kernel(trace: dict) -> dict:
+    """``trace`` as the paged kernel runs it: inside a run of
+    ``jit__decode`` one Mosaic call stands where the gather stood and
+    ``cache_attend`` is gone; the prefill chunk keeps its gather."""
+    (dev,) = trace["devices"]
+    ops = []
+    for name, start, dur, op_name in dev["ops"]:
+        if op_name.startswith(D) and "/gather_kv/" in op_name:
+            name = "paged_attention.1"
+            op_name = f"{D}/blk0/attend/paged_attention/pallas_call"
+        if not (op_name.startswith(D) and "/cache_attend/" in op_name):
+            ops.append([name, start, dur, op_name])
+    return {"host": trace["host"], "devices": [dict(dev, ops=ops)]}
+
+
+SERVE_KERNEL = under_the_kernel(SERVE)
+
 #: one chunk of two training steps
 TRAIN = {
     "host": [
@@ -201,13 +219,16 @@ def recorded(name):
 
 
 @pytest.mark.parametrize("name,program,scopes,unscoped_share", [
-    # serving: the relayout copies of the whole pools at the programs'
-    # entry and exit carry an argument's name or none (PERF.md section 5).
-    # The cuts were renamed to their cells' names, bytes unchanged (PR
-    # 26); the ids are the ones the tests have had since PR 24
+    # serving, re-recorded by PR 33 (the decode tick on the paged kernel,
+    # a prefill chunk on the gather path beside it): unscoped are the
+    # waits for the weights streamed in beside the kernel and a copy of
+    # each layer's qkv weight (PERF.md section 5). The cuts carry their
+    # cells' names (PR 26); the ids are the ones the tests have had since
+    # PR 24
     pytest.param(
         "scopes_gpt2_medium_serve_closed.json", "jit__decode",
-        {"gather_kv", "kv_write", "cache_attend", "qkv", "mlp"}, 0.75,
+        {"paged_attention", "kv_write", "gather_kv", "cache_attend", "qkv",
+         "mlp"}, 0.75,
         id="scopes_serve_v5e.json-jit__decode-scopes0-0.75",
     ),
     pytest.param(
@@ -226,7 +247,9 @@ def test_reductions_on_recorded_cuts(name, program, scopes, unscoped_share):
     assert total == pytest.approx(sum(e[2] for e in ops) / 1e9)
     unscoped = table.get(pt.UNSCOPED, {"fwd": 0.0, "bwd": 0.0})
     assert (unscoped["fwd"] + unscoped["bwd"]) < unscoped_share * total
-    assert pt.unscoped_rows(trace, None)[0][0] in ("copy", "copy-done")
+    assert pt.unscoped_rows(trace, None)[0][0] in (
+        "copy", "copy-done", "slice-done",
+    )
     runs = pt.module_runs(trace, program)
     assert runs and all(0 < r["busy_ns"] <= r["dur_ns"] for r in runs)
     assert all(v >= 0 for v in pt.gaps_by_span(trace).values())
@@ -246,7 +269,7 @@ READERS = {
     "sched_host_ms_per_tick": (SERVE, (600 - 460) / 1e6),
     "decode_device_ms": (SERVE, 400 / 1e6),
     "prefill_chunk_device_ms": (SERVE, 300 / 1e6),
-    "kv_gather_ms_per_tick": (SERVE, (100 + 120) / 2 / 1e6),
+    "paged_attention_ms_per_tick": (SERVE_KERNEL, (100 + 120) / 2 / 1e6),
     "attend_ms_per_tick": (SERVE, (250 + 250) / 2 / 1e6),
     "bn_ms_per_step": (TRAIN, 240 / 2 / 1e6),
     "conv_ms_per_step": (TRAIN, 500 / 2 / 1e6),
@@ -264,7 +287,8 @@ def test_reader_on_a_hand_made_run_and_on_an_empty_one(monkeypatch, name):
     run = {"trace": {"busy_s": 1.0, "window_s": 1.0}, "driver": FakeDriver()}
     monkeypatch.setattr(pt, "load", lambda trace_dir: trace)
     assert read(run) == pytest.approx(want)
-    # the other cell's trace holds nothing of this metric's
+    # the other cell's trace holds nothing of this metric's (nor does
+    # the gather path of the paged kernel's)
     other = TRAIN if trace is SERVE else SERVE
     monkeypatch.setattr(pt, "load", lambda trace_dir: other)
     assert read(run) is None
