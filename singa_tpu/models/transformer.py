@@ -76,6 +76,45 @@ class TransformerConfig:
     #: position is still masked.
     diffusion_block: int = 0
     mask_id: int = 0
+    #: the dense MLP: "gelu" (``mlp/up``, ``mlp/down``) | "swiglu"
+    #: (``(silu(x Wg) * (x Wu)) Wd`` with ``mlp/gate`` beside them)
+    mlp: str = "gelu"
+    #: layer types: the first ``dense_layers`` blocks keep the dense MLP
+    #: (``d_ff`` wide) in a model whose other blocks are top-k expert
+    #: layers
+    dense_layers: int = 0
+    #: the top-k router's scores: "softmax" | "sigmoid" (each expert on
+    #: its own)
+    moe_score: str = "softmax"
+    #: a bias per expert (``moe/bias``) added to the scores to CHOOSE the
+    #: top k, and left out of the gates
+    moe_bias: bool = False
+    #: the gates, normalised over the chosen, times this
+    moe_scale: float = 1.0
+    #: > 0: a shared SwiGLU expert this wide runs on every token beside
+    #: the routed ones (``moe/s_gate``, ``s_up``, ``s_down``)
+    moe_shared_d_ff: int = 0
+    #: the experts this device holds of each layer's ``moe_experts``:
+    #: (first, how many). The router stays ``moe_experts`` wide, the
+    #: expert weights hold that many, and the layer leaves out what the
+    #: others would add (parallel/moe.py). () = all of them.
+    moe_held: tuple = ()
+    #: > 0: latent attention. K and V of every head come out of ONE
+    #: latent of this width a token (``attn/kv_a``, its RMSNorm,
+    #: ``attn/kv_b``) and a rotary key of ``rope_dim`` shared by all
+    #: heads; queries come through a latent of ``q_latent``
+    #: (``attn/q_a``, its RMSNorm, ``attn/q_b``). ``head_dim`` is then
+    #: the width of a head's UNROTATED query/key part, ``rope_dim`` of
+    #: its rotated part and ``v_head_dim`` of its value. What a cache
+    #: holds is the latent after its norm beside the rotated key:
+    #: ``latent_width`` values a token a layer (serve/engine.py).
+    kv_latent: int = 0
+    q_latent: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
+    #: YaRN scaling of the rotary frequencies: (factor, original length,
+    #: beta_fast, beta_slow, mscale, mscale_all_dim); () = none
+    rope_yarn: tuple = ()
 
     def __post_init__(self):
         if not self.head_dim:
@@ -99,6 +138,30 @@ class TransformerConfig:
                 f"moe_top_k {self.moe_top_k} needs moe_experts >= it "
                 f"({self.moe_experts}) and a moe_d_ff ({self.moe_d_ff})"
             )
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp {self.mlp!r}: gelu or swiglu")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_score {self.moe_score!r}: softmax or sigmoid"
+            )
+        if self.moe_held:
+            first, n = self.moe_held
+            if not (self.moe_top_k and 0 <= first and n > 0
+                    and first + n <= self.moe_experts):
+                raise ValueError(
+                    f"moe_held {self.moe_held} names no share of "
+                    f"{self.moe_experts} top-k experts"
+                )
+        if self.kv_latent and not (
+            self.q_latent and self.rope_dim and self.v_head_dim
+            and self.pos == "rope" and self.norm == "rmsnorm"
+            and not self.gqa and not self.qk_norm
+        ):
+            raise ValueError(
+                "kv_latent needs q_latent, rope_dim and v_head_dim, "
+                "pos = 'rope', norm = 'rmsnorm', and neither fewer K/V "
+                "heads nor qk_norm"
+            )
 
     @property
     def qkv_width(self) -> int:
@@ -108,6 +171,25 @@ class TransformerConfig:
     @property
     def gqa(self) -> bool:
         return self.n_kv_heads != self.n_heads
+
+    def expert_layer(self, i: int) -> bool:
+        """Whether block ``i`` is a top-k expert layer."""
+        return bool(self.moe_top_k) and i >= self.dense_layers
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent cache holds a token a layer."""
+        return self.kv_latent + self.rope_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """What the scores are multiplied by before the softmax: one
+        over the root of a query's width, and under YaRN with an
+        ``mscale_all_dim`` the square of its magnitude correction."""
+        scale = (self.head_dim + self.rope_dim) ** -0.5
+        if self.rope_yarn and self.rope_yarn[5]:
+            scale *= _yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
+        return scale
 
 
 def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
@@ -127,7 +209,9 @@ def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
         if bias:
             params[f"{name}/bias"] = jnp.zeros((width,))
 
-    keys = iter(jax.random.split(rng, 2 + 4 * cfg.n_layers))
+    # (a latent block draws five attention matrices, a gated MLP three)
+    per_layer = 8 if cfg.kv_latent or cfg.mlp == "swiglu" else 4
+    keys = iter(jax.random.split(rng, 2 + per_layer * cfg.n_layers))
     params["embed/tok"] = norm(next(keys), (cfg.vocab, cfg.d_model), 0.02)
     pos_key = next(keys)
     if cfg.pos == "learned":
@@ -136,28 +220,56 @@ def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
         p = f"blk{i}"
         d, f = cfg.d_model, cfg.d_ff
         norm_params(f"{p}/ln1", d)
-        params[f"{p}/attn/qkv"] = norm(
-            next(keys), (d, cfg.qkv_width), 1 / math.sqrt(d)
-        )
+        if cfg.kv_latent:
+            h, rq, rkv = cfg.n_heads, cfg.q_latent, cfg.kv_latent
+            params[f"{p}/attn/q_a"] = norm(
+                next(keys), (d, rq), 1 / math.sqrt(d)
+            )
+            params[f"{p}/attn/q_a_norm"] = jnp.ones((rq,))
+            params[f"{p}/attn/q_b"] = norm(
+                next(keys), (rq, h * (cfg.head_dim + cfg.rope_dim)),
+                1 / math.sqrt(rq),
+            )
+            params[f"{p}/attn/kv_a"] = norm(
+                next(keys), (d, cfg.latent_width), 1 / math.sqrt(d)
+            )
+            params[f"{p}/attn/kv_a_norm"] = jnp.ones((rkv,))
+            params[f"{p}/attn/kv_b"] = norm(
+                next(keys), (rkv, h * (cfg.head_dim + cfg.v_head_dim)),
+                1 / math.sqrt(rkv),
+            )
+        else:
+            params[f"{p}/attn/qkv"] = norm(
+                next(keys), (d, cfg.qkv_width), 1 / math.sqrt(d)
+            )
         params[f"{p}/attn/out"] = norm(
-            next(keys), (cfg.n_heads * cfg.head_dim, d),
+            next(keys),
+            (cfg.n_heads * (cfg.v_head_dim or cfg.head_dim), d),
             1 / math.sqrt(d * 2 * cfg.n_layers),
         )
         if cfg.qk_norm:
             params[f"{p}/attn/q_norm"] = jnp.ones((cfg.head_dim,))
             params[f"{p}/attn/k_norm"] = jnp.ones((cfg.head_dim,))
         norm_params(f"{p}/ln2", d)
-        if cfg.moe_experts:
+        if cfg.moe_experts and (cfg.expert_layer(i) or not cfg.moe_top_k):
             from ..parallel.moe import init_moe, init_moe_topk
 
             moe = (
-                init_moe_topk(next(keys), d, cfg.moe_d_ff, cfg.moe_experts)
+                init_moe_topk(
+                    next(keys), d, cfg.moe_d_ff, cfg.moe_experts,
+                    held=cfg.moe_held[1] if cfg.moe_held else 0,
+                    bias=cfg.moe_bias, shared_d_ff=cfg.moe_shared_d_ff,
+                )
                 if cfg.moe_top_k
                 else init_moe(next(keys), d, f, cfg.moe_experts)
             )
             for k, v in moe.items():
                 params[f"{p}/moe/{k}"] = v
         else:
+            if cfg.mlp == "swiglu":
+                params[f"{p}/mlp/gate"] = norm(
+                    next(keys), (d, f), 1 / math.sqrt(d)
+                )
             params[f"{p}/mlp/up"] = norm(
                 next(keys), (d, f), 1 / math.sqrt(d)
             )
@@ -237,15 +349,53 @@ def _norm(params, name, x, cfg):
     )
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor``
+    times."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _yarn_inv_freq(dim: int, theta: float, yarn: tuple):
+    """The ``dim // 2`` rotary frequencies under YaRN, float32: pair i
+    keeps ``theta ** (-2i / dim)`` where it turns more than
+    ``beta_fast`` times within the original length, takes it divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and a
+    linear blend of the two between."""
+    factor, orig, beta_fast, beta_slow = yarn[:4]
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    freq = theta ** (-i / (dim // 2))
+    keep = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return freq / factor * (1.0 - keep) + freq * keep
+
+
 @jax.named_scope("rope")
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, yarn=()):
     """Rotate-half rotary embedding: ``x`` (B, H, S, D) at ``positions``
-    (B, S). Pair (i, i + D/2) turns by ``pos * theta ** (-2i / D)``;
+    (B, S). Pair (i, i + D/2) turns by ``pos * theta ** (-2i / D)``, or
+    by ``yarn``'s frequencies (``TransformerConfig.rope_yarn``), whose
+    cos and sin also carry the ratio of its two magnitude corrections;
     angles, sines and the rotation are float32, the result ``x``'s type."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn:
+        inv = _yarn_inv_freq(2 * half, theta, yarn)
+    else:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None, :, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn:
+        m = _yarn_mscale(yarn[0], yarn[4]) / _yarn_mscale(yarn[0], yarn[5])
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
@@ -290,13 +440,73 @@ def _attend(q, k, v, cfg: TransformerConfig, mesh):
     return attention(q, k, v, causal=True)
 
 
+def _latent_qk(params, p, h, positions, cfg):
+    """Latent attention's side of ``qkv``: h (B, S, d) -> the queries
+    (B, H, S, head_dim + rope_dim), each head's unrotated part then its
+    rotated part, and what a cache keeps of the token, (B, S,
+    latent_width): the K/V latent after its norm, then the ONE rotary
+    key all heads share, rotated."""
+    b, s, _ = h.shape
+    scope = jax.named_scope
+    hq, dn, r = cfg.n_heads, cfg.head_dim, cfg.kv_latent
+
+    def rope(x):
+        return _rope(x, positions, cfg.rope_theta, cfg.rope_yarn)
+
+    with scope("q_latent"):
+        cq = _rmsnorm(
+            h @ params[f"{p}/attn/q_a"], params[f"{p}/attn/q_a_norm"],
+            cfg.norm_eps,
+        )
+        q = jnp.moveaxis(
+            (cq @ params[f"{p}/attn/q_b"]).reshape(b, s, hq, -1), 2, 1
+        )
+        q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+    with scope("kv_latent"):
+        kv = h @ params[f"{p}/attn/kv_a"]
+        ckv = _rmsnorm(
+            kv[..., :r], params[f"{p}/attn/kv_a_norm"], cfg.norm_eps
+        )
+        kpe = rope(kv[:, None, :, r:])[:, 0]
+        return q, jnp.concatenate([ckv, kpe], axis=-1)
+
+
+def _packed_qkv(params, p, h, positions, cfg):
+    """The packed projection's side of ``qkv``: h (B, S, d) -> q
+    (B, H, S, D) and k, v (B, n_kv_heads, S, D), with the config's
+    QK-norm and rotation on q and k."""
+    b, s, _ = h.shape
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qkv = h @ params[f"{p}/attn/qkv"]
+    if cfg.gqa:
+        q, k, v = (
+            jnp.moveaxis(part.reshape(b, s, -1, hd), 2, 1)
+            for part in jnp.split(qkv, [hq * hd, (hq + hkv) * hd], axis=-1)
+        )
+    else:
+        qkv = qkv.reshape(b, s, 3, hq, hd)
+        q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rmsnorm(q, params[f"{p}/attn/q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, params[f"{p}/attn/k_norm"], cfg.norm_eps)
+    if cfg.pos == "rope":
+        q = _rope(q, positions, cfg.rope_theta, cfg.rope_yarn)
+        k = _rope(k, positions, cfg.rope_theta, cfg.rope_yarn)
+    return q, k, v
+
+
 def _block_apply(params, p, x, attend, cfg, mesh=None,
                  moe_capacity_factor=None, positions=None, valid=None):
     """One transformer block with a pluggable attention implementation.
 
     ``attend(q, k, v) -> (o, extra)`` receives q (B, H, S, D) and k, v
     (B, n_kv_heads, S, D) and returns (B, H, S, D); ``extra`` passes
-    through (K/V caches for decode, None otherwise).
+    through (K/V caches for decode, None otherwise). Under latent
+    attention (``cfg.kv_latent``) it receives q (B, H, S, head_dim +
+    rope_dim), the tokens' latents (B, S, latent_width) for k and None
+    for v, and returns (B, H, S, v_head_dim): ``latent_attend`` is the
+    body behind it.
     The SINGLE definition of block semantics — lm_apply, generate()'s
     prefill, and the KV-cache decode step all run this body, so the
     train->decode bit-exact parity cannot silently diverge. The config's
@@ -316,52 +526,42 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     Every operation is named: the block's ``p`` (``blk3``) and inside it
     ``ln1``, ``qkv`` (holding ``qk_norm`` and ``rope``), ``attend``
     (whatever implements it), ``attn_out``, ``ln2``, ``mlp`` or ``moe``
-    (the top-k layer: ``route``, ``experts``, ``combine``). A trace is
-    read by these names."""
+    (the top-k layer: ``route``, ``experts``, ``combine``, ``shared``).
+    Latent attention's ``qkv`` holds ``q_latent`` and ``kv_latent``. A
+    trace is read by these names."""
     b, s, _ = x.shape
     scope = jax.named_scope
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with scope(p):
         with scope("ln1"):
             h = _norm(params, f"{p}/ln1", x, cfg)
         with scope("qkv"):
-            qkv = h @ params[f"{p}/attn/qkv"]
-            if cfg.gqa:
-                q, k, v = (
-                    jnp.moveaxis(part.reshape(b, s, -1, hd), 2, 1)
-                    for part in jnp.split(
-                        qkv, [hq * hd, (hq + hkv) * hd], axis=-1
-                    )
-                )
+            if cfg.kv_latent:
+                q, k, v = *_latent_qk(params, p, h, positions, cfg), None
             else:
-                qkv = qkv.reshape(b, s, 3, hq, hd)
-                q, k, v = (jnp.moveaxis(qkv[:, :, j], 2, 1) for j in range(3))
-            if cfg.qk_norm:
-                with scope("qk_norm"):
-                    q = _rmsnorm(q, params[f"{p}/attn/q_norm"], cfg.norm_eps)
-                    k = _rmsnorm(k, params[f"{p}/attn/k_norm"], cfg.norm_eps)
-            if cfg.pos == "rope":
-                q = _rope(q, positions, cfg.rope_theta)
-                k = _rope(k, positions, cfg.rope_theta)
+                q, k, v = _packed_qkv(params, p, h, positions, cfg)
         with scope("attend"):
             o, extra = attend(q, k, v)
         with scope("attn_out"):
-            o = jnp.moveaxis(o, 1, 2).reshape(b, s, hq * hd)
+            o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
             x = x + o @ params[f"{p}/attn/out"]
         with scope("ln2"):
             h = _norm(params, f"{p}/ln2", x, cfg)
         aux = jnp.float32(0.0)
-        if cfg.moe_top_k:
-            from ..parallel.moe import MOE_TOPK_PARAMS, moe_topk_ffn
+        if cfg.expert_layer(int(p.removeprefix("blk"))):
+            from ..parallel import moe
 
+            names = moe.MOE_TOPK_PARAMS + (
+                (moe.MOE_BIAS_PARAM,) if cfg.moe_bias else ()
+            ) + (moe.MOE_SHARED_PARAMS if cfg.moe_shared_d_ff else ())
             with scope("moe"):
-                y, aux = moe_topk_ffn(
-                    h,
-                    {k2: params[f"{p}/moe/{k2}"] for k2 in MOE_TOPK_PARAMS},
-                    cfg.moe_top_k, valid=valid,
+                y, aux = moe.moe_topk_ffn(
+                    h, {k2: params[f"{p}/moe/{k2}"] for k2 in names},
+                    cfg.moe_top_k, valid=valid, score=cfg.moe_score,
+                    scale=cfg.moe_scale,
+                    held_from=cfg.moe_held[0] if cfg.moe_held else 0,
                 )
                 x = x + y
-        elif cfg.moe_experts:
+        elif cfg.moe_experts and not cfg.moe_top_k:
             from ..parallel.moe import moe_ffn, moe_ffn_dense
 
             moe_params = {
@@ -379,6 +579,13 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
                 else:
                     y, aux = moe_ffn_dense(h, moe_params)
                 x = x + y
+        elif cfg.mlp == "swiglu":
+            with scope("mlp"):
+                f32 = jnp.float32
+                a = (h @ params[f"{p}/mlp/gate"]).astype(f32)
+                u = (h @ params[f"{p}/mlp/up"]).astype(f32)
+                h = (jax.nn.silu(a) * u).astype(x.dtype)
+                x = x + h @ params[f"{p}/mlp/down"]
         else:
             with scope("mlp"):
                 h = jax.nn.gelu(h @ params[f"{p}/mlp/up"])
@@ -421,16 +628,28 @@ def lm_apply(
     with jax.named_scope("embed"):
         x = embed(params, tokens, slice(0, s), cfg)
     aux_total = jnp.float32(0.0)
-    if cfg.gqa or cfg.diffusion_block:
-        # the cache-free side of the serving parity tests: the sequence's
-        # own K and V are the whole cache, each query's limit its mask
-        limits = block_limits(positions, cfg)
-        attend = lambda q, k, v: (cache_attend(q, k, v, limits), None)  # noqa: E731
-    else:
-        attend = lambda q, k, v: (_attend(q, k, v, cfg, mesh), None)  # noqa: E731
+    limits = block_limits(positions, cfg)
+
+    def mk_attend(i):
+        if cfg.kv_latent:
+            # the sequence's own latents are the whole cache, and K and
+            # V of every head are made from them: the prefill's form
+            w_kvb = params[f"blk{i}/attn/kv_b"]
+            return lambda q, lat, _: (
+                latent_attend(q, lat, w_kvb, limits, cfg, absorbed=False),
+                None,
+            )
+        if cfg.gqa or cfg.diffusion_block:
+            # the cache-free side of the serving parity tests: the
+            # sequence's own K and V are the whole cache, each query's
+            # limit its mask
+            return lambda q, k, v: (cache_attend(q, k, v, limits), None)
+        return lambda q, k, v: (_attend(q, k, v, cfg, mesh), None)
+
     for i in range(cfg.n_layers):
         x, aux, _ = _block_apply(
-            params, f"blk{i}", x, attend, cfg, mesh, positions=positions
+            params, f"blk{i}", x, mk_attend(i), cfg, mesh,
+            positions=positions,
         )
         if not cfg.moe_top_k:
             aux_total = aux_total + aux
@@ -484,6 +703,133 @@ def cache_attend(q, k_cache, v_cache, positions):
     s = jnp.where(mask[:, :, None], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     return jnp.einsum("bhgqk,bhkd->bhgqd", w, v_cache).reshape(b, h, nq, d)
+
+
+#: cached positions a materialised latent pass makes keys and values of
+#: at once: (H, Q, block) float32 scores for a 512-token chunk of 64
+#: heads are 67 MB at 512, and 1.7 GB against all 12,800 positions of a
+#: published serving limit
+LATENT_KEY_BLOCK = 512
+
+
+@jax.named_scope("absorb")
+def latent_absorb(q, w_kvb, cfg, width: int):
+    """Queries (B, H, Q, head_dim + rope_dim) taken INTO the latent
+    space and laid out as a cache row is: ``q_nope_h W_UK_h^T``
+    (kv_latent), the rotated part as it is, zeros up to ``width`` (a
+    paged pool's rows end in zeros). A row's product with it is the
+    query's score against that token."""
+    h, dn = q.shape[1], cfg.head_dim
+    w = w_kvb.reshape(cfg.kv_latent, h, -1)
+    q_lat = jnp.einsum(
+        "bhqn,rhn->bhqr", q[..., :dn], w[..., :dn],
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
+    qc = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)
+    return jnp.pad(qc, [(0, 0)] * 3 + [(0, width - qc.shape[-1])])
+
+
+@jax.named_scope("lift")
+def latent_lift(o_lat, w_kvb, cfg):
+    """The weighted sum of latents (B, H, Q, kv_latent) lifted to each
+    head's values by ``W_UV_h``: (B, H, Q, v_head_dim)."""
+    w = w_kvb.reshape(cfg.kv_latent, o_lat.shape[1], -1)
+    return jnp.einsum("bhqr,rhv->bhqv", o_lat, w[..., cfg.head_dim:])
+
+
+@jax.named_scope("cache_attend")
+def latent_attend(q, lat, w_kvb, positions, cfg, absorbed: bool):
+    """Masked attention of Q queries against a FULL cache of latents —
+    ``cache_attend``'s counterpart for latent attention, and as it is,
+    the one body every path shares (``lm_apply`` here, the paged
+    engine's gathered latents in serve/engine.py).
+
+    ``q`` (B, H, Q, head_dim + rope_dim) holds each head's unrotated
+    part and then its rotated part; ``lat`` (B, C, >= latent_width) the
+    cache: a token's normed K/V latent, then the rotated key all heads
+    share (a paged pool's rows may end in zeros, which the absorbed
+    form meets with zeros of the query's); ``w_kvb`` (kv_latent,
+    H * (head_dim + v_head_dim)) makes head h's unrotated keys
+    (``W_UK_h``) and values (``W_UV_h``) out of a latent; ``positions`` (B, Q) is the last cache entry each query may
+    see. -> (B, H, Q, v_head_dim).
+
+        score_h(t, s) = scale * (q_nope_h(t) . (c_s W_UK_h)
+                                 + q_pe_h(t) . k_pe(s))
+        o_h(t) = sum_s p_h(t, s) (c_s W_UV_h)
+
+    Two ways round the same sums. MATERIALISED (``absorbed`` False, a
+    prefill chunk's many queries): keys and values of every head are
+    made from the latents, ``LATENT_KEY_BLOCK`` cached positions at a
+    time, and attended to with a running softmax; the walk ends at the
+    last block any query may see, so a chunk early in a long cache pays
+    for what it reads and not for the cache's length. ABSORBED (a
+    decode tick's one query a sequence): the query is taken INTO the
+    latent space, ``q_lat_h = q_nope_h W_UK_h^T``, scores and the
+    weighted sum run over the latents themselves — the cache is read
+    once, as it lies, for all heads — and ``W_UV_h`` lifts the result.
+    Scores and the softmax are float32 whatever the cache's type;
+    entries beyond a query's limit score -1e30 and weigh exactly 0."""
+    b, h, nq, _ = q.shape
+    n_cache = lat.shape[1]
+    r, dn, dv = cfg.kv_latent, cfg.head_dim, cfg.v_head_dim
+    scale = cfg.attn_scale
+    f32 = jnp.float32
+    w = w_kvb.reshape(r, h, dn + dv)
+
+    if absorbed:
+        mask = (
+            jnp.arange(n_cache)[None, None, None, :]
+            <= positions[:, None, :, None]
+        )
+        qc = latent_absorb(q, w_kvb, cfg, lat.shape[-1])
+        s = jnp.einsum("bhqk,bck->bhqc", qc, lat, preferred_element_type=f32)
+        p = jax.nn.softmax(jnp.where(mask, s * scale, -1e30), axis=-1)
+        # the whole row, rotary key and all, so that the cache is read
+        # as it lies; the key's columns of the sum are dropped
+        o_lat = jnp.einsum("bhqc,bck->bhqk", p.astype(lat.dtype), lat)[..., :r]
+        return latent_lift(o_lat, w_kvb, cfg)
+
+    kb = LATENT_KEY_BLOCK if n_cache % LATENT_KEY_BLOCK == 0 else n_cache
+
+    def block(j, carry):
+        """Cached positions [j * kb, (j + 1) * kb) into the running
+        maximum, the running sum and the weighted values so far."""
+        m, l, acc = carry
+        blk = jax.lax.dynamic_slice_in_dim(lat, j * kb, kb, axis=1)
+        with jax.named_scope("materialise"):
+            kv = jnp.einsum("bcr,rhx->bhcx", blk[..., :r], w)
+            k = jnp.concatenate([
+                kv[..., :dn],
+                jnp.broadcast_to(
+                    blk[:, None, :, r:r + cfg.rope_dim],
+                    (b, h, kb, cfg.rope_dim),
+                ),
+            ], axis=-1)
+        s = jnp.einsum(
+            "bhqd,bhcd->bhqc", q, k, preferred_element_type=f32
+        ) * scale
+        seen = (
+            j * kb + jnp.arange(kb)[None, None, None, :]
+            <= positions[:, None, :, None]
+        )
+        s = jnp.where(seen, s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        grow = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        acc = acc * grow[..., None] + jnp.einsum(
+            "bhqc,bhcv->bhqv", p.astype(lat.dtype), kv[..., dn:],
+            preferred_element_type=f32,
+        )
+        return m_new, l * grow + jnp.sum(p, axis=-1), acc
+
+    # every query sees position 0, so block 0 leaves a real maximum
+    # behind and a later block wholly beyond a query's limit weighs 0
+    n_blocks = jnp.clip(jnp.max(positions) // kb + 1, 1, n_cache // kb)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((b, h, nq), -1e30, f32), jnp.zeros((b, h, nq), f32),
+        jnp.zeros((b, h, nq, dv), f32),
+    ))
+    return (acc / l[..., None]).astype(q.dtype)
 
 
 def _block_step(params, p, x, k_cache, v_cache, pos, cfg):
@@ -552,6 +898,11 @@ def generate(
     batch-independence explicitly).
     """
     b, plen = prompt.shape
+    if cfg.kv_latent:
+        raise ValueError(
+            "generate: a latent cache (kv_latent) is served by "
+            "serve/engine.py alone"
+        )
     if plen < 1:
         raise ValueError("generate: prompt must hold at least one token")
     total = plen + n_tokens

@@ -352,14 +352,7 @@ def _call_one_query(q, k_pool, v_pool, tables, positions, interpret):
     group = max(1, min(_CHUNK_POSITIONS // bl, mb))
     pos = positions[:, 0].astype(jnp.int32)
     nlive = live_blocks(pos, bl, mb).astype(jnp.int32)
-    chunks = (nlive + group - 1) // group                # (S,) a sequence
-    ends = jnp.cumsum(chunks)
-    item = jnp.arange(s * -(-mb // group), dtype=jnp.int32)
-    seq = jnp.minimum(
-        jnp.searchsorted(ends, item, side="right", method="compare_all"),
-        s - 1,
-    ).astype(jnp.int32)
-    chunk = item - (ends - chunks)[seq]
+    seq, chunk, n_items = _live_items(nlive, group, mb)
     fold = (
         jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]
     ).astype(jnp.float32)                                # (HD, H)
@@ -392,12 +385,206 @@ def _call_one_query(q, k_pool, v_pool, tables, positions, interpret):
         interpret=interpret,
         name="paged_attention",
     )(
-        tables.reshape(-1).astype(jnp.int32), nlive, seq,
-        chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32), pos,
-        jnp.moveaxis(q, 1, 2).reshape(s, 1, hd), fold, fold.T,
+        tables.reshape(-1).astype(jnp.int32), nlive, seq, chunk, n_items,
+        pos, jnp.moveaxis(q, 1, 2).reshape(s, 1, hd), fold, fold.T,
         k_pool, v_pool,
     )
     return jnp.moveaxis(out.reshape(s, 1, h, d), 2, 1)
+
+
+#: pool positions one step of the latent kernel fetches and folds: four
+#: blocks of 128 at a published serving shape, 0.65 MB of bfloat16 rows
+_LATENT_CHUNK_POSITIONS = 512
+#: what the latent kernel may hold in VMEM: the queries and the results
+#: of every slot (3.9 and 3.1 MB at 48 slots of 64 heads) beside its two
+#: buffers
+_LATENT_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _latent_kernel(
+    tab_ref, nlive_ref, seq_ref, chunk_ref, n_ref, pos_ref,
+    q_ref, pool_hbm, o_ref,
+    buf, sem, acc, m, l,
+    *, block_len, mb, group, scale,
+):
+    """The decode tick of a LATENT cache — one absorbed query a
+    sequence, (H, W) wide: every head's query taken into the latent
+    space — as one program over a flat list of live chunks, as
+    ``_one_query_kernel`` walks one: item ``i`` is chunk
+    ``chunk_ref[i]`` (``group`` consecutive table entries) of sequence
+    ``seq_ref[i]``, ``n_ref[0]`` items are walked, and a chunk's live
+    blocks are copied by hand into one of two ``(group * block_len, W)``
+    buffers while the chunk before is folded.
+
+    A latent row serves every head as key AND as value, so a chunk is
+    two plain products on the MXU: scores ``(H, W) x (W, rows)``, then
+    probabilities ``(H, rows) x (rows, W)`` into the running sum; the
+    statistics are a column a head. The row's tail beyond the latent
+    (the rotary key, a pool's zeros) meets the query's own tail in the
+    scores and is dropped from the result by the caller's width
+    (``o_ref`` holds the first columns)."""
+    gbl = group * block_len
+    n = n_ref[0]
+    out_w = o_ref.shape[-1]
+
+    def copies(i, b):
+        """Item i's (is it live?, its copy), a block each."""
+        seq = seq_ref[i]
+        for g in range(group):
+            blk = chunk_ref[i] * group + g
+            bid = tab_ref[seq * mb + jnp.minimum(blk, mb - 1)]
+            rows = pl.ds(g * block_len, block_len)
+            yield blk < nlive_ref[seq], pltpu.make_async_copy(
+                pool_hbm.at[bid], buf.at[b, rows], sem.at[b]
+            )
+
+    def each_copy(i, b, do):
+        for live, copy in copies(i, b):
+            @pl.when(live)
+            def _():
+                do(copy)
+
+    # rows a part-filled chunk leaves stale are masked, but 0 * NaN is
+    # NaN: the buffers start as zeros, and hold pool bytes ever after
+    buf[...] = jnp.zeros_like(buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _first():
+        each_copy(0, 0, lambda copy: copy.start())
+
+    def fold_item(i, carry):
+        b = i % 2
+
+        @pl.when(i + 1 < n)
+        def _next():
+            each_copy(i + 1, 1 - b, lambda copy: copy.start())
+
+        each_copy(i, b, lambda copy: copy.wait())
+        seq, c = seq_ref[i], chunk_ref[i]
+
+        @pl.when(c == 0)
+        def _init():
+            acc[...] = jnp.zeros_like(acc)
+            m[...] = jnp.full_like(m, NEG_INF)
+            l[...] = jnp.zeros_like(l)
+
+        rows = buf[b]                                            # (GBL, W)
+        scores = jax.lax.dot_general(
+            q_ref[seq], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                                # (H, GBL)
+        kpos = c * gbl + jax.lax.broadcasted_iota(jnp.int32, (1, gbl), 1)
+        mask = kpos <= pos_ref[seq]
+        scores = jnp.where(mask, scores, NEG_INF)
+        m_prev = m[...]                                          # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)        # (H, GBL)
+        l[...] = l[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m[...] = m_new
+        acc[...] = acc[...] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows, preferred_element_type=jnp.float32
+        )
+
+        @pl.when((c + 1) * group >= nlive_ref[seq])
+        def _finish():
+            total = l[...]
+            o_ref[seq] = (
+                acc[...][:, :out_w] / jnp.where(total == 0.0, 1.0, total)
+            ).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, n, fold_item, 0)
+
+
+def latent_fusable(block_len: int, dtype) -> str | None:
+    """None if the latent kernel can serve this pool, else the reason it
+    cannot: it copies blocks by hand onto whole register tiles, so a
+    block is a multiple of the pool dtype's tile rows."""
+    if block_len % _sublanes(dtype):
+        return (
+            f"kv_block_len {block_len} is no multiple of "
+            f"{_sublanes(dtype)} rows of {jnp.dtype(dtype).name}"
+        )
+    return None
+
+
+def paged_latent_attention(
+    q, pool, tables, positions, *, scale: float, out_width: int,
+    interpret=None,
+):
+    """Masked paged attention of ONE absorbed query a sequence over a
+    latent pool, read in place through the block table.
+
+    ``q`` (S, H, W): every head's query taken into the latent space and
+    laid out as a pool row is (``models.transformer.latent_absorb``);
+    ``pool`` (n_blocks, block_len, W), each row a token's latent (the
+    fresh token's already scattered in); ``tables`` (S, max_blocks);
+    ``positions`` (S,) the last pool position each sequence may see,
+    -1 for a sequence with nothing to attend to (a dead lane: its
+    result is zeros). -> (S, H, out_width): the first ``out_width``
+    columns of ``softmax(scale * q rows^T) rows``, allclose to
+    ``latent_attend``'s absorbed form over the gathered view."""
+    s, h, w = q.shape
+    _, bl, pw = pool.shape
+    if pw != w:
+        raise ValueError(
+            f"pool rows are {pw} wide, the absorbed queries {w}"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    mb = tables.shape[1]
+    group = max(1, min(_LATENT_CHUNK_POSITIONS // bl, mb))
+    pos = positions.astype(jnp.int32)
+    nlive = live_blocks(pos, bl, mb).astype(jnp.int32)
+    seq, chunk, n = _live_items(nlive, group, mb)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kernel, block_len=bl, mb=mb, group=group, scale=scale,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(1,),
+            in_specs=[whole(s, h, w), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole(s, h, out_width),
+            scratch_shapes=[
+                pltpu.VMEM((2, group * bl, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((h, w), jnp.float32),       # acc
+                pltpu.VMEM((h, 1), jnp.float32),       # m (running max)
+                pltpu.VMEM((h, 1), jnp.float32),       # l (running sum)
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, h, out_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM_BYTES
+        ),
+        interpret=bool(interpret),
+        name="paged_latent_attention",
+    )(tables.reshape(-1).astype(jnp.int32), nlive, seq, chunk, n, pos, q, pool)
+
+
+def _live_items(nlive, group: int, mb: int):
+    """The flat list both one-query kernels walk: for ``nlive`` (S,)
+    live blocks a sequence and chunks of ``group`` table entries ->
+    (sequence of item i, its chunk within the sequence, how many items
+    there are (1,)), the first two ``S * ceil(mb / group)`` long."""
+    s = nlive.shape[0]
+    chunks = (nlive + group - 1) // group                # (S,) a sequence
+    ends = jnp.cumsum(chunks)
+    item = jnp.arange(s * -(-mb // group), dtype=jnp.int32)
+    seq = jnp.minimum(
+        jnp.searchsorted(ends, item, side="right", method="compare_all"),
+        s - 1,
+    ).astype(jnp.int32)
+    chunk = item - (ends - chunks)[seq]
+    return seq, chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32)
 
 
 def live_blocks(last_position, block_len, max_blocks):

@@ -15,10 +15,12 @@ combine is one psum over that axis. Under shard_map each device:
 Dropped tokens (over capacity) pass through on the residual path, like
 Switch Transformer. Routing/combine math stays fp32 under bf16 compute.
 
-Beside it, for serving, ``moe_topk_ffn``: top-k of a softmax over all
-experts, renormalised, SwiGLU experts, NO capacity and no dropped token
-(the layer of the Qwen3-MoE lineage). It is one device's layer: it holds
-every expert it routes over.
+Beside it, for serving, ``moe_topk_ffn``: top-k of a softmax or of
+sigmoid scores over all experts, renormalised, SwiGLU experts, NO
+capacity and no dropped token (the layers of the Qwen3-MoE and the
+DeepSeek-V3 lineages: the latter adds a selection bias, a scaling factor
+and a shared expert). It is one device's layer: it routes over every
+expert and computes those it is told it holds, all of them by default.
 """
 
 from __future__ import annotations
@@ -52,41 +54,116 @@ def init_moe(
     }
 
 
-#: the top-k layer's parameters: router ``gate`` (D, E); per expert the
-#: SwiGLU gate and up projections ``w_gate`` and ``w_up`` (E, D, F) and
-#: ``w_down`` (E, F, D). Expert-major, as the TPU compiler lays the
-#: three products out: stored (D, E, F) it copies 0.4 GB a matrix into
-#: this order in every pass (read off the compiled text, PERF.md PR 28)
+#: the top-k layer's parameters: router ``gate`` (D, E); per HELD expert
+#: the SwiGLU gate and up projections ``w_gate`` and ``w_up`` (H, D, F)
+#: and ``w_down`` (H, F, D), H = E unless the layer is told its share.
+#: Expert-major, as the TPU compiler lays the three products out: stored
+#: (D, E, F) it copies 0.4 GB a matrix into this order in every pass
+#: (read off the compiled text, PERF.md PR 28)
 MOE_TOPK_PARAMS = ("gate", "w_gate", "w_up", "w_down")
+#: the router's selection bias (E,), where the model has one
+MOE_BIAS_PARAM = "bias"
+#: the shared expert's SwiGLU, (D, Fs), (D, Fs) and (Fs, D)
+MOE_SHARED_PARAMS = ("s_gate", "s_up", "s_down")
 
 
 def init_moe_topk(
-    rng: jax.Array, d_model: int, d_ff: int, n_experts: int
+    rng: jax.Array, d_model: int, d_ff: int, n_experts: int, *,
+    held: int = 0, bias: bool = False, shared_d_ff: int = 0,
 ) -> dict:
-    """Param pytree of ``moe_topk_ffn`` (names: ``MOE_TOPK_PARAMS``)."""
+    """Param pytree of ``moe_topk_ffn`` (names: ``MOE_TOPK_PARAMS``, and
+    ``MOE_BIAS_PARAM`` / ``MOE_SHARED_PARAMS`` where asked for). The
+    router is ``n_experts`` wide; ``held`` experts have weights here
+    (0 = all)."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
     s = 1.0 / np.sqrt(d_model)
-    return {
+    h = held or n_experts
+    out = {
         "gate": s * jax.random.normal(kr, (d_model, n_experts)),
-        "w_gate": s * jax.random.normal(kg, (n_experts, d_model, d_ff)),
-        "w_up": s * jax.random.normal(ku, (n_experts, d_model, d_ff)),
+        "w_gate": s * jax.random.normal(kg, (h, d_model, d_ff)),
+        "w_up": s * jax.random.normal(ku, (h, d_model, d_ff)),
         "w_down": (1.0 / np.sqrt(d_ff))
-        * jax.random.normal(kd, (n_experts, d_ff, d_model)),
+        * jax.random.normal(kd, (h, d_ff, d_model)),
     }
+    if bias:
+        out[MOE_BIAS_PARAM] = 0.1 * jax.random.normal(
+            jax.random.fold_in(rng, 1), (n_experts,)
+        )
+    if shared_d_ff:
+        k1, k2, k3 = jax.random.split(jax.random.fold_in(rng, 2), 3)
+        out["s_gate"] = s * jax.random.normal(k1, (d_model, shared_d_ff))
+        out["s_up"] = s * jax.random.normal(k2, (d_model, shared_d_ff))
+        out["s_down"] = (1.0 / np.sqrt(shared_d_ff)) * jax.random.normal(
+            k3, (shared_d_ff, d_model)
+        )
+    return out
 
 
-def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None):
-    """Drop-free top-k SwiGLU experts: x (B, S, D) ->
-    (y (B, S, D), int32 [experts hit, most tokens one expert took]).
+def topk_gates(x2d, params: dict, top_k: int, score: str = "softmax",
+               scale: float = 1.0):
+    """The router of ``moe_topk_ffn``: x2d (N, D) -> (gates (N, E)
+    float32, zero outside each token's top k; chosen (N, E) bool).
 
-        p = softmax_f32(x Wr) over ALL experts;  T = top-k(p)
-        g_e = p_e / sum_{T} p  for e in T, else 0
-        y = sum_e g_e * ((silu(x Wg_e) * (x Wu_e)) Wd_e)
+        s = softmax_f32(x Wr) or sigmoid_f32(x Wr), over ALL experts
+        T = top-k(s + b)       b the selection bias, where there is one
+        g_e = s_e / sum_{T} s * scale   for e in T, else 0
 
-    HOW: every expert runs on every token and ``g`` (zero outside T)
-    weights the sum — two products (N, D) x (E, D, F) batched over the
-    experts and one contraction over (E, F) jointly, so the (N, E, D)
-    per-expert outputs are never formed. There is no capacity, no sort and no dispatch
+    The bias CHOOSES and does not weigh: the gates are made of ``s``
+    alone. Sigmoid scores need not sum to anything, so their sum over T
+    is kept off zero by 1e-20, as the lineage's modelling code does."""
+    n, e = x2d.shape[0], params["gate"].shape[1]
+    logits = jnp.matmul(
+        x2d.astype(jnp.float32), params["gate"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)                  # (N, E)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"moe score {score!r}: softmax or sigmoid")
+    bias = params.get(MOE_BIAS_PARAM)
+    if bias is None:
+        top_s, top_e = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+    chosen = jnp.zeros((n, e), bool).at[
+        jnp.arange(n)[:, None], top_e
+    ].set(True)
+    total = jnp.sum(top_s, axis=-1, keepdims=True)
+    if score == "sigmoid":
+        total = total + 1e-20
+    gates = jnp.where(chosen, scores, 0.0) / total
+    if scale != 1.0:
+        gates = gates * scale
+    return gates, chosen
+
+
+def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
+                 score: str = "softmax", scale: float = 1.0,
+                 held_from: int = 0):
+    """Drop-free top-k SwiGLU experts: x (B, S, D) -> (y (B, S, D),
+    int32 [held experts hit, most tokens one held expert took,
+    token-expert pairs routed to held experts]).
+
+        g = topk_gates(x)          (softmax or sigmoid, bias, scale)
+        y = sum_{e held} g_e * ((silu(x Wg_e) * (x Wu_e)) Wd_e)  +  S(x)
+
+    THE SHARE: the router is as wide as the model's expert count E; the
+    expert weights hold H <= E experts, ``[held_from, held_from + H)``
+    of them. The gates are computed over all E and the sum runs over the
+    experts held: what the others would have added is left out (their
+    chips' part of an expert-parallel layer; no exchange stands in for
+    it here). H = E, the default, is the whole layer. ``S`` is the
+    shared expert (``MOE_SHARED_PARAMS``), computed here in full for
+    every token, where the model has one.
+
+    HOW: every held expert runs on every token and ``g`` (zero outside
+    T) weights the sum — two products (N, D) x (H, D, F) batched over
+    the experts and one contraction over (H, F) jointly, so the
+    (N, H, D) per-expert outputs are never formed. There is no capacity,
+    no sort and no dispatch
     buffer, so no routing pattern can drop a token or leave a term out,
     and a skewed router costs what a flat one does. It is the form for
     a serving pass of a few hundred tokens over many narrow experts:
@@ -99,32 +176,26 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None):
     At thousands of tokens a pass a sorted, grouped product is the form
     to write instead.
 
-    Router, softmax, gates and the SwiGLU are float32; the products take
+    Router, scores, gates and the SwiGLU are float32; the products take
     ``x`` and the weights as stored, accumulate in float32 and come out
     in ``x``'s type. ``valid``
-    (B, S) marks the tokens the two counters count (None = all)."""
+    (B, S) marks the tokens the counters count (None = all)."""
     b, s, d = x.shape
     n = b * s
     x2d = x.reshape(n, d)
-    e = params["gate"].shape[1]
+    held = params["w_gate"].shape[0]
     with jax.named_scope("route"):
-        logits = jnp.matmul(
-            x2d.astype(jnp.float32), params["gate"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        probs = jax.nn.softmax(logits, axis=-1)                   # (N, E)
-        top_p, top_e = jax.lax.top_k(probs, top_k)
-        chosen = jnp.zeros((n, e), bool).at[
-            jnp.arange(n)[:, None], top_e
-        ].set(True)
-        gates = jnp.where(chosen, probs, 0.0) / jnp.sum(
-            top_p, axis=-1, keepdims=True
-        )
+        gates, chosen = topk_gates(x2d, params, top_k, score, scale)
+        if held != chosen.shape[1]:
+            gates = gates[:, held_from:held_from + held]
+            chosen = chosen[:, held_from:held_from + held]
         counted = chosen if valid is None else (
             chosen & valid.reshape(n, 1)
         )
-        load = jnp.sum(counted, axis=0, dtype=jnp.int32)          # (E,)
-        stats = jnp.stack([jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+        load = jnp.sum(counted, axis=0, dtype=jnp.int32)          # (H,)
+        stats = jnp.stack([
+            jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load), jnp.sum(load),
+        ])
     with jax.named_scope("experts"):
         f32 = jnp.float32
         a = jnp.einsum("nd,edf->enf", x2d, params["w_gate"]).astype(f32)
@@ -132,6 +203,13 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None):
         h = (jax.nn.silu(a) * u * gates.T[:, :, None]).astype(x.dtype)
     with jax.named_scope("combine"):
         y = jnp.einsum("enf,efd->nd", h, params["w_down"])
+    if "s_gate" in params:
+        with jax.named_scope("shared"):
+            a = jnp.matmul(x2d, params["s_gate"]).astype(f32)
+            u = jnp.matmul(x2d, params["s_up"]).astype(f32)
+            y = y + jnp.matmul(
+                (jax.nn.silu(a) * u).astype(x.dtype), params["s_down"]
+            )
     return y.reshape(b, s, d), stats
 
 

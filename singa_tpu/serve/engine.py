@@ -60,6 +60,22 @@ greedy-only per slot: a temperature > 0 slot rides the verify tick
 with zero drafts (it emits its one sampled token per tick; its key
 discipline — one split per emitted token — is identical either way).
 
+A LATENT CACHE (a model with ``kv_latent``, models/transformer.py): the
+pool's row is ONE latent a token a layer — the K/V latent after its norm
+beside the rotated key all heads share, ``latent_width`` values — and
+there is no V pool beside it (``state["v"]`` is empty). The same two
+programs serve it through the same closures' seam, each in the form its
+shape wants (``latent_attend``): a prefill chunk MATERIALISES K and V of
+the slot's gathered latents, a block of cached positions at a time up
+to the last one a query may see; a decode tick ABSORBS its one query a
+slot into the latent space and reads the latents as they lie, in place
+through the block table where the latent kernel runs
+(``ops/paged_attention.paged_latent_attention``, chosen as the MHA
+kernel is), else as a gathered view. Which form is chosen in code from
+the shape, as the pool's row is from the configuration. What has no
+latent form yet (speculation, the prefix cache, slot export/import, a
+TP mesh) is refused by the field's name.
+
 Sharding: pass a mesh and the pools lay their last dim (H * D, heads
 major: serve/kv_pool.py) out over the ``model`` axis, whole heads a
 shard (parallel/shardings.serving_kv_shardings) — the serving analog
@@ -100,9 +116,25 @@ from ..models.transformer import (
     block_limits,
     cache_attend,
     embed,
+    latent_absorb,
+    latent_attend,
+    latent_lift,
     lm_head,
 )
 from .kv_pool import BlockAllocator, KVPool, PoolExhausted
+
+
+#: what a decode pass of a model with top-k expert layers appends to its
+#: tokens, int32, all over the pass's LIVE slots: held experts that drew
+#: a token summed over the expert layers; the most tokens one held
+#: expert of one layer took; token-expert pairs routed to held experts
+#: summed over the layers; cache rows the pass's attention had to read
+#: a layer (each live slot's position + 1); and the pairs the prefill
+#: chunks since the pass before routed to held experts
+DECODE_COUNTERS = (
+    "experts_hit", "expert_max_load", "held_pairs", "cache_rows",
+    "chunk_held_pairs",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,15 +236,15 @@ def choose_attend(cfg, serving, mesh, platform: str) -> str:
 
     A pinned ``serving.attend_impl`` is returned as it is. Unset, the
     kernel runs where it compiles through Mosaic and knows the model:
-    it walks one K/V head a query head and has no block step
-    (``Engine._beyond_gpt2``), GSPMD cannot partition a Mosaic call
-    (a mesh), and off a TPU it would run through the Pallas
-    interpreter, a grid step at a time."""
+    it walks one K/V head a query head, or a latent cache's one row a
+    token, and has no block step (``Engine._no_kernel``), GSPMD cannot
+    partition a Mosaic call (a mesh), and off a TPU it would run
+    through the Pallas interpreter, a grid step at a time."""
     if serving.attend_impl is not None:
         return serving.attend_impl
     from ..ops.paged_attention import fusable
 
-    why = Engine._beyond_gpt2(cfg) or fusable(serving.kv_block_len)
+    why = Engine._no_kernel(cfg) or fusable(serving.kv_block_len)
     if why is None and mesh is not None:
         why = "a tensor-parallel mesh"
     if why is None and platform != "tpu":
@@ -266,9 +298,13 @@ class Engine:
         )
         self._fused = self.attend_choice == "fused"
         if self._fused:
-            from ..ops.paged_attention import fusable
+            from ..ops.paged_attention import fusable, latent_fusable
 
             reason = fusable(self.serving.kv_block_len)
+            if reason is None and cfg.kv_latent:
+                reason = latent_fusable(
+                    self.serving.kv_block_len, params["embed/tok"].dtype
+                )
             if reason is not None:
                 # the runtime rejection KRN001 statically mirrors
                 raise ValueError(
@@ -306,8 +342,14 @@ class Engine:
         self._slot_version: dict[int, int] = {}
         s, mb = self.serving.slots, self.pool.max_blocks_per_seq
         # a pool row is one token's K (or V) for the K/V heads alone,
-        # in the parameters' own type (float32 parameters: float32 pools)
-        shape = self.pool.array_shape(cfg.n_kv_heads, cfg.head_dim)
+        # in the parameters' own type (float32 parameters: float32
+        # pools); under latent attention ONE row a token, its latent
+        # (``state["k"]`` holds those pools and ``state["v"]`` none)
+        shape = (
+            self.pool.array_shape(1, KVPool.latent_row(cfg.latent_width))
+            if cfg.kv_latent
+            else self.pool.array_shape(cfg.n_kv_heads, cfg.head_dim)
+        )
         pool_dtype = params["embed/tok"].dtype
         pool_sh = state_sh = None
         if mesh is not None:
@@ -333,9 +375,20 @@ class Engine:
             ),
             "v": tuple(
                 put(jnp.zeros(shape, pool_dtype), pool_sh)
-                for _ in range(cfg.n_layers)
+                for _ in range(0 if cfg.kv_latent else cfg.n_layers)
             ),
         }
+        #: counters a one-token decode pass appends to its tokens (the
+        #: scheduler reads them with the pass, one tick late): for a
+        #: model with top-k expert layers ``DECODE_COUNTERS``, else none
+        self.decode_counters = (
+            len(DECODE_COUNTERS)
+            if cfg.moe_top_k and not cfg.diffusion_block else 0
+        )
+        if self.decode_counters:
+            # pairs the prefill chunks since the last decode pass routed
+            # to held experts: the next pass hands it on and zeroes it
+            self.state["chunk_pairs"] = jnp.zeros((), jnp.int32)
         if cfg.diffusion_block:
             # per-slot block lanes: the current block's tokens and which
             # of its positions are still masked (``pos`` is its start)
@@ -387,9 +440,9 @@ class Engine:
     @staticmethod
     def _refuse_what_cannot_run(cfg, serving, mesh) -> None:
         """What no program here computes for a model with fewer K/V
-        heads than query heads or generated by diffusion over blocks is
-        refused by the field's name, not run wrongly (ROADMAP Queue 2
-        keeps the list)."""
+        heads than query heads, with a latent cache or generated by
+        diffusion over blocks is refused by the field's name, not run
+        wrongly (ROADMAP Queue 2 keeps the list)."""
         why = Engine._beyond_gpt2(cfg)
         if why is None:
             return
@@ -397,7 +450,8 @@ class Engine:
             "speculate (spec_k)": serving.spec_k > 0,
             "prefix_cache": serving.prefix_cache,
             "kernels.paged_attention = fused (attend_impl)":
-                serving.attend_impl == "fused",
+                serving.attend_impl == "fused"
+                and Engine._no_kernel(cfg) is not None,
             "a tensor-parallel mesh": mesh is not None,
         }
         for what, asked in refused.items():
@@ -436,14 +490,24 @@ class Engine:
             )
 
     @staticmethod
-    def _beyond_gpt2(cfg) -> str | None:
-        """The field (with its value) for which the refusals above
-        hold, None for a model that every path here serves."""
+    def _no_kernel(cfg) -> str | None:
+        """The field (with its value) of a model that the paged kernels
+        (ops/paged_attention.py) do not know, None where one of them
+        serves the decode tick: one K/V head a query head, or a latent
+        cache."""
         if cfg.diffusion_block:
             return f"diffusion_block = {cfg.diffusion_block}"
         if cfg.gqa:
             return f"n_kv_heads = {cfg.n_kv_heads} != n_heads = {cfg.n_heads}"
         return None
+
+    @staticmethod
+    def _beyond_gpt2(cfg) -> str | None:
+        """The field (with its value) for which the refusals above
+        hold, None for a model that every path here serves."""
+        if cfg.kv_latent:
+            return f"kv_latent = {cfg.kv_latent}"
+        return Engine._no_kernel(cfg)
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -476,6 +540,15 @@ class Engine:
         program shares; in a trace its operations are ``kv_write``."""
         return pool_arr.at[bid, off].set(fresh.reshape(*bid.shape, -1))
 
+    def _latent_write(self, pool_arr, bid, off, lat):
+        """``_kv_write`` of latents ``lat`` (..., latent_width): each
+        row's tail up to the pool's width is zeros."""
+        pad = pool_arr.shape[-1] - lat.shape[-1]
+        return self._kv_write(
+            pool_arr, bid, off,
+            jnp.pad(lat, [(0, 0)] * (lat.ndim - 1) + [(0, pad)]),
+        )
+
     def _blocks_out(self, pool_arr, row):
         """Blocks ``row`` of one pool in the shape that leaves the
         engine: (n, H, BL, D), the fleet's wire format."""
@@ -489,6 +562,15 @@ class Engine:
         to blocks ``row``: ``_blocks_out``'s inverse."""
         b = jnp.moveaxis(blocks, 1, 2)
         return pool_arr.at[row].set(b.reshape(*b.shape[:2], -1))
+
+    @jax.named_scope("gather_kv")
+    def _gather_latent(self, pool_arr, tables):
+        """(NB, BL, W) latent pool + (S', MB) tables -> the (S', CL, W)
+        dense view of each sequence's latents, the rows' zero tails
+        (``KVPool.latent_row``) with them: ``_gather`` with no head to
+        take out of a row."""
+        g = pool_arr.at[tables].get(mode="promise_in_bounds")
+        return g.reshape(g.shape[0], self.pool.cache_len, -1)
 
     @jax.named_scope("gather_kv")
     def _gather_kv(self, kp, vp, tables):
@@ -532,6 +614,23 @@ class Engine:
             interpret=self.serving.interpret,
         )
 
+    @jax.named_scope("paged_attention")
+    def _paged_latent_attend(self, q, pool_arr, w_kvb, state, live):
+        """The fused path's attend over a latent pool (the decode tick):
+        the one query a slot is taken into the latent space, the kernel
+        reads the slot's live blocks in place through its table (a dead
+        lane has none), and ``W_UV`` lifts what comes back."""
+        from ..ops.paged_attention import paged_latent_attention
+
+        mcfg = self.cfg
+        qc = latent_absorb(q, w_kvb, mcfg, pool_arr.shape[-1])
+        o_lat = paged_latent_attention(
+            qc[:, :, 0], pool_arr, state["tables"],
+            jnp.where(live, state["pos"], -1), scale=mcfg.attn_scale,
+            out_width=mcfg.kv_latent, interpret=self.serving.interpret,
+        )
+        return latent_lift(o_lat[:, :, None], w_kvb, mcfg)
+
     def _sample(self, logits, keys, temps, live, prev):
         """Per-slot sampling through the temperature LANE: greedy argmax
         where a slot's temperature is 0 (bit-for-bit the generate()
@@ -572,6 +671,21 @@ class Engine:
         new_k, new_v = [], []
 
         def mk_attend(i):
+            def attend_latent(q, lat, _):
+                # the decode tick's form: queries taken into the latent
+                # space, the latents read as they lie — in place by the
+                # kernel, or as a gathered view
+                lp = self._latent_write(state["k"][i], bid, off, lat[:, 0])
+                w_kvb = params[f"blk{i}/attn/kv_b"]
+                if self._fused:
+                    o = self._paged_latent_attend(q, lp, w_kvb, state, live)
+                else:
+                    o = latent_attend(
+                        q, self._gather_latent(lp, state["tables"]), w_kvb,
+                        pos[:, None], mcfg, absorbed=True,
+                    )
+                return o, (lp, None)
+
             def attend(q, k, v):
                 kp = self._kv_write(state["k"][i], bid, off, k[:, :, 0, :])
                 vp = self._kv_write(state["v"][i], bid, off, v[:, :, 0, :])
@@ -586,16 +700,19 @@ class Engine:
                         pos[:, None],
                     )
                 return o, (kp, vp)
-            return attend
+            return attend_latent if mcfg.kv_latent else attend
 
+        stats = []
         for i in range(mcfg.n_layers):
-            x, _, (kp, vp) = _block_apply(
+            x, aux, (kp, vp) = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
-                positions=pos[:, None],
+                positions=pos[:, None], valid=live[:, None],
             )
             new_k.append(kp)
             new_v.append(vp)
+            if mcfg.expert_layer(i):
+                stats.append(aux)
         logits = lm_head(params, x, mcfg)[:, 0]
         with jax.named_scope("sample"):
             new_rng, keys = self._split_keys(state)
@@ -606,9 +723,17 @@ class Engine:
             "pos": pos + live.astype(jnp.int32),
             "rng": new_rng,
             "k": tuple(new_k),
-            "v": tuple(new_v),
+            "v": tuple(v for v in new_v if v is not None),
         }
-        return new_state, jnp.where(live, nxt, jnp.int32(-1))
+        out = jnp.where(live, nxt, jnp.int32(-1))
+        if self.decode_counters:
+            st = jnp.stack(stats)                                # (L, 3)
+            out = jnp.concatenate([out, jnp.stack([
+                jnp.sum(st[:, 0]), jnp.max(st[:, 1]), jnp.sum(st[:, 2]),
+                jnp.sum(jnp.where(live, pos + 1, 0)), state["chunk_pairs"],
+            ])])
+            new_state["chunk_pairs"] = jnp.zeros((), jnp.int32)
+        return new_state, out
 
     def _prefill(self, params, state, slot, chunk, pos0, n_valid):
         """One (1, C) prompt chunk of ``slot`` at absolute positions
@@ -640,6 +765,17 @@ class Engine:
         new_k, new_v = [], []
 
         def mk_attend(i):
+            def attend_latent(q, lat, _):
+                # the chunk's form: K and V made from the slot's
+                # gathered latents, as far as a query of it may see
+                lp = self._latent_write(state["k"][i], bid, off, lat[0])
+                o = latent_attend(
+                    q, self._gather_latent(lp, row[None]),
+                    params[f"blk{i}/attn/kv_b"], limits[None], mcfg,
+                    absorbed=False,
+                )
+                return o, (lp, None)
+
             def attend(q, k, v):
                 kp = self._kv_write(
                     state["k"][i], bid, off, jnp.moveaxis(k[0], 1, 0)
@@ -656,17 +792,25 @@ class Engine:
                     limits[None],
                 )
                 return o, (kp, vp)
-            return attend
+            return attend_latent if mcfg.kv_latent else attend
 
+        held_pairs = jnp.zeros((), jnp.int32)
         for i in range(mcfg.n_layers):
-            x, _, (kp, vp) = _block_apply(
+            x, aux, (kp, vp) = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
                 positions=p[None], valid=valid[None],
             )
             new_k.append(kp)
             new_v.append(vp)
-        new_state = {**state, "k": tuple(new_k), "v": tuple(new_v)}
+            if mcfg.expert_layer(i):
+                held_pairs = held_pairs + aux[2]
+        new_state = {
+            **state, "k": tuple(new_k),
+            "v": tuple(v for v in new_v if v is not None),
+        }
+        if self.decode_counters:
+            new_state["chunk_pairs"] = state["chunk_pairs"] + held_pairs
         if mcfg.diffusion_block:
             return new_state, jnp.float32(0.0)
         logits = lm_head(params, x, mcfg)[0]
@@ -1304,7 +1448,9 @@ class Engine:
 
     def decode(self):
         """One tick: every live slot advances one token. -> emitted
-        (slots,) int32 device array, -1 on dead slots."""
+        (slots,) int32 device array, -1 on dead slots, followed by the
+        pass's ``decode_counters`` counters where the model has any
+        (``DECODE_COUNTERS``)."""
         self.state, emitted = self._decode_jit(self.params, self.state)
         return emitted
 

@@ -15,7 +15,10 @@ inside a sequence's last block, bounded by ``block_len - 1`` positions.
 THE STORED SHAPE is ``KVPool.array_shape``: ``(n_blocks, block_len,
 heads * head_dim)`` — a block is ``block_len`` token rows, a row holds
 one token's K (or V) for every head side by side, head ``h`` in
-columns ``[h * head_dim, (h + 1) * head_dim)``. It is one shape for
+columns ``[h * head_dim, (h + 1) * head_dim)``. Under latent attention
+a row is ONE latent a token (its K/V latent, then the rotary key all
+heads share; ``array_shape(1, KVPool.latent_row(width))``) and a layer
+has one pool, not a K pool beside a V pool. It is one shape for
 every model, from the model's own numbers, and it is the shape the
 serving programs USE: a write is one whole row a token
 (``Engine._kv_write``), a gather is whole blocks (``Engine._gather``,
@@ -116,6 +119,21 @@ class KVPool:
         ``block_len`` token rows of ``n_heads * head_dim`` values, heads
         major within a row (the module header says why)."""
         return (self.n_blocks, self.block_len, n_heads * head_dim)
+
+    @staticmethod
+    def latent_row(width: int) -> int:
+        """Values in a latent pool's row for a latent ``width`` wide:
+        ``width`` rounded up to whole 128-lane tiles (576 -> 640, the
+        tail zeros). A row that ends inside a tile makes the TPU runtime
+        store the pool with ANOTHER dimension minor-most (the block
+        length where that is a multiple of 128, else the block index),
+        and every program that touches the pools then copies each of
+        them into the scatter's layout on its way in and back on its way
+        out: four copies of 0.7 GB a layer a decode tick at the
+        published size (read off the compiled text, PERF.md PR 34), the
+        fault the module header tells of 64-wide heads. A ninth of the
+        pool is the price."""
+        return -(-width // 128) * 128
 
     @classmethod
     def for_model(cls, max_len: int, block_len: int, n_blocks: int = 0,

@@ -154,6 +154,15 @@ class Scheduler:
         self.tokens_delivered = 0
         self.experts_hit = 0
         self.expert_max_load = 0
+        #: one-token passes of a model with top-k expert layers count
+        #: those two as well (held experts only, where the engine holds
+        #: a share), and (``engine.DECODE_COUNTERS``) the token-expert
+        #: pairs the passes routed to held experts, the cache rows their
+        #: attention had to read a layer, and the pairs of the prefill
+        #: chunks between them
+        self.held_pairs = 0
+        self.cache_rows = 0
+        self.chunk_held_pairs = 0
         #: decode and block steps run ONE PASS AHEAD of the host: (the
         #: device result of the pass dispatched last tick, the requests
         #: it served by slot), read this tick after the next is
@@ -236,6 +245,7 @@ class Scheduler:
         self.block_passes = self.block_commits = 0
         self.tokens_delivered = 0
         self.experts_hit = self.expert_max_load = 0
+        self.held_pairs = self.cache_rows = self.chunk_held_pairs = 0
         self.engine.allocator.reset_stats()
         self._live_ticks = self.lanes_unread = 0
         self.backpressure_ticks = 0
@@ -662,6 +672,17 @@ class Scheduler:
                         for s, r in served.items() if r.status == "decoding"
                     }
                 else:
+                    n = self.engine.decode_counters
+                    if n:
+                        hit, most, pairs, rows, chunk = out[-n:].tolist()
+                        self.experts_hit += hit
+                        self.expert_max_load = max(
+                            self.expert_max_load, most
+                        )
+                        self.held_pairs += pairs
+                        self.cache_rows += rows
+                        self.chunk_held_pairs += chunk
+                        out = out[:-n]
                     emitted = out[:, None].tolist()
         with span("sched.emit", tick=tick) as fan_out:
             for slot, req in sorted(served.items()):

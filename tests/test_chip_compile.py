@@ -286,3 +286,106 @@ def test_quant_acc_compiles(one_chip, n_elements):
         jax.ShapeDtypeStruct((n_elements,), jnp.float32, sharding=one_chip),
     )
     assert "tpu_custom_call" in text
+
+
+# -- a latent cache at the published width of ``kimi_k2_serve_long`` ------
+
+
+def test_latent_kernel_compiles_at_the_cells_shape(one_chip):
+    """48 absorbed queries of 64 heads, 640 wide, over a bfloat16 pool
+    of 4,801 blocks of 128 latent rows: through Mosaic, within the
+    kernel's own VMEM limit."""
+    from singa_tpu.ops.paged_attention import paged_latent_attention
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda q, p, t, pos: paged_latent_attention(
+            q, p, t, pos, scale=0.13, out_width=512, interpret=False
+        ),
+        sds((48, 64, 640), jnp.bfloat16),
+        sds((48 * 100 + 1, 128, 640), jnp.bfloat16),
+        sds((48, 100), jnp.int32), sds((48,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def latent_cell_on_tpu(one_chip):
+    """The engine of ``kimi_k2_serve_long`` (48 slots, blocks of 128,
+    512-token chunks, the published widths, 12 of 384 experts held) cut
+    to its dense layer and one expert layer, as the chip builds it, and
+    its arguments as shapes on the described chip: the pools are
+    ``bf16[4801, 128, 640]``, 787 MB each."""
+    cfg = TransformerConfig(
+        vocab=20480, d_model=7168, n_heads=64, n_layers=2, d_ff=18432,
+        max_len=12800, norm="rmsnorm", norm_eps=1e-6, pos="rope",
+        rope_theta=50000.0, rope_yarn=(32.0, 4096, 1, 1, 1, 1),
+        head_dim=128, rope_dim=64, v_head_dim=128, kv_latent=512,
+        q_latent=1536, tied_head=False, mlp="swiglu", dense_layers=1,
+        moe_experts=384, moe_top_k=8, moe_d_ff=2048, moe_score="sigmoid",
+        moe_bias=True, moe_scale=2.827, moe_shared_d_ff=2048,
+        moe_held=(96, 12),
+    )
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)),
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        # the least pool an engine takes: the cell's is set below, as
+        # shapes (its zeros would be 1.6 GB here)
+        eng = Engine(params, cfg, EngineConfig(
+            slots=48, kv_block_len=128, kv_blocks=101, max_prefill_chunk=512,
+        ))
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def sds(a):
+        shape = (4801,) + a.shape[1:] if a.shape[1:] == (128, 640) else a.shape
+        return arg(a.dtype, *shape)
+
+    state = jax.tree.map(sds, eng.state)
+    head = (jax.tree.map(sds, params), state)
+    i32 = lambda *shape: arg(jnp.int32, *shape)  # noqa: E731
+    return eng, {
+        "decode": (eng._decode, head),
+        "prefill": (eng._prefill, head + (i32(), i32(512), i32(), i32())),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_programs_relayout_no_pool(
+    latent_cell_on_tpu, program, monkeypatch
+):
+    """A latent pool's row is rounded up to whole 128-lane tiles
+    (``KVPool.latent_row``) so that it arrives row-major and no program
+    copies it: at 576 wide the runtime stored it block-length-minor and
+    each program copied every pool in and out (PERF.md, PR 34). The
+    decode tick holds the latent kernel and no dense view of a pool;
+    the prefill chunk keeps its one-slot gather."""
+    eng, programs = latent_cell_on_tpu
+    assert eng.attend_choice == "fused"
+    assert eng.state["k"][0].shape[1:] == (128, 640) and not eng.state["v"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = programs[program]
+    text = (
+        jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    )
+    assert not re.findall(r"= bf16\[4801,128,640\]\S* copy\(", text)
+    header = text[:text.index("\n")]
+    arrive, leave = (
+        re.findall(r"bf16\[4801,128,640\](\{[^}]*\})", side)
+        for side in re.search(
+            r"entry_computation_layout=\{\((.*)\)->(.*)\}", header
+        ).groups()
+    )
+    assert len(arrive) == 2 and set(arrive) == set(leave) == {
+        "{2,1,0:T(8,128)(2,1)}"
+    }
+    assert header.count("-alias)") >= 2
+    assert ("tpu_custom_call" in text) == (program == "decode")
+    dense = "bf16[48,12800,640]" in text
+    assert dense is False

@@ -369,7 +369,8 @@ def test_decode_under_the_kernel_names_it_inside_attend(
 
 @pytest.mark.parametrize("scope", ["gather_kv", "cache_attend"])
 def test_decode_under_the_kernel_has_no_gather(kernel_decode_names, scope):
-    # ``kv_gather_ms_per_tick`` reads the scope and falls silent
+    # ``paged_attention_ms_per_tick`` reads the kernel's scope instead;
+    # ``kv_gather_ms_per_block_step`` reads this one where it is left
     assert not any(f"/{scope}/" in n for n in kernel_decode_names)
 
 
@@ -532,3 +533,99 @@ def test_block_step_tick_has_the_unchanged_span_set(tmp_path):
     assert len(dispatches) == sched.decode_ticks + 1
     emitted = sum(s[3]["emitted"] for s in spans if s[0] == "sched.emit")
     assert emitted == sched.tokens_delivered == 18
+
+
+# ---------------------------------------------------------------------
+# a latent cache: latent attention, a dense first layer, held experts
+# ---------------------------------------------------------------------
+
+
+def tiny_latent_engine(**serving):
+    cfg = TransformerConfig(
+        vocab=40, d_model=32, n_heads=4, n_layers=2, d_ff=48, max_len=32,
+        norm="rmsnorm", pos="rope", head_dim=8, tied_head=False,
+        mlp="swiglu", dense_layers=1, moe_experts=8, moe_top_k=2,
+        moe_d_ff=16, moe_score="sigmoid", moe_bias=True, moe_scale=2.5,
+        moe_shared_d_ff=16, moe_held=(2, 4), kv_latent=16, q_latent=24,
+        rope_dim=4, v_head_dim=8, rope_yarn=(4.0, 16, 1, 1, 1.0, 1.0),
+    )
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    return Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, max_prefill_chunk=4, **serving
+    ))
+
+
+def test_latent_decode_under_the_kernel_names_it_inside_attend():
+    """The latent kernel (interpreted here; on a TPU the engine chooses
+    it itself) runs under the scope ``paged_attention_ms_per_tick``
+    reads, with the query's way into the latent space and back beside
+    it, and no gather."""
+    eng = tiny_latent_engine(attend_impl="fused")
+    text = eng._decode_jit.lower(eng.params, eng.state).compile().as_text()
+    names = {n for _, n in instructions(text)}
+    for scope in ("blk0/attend/paged_attention", "blk1/attend/paged_attention",
+                  "blk0/attend/paged_attention/absorb",
+                  "blk0/attend/paged_attention/lift", "blk0/attend/kv_write"):
+        assert any(f"jit(_decode)/{scope}/" in n for n in names), scope
+    assert not any("/gather_kv/" in n or "/cache_attend/" in n for n in names)
+    jaxpr = str(jax.make_jaxpr(eng._decode)(eng.params, eng.state))
+    assert "name=paged_latent_attention" in jaxpr
+
+
+@pytest.fixture(scope="module")
+def latent_texts():
+    eng = tiny_latent_engine()
+    slot, chunk = jnp.int32(0), jnp.zeros((4,), jnp.int32)
+    lowered = {
+        "_decode": eng._decode_jit.lower(eng.params, eng.state),
+        "_prefill": eng._prefill_jit.lower(
+            eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(4)
+        ),
+    }
+    return {k: v.compile().as_text() for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [
+    # the names the benchmark's readers know, with the finer ones inside
+    ("_decode", "blk0/qkv/q_latent"), ("_decode", "blk0/qkv/kv_latent"),
+    ("_decode", "blk1/qkv/kv_latent/rope"),
+    ("_decode", "blk0/attend/kv_write"), ("_decode", "blk1/attend/gather_kv"),
+    ("_decode", "blk0/attend/cache_attend"),
+    ("_decode", "blk1/attend/cache_attend/absorb"),
+    ("_decode", "blk1/attend/cache_attend/lift"),
+    ("_decode", "blk0/mlp"), ("_decode", "blk1/moe/route"),
+    ("_decode", "blk1/moe/experts"), ("_decode", "blk1/moe/combine"),
+    ("_decode", "blk1/moe/shared"), ("_decode", "blk0/attn_out"),
+    ("_decode", "lm_head"), ("_decode", "sample"),
+    ("_prefill", "blk0/attend/cache_attend/while/body/materialise"),
+    ("_prefill", "blk1/attend/gather_kv"), ("_prefill", "blk1/moe/shared"),
+    ("_prefill", "blk0/attend/kv_write"), ("_prefill", "blk0/mlp"),
+])
+def test_latent_programs_name_their_operations(latent_texts, program, scope):
+    assert f"HloModule jit_{program}," in latent_texts[program]
+    names = {n for _, n in instructions(latent_texts[program])}
+    assert any(f"jit({program})/{scope}/" in n for n in names), scope
+
+
+def test_latent_scope_names_are_plain_segments(latent_texts):
+    """No new name holds the separator of a scope path, and what the
+    benchmark's readers book device time to is a name they know: the
+    finer scopes lie INSIDE ``qkv``, ``cache_attend`` and ``moe``."""
+    from benchmark import program_trace
+
+    new = ("q_latent", "kv_latent", "absorb", "lift", "materialise", "shared")
+    assert not any("/" in n for n in new)
+    assert not any(program_trace.KNOWN.match(n) for n in new)
+    for text in latent_texts.values():
+        for _, name in instructions(text):
+            parts = name.split("/")
+            for n in new:
+                if n in parts:
+                    assert any(
+                        program_trace.KNOWN.match(p) for p in parts[:parts.index(n)]
+                        if not p.startswith("blk")
+                    ), name
+    # the absorbed tick forms no key or value of a head: the decode
+    # program holds no ``materialise``, the chunk no ``absorb``
+    assert "materialise" not in latent_texts["_decode"]
+    assert "absorb" not in latent_texts["_prefill"]
