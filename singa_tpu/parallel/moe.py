@@ -20,7 +20,10 @@ sigmoid scores over all experts, renormalised, SwiGLU experts, NO
 capacity and no dropped token (the layers of the Qwen3-MoE and the
 DeepSeek-V3 lineages: the latter adds a selection bias, a scaling factor
 and a shared expert). It is one device's layer: it routes over every
-expert and computes those it is told it holds, all of them by default.
+expert and computes those it is told it holds, all of them by default,
+in one of two forms that ``choose_expert_form`` picks from the pass's
+shapes: every held expert on every token, or the routed token-expert
+pairs alone, sorted by expert, through a grouped product.
 """
 
 from __future__ import annotations
@@ -140,6 +143,182 @@ def topk_gates(x2d, params: dict, top_k: int, score: str = "softmax",
     return gates, chosen
 
 
+#: rows of the grouped product's row tile (megablox ``tm``): a held
+#: expert with any pair in a window of pairs costs whole tiles of rows
+PAIR_TILE = 128
+#: tokens a pass up to which the dense product rides on the weight
+#: reads of a v5e: N tokens do N FLOPs a byte of bfloat16 weights and
+#: the chip has 240 FLOPs a byte; a quarter over that the grouped
+#: form's sort, gather and combine are paid for (``moe_topk_ffn``, HOW)
+DENSE_TOKENS = 300
+#: tokens a pass beyond which no chip reading holds: the grouped form's
+#: combine is a 0/1 matrix product, quadratic in the pass's tokens
+GROUPED_TOKENS = 2048
+#: share of the held experts a flat router must leave without a token
+#: before skipping their weights pays for the grouped form's overhead
+IDLE_SHARE = 0.25
+
+
+def choose_expert_form(n: int, held: int, experts: int, top_k: int,
+                       platform: str) -> str:
+    """Which form ``moe_topk_ffn`` computes the held experts in, from
+    what a trace can see: ``"dense: <why>"`` or ``"grouped: <why>"``
+    for a pass of ``n`` tokens over ``held`` of ``experts`` experts,
+    ``top_k`` a token, on ``platform``. A pure function (as
+    ``serve/engine.py`` ``choose_attend`` is), so the engine can record
+    what each of its programs compiled with and a CPU test can ask it
+    about a TPU.
+
+    Dense off a TPU (the grouped kernel would run through the Pallas
+    interpreter, a grid step at a time). On one, grouped for either of
+    the two things it saves. ARITHMETIC: the dense product is bound by
+    it (``n`` over ``DENSE_TOKENS``) and the rows the grouped product
+    computes an expert, its ``n * top_k / experts`` routed pairs under
+    a flat router and a row tile's rounding, are under half of the
+    ``n`` the dense one computes. WEIGHT READS: the dense product reads
+    every held expert, the grouped one those that drew a token, and a
+    flat router leaves ``(1 - top_k / experts) ** n`` of them without
+    (``IDLE_SHARE`` or more). Dense everywhere else: there every
+    expert's weights are read whatever is done and the idle products
+    ride on the reads."""
+    if platform != "tpu":
+        return f"dense: platform = {platform}"
+    routed = n * top_k / experts
+    idle = (1.0 - top_k / experts) ** n
+    if n > GROUPED_TOKENS:
+        return f"dense: {n} tokens a pass, over {GROUPED_TOKENS}"
+    if n > DENSE_TOKENS and 2 * (routed + PAIR_TILE) < n:
+        return (
+            f"grouped: {n} tokens a pass are over {DENSE_TOKENS}, "
+            f"{routed:.1f} routed rows an expert for {n} dense "
+            f"({n * top_k * held // experts} pairs for {n * held})"
+        )
+    if idle >= IDLE_SHARE:
+        return (
+            f"grouped: a flat router leaves {100 * idle:.0f} % of the "
+            f"held experts without one of {n} tokens"
+        )
+    if n <= DENSE_TOKENS:
+        return (
+            f"dense: {n} tokens a pass ride on the weight reads "
+            f"(<= {DENSE_TOKENS})"
+        )
+    return (
+        f"dense: {routed:.1f} routed rows an expert and a tile of "
+        f"{PAIR_TILE} are half of {n} or more"
+    )
+
+
+def _grouped_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of one grouped product (P, k) x (H, k, n): whole
+    lanes, the widest n-tile up to 2048 and the deepest k-tile that
+    keep a weight block at 4 MiB or under — two of them in flight, the
+    row tiles and the float32 accumulator fit the kernel's default
+    16 MiB of VMEM (12.3 MB at the published widths), and a block is
+    long enough to stream at the HBM's rate."""
+    def widest(size, limit):
+        fits = [
+            t for t in range(128, min(size, limit) + 1, 128)
+            if size % t == 0
+        ]
+        return fits[-1] if fits else size
+
+    tn = widest(n, 2048)
+    tk = widest(k, max(128, (4 << 20) // (tn * itemsize)))
+    return PAIR_TILE, tk, tn
+
+
+def _experts_dense(x2d, params, gates):
+    """Every held expert on every token, ``gates`` (N, H) weighting the
+    sum: two products batched over the experts and one contraction over
+    (H, F) jointly, so no (N, H, D) per-expert output is formed."""
+    f32 = jnp.float32
+    with jax.named_scope("experts"):
+        a = jnp.einsum("nd,edf->enf", x2d, params["w_gate"]).astype(f32)
+        u = jnp.einsum("nd,edf->enf", x2d, params["w_up"]).astype(f32)
+        h = (jax.nn.silu(a) * u * gates.T[:, :, None]).astype(x2d.dtype)
+    with jax.named_scope("combine"):
+        return jnp.einsum("enf,efd->nd", h, params["w_down"])
+
+
+def _experts_grouped(x2d, params, gates, pairs, top_k: int, start=None):
+    """The token-expert ``pairs`` (N, H) alone: the list of pairs sorted
+    by expert, walked a WINDOW of R = N (a whole number of row tiles)
+    pairs at a time; a window gathers its pairs' rows of ``x``, runs
+    the three grouped products (megablox ``gmm``: it visits the row
+    tiles the group sizes cover and skips an expert with no pair) and
+    adds each pair's output, times its gate, into its token's row in
+    float32, on top of ``start`` (N, D) where there is one.
+    ``min(top_k, H)`` windows hold the worst case (every token on its
+    full count of held experts); a window past the last pair is skipped
+    by a ``lax.cond``, so the work follows the router and nothing is
+    dropped. A flat router fills a quarter of the first window at 12 of
+    384 experts held."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    n, d = x2d.shape
+    held = pairs.shape[1]
+    f32 = jnp.float32
+    interpret = jax.default_backend() != "tpu"
+    rows = -(-n // PAIR_TILE) * PAIR_TILE
+    windows = -(-n * min(top_k, held) // rows)
+
+    def product(lhs, w, sizes):
+        return megablox.gmm(
+            lhs, w, sizes, f32,
+            _grouped_tiling(w.shape[1], w.shape[2], w.dtype.itemsize),
+            interpret=interpret,
+        )
+
+    with jax.named_scope("route"):
+        flat = pairs.T.reshape(-1)                    # expert-major
+        # the pairs first, by expert and then by token: what a stable
+        # sort of "is no pair" leaves in front
+        order = jnp.argsort(~flat, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, max(0, rows * windows - n * held)))
+        pair_gate = gates.T.reshape(-1)
+        ends = jnp.cumsum(jnp.sum(pairs, axis=0, dtype=jnp.int32))
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+        total = ends[-1]
+
+    def window(w, y):
+        lo = w * rows
+
+        def run(y):
+            with jax.named_scope("route"):
+                at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+                tok = at % n
+                live = (lo + jnp.arange(rows) < total)[:, None]
+                sizes = (
+                    jnp.clip(ends - lo, 0, rows)
+                    - jnp.clip(starts - lo, 0, rows)
+                )
+            with jax.named_scope("experts"):
+                xs = jnp.where(live, x2d[tok], 0)
+                a = product(xs, params["w_gate"], sizes)
+                u = product(xs, params["w_up"], sizes)
+                h = (jax.nn.silu(a) * u).astype(x2d.dtype)
+            with jax.named_scope("combine"):
+                out = product(h, params["w_down"], sizes)
+                # rows past the last pair are the kernel's to leave
+                # unwritten, here and in its gradients: they are
+                # selected out on both sides, never multiplied out
+                out = jnp.where(live, out, 0.0) * pair_gate[at][:, None]
+                # each row into its token's: a 0/1 matrix on the MXU in
+                # three bfloat16 passes (0.04 ms at 512 x 512 x 7168
+                # where a scatter-add of 512 rows reads 0.44: PERF.md,
+                # PR 35)
+                put = (jnp.arange(n)[:, None] == tok[None]).astype(f32)
+                return y + jnp.matmul(
+                    put, out, precision=jax.lax.Precision.HIGH
+                )
+
+        return jax.lax.cond(lo < total, run, lambda y: y, y)
+
+    y = jnp.zeros((n, d), f32) if start is None else start
+    return jax.lax.fori_loop(0, windows, window, y).astype(x2d.dtype)
+
+
 def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
                  score: str = "softmax", scale: float = 1.0,
                  held_from: int = 0):
@@ -159,33 +338,62 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
     shared expert (``MOE_SHARED_PARAMS``), computed here in full for
     every token, where the model has one.
 
-    HOW: every held expert runs on every token and ``g`` (zero outside
-    T) weights the sum — two products (N, D) x (H, D, F) batched over
-    the experts and one contraction over (H, F) jointly, so the
-    (N, H, D) per-expert outputs are never formed. There is no capacity,
-    no sort and no dispatch
-    buffer, so no routing pattern can drop a token or leave a term out,
-    and a skewed router costs what a flat one does. It is the form for
-    a serving pass of a few hundred tokens over many narrow experts:
-    there every expert's weights are read whatever is done (256 tokens x
-    top-8 over 128 experts leave no expert idle), and at N tokens the
-    products cost N FLOPs a weight byte — at N = 256 about the v5e's
-    own ratio of FLOPs to bytes (240), so the idle products ride on the
-    weight reads: 1.80 ms a layer at the published size against 1.64 ms
-    for reading the layer's experts and doing nothing (PERF.md, PR 28).
-    At thousands of tokens a pass a sorted, grouped product is the form
-    to write instead.
+    HOW, in one of two forms that ``choose_expert_form`` picks at trace
+    time from the pass's static shapes and the platform. DENSE
+    (``_experts_dense``): every held expert runs on every token and
+    ``g`` (zero outside T) weights the sum — two products
+    (N, D) x (H, D, F) batched over the experts and one contraction
+    over (H, F) jointly. No sort and no dispatch buffer, and a skewed
+    router costs what a flat one does. It is the form for a serving
+    pass of a few hundred tokens over many narrow experts: there every
+    expert's weights are read whatever is done (256 tokens x top-8 over
+    128 experts leave no expert idle), and at N tokens the products
+    cost N FLOPs a weight byte — at N = 256 about the v5e's own ratio
+    of FLOPs to bytes (240), so the idle products ride on the weight
+    reads. GROUPED (``_experts_grouped``): the routed (token, held
+    expert) pairs alone, sorted by expert — a gather of their rows of
+    ``x``, three grouped products (P, D) x (H, D, F) / (P, F) x
+    (H, F, D) through the Pallas grouped-matmul kernel that ships with
+    jax (megablox ``gmm``, which walks the row tiles the group sizes
+    cover and skips an expert with no pair), each pair's row times its
+    gate summed into its token's row in float32. It is the form where
+    the dense product is bound by arithmetic, and where a pass leaves
+    many held experts without a token, whose weights it does not read.
+    NEITHER has a capacity: the grouped form walks the sorted list a
+    window of N pairs at a time for as many windows as the worst
+    routing fills (``min(top_k, H)``: every token on its full count of
+    held experts) and skips the empty ones, so no routing pattern can
+    drop a token or leave a term out. Pairs of tokens that ``valid``
+    marks out are not computed there (their rows of ``y`` hold the
+    shared expert's output alone).
+
+    The two chip readings the rule's margin rests on (one layer alone
+    on a v5e, host clock, bfloat16; PERF.md, PR 35): 256 tokens over
+    128 of 128 experts of 2048 x 768, dense 1.87 ms against grouped
+    2.33 (and 1.64 for reading the layer's experts and doing nothing,
+    PR 28): stays dense. 512 tokens over 12 of 384 experts of
+    7168 x 2048, 128 pairs for 6,144 dense rows: dense 3.41 ms, at 92 %
+    of the MXU's peak, against grouped 2.20, its three products 1.53
+    for 1.44 of reading their weights: goes grouped. ``jax.lax.
+    ragged_dot`` is compiled by the TPU compiler to a grouped kernel of
+    its own whose row tile is min(P, 512) and cannot be set: 3.50 ms
+    for the three products at P = 512, dense's 3.02 and more.
 
     Router, scores, gates and the SwiGLU are float32; the products take
     ``x`` and the weights as stored, accumulate in float32 and come out
     in ``x``'s type. ``valid``
-    (B, S) marks the tokens the counters count (None = all)."""
+    (B, S) marks the tokens the counters count (None = all). The
+    products of both forms have derivatives (``gmm`` carries its own),
+    so the layer trains in whichever form a pass takes."""
     b, s, d = x.shape
     n = b * s
     x2d = x.reshape(n, d)
     held = params["w_gate"].shape[0]
     with jax.named_scope("route"):
         gates, chosen = topk_gates(x2d, params, top_k, score, scale)
+        form = choose_expert_form(
+            n, held, chosen.shape[1], top_k, jax.default_backend()
+        )
         if held != chosen.shape[1]:
             gates = gates[:, held_from:held_from + held]
             chosen = chosen[:, held_from:held_from + held]
@@ -196,20 +404,27 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
         stats = jnp.stack([
             jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load), jnp.sum(load),
         ])
-    with jax.named_scope("experts"):
+
+    def shared(out_type=None):
         f32 = jnp.float32
-        a = jnp.einsum("nd,edf->enf", x2d, params["w_gate"]).astype(f32)
-        u = jnp.einsum("nd,edf->enf", x2d, params["w_up"]).astype(f32)
-        h = (jax.nn.silu(a) * u * gates.T[:, :, None]).astype(x.dtype)
-    with jax.named_scope("combine"):
-        y = jnp.einsum("enf,efd->nd", h, params["w_down"])
-    if "s_gate" in params:
         with jax.named_scope("shared"):
             a = jnp.matmul(x2d, params["s_gate"]).astype(f32)
             u = jnp.matmul(x2d, params["s_up"]).astype(f32)
-            y = y + jnp.matmul(
-                (jax.nn.silu(a) * u).astype(x.dtype), params["s_down"]
+            return jnp.matmul(
+                (jax.nn.silu(a) * u).astype(x.dtype), params["s_down"],
+                preferred_element_type=out_type,
             )
+
+    if form.startswith("grouped"):
+        # the windows' float32 sums start from the shared expert's
+        y = _experts_grouped(
+            x2d, params, gates, counted, top_k,
+            shared(jnp.float32) if "s_gate" in params else None,
+        )
+    else:
+        y = _experts_dense(x2d, params, gates)
+        if "s_gate" in params:
+            y = y + shared()
     return y.reshape(b, s, d), stats
 
 

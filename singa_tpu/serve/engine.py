@@ -297,6 +297,13 @@ class Engine:
             cfg, self.serving, mesh, jax.default_backend()
         )
         self._fused = self.attend_choice == "fused"
+        #: the form each program's top-k expert layers compile in, with
+        #: the chooser's reason (parallel/moe.py ``choose_expert_form``
+        #: asked what ``moe_topk_ffn`` asks it while the program is
+        #: traced); empty for a model without such layers
+        self.expert_forms = self._expert_forms(
+            cfg, self.serving, jax.default_backend()
+        )
         if self._fused:
             from ..ops.paged_attention import fusable, latent_fusable
 
@@ -488,6 +495,28 @@ class Engine:
             raise ValueError(
                 f"serving: {what} cannot run for a model with {why}"
             )
+
+    @staticmethod
+    def _expert_forms(cfg, serving, platform: str) -> dict:
+        """Program name -> ``choose_expert_form`` of the tokens a pass
+        of it holds: every slot's one token (or block) for the tick, a
+        whole chunk for the prefill. The shapes are static, so this is
+        what each compiled program took."""
+        if not cfg.moe_top_k:
+            return {}
+        from ..parallel.moe import choose_expert_form
+
+        tick = (
+            ("jit__block_step", serving.slots * cfg.diffusion_block)
+            if cfg.diffusion_block else ("jit__decode", serving.slots)
+        )
+        held = cfg.moe_held[1] if cfg.moe_held else cfg.moe_experts
+        return {
+            name: choose_expert_form(
+                n, held, cfg.moe_experts, cfg.moe_top_k, platform
+            )
+            for name, n in (tick, ("jit__prefill", serving.max_prefill_chunk))
+        }
 
     @staticmethod
     def _no_kernel(cfg) -> str | None:

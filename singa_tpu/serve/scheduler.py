@@ -226,6 +226,12 @@ class Scheduler:
             "kernel_select", site="serve.paged_attention",
             impl=engine.attend_choice,
         )
+        # and the form each program's expert layers compiled in
+        # (parallel/moe.py choose_expert_form), where the model has any
+        for program, form in engine.expert_forms.items():
+            self._event(
+                "kernel_select", site=f"moe.experts.{program}", impl=form
+            )
 
     def reset_counters(self) -> None:
         """Zero every accumulated statistic (ticks, token/draft counts,
@@ -802,6 +808,8 @@ class Scheduler:
             "kv_blocks_free": self.engine.allocator.free_blocks,
             "queue_depth": len(self._queue),
         }
+        if self.engine.expert_forms:
+            out["expert_forms"] = dict(self.engine.expert_forms)
         if self.spec_k > 0:
             # acceptance rate = accepted draft tokens / drafted; the
             # emitted bonus tokens ride free either way

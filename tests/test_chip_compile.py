@@ -356,9 +356,23 @@ def latent_cell_on_tpu(one_chip):
     }
 
 
+@pytest.fixture(scope="module")
+def latent_texts(latent_cell_on_tpu):
+    """The cell's two programs compiled for the described chip (a
+    minute for the chunk: made once for the tests below)."""
+    _, programs = latent_cell_on_tpu
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+            .as_text()
+            for name, (fn, args) in programs.items()
+        }
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_latent_programs_relayout_no_pool(
-    latent_cell_on_tpu, program, monkeypatch
+    latent_cell_on_tpu, latent_texts, program
 ):
     """A latent pool's row is rounded up to whole 128-lane tiles
     (``KVPool.latent_row``) so that it arrives row-major and no program
@@ -366,14 +380,10 @@ def test_latent_programs_relayout_no_pool(
     each program copied every pool in and out (PERF.md, PR 34). The
     decode tick holds the latent kernel and no dense view of a pool;
     the prefill chunk keeps its one-slot gather."""
-    eng, programs = latent_cell_on_tpu
+    eng, _ = latent_cell_on_tpu
     assert eng.attend_choice == "fused"
     assert eng.state["k"][0].shape[1:] == (128, 640) and not eng.state["v"]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    fn, args = programs[program]
-    text = (
-        jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
-    )
+    text = latent_texts[program]
     assert not re.findall(r"= bf16\[4801,128,640\]\S* copy\(", text)
     header = text[:text.index("\n")]
     arrive, leave = (
@@ -386,6 +396,69 @@ def test_latent_programs_relayout_no_pool(
         "{2,1,0:T(8,128)(2,1)}"
     }
     assert header.count("-alias)") >= 2
-    assert ("tpu_custom_call" in text) == (program == "decode")
+    assert ("paged_latent_attention" in text) == (program == "decode")
     dense = "bf16[48,12800,640]" in text
     assert dense is False
+
+
+@pytest.mark.parametrize("program,n", [("prefill", 512), ("decode", 48)])
+def test_latent_programs_hold_the_grouped_expert_product(
+    latent_cell_on_tpu, latent_texts, program, n
+):
+    """PR 35: a chunk of 512 tokens over 12 of 384 experts computes the
+    routed pairs alone — the three grouped products of its expert layer
+    are megablox ``gmm`` Mosaic calls under ``experts`` and ``combine``
+    and no per-expert activation ``(12, 512, 2048)`` is formed; the
+    tick's 48 tokens take the same form (a flat router leaves a third
+    of the held experts without a token)."""
+    eng, _ = latent_cell_on_tpu
+    assert eng.expert_forms[f"jit__{program}"].startswith("grouped"), (
+        eng.expert_forms
+    )
+    text = latent_texts[program]
+    calls = re.findall(
+        r'%gmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text,
+    )
+    scope = f"jit(_{program})/blk1/moe/"
+    assert len(calls) == 3 and all(
+        c.startswith(scope) and c.endswith("jit(gmm)/pallas_call")
+        for c in calls
+    ), calls
+    assert sum("/experts/" in c for c in calls) == 2
+    assert sum("/combine/" in c for c in calls) == 1
+    assert f"[12,{n},2048]" not in text
+
+
+def test_a_block_step_over_every_expert_stays_dense(one_chip, monkeypatch):
+    """``sdar_30b_a3b_serve_blocks``' pass (64 slots x a block of 4 =
+    256 tokens over 128 of 128 experts, published widths, one layer)
+    rides on the weight reads: the chooser leaves it the dense product
+    and its compiled text holds no grouped kernel."""
+    cfg = TransformerConfig(
+        vocab=4096, d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+        n_layers=1, d_ff=768, max_len=1536, norm="rmsnorm", norm_eps=1e-6,
+        pos="rope", rope_theta=1e6, qk_norm=True, tied_head=False,
+        moe_experts=128, moe_top_k=8, moe_d_ff=768,
+        diffusion_block=4, mask_id=4095,
+    )
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)),
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = Engine(params, cfg, EngineConfig(
+        slots=64, kv_block_len=16, kv_blocks=129, max_prefill_chunk=256,
+        block_steps=2,
+    ))
+    assert all(f.startswith("dense: 256 tokens") for f in
+               eng.expert_forms.values()), eng.expert_forms
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    text = jax.jit(eng._block_step, donate_argnums=(1,)).lower(
+        jax.tree.map(sds, params), jax.tree.map(sds, eng.state)
+    ).compile().as_text()
+    assert "jit(gmm)" not in text and "tpu_custom_call" not in text
+    assert "bf16[128,256,768]" in text or "f32[128,256,768]" in text

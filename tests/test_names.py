@@ -452,6 +452,34 @@ def test_pallas_kernels_are_named(kernel):
     assert f"name={kernel}" in str(jaxpr)
 
 
+def test_the_grouped_expert_product_is_named(monkeypatch):
+    """The grouped form of ``moe_topk_ffn`` (PR 35) runs its three
+    products through the kernel that ships with jax (megablox ``gmm``),
+    whose ``pallas_call`` takes no ``name=``: what a compiled program's
+    text (``%gmm.N = ... custom_call_target="tpu_custom_call"``) and a
+    device trace show is its jitted wrapper's name, under the layer's
+    own scopes. ``tests/test_chip_compile.py`` reads the same name in
+    the text compiled for the chip."""
+    from singa_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "choose_expert_form", lambda *a: "grouped: test")
+    eng = tiny_latent_engine()
+    slot, chunk = jnp.int32(0), jnp.zeros((4,), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(eng._prefill)(
+        eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(4)
+    ))
+    assert "jit[name=gmm " in jaxpr and "pallas_call" in jaxpr
+    text = eng._prefill_jit.lower(
+        eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(4)
+    ).compile().as_text()
+    names = {n for _, n in instructions(text)}
+    for scope in ("route", "experts", "combine", "shared"):
+        assert any("jit(_prefill)/blk1/moe/" in n and f"/{scope}/" in n
+                   for n in names), scope
+    assert any("/experts/jit(gmm)/" in n for n in names)
+    assert any("/combine/jit(gmm)/" in n for n in names)
+
+
 def test_compile_cache_key_is_salted_by_the_names_version(monkeypatch):
     """JAX leaves names out of the persistent cache's key: an executable
     cached by code that named its operations otherwise would be served
