@@ -32,6 +32,10 @@ from ..ops.attention import attention
 from ..parallel.ring import ring_attention
 
 
+#: what ``TransformerConfig.layers`` may name
+LAYER_KINDS = ("mamba", "attn", "moe", "mlp")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int
@@ -115,6 +119,29 @@ class TransformerConfig:
     #: YaRN scaling of the rotary frequencies: (factor, original length,
     #: beta_fast, beta_slow, mscale, mscale_all_dim); () = none
     rope_yarn: tuple = ()
+    #: layer kinds, one a layer: a block of such a model is ONE mixer,
+    #: ``x + mixer(norm(x))``, the mixer a Mamba-2 layer ("mamba",
+    #: ops/ssm.py), attention ("attn"), a top-k expert layer ("moe") or
+    #: the dense MLP ("mlp"). () = today's blocks: attention then MLP or
+    #: experts in every one.
+    layers: tuple = ()
+    #: the Mamba-2 layers' sizes: heads of ``mamba_head_dim``, a state
+    #: of ``ssm_state`` a head channel, B and C shared by the heads of
+    #: each of ``ssm_groups`` groups, a causal depthwise convolution
+    #: ``conv_kernel`` long, the chunked scan's blocks of ``ssm_block``
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_kernel: int = 4
+    ssm_block: int = 128
+    #: the routed and the shared experts: "swiglu" (three matrices an
+    #: expert) | "relu2" (``relu(x U)^2 D``, no gate matrix)
+    moe_act: str = "swiglu"
+    #: > 0: the routed experts live in a latent this wide (``moe/
+    #: lat_down`` before them, ``moe/lat_up`` after the combine); router
+    #: and shared expert read the full width
+    moe_latent: int = 0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -129,8 +156,8 @@ class TransformerConfig:
             )
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm {self.norm!r}: layernorm or rmsnorm")
-        if self.pos not in ("learned", "rope"):
-            raise ValueError(f"pos {self.pos!r}: learned or rope")
+        if self.pos not in ("learned", "rope", "none"):
+            raise ValueError(f"pos {self.pos!r}: learned, rope or none")
         if self.moe_top_k and not (
             0 < self.moe_top_k <= self.moe_experts and self.moe_d_ff > 0
         ):
@@ -138,8 +165,10 @@ class TransformerConfig:
                 f"moe_top_k {self.moe_top_k} needs moe_experts >= it "
                 f"({self.moe_experts}) and a moe_d_ff ({self.moe_d_ff})"
             )
-        if self.mlp not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp {self.mlp!r}: gelu or swiglu")
+        if self.mlp not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(f"mlp {self.mlp!r}: gelu, swiglu or relu2")
+        if self.moe_act not in ("swiglu", "relu2"):
+            raise ValueError(f"moe_act {self.moe_act!r}: swiglu or relu2")
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_score {self.moe_score!r}: softmax or sigmoid"
@@ -162,6 +191,28 @@ class TransformerConfig:
                 "pos = 'rope', norm = 'rmsnorm', and neither fewer K/V "
                 "heads nor qk_norm"
             )
+        if self.layers:
+            unknown = set(self.layers) - set(LAYER_KINDS)
+            if unknown or len(self.layers) != self.n_layers:
+                raise ValueError(
+                    f"layers {self.layers}: n_layers = {self.n_layers} "
+                    f"kinds out of {LAYER_KINDS}"
+                )
+            if "moe" in self.layers and not self.moe_top_k:
+                raise ValueError("layers: a 'moe' layer needs moe_top_k")
+            if "mamba" in self.layers and not (
+                self.mamba_heads and self.mamba_head_dim and self.ssm_state
+                and self.mamba_heads % self.ssm_groups == 0
+            ):
+                raise ValueError(
+                    "layers: a 'mamba' layer needs mamba_heads (a multiple "
+                    "of ssm_groups), mamba_head_dim and ssm_state"
+                )
+            if self.kv_latent or self.diffusion_block or self.dense_layers:
+                raise ValueError(
+                    "layers: kv_latent, diffusion_block and dense_layers "
+                    "belong to blocks of two mixers"
+                )
 
     @property
     def qkv_width(self) -> int:
@@ -174,7 +225,24 @@ class TransformerConfig:
 
     def expert_layer(self, i: int) -> bool:
         """Whether block ``i`` is a top-k expert layer."""
+        if self.layers:
+            return self.layers[i] == "moe"
         return bool(self.moe_top_k) and i >= self.dense_layers
+
+    def layers_of(self, kind: str) -> tuple:
+        """The indices of the blocks that hold a ``kind`` mixer; every
+        block holds attention where the model has no ``layers``."""
+        if not self.layers:
+            return tuple(range(self.n_layers)) if kind == "attn" else ()
+        return tuple(i for i, k in enumerate(self.layers) if k == kind)
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of a Mamba-2 layer's convolution: x, B and C."""
+        return (
+            self.mamba_heads * self.mamba_head_dim
+            + 2 * self.ssm_groups * self.ssm_state
+        )
 
     @property
     def latent_width(self) -> int:
@@ -218,70 +286,114 @@ def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
         params["embed/pos"] = norm(pos_key, (cfg.max_len, cfg.d_model), 0.02)
     for i in range(cfg.n_layers):
         p = f"blk{i}"
-        d, f = cfg.d_model, cfg.d_ff
-        norm_params(f"{p}/ln1", d)
-        if cfg.kv_latent:
-            h, rq, rkv = cfg.n_heads, cfg.q_latent, cfg.kv_latent
-            params[f"{p}/attn/q_a"] = norm(
-                next(keys), (d, rq), 1 / math.sqrt(d)
-            )
-            params[f"{p}/attn/q_a_norm"] = jnp.ones((rq,))
-            params[f"{p}/attn/q_b"] = norm(
-                next(keys), (rq, h * (cfg.head_dim + cfg.rope_dim)),
-                1 / math.sqrt(rq),
-            )
-            params[f"{p}/attn/kv_a"] = norm(
-                next(keys), (d, cfg.latent_width), 1 / math.sqrt(d)
-            )
-            params[f"{p}/attn/kv_a_norm"] = jnp.ones((rkv,))
-            params[f"{p}/attn/kv_b"] = norm(
-                next(keys), (rkv, h * (cfg.head_dim + cfg.v_head_dim)),
-                1 / math.sqrt(rkv),
-            )
-        else:
-            params[f"{p}/attn/qkv"] = norm(
-                next(keys), (d, cfg.qkv_width), 1 / math.sqrt(d)
-            )
-        params[f"{p}/attn/out"] = norm(
-            next(keys),
-            (cfg.n_heads * (cfg.v_head_dim or cfg.head_dim), d),
-            1 / math.sqrt(d * 2 * cfg.n_layers),
-        )
-        if cfg.qk_norm:
-            params[f"{p}/attn/q_norm"] = jnp.ones((cfg.head_dim,))
-            params[f"{p}/attn/k_norm"] = jnp.ones((cfg.head_dim,))
-        norm_params(f"{p}/ln2", d)
-        if cfg.moe_experts and (cfg.expert_layer(i) or not cfg.moe_top_k):
-            from ..parallel.moe import init_moe, init_moe_topk
-
-            moe = (
-                init_moe_topk(
-                    next(keys), d, cfg.moe_d_ff, cfg.moe_experts,
-                    held=cfg.moe_held[1] if cfg.moe_held else 0,
-                    bias=cfg.moe_bias, shared_d_ff=cfg.moe_shared_d_ff,
-                )
-                if cfg.moe_top_k
-                else init_moe(next(keys), d, f, cfg.moe_experts)
-            )
-            for k, v in moe.items():
-                params[f"{p}/moe/{k}"] = v
-        else:
-            if cfg.mlp == "swiglu":
-                params[f"{p}/mlp/gate"] = norm(
-                    next(keys), (d, f), 1 / math.sqrt(d)
-                )
-            params[f"{p}/mlp/up"] = norm(
-                next(keys), (d, f), 1 / math.sqrt(d)
-            )
-            params[f"{p}/mlp/down"] = norm(
-                next(keys), (f, d), 1 / math.sqrt(f * 2 * cfg.n_layers)
-            )
+        kind = cfg.layers[i] if cfg.layers else None   # None: two mixers
+        norm_params(f"{p}/ln1", cfg.d_model)
+        if kind == "mamba":
+            _init_mamba(params, p, cfg, norm, keys)
+        if kind in (None, "attn"):
+            _init_attention(params, p, cfg, norm, keys)
+        if kind is None:
+            norm_params(f"{p}/ln2", cfg.d_model)
+        if kind in (None, "moe", "mlp"):
+            _init_ffn(params, p, i, cfg, norm, keys)
     norm_params("ln_f", cfg.d_model)
     if not cfg.tied_head:
         params["head/out"] = norm(
             jax.random.fold_in(rng, 1), (cfg.d_model, cfg.vocab), 0.02
         )
     return params
+
+
+def _init_attention(params, p, cfg: TransformerConfig, norm, keys) -> None:
+    """A block's attention parameters under ``p``, drawn from ``keys``
+    by ``norm(key, shape, scale)``."""
+    d = cfg.d_model
+    if cfg.kv_latent:
+        h, rq, rkv = cfg.n_heads, cfg.q_latent, cfg.kv_latent
+        params[f"{p}/attn/q_a"] = norm(next(keys), (d, rq), 1 / math.sqrt(d))
+        params[f"{p}/attn/q_a_norm"] = jnp.ones((rq,))
+        params[f"{p}/attn/q_b"] = norm(
+            next(keys), (rq, h * (cfg.head_dim + cfg.rope_dim)),
+            1 / math.sqrt(rq),
+        )
+        params[f"{p}/attn/kv_a"] = norm(
+            next(keys), (d, cfg.latent_width), 1 / math.sqrt(d)
+        )
+        params[f"{p}/attn/kv_a_norm"] = jnp.ones((rkv,))
+        params[f"{p}/attn/kv_b"] = norm(
+            next(keys), (rkv, h * (cfg.head_dim + cfg.v_head_dim)),
+            1 / math.sqrt(rkv),
+        )
+    else:
+        params[f"{p}/attn/qkv"] = norm(
+            next(keys), (d, cfg.qkv_width), 1 / math.sqrt(d)
+        )
+    params[f"{p}/attn/out"] = norm(
+        next(keys),
+        (cfg.n_heads * (cfg.v_head_dim or cfg.head_dim), d),
+        1 / math.sqrt(d * 2 * cfg.n_layers),
+    )
+    if cfg.qk_norm:
+        params[f"{p}/attn/q_norm"] = jnp.ones((cfg.head_dim,))
+        params[f"{p}/attn/k_norm"] = jnp.ones((cfg.head_dim,))
+
+
+def _init_ffn(params, p, i: int, cfg: TransformerConfig, norm, keys) -> None:
+    """Block ``i``'s position-wise parameters under ``p``: the expert
+    layer's own tree (parallel/moe.py) under ``moe/``, or the dense
+    MLP's."""
+    d, f = cfg.d_model, cfg.d_ff
+    if cfg.moe_experts and (cfg.expert_layer(i) or not cfg.moe_top_k):
+        from ..parallel.moe import init_moe, init_moe_topk
+
+        moe = (
+            init_moe_topk(
+                next(keys), d, cfg.moe_d_ff, cfg.moe_experts,
+                held=cfg.moe_held[1] if cfg.moe_held else 0,
+                bias=cfg.moe_bias, shared_d_ff=cfg.moe_shared_d_ff,
+                act=cfg.moe_act, latent=cfg.moe_latent,
+            )
+            if cfg.moe_top_k
+            else init_moe(next(keys), d, f, cfg.moe_experts)
+        )
+        for k, v in moe.items():
+            params[f"{p}/moe/{k}"] = v
+        return
+    if cfg.mlp == "swiglu":
+        params[f"{p}/mlp/gate"] = norm(next(keys), (d, f), 1 / math.sqrt(d))
+    params[f"{p}/mlp/up"] = norm(next(keys), (d, f), 1 / math.sqrt(d))
+    params[f"{p}/mlp/down"] = norm(
+        next(keys), (f, d), 1 / math.sqrt(f * 2 * cfg.n_layers)
+    )
+
+
+def _init_mamba(params, p, cfg: TransformerConfig, norm, keys) -> None:
+    """A block's Mamba-2 parameters under ``p`` (ops/ssm.py
+    ``MAMBA_PARAMS``), the lineage's initialisation: the step ``dt``
+    log-uniform in [1e-3, 1e-1] through the inverse of the softplus,
+    ``A = -(1..H)``, the convolution uniform in +-1/sqrt(K)."""
+    d, h, k = cfg.d_model, cfg.mamba_heads, cfg.conv_kernel
+    d_in = h * cfg.mamba_head_dim
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (h,), minval=math.log(1e-3), maxval=math.log(1e-1)
+    ))
+    params.update({
+        f"{p}/mamba/in_proj": norm(
+            next(keys), (d, d_in + cfg.conv_dim + h), 1 / math.sqrt(d)
+        ),
+        f"{p}/mamba/conv_w": jax.random.uniform(
+            next(keys), (k, cfg.conv_dim), minval=-1 / math.sqrt(k),
+            maxval=1 / math.sqrt(k),
+        ),
+        f"{p}/mamba/conv_b": jnp.zeros((cfg.conv_dim,)),
+        f"{p}/mamba/dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        f"{p}/mamba/A_log": jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)),
+        f"{p}/mamba/D": jnp.ones((h,)),
+        f"{p}/mamba/norm": jnp.ones((d_in,)),
+        f"{p}/mamba/out_proj": norm(
+            next(keys), (d_in, d), 1 / math.sqrt(d_in * 2 * cfg.n_layers)
+        ),
+    })
 
 
 def lm_param_shardings(mesh, params: dict, axis: str = "model") -> dict:
@@ -405,7 +517,9 @@ def _rope(x, positions, theta, yarn=()):
 
 def embed(params, tokens, positions, cfg):
     """Token embedding, plus the learned position rows where the config
-    has a table (rotary positions enter in the block, on q and k)."""
+    has a table (rotary positions enter in the block, on q and k; a
+    model with ``pos = "none"`` has no positional term at all: its
+    recurrent layers carry the order)."""
     x = params["embed/tok"][tokens]
     if cfg.pos == "learned":
         x = x + params["embed/pos"][positions]
@@ -496,8 +610,87 @@ def _packed_qkv(params, p, h, positions, cfg):
     return q, k, v
 
 
+def _attention(params, p, h, x, attend, cfg, positions):
+    """The attention mixer on the normed ``h``: ``qkv``, ``attend``
+    (whatever implements it), ``attn_out``. -> (x + what attention
+    gives, ``attend``'s extra)."""
+    b, s, _ = x.shape
+    scope = jax.named_scope
+    with scope("qkv"):
+        if cfg.kv_latent:
+            q, k, v = *_latent_qk(params, p, h, positions, cfg), None
+        else:
+            q, k, v = _packed_qkv(params, p, h, positions, cfg)
+    with scope("attend"):
+        o, extra = attend(q, k, v)
+    with scope("attn_out"):
+        o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
+        x = x + o @ params[f"{p}/attn/out"]
+    return x, extra
+
+
+def _ffn(params, p, h, x, cfg, mesh, moe_capacity_factor, valid,
+         experts: bool):
+    """The position-wise mixer on the normed ``h``: the top-k expert
+    layer (``experts``), the Switch layer, or the config's dense MLP.
+    -> (x + what it gives, aux)."""
+    scope = jax.named_scope
+    aux = jnp.float32(0.0)
+    if experts:
+        from ..parallel import moe
+
+        names = moe.topk_param_names(
+            cfg.moe_act, cfg.moe_bias, bool(cfg.moe_shared_d_ff),
+            bool(cfg.moe_latent),
+        )
+        with scope("moe"):
+            y, aux = moe.moe_topk_ffn(
+                h, {k2: params[f"{p}/moe/{k2}"] for k2 in names},
+                cfg.moe_top_k, valid=valid, score=cfg.moe_score,
+                scale=cfg.moe_scale,
+                held_from=cfg.moe_held[0] if cfg.moe_held else 0,
+            )
+            x = x + y
+    elif cfg.moe_experts and not cfg.moe_top_k:
+        from ..parallel.moe import moe_ffn, moe_ffn_dense
+
+        moe_params = {
+            k2: params[f"{p}/moe/{k2}"] for k2 in ("gate", "up", "down")
+        }
+        with scope("moe"):
+            if mesh is not None and "expert" in getattr(
+                mesh, "shape", {}
+            ):
+                y, aux = moe_ffn(h, moe_params, mesh)
+            elif moe_capacity_factor is not None:
+                y, aux = moe_ffn_dense(
+                    h, moe_params, capacity_factor=moe_capacity_factor
+                )
+            else:
+                y, aux = moe_ffn_dense(h, moe_params)
+            x = x + y
+    elif cfg.mlp == "swiglu":
+        with scope("mlp"):
+            f32 = jnp.float32
+            a = (h @ params[f"{p}/mlp/gate"]).astype(f32)
+            u = (h @ params[f"{p}/mlp/up"]).astype(f32)
+            h = (jax.nn.silu(a) * u).astype(x.dtype)
+            x = x + h @ params[f"{p}/mlp/down"]
+    elif cfg.mlp == "relu2":
+        with scope("mlp"):
+            u = (h @ params[f"{p}/mlp/up"]).astype(jnp.float32)
+            h = jnp.square(jax.nn.relu(u)).astype(x.dtype)
+            x = x + h @ params[f"{p}/mlp/down"]
+    else:
+        with scope("mlp"):
+            h = jax.nn.gelu(h @ params[f"{p}/mlp/up"])
+            x = x + h @ params[f"{p}/mlp/down"]
+    return x, aux
+
+
 def _block_apply(params, p, x, attend, cfg, mesh=None,
-                 moe_capacity_factor=None, positions=None, valid=None):
+                 moe_capacity_factor=None, positions=None, valid=None,
+                 carried=None):
     """One transformer block with a pluggable attention implementation.
 
     ``attend(q, k, v) -> (o, extra)`` receives q (B, H, S, D) and k, v
@@ -513,11 +706,20 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     fields choose among its operations (LayerNorm or RMSNorm, QK-norm,
     rotary positions, fewer K/V heads, GELU MLP, Switch or top-k
     experts); there is no second body.
+
+    A model with ``cfg.layers`` has ONE mixer a block,
+    ``x + mixer(ln1(x))``, of the block's kind: attention as above (an
+    ``attend`` is only read there), the top-k expert layer or the dense
+    MLP, or a Mamba-2 layer (ops/ssm.py ``mamba2_mixer``). The last
+    starts from ``carried`` — a (state, convolution tail) a sequence, or
+    None for a sequence's start — steps over the positions ``valid``
+    marks, and hands the new pair back as ``extra``.
     ``moe_capacity_factor`` overrides the Switch MoE's capacity (decode
     passes E so routing is drop-free; None keeps the training default).
     ``positions`` (B, S) are the tokens' own positions, read by rotary
     embedding alone. ``valid`` (B, S) marks the tokens that count in the
-    top-k expert layer's two counters (None = all).
+    top-k expert layer's two counters and in a recurrent state (None =
+    all).
 
     -> (x, aux, extra): ``aux`` is the Switch layer's load-balancing
     loss (0.0 for a dense FFN), or for the top-k layer its counters,
@@ -526,70 +728,40 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     Every operation is named: the block's ``p`` (``blk3``) and inside it
     ``ln1``, ``qkv`` (holding ``qk_norm`` and ``rope``), ``attend``
     (whatever implements it), ``attn_out``, ``ln2``, ``mlp`` or ``moe``
-    (the top-k layer: ``route``, ``experts``, ``combine``, ``shared``).
-    Latent attention's ``qkv`` holds ``q_latent`` and ``kv_latent``. A
-    trace is read by these names."""
-    b, s, _ = x.shape
+    (the top-k layer: ``route``, ``experts``, ``combine``, ``shared``,
+    and ``latent_down`` / ``latent_up`` where the experts live in a
+    latent), or ``mamba`` (``in_proj``, ``conv``, ``scan`` or ``step``,
+    ``gate_norm``, ``out_proj``). Latent attention's ``qkv`` holds
+    ``q_latent`` and ``kv_latent``. A trace is read by these names."""
     scope = jax.named_scope
+    i = int(p.removeprefix("blk"))
+    kind = cfg.layers[i] if cfg.layers else None
+    aux, extra = jnp.float32(0.0), None
     with scope(p):
         with scope("ln1"):
             h = _norm(params, f"{p}/ln1", x, cfg)
-        with scope("qkv"):
-            if cfg.kv_latent:
-                q, k, v = *_latent_qk(params, p, h, positions, cfg), None
-            else:
-                q, k, v = _packed_qkv(params, p, h, positions, cfg)
-        with scope("attend"):
-            o, extra = attend(q, k, v)
-        with scope("attn_out"):
-            o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
-            x = x + o @ params[f"{p}/attn/out"]
-        with scope("ln2"):
-            h = _norm(params, f"{p}/ln2", x, cfg)
-        aux = jnp.float32(0.0)
-        if cfg.expert_layer(int(p.removeprefix("blk"))):
-            from ..parallel import moe
+        if kind == "mamba":
+            from ..ops import ssm
 
-            names = moe.MOE_TOPK_PARAMS + (
-                (moe.MOE_BIAS_PARAM,) if cfg.moe_bias else ()
-            ) + (moe.MOE_SHARED_PARAMS if cfg.moe_shared_d_ff else ())
-            with scope("moe"):
-                y, aux = moe.moe_topk_ffn(
-                    h, {k2: params[f"{p}/moe/{k2}"] for k2 in names},
-                    cfg.moe_top_k, valid=valid, score=cfg.moe_score,
-                    scale=cfg.moe_scale,
-                    held_from=cfg.moe_held[0] if cfg.moe_held else 0,
+            with scope("mamba"):
+                y, extra = ssm.mamba2_mixer(
+                    {k: params[f"{p}/mamba/{k}"] for k in ssm.MAMBA_PARAMS},
+                    h, heads=cfg.mamba_heads, head_dim=cfg.mamba_head_dim,
+                    state_dim=cfg.ssm_state, groups=cfg.ssm_groups,
+                    block=cfg.ssm_block, eps=cfg.norm_eps, carried=carried,
+                    valid=valid,
                 )
                 x = x + y
-        elif cfg.moe_experts and not cfg.moe_top_k:
-            from ..parallel.moe import moe_ffn, moe_ffn_dense
-
-            moe_params = {
-                k2: params[f"{p}/moe/{k2}"] for k2 in ("gate", "up", "down")
-            }
-            with scope("moe"):
-                if mesh is not None and "expert" in getattr(
-                    mesh, "shape", {}
-                ):
-                    y, aux = moe_ffn(h, moe_params, mesh)
-                elif moe_capacity_factor is not None:
-                    y, aux = moe_ffn_dense(
-                        h, moe_params, capacity_factor=moe_capacity_factor
-                    )
-                else:
-                    y, aux = moe_ffn_dense(h, moe_params)
-                x = x + y
-        elif cfg.mlp == "swiglu":
-            with scope("mlp"):
-                f32 = jnp.float32
-                a = (h @ params[f"{p}/mlp/gate"]).astype(f32)
-                u = (h @ params[f"{p}/mlp/up"]).astype(f32)
-                h = (jax.nn.silu(a) * u).astype(x.dtype)
-                x = x + h @ params[f"{p}/mlp/down"]
-        else:
-            with scope("mlp"):
-                h = jax.nn.gelu(h @ params[f"{p}/mlp/up"])
-                x = x + h @ params[f"{p}/mlp/down"]
+        if kind in (None, "attn"):
+            x, extra = _attention(params, p, h, x, attend, cfg, positions)
+        if kind is None:
+            with scope("ln2"):
+                h = _norm(params, f"{p}/ln2", x, cfg)
+        if kind in (None, "moe", "mlp"):
+            x, aux = _ffn(
+                params, p, h, x, cfg, mesh, moe_capacity_factor, valid,
+                experts=cfg.expert_layer(i),
+            )
     return x, aux, extra
 
 
@@ -898,10 +1070,10 @@ def generate(
     batch-independence explicitly).
     """
     b, plen = prompt.shape
-    if cfg.kv_latent:
+    if cfg.kv_latent or cfg.layers:
         raise ValueError(
-            "generate: a latent cache (kv_latent) is served by "
-            "serve/engine.py alone"
+            "generate: a latent cache (kv_latent) and a model of "
+            "one-mixer blocks (layers) are served by serve/engine.py alone"
         )
     if plen < 1:
         raise ValueError("generate: prompt must hold at least one token")

@@ -68,25 +68,45 @@ MOE_TOPK_PARAMS = ("gate", "w_gate", "w_up", "w_down")
 MOE_BIAS_PARAM = "bias"
 #: the shared expert's SwiGLU, (D, Fs), (D, Fs) and (Fs, D)
 MOE_SHARED_PARAMS = ("s_gate", "s_up", "s_down")
+#: the projections into and out of the latent the routed experts live
+#: in, (D, L) and (L, D), where the model has one: the experts' matrices
+#: are then (H, L, F) and (H, F, L)
+MOE_LATENT_PARAMS = ("lat_down", "lat_up")
+
+
+def topk_param_names(act: str = "swiglu", bias: bool = False,
+                     shared: bool = False, latent: bool = False) -> tuple:
+    """The names of a top-k layer's parameters: a "relu2" expert
+    (``relu(x U)^2 D``) has no gate matrix, routed or shared."""
+    names = MOE_TOPK_PARAMS + ((MOE_BIAS_PARAM,) if bias else ()) + (
+        MOE_SHARED_PARAMS if shared else ()
+    ) + (MOE_LATENT_PARAMS if latent else ())
+    if act == "relu2":
+        names = tuple(n for n in names if n not in ("w_gate", "s_gate"))
+    return names
 
 
 def init_moe_topk(
     rng: jax.Array, d_model: int, d_ff: int, n_experts: int, *,
     held: int = 0, bias: bool = False, shared_d_ff: int = 0,
+    act: str = "swiglu", latent: int = 0,
 ) -> dict:
-    """Param pytree of ``moe_topk_ffn`` (names: ``MOE_TOPK_PARAMS``, and
-    ``MOE_BIAS_PARAM`` / ``MOE_SHARED_PARAMS`` where asked for). The
+    """Param pytree of ``moe_topk_ffn`` (``topk_param_names``). The
     router is ``n_experts`` wide; ``held`` experts have weights here
-    (0 = all)."""
+    (0 = all); with a ``latent`` the routed experts are that wide and
+    the two projections stand beside them."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
     s = 1.0 / np.sqrt(d_model)
     h = held or n_experts
+    width = latent or d_model
     out = {
         "gate": s * jax.random.normal(kr, (d_model, n_experts)),
-        "w_gate": s * jax.random.normal(kg, (h, d_model, d_ff)),
-        "w_up": s * jax.random.normal(ku, (h, d_model, d_ff)),
+        "w_gate": (1.0 / np.sqrt(width))
+        * jax.random.normal(kg, (h, width, d_ff)),
+        "w_up": (1.0 / np.sqrt(width))
+        * jax.random.normal(ku, (h, width, d_ff)),
         "w_down": (1.0 / np.sqrt(d_ff))
-        * jax.random.normal(kd, (h, d_ff, d_model)),
+        * jax.random.normal(kd, (h, d_ff, width)),
     }
     if bias:
         out[MOE_BIAS_PARAM] = 0.1 * jax.random.normal(
@@ -99,7 +119,14 @@ def init_moe_topk(
         out["s_down"] = (1.0 / np.sqrt(shared_d_ff)) * jax.random.normal(
             k3, (shared_d_ff, d_model)
         )
-    return out
+    if latent:
+        k1, k2 = jax.random.split(jax.random.fold_in(rng, 3))
+        out["lat_down"] = s * jax.random.normal(k1, (d_model, latent))
+        out["lat_up"] = (1.0 / np.sqrt(latent)) * jax.random.normal(
+            k2, (latent, d_model)
+        )
+    names = topk_param_names(act, bias, bool(shared_d_ff), bool(latent))
+    return {k: out[k] for k in names}
 
 
 def topk_gates(x2d, params: dict, top_k: int, score: str = "softmax",
@@ -228,15 +255,29 @@ def _grouped_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
     return PAIR_TILE, tk, tn
 
 
+def _expert_act(x, params, product, gate: str, up: str):
+    """What an expert's first matrices make of its rows, float32:
+    ``silu(x Wg) * (x Wu)`` where the layer has a gate matrix, else
+    ``relu(x U)^2``. ``product(x, w)`` is the form's own."""
+    f32 = jnp.float32
+    if gate in params:
+        a = product(x, params[gate]).astype(f32)
+        u = product(x, params[up]).astype(f32)
+        return jax.nn.silu(a) * u
+    return jnp.square(jax.nn.relu(product(x, params[up]).astype(f32)))
+
+
 def _experts_dense(x2d, params, gates):
     """Every held expert on every token, ``gates`` (N, H) weighting the
-    sum: two products batched over the experts and one contraction over
-    (H, F) jointly, so no (N, H, D) per-expert output is formed."""
-    f32 = jnp.float32
+    sum: two products (one for experts without a gate matrix) batched
+    over the experts and one contraction over (H, F) jointly, so no
+    (N, H, D) per-expert output is formed."""
     with jax.named_scope("experts"):
-        a = jnp.einsum("nd,edf->enf", x2d, params["w_gate"]).astype(f32)
-        u = jnp.einsum("nd,edf->enf", x2d, params["w_up"]).astype(f32)
-        h = (jax.nn.silu(a) * u * gates.T[:, :, None]).astype(x2d.dtype)
+        h = _expert_act(
+            x2d, params, lambda x, w: jnp.einsum("nd,edf->enf", x, w),
+            "w_gate", "w_up",
+        )
+        h = (h * gates.T[:, :, None]).astype(x2d.dtype)
     with jax.named_scope("combine"):
         return jnp.einsum("enf,efd->nd", h, params["w_down"])
 
@@ -295,9 +336,10 @@ def _experts_grouped(x2d, params, gates, pairs, top_k: int, start=None):
                 )
             with jax.named_scope("experts"):
                 xs = jnp.where(live, x2d[tok], 0)
-                a = product(xs, params["w_gate"], sizes)
-                u = product(xs, params["w_up"], sizes)
-                h = (jax.nn.silu(a) * u).astype(x2d.dtype)
+                h = _expert_act(
+                    xs, params, lambda x, w: product(x, w, sizes),
+                    "w_gate", "w_up",
+                ).astype(x2d.dtype)
             with jax.named_scope("combine"):
                 out = product(h, params["w_down"], sizes)
                 # rows past the last pair are the kernel's to leave
@@ -322,12 +364,23 @@ def _experts_grouped(x2d, params, gates, pairs, top_k: int, start=None):
 def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
                  score: str = "softmax", scale: float = 1.0,
                  held_from: int = 0):
-    """Drop-free top-k SwiGLU experts: x (B, S, D) -> (y (B, S, D),
+    """Drop-free top-k experts: x (B, S, D) -> (y (B, S, D),
     int32 [held experts hit, most tokens one held expert took,
     token-expert pairs routed to held experts]).
 
         g = topk_gates(x)          (softmax or sigmoid, bias, scale)
         y = sum_{e held} g_e * ((silu(x Wg_e) * (x Wu_e)) Wd_e)  +  S(x)
+
+    An expert is a SwiGLU, or, where the layer's parameters hold no
+    gate matrix (``topk_param_names`` of "relu2"), ``relu(x U_e)^2
+    D_e``; the shared expert likewise. IN A LATENT
+    (``MOE_LATENT_PARAMS``): the routed experts read ``x W_1`` (D -> L)
+    and their weighted sum goes back through ``W_2`` (L -> D), while the
+    router and the shared expert read the full width:
+
+        y = (sum_{e held} g_e f_e(x W_1)) W_2  +  S(x)
+
+    ``W_2`` is linear, so the shares' routed parts still add up.
 
     THE SHARE: the router is as wide as the model's expert count E; the
     expert weights hold H <= E experts, ``[held_from, held_from + H)``
@@ -388,7 +441,7 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
     b, s, d = x.shape
     n = b * s
     x2d = x.reshape(n, d)
-    held = params["w_gate"].shape[0]
+    held = params["w_up"].shape[0]
     with jax.named_scope("route"):
         gates, chosen = topk_gates(x2d, params, top_k, score, scale)
         form = choose_expert_form(
@@ -406,25 +459,33 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
         ])
 
     def shared(out_type=None):
-        f32 = jnp.float32
         with jax.named_scope("shared"):
-            a = jnp.matmul(x2d, params["s_gate"]).astype(f32)
-            u = jnp.matmul(x2d, params["s_up"]).astype(f32)
+            h = _expert_act(x2d, params, jnp.matmul, "s_gate", "s_up")
             return jnp.matmul(
-                (jax.nn.silu(a) * u).astype(x.dtype), params["s_down"],
+                h.astype(x.dtype), params["s_down"],
                 preferred_element_type=out_type,
             )
 
-    if form.startswith("grouped"):
-        # the windows' float32 sums start from the shared expert's
+    has_shared, latent = "s_up" in params, "lat_down" in params
+    grouped = form.startswith("grouped")
+    xe = x2d
+    if latent:
+        with jax.named_scope("latent_down"):
+            xe = jnp.matmul(x2d, params["lat_down"])
+    if grouped:
+        # the windows' float32 sums start from the shared expert's,
+        # where both are as wide as the model
         y = _experts_grouped(
-            x2d, params, gates, counted, top_k,
-            shared(jnp.float32) if "s_gate" in params else None,
+            xe, params, gates, counted, top_k,
+            shared(jnp.float32) if has_shared and not latent else None,
         )
     else:
-        y = _experts_dense(x2d, params, gates)
-        if "s_gate" in params:
-            y = y + shared()
+        y = _experts_dense(xe, params, gates)
+    if latent:
+        with jax.named_scope("latent_up"):
+            y = jnp.matmul(y, params["lat_up"])
+    if has_shared and (latent or not grouped):
+        y = y + shared()
     return y.reshape(b, s, d), stats
 
 

@@ -135,6 +135,9 @@ DECODE_COUNTERS = (
     "experts_hit", "expert_max_load", "held_pairs", "cache_rows",
     "chunk_held_pairs",
 )
+#: and what a decode pass of a model with recurrent layers appends after
+#: them: the slots whose state the pass advanced (its live lanes)
+STATE_COUNTERS = ("state_slots_live",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -304,6 +307,10 @@ class Engine:
         self.expert_forms = self._expert_forms(
             cfg, self.serving, jax.default_backend()
         )
+        #: and the form each program's Mamba-2 layers compute their
+        #: recurrence in (ops/ssm.py ``choose_mamba_form``); empty for a
+        #: model without such layers
+        self.mamba_forms = self._mamba_forms(cfg, self.serving)
         if self._fused:
             from ..ops.paged_attention import fusable, latent_fusable
 
@@ -327,6 +334,13 @@ class Engine:
             cfg.max_len, self.serving.kv_block_len,
             self.serving.kv_blocks, self.serving.slots,
         )
+        #: layer -> its place in ``state["k"]`` / ``state["v"]``: pools
+        #: are kept for the layers that hold attention alone, and layer
+        #: -> its place in ``state["ssm"]`` / ``state["conv"]`` for those
+        #: that hold a Mamba-2 mixer (every layer and none for a model
+        #: without ``layers``)
+        self._kv_at = {i: j for j, i in enumerate(cfg.layers_of("attn"))}
+        self._state_at = {i: j for j, i in enumerate(cfg.layers_of("mamba"))}
         self.allocator = BlockAllocator(
             self.pool,
             prefix_cache=self.serving.prefix_cache,
@@ -378,21 +392,41 @@ class Engine:
             "tables": put(jnp.zeros((s, mb), jnp.int32), state_sh),
             "k": tuple(
                 put(jnp.zeros(shape, pool_dtype), pool_sh)
-                for _ in range(cfg.n_layers)
+                for _ in self._kv_at
             ),
             "v": tuple(
                 put(jnp.zeros(shape, pool_dtype), pool_sh)
-                for _ in range(0 if cfg.kv_latent else cfg.n_layers)
+                for _ in (() if cfg.kv_latent else self._kv_at)
             ),
         }
-        #: counters a one-token decode pass appends to its tokens (the
-        #: scheduler reads them with the pass, one tick late): for a
-        #: model with top-k expert layers ``DECODE_COUNTERS``, else none
-        self.decode_counters = (
-            len(DECODE_COUNTERS)
-            if cfg.moe_top_k and not cfg.diffusion_block else 0
-        )
-        if self.decode_counters:
+        if self._state_at:
+            # slot-resident recurrent state, one array a Mamba-2 layer:
+            # the float32 state and the convolution's tail, zeroed at
+            # admission, advanced by the valid positions alone
+            self.state["ssm"] = tuple(
+                jnp.zeros(
+                    (s, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
+                    jnp.float32,
+                ) for _ in self._state_at
+            )
+            # (the tail's few rows lead: (K - 1, slots, C) tiles whole,
+            # and is how the TPU compiler lays it out whatever it is
+            # handed; slots-major it copied the tail in and out of every
+            # tick, read off the compiled text)
+            self.state["conv"] = tuple(
+                jnp.zeros((cfg.conv_kernel - 1, s, cfg.conv_dim), pool_dtype)
+                for _ in self._state_at
+            )
+        #: the counters a one-token decode pass appends to its tokens,
+        #: by name (the scheduler reads them with the pass, one tick
+        #: late): ``DECODE_COUNTERS`` for a model with top-k expert
+        #: layers, then ``STATE_COUNTERS`` for one with recurrent layers
+        self.decode_counter_names = (
+            DECODE_COUNTERS
+            if cfg.moe_top_k and not cfg.diffusion_block else ()
+        ) + (STATE_COUNTERS if self._state_at else ())
+        self.decode_counters = len(self.decode_counter_names)
+        if "chunk_held_pairs" in self.decode_counter_names:
             # pairs the prefill chunks since the last decode pass routed
             # to held experts: the next pass hands it on and zeroes it
             self.state["chunk_pairs"] = jnp.zeros((), jnp.int32)
@@ -447,9 +481,10 @@ class Engine:
     @staticmethod
     def _refuse_what_cannot_run(cfg, serving, mesh) -> None:
         """What no program here computes for a model with fewer K/V
-        heads than query heads, with a latent cache or generated by
-        diffusion over blocks is refused by the field's name, not run
-        wrongly (ROADMAP Queue 2 keeps the list)."""
+        heads than query heads, with a latent cache, of one-mixer
+        ``layers`` (recurrent state beside pools for some layers) or
+        generated by diffusion over blocks is refused by the field's
+        name, not run wrongly (ROADMAP Queue 2 keeps the list)."""
         why = Engine._beyond_gpt2(cfg)
         if why is None:
             return
@@ -519,6 +554,22 @@ class Engine:
         }
 
     @staticmethod
+    def _mamba_forms(cfg, serving) -> dict:
+        """Program name -> ``choose_mamba_form`` of the positions a
+        sequence has in a pass of it: one for the tick, a whole chunk
+        for the prefill, both from a slot's carried state."""
+        if not cfg.layers_of("mamba"):
+            return {}
+        from ..ops.ssm import choose_mamba_form
+
+        return {
+            name: choose_mamba_form(n, cfg.ssm_block, True)
+            for name, n in (
+                ("jit__decode", 1), ("jit__prefill", serving.max_prefill_chunk)
+            )
+        }
+
+    @staticmethod
     def _no_kernel(cfg) -> str | None:
         """The field (with its value) of a model that the paged kernels
         (ops/paged_attention.py) do not know, None where one of them
@@ -536,6 +587,12 @@ class Engine:
         hold, None for a model that every path here serves."""
         if cfg.kv_latent:
             return f"kv_latent = {cfg.kv_latent}"
+        if cfg.layers:
+            # pools for some layers only, and recurrent state that no
+            # block of a cache holds: nothing of it is indexed by
+            # content, rewound, or in the fleet's wire format
+            kinds = sorted(set(cfg.layers))
+            return f"layers = {len(cfg.layers)} one-mixer blocks of {kinds}"
         return Engine._no_kernel(cfg)
 
     # ------------------------------------------------------------------
@@ -699,13 +756,15 @@ class Engine:
         off = pos % cfg.block_len
         new_k, new_v = [], []
 
-        def mk_attend(i):
+        def mk_attend(layer):
+            i = self._kv_at.get(layer)      # None: no attention there
+
             def attend_latent(q, lat, _):
                 # the decode tick's form: queries taken into the latent
                 # space, the latents read as they lie — in place by the
                 # kernel, or as a gathered view
                 lp = self._latent_write(state["k"][i], bid, off, lat[:, 0])
-                w_kvb = params[f"blk{i}/attn/kv_b"]
+                w_kvb = params[f"blk{layer}/attn/kv_b"]
                 if self._fused:
                     o = self._paged_latent_attend(q, lp, w_kvb, state, live)
                 else:
@@ -731,15 +790,25 @@ class Engine:
                 return o, (kp, vp)
             return attend_latent if mcfg.kv_latent else attend
 
-        stats = []
+        stats, new_ssm, new_conv = [], [], []
         for i in range(mcfg.n_layers):
-            x, aux, (kp, vp) = _block_apply(
+            j = self._state_at.get(i)
+            x, aux, extra = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
                 positions=pos[:, None], valid=live[:, None],
+                carried=None if j is None else (
+                    state["ssm"][j], jnp.moveaxis(state["conv"][j], 0, 1)
+                ),
             )
-            new_k.append(kp)
-            new_v.append(vp)
+            if j is not None:
+                # one step a live lane from its own state; a dead lane's
+                # state and tail come back as they went in
+                new_ssm.append(extra[0])
+                new_conv.append(jnp.moveaxis(extra[1], 1, 0))
+            elif i in self._kv_at:
+                new_k.append(extra[0])
+                new_v.append(extra[1])
             if mcfg.expert_layer(i):
                 stats.append(aux)
         logits = lm_head(params, x, mcfg)[:, 0]
@@ -754,14 +823,26 @@ class Engine:
             "k": tuple(new_k),
             "v": tuple(v for v in new_v if v is not None),
         }
+        if new_ssm:
+            new_state["ssm"], new_state["conv"] = tuple(new_ssm), tuple(new_conv)
         out = jnp.where(live, nxt, jnp.int32(-1))
         if self.decode_counters:
-            st = jnp.stack(stats)                                # (L, 3)
-            out = jnp.concatenate([out, jnp.stack([
-                jnp.sum(st[:, 0]), jnp.max(st[:, 1]), jnp.sum(st[:, 2]),
-                jnp.sum(jnp.where(live, pos + 1, 0)), state["chunk_pairs"],
-            ])])
-            new_state["chunk_pairs"] = jnp.zeros((), jnp.int32)
+            counted = {}
+            if stats:
+                st = jnp.stack(stats)                            # (L, 3)
+                counted.update(
+                    experts_hit=jnp.sum(st[:, 0]),
+                    expert_max_load=jnp.max(st[:, 1]),
+                    held_pairs=jnp.sum(st[:, 2]),
+                    cache_rows=jnp.sum(jnp.where(live, pos + 1, 0)),
+                    chunk_held_pairs=state["chunk_pairs"],
+                )
+                new_state["chunk_pairs"] = jnp.zeros((), jnp.int32)
+            if new_ssm:
+                counted["state_slots_live"] = jnp.sum(live, dtype=jnp.int32)
+            out = jnp.concatenate([out, jnp.stack(
+                [counted[name] for name in self.decode_counter_names]
+            )])
         return new_state, out
 
     def _prefill(self, params, state, slot, chunk, pos0, n_valid):
@@ -793,14 +874,16 @@ class Engine:
         off = p_safe % cfg.block_len
         new_k, new_v = [], []
 
-        def mk_attend(i):
+        def mk_attend(layer):
+            i = self._kv_at.get(layer)      # None: no attention there
+
             def attend_latent(q, lat, _):
                 # the chunk's form: K and V made from the slot's
                 # gathered latents, as far as a query of it may see
                 lp = self._latent_write(state["k"][i], bid, off, lat[0])
                 o = latent_attend(
                     q, self._gather_latent(lp, row[None]),
-                    params[f"blk{i}/attn/kv_b"], limits[None], mcfg,
+                    params[f"blk{layer}/attn/kv_b"], limits[None], mcfg,
                     absorbed=False,
                 )
                 return o, (lp, None)
@@ -824,21 +907,37 @@ class Engine:
             return attend_latent if mcfg.kv_latent else attend
 
         held_pairs = jnp.zeros((), jnp.int32)
+        new_ssm, new_conv = [], []
         for i in range(mcfg.n_layers):
-            x, aux, (kp, vp) = _block_apply(
+            j = self._state_at.get(i)
+            x, aux, extra = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
                 positions=p[None], valid=valid[None],
+                carried=None if j is None else (
+                    state["ssm"][j][slot][None],
+                    state["conv"][j][:, slot][None],
+                ),
             )
-            new_k.append(kp)
-            new_v.append(vp)
+            if j is not None:
+                # the chunk starts from the slot's state (zeros at
+                # admission) and leaves what its valid positions made
+                new_ssm.append(state["ssm"][j].at[slot].set(extra[0][0]))
+                new_conv.append(
+                    state["conv"][j].at[:, slot].set(extra[1][0])
+                )
+            elif i in self._kv_at:
+                new_k.append(extra[0])
+                new_v.append(extra[1])
             if mcfg.expert_layer(i):
                 held_pairs = held_pairs + aux[2]
         new_state = {
             **state, "k": tuple(new_k),
             "v": tuple(v for v in new_v if v is not None),
         }
-        if self.decode_counters:
+        if new_ssm:
+            new_state["ssm"], new_state["conv"] = tuple(new_ssm), tuple(new_conv)
+        if "chunk_pairs" in state:
             new_state["chunk_pairs"] = state["chunk_pairs"] + held_pairs
         if mcfg.diffusion_block:
             return new_state, jnp.float32(0.0)
@@ -1118,12 +1217,18 @@ class Engine:
         }
 
     def _admit_prog(self, state, slot, row):
-        return {
+        out = {
             **state,
             "tables": state["tables"].at[slot].set(row),
             "pos": state["pos"].at[slot].set(0),
             "live": state["live"].at[slot].set(False),
         }
+        # a sequence starts from a zero state and an empty convolution
+        # tail; retirement needs nothing more
+        if "ssm" in state:
+            out["ssm"] = tuple(a.at[slot].set(0) for a in state["ssm"])
+            out["conv"] = tuple(a.at[:, slot].set(0) for a in state["conv"])
+        return out
 
     def _activate_prog(self, state, slot, last_logits, plen, seed, temp):
         rng = jax.random.PRNGKey(seed)

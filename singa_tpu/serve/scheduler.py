@@ -163,6 +163,9 @@ class Scheduler:
         self.held_pairs = 0
         self.cache_rows = 0
         self.chunk_held_pairs = 0
+        #: and of a model with recurrent layers the slots whose state
+        #: the passes advanced (``engine.STATE_COUNTERS``)
+        self.state_slots_live = 0
         #: decode and block steps run ONE PASS AHEAD of the host: (the
         #: device result of the pass dispatched last tick, the requests
         #: it served by slot), read this tick after the next is
@@ -232,6 +235,10 @@ class Scheduler:
             self._event(
                 "kernel_select", site=f"moe.experts.{program}", impl=form
             )
+        for program, form in engine.mamba_forms.items():
+            self._event(
+                "kernel_select", site=f"mamba.scan.{program}", impl=form
+            )
 
     def reset_counters(self) -> None:
         """Zero every accumulated statistic (ticks, token/draft counts,
@@ -252,6 +259,7 @@ class Scheduler:
         self.tokens_delivered = 0
         self.experts_hit = self.expert_max_load = 0
         self.held_pairs = self.cache_rows = self.chunk_held_pairs = 0
+        self.state_slots_live = 0
         self.engine.allocator.reset_stats()
         self._live_ticks = self.lanes_unread = 0
         self.backpressure_ticks = 0
@@ -680,14 +688,17 @@ class Scheduler:
                 else:
                     n = self.engine.decode_counters
                     if n:
-                        hit, most, pairs, rows, chunk = out[-n:].tolist()
-                        self.experts_hit += hit
-                        self.expert_max_load = max(
-                            self.expert_max_load, most
-                        )
-                        self.held_pairs += pairs
-                        self.cache_rows += rows
-                        self.chunk_held_pairs += chunk
+                        # each counter adds up under its own name; the
+                        # most one expert took is a maximum
+                        for name, value in zip(
+                            self.engine.decode_counter_names,
+                            out[-n:].tolist(),
+                        ):
+                            had = getattr(self, name)
+                            setattr(self, name, (
+                                max(had, value) if name == "expert_max_load"
+                                else had + value
+                            ))
                         out = out[:-n]
                     emitted = out[:, None].tolist()
         with span("sched.emit", tick=tick) as fan_out:
@@ -810,6 +821,8 @@ class Scheduler:
         }
         if self.engine.expert_forms:
             out["expert_forms"] = dict(self.engine.expert_forms)
+        if self.engine.mamba_forms:
+            out["mamba_forms"] = dict(self.engine.mamba_forms)
         if self.spec_k > 0:
             # acceptance rate = accepted draft tokens / drafted; the
             # emitted bonus tokens ride free either way

@@ -462,3 +462,130 @@ def test_a_block_step_over_every_expert_stays_dense(one_chip, monkeypatch):
     ).compile().as_text()
     assert "jit(gmm)" not in text and "tpu_custom_call" not in text
     assert "bf16[128,256,768]" in text or "f32[128,256,768]" in text
+
+
+# -- one-mixer layers: recurrent state beside a paged pool ---------------
+
+#: slots the engine below is built with (its zeros stay small); the
+#: shapes handed to the compiler hold the cell's 128 in their place
+_FEW = 5
+
+
+@pytest.fixture(scope="module")
+def hybrid_cell_on_tpu(one_chip):
+    """The engine of ``nemotron_3_super_serve_chat`` (128 slots, blocks
+    of 128, 512-token chunks, the published widths, 128 of 512 experts
+    held) cut to its attention layer, one expert layer and one Mamba
+    layer, as the chip builds it, and its arguments as shapes on the
+    described chip: the state is ``f32[128, 128, 64, 128]``, 537 MB a
+    layer, the tail ``bf16[3, 128, 10240]``, the pools
+    ``bf16[4865, 128, 256]``."""
+    cfg = TransformerConfig(
+        vocab=32768, d_model=4096, n_heads=32, n_kv_heads=2, head_dim=128,
+        n_layers=3, layers=("attn", "moe", "mamba"), d_ff=2688, max_len=4864,
+        norm="rmsnorm", pos="none", mlp="relu2", tied_head=False,
+        mamba_heads=128, mamba_head_dim=64, ssm_state=128, ssm_groups=8,
+        moe_experts=512, moe_top_k=22, moe_d_ff=2688, moe_score="sigmoid",
+        moe_bias=True, moe_scale=5.0, moe_shared_d_ff=5376,
+        moe_held=(128, 128), moe_act="relu2", moe_latent=1024,
+    )
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)),
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        eng = Engine(params, cfg, EngineConfig(
+            slots=_FEW, kv_block_len=128, kv_blocks=39, max_prefill_chunk=512,
+        ))
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def sized(a):
+        if a.shape[:2] == (39, 128):            # a pool: every slot's blocks
+            return arg(a.dtype, 128 * 38 + 1, *a.shape[1:])
+        return arg(a.dtype, *(128 if d == _FEW else d for d in a.shape))
+
+    head = (
+        jax.tree.map(lambda a: arg(a.dtype, *a.shape), params),
+        jax.tree.map(sized, eng.state),
+    )
+    i32 = lambda *shape: arg(jnp.int32, *shape)  # noqa: E731
+    programs = {
+        "decode": (eng._decode, head),
+        "prefill": (eng._prefill, head + (i32(), i32(512), i32(), i32())),
+    }
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        texts = {
+            name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+            .as_text()
+            for name, (fn, args) in programs.items()
+        }
+    return eng, texts
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_programs_relayout_no_state(hybrid_cell_on_tpu, program):
+    """The recurrent state, the convolution's tail and the attention
+    layer's pools arrive row-major, leave as they arrived and alias
+    their inputs; no program copies a state array at all, and the one
+    copy a tick makes of the 7.9 MB tail keeps its layout."""
+    eng, texts = hybrid_cell_on_tpu
+    assert len(eng.state["k"]) == len(eng.state["ssm"]) == 1
+    text = texts[program]
+    header = text[:text.index("\n")]
+    sides = re.search(
+        r"entry_computation_layout=\{\((.*)\)->(.*)\}", header
+    ).groups()
+    for dims, layout, n in (
+        (r"f32\[128,128,64,128\]", "{3,2,1,0:T(8,128)}", 1),
+        (r"bf16\[3,128,10240\]", "{2,1,0:T(8,128)(2,1)}", 1),
+        (r"bf16\[4865,128,256\]", "{2,1,0:T(8,128)(2,1)}", 2),
+    ):
+        arrive, leave = (
+            re.findall(dims + r"(\{[^}]*\})", side) for side in sides
+        )
+        assert arrive == leave == [layout] * n, (dims, arrive, leave)
+    assert header.count("-alias)") >= 4
+    assert not re.findall(r"= f32\[128,128,64,128\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[128,8,16,64,128\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[4865,128,256\]\S* copy\(", text)
+    for layout in re.findall(r"= bf16\[3,128,10240\](\{[^}]*\})\S* copy\(", text):
+        assert layout.startswith("{2,1,0:"), layout
+    assert not re.findall(r"= bf16\[128,3,10240\]\S* copy\(", text)
+    assert "tpu_custom_call" not in text or program == "prefill"
+
+
+def test_hybrid_programs_take_their_forms(hybrid_cell_on_tpu):
+    """A tick's 128 tokens over 128 held experts stay dense (no grouped
+    kernel in the tick, and its Mamba layer is one ``step``); a chunk's
+    512 go grouped: TWO megablox calls an expert layer (an expert has no
+    gate matrix), under ``experts`` and ``combine``, in the latent's
+    width, and no per-expert activation ``(128, 512, 2688)`` is
+    formed."""
+    from singa_tpu.parallel.moe import choose_expert_form
+
+    eng, texts = hybrid_cell_on_tpu
+    assert choose_expert_form(128, 128, 512, 22, "tpu").startswith("dense")
+    assert eng.expert_forms["jit__prefill"].startswith("grouped: 512 tokens")
+    assert eng.mamba_forms["jit__decode"].startswith("step")
+    assert eng.mamba_forms["jit__prefill"].startswith(
+        "chunked: 512 positions in 4 blocks of 128"
+    )
+    assert "jit(gmm)" not in texts["decode"]
+    assert "[128,128,2688]" in texts["decode"]      # the dense activation
+    calls = re.findall(
+        r'%gmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', texts["prefill"],
+    )
+    assert len(calls) == 2 and all(
+        c.startswith("jit(_prefill)/blk1/moe/")
+        and c.endswith("jit(gmm)/pallas_call") for c in calls
+    ), calls
+    assert sum("/experts/" in c for c in calls) == 1
+    assert sum("/combine/" in c for c in calls) == 1
+    assert "[128,512,2688]" not in texts["prefill"]
+    assert "/mamba/step/" in texts["decode"]
+    assert "/mamba/scan/" in texts["prefill"]

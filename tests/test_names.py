@@ -657,3 +657,98 @@ def test_latent_scope_names_are_plain_segments(latent_texts):
     # program holds no ``materialise``, the chunk no ``absorb``
     assert "materialise" not in latent_texts["_decode"]
     assert "absorb" not in latent_texts["_prefill"]
+
+
+# ---------------------------------------------------------------------
+# one-mixer layers: Mamba-2 with recurrent state, latent ReLU^2 experts
+# ---------------------------------------------------------------------
+
+
+def tiny_hybrid_engine(**serving):
+    cfg = TransformerConfig(
+        vocab=40, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        n_layers=3, layers=("attn", "moe", "mamba"), d_ff=24, max_len=32,
+        norm="rmsnorm", pos="none", mlp="relu2", tied_head=False,
+        mamba_heads=8, mamba_head_dim=4, ssm_state=8, ssm_groups=2,
+        ssm_block=4, moe_experts=8, moe_top_k=3, moe_d_ff=16,
+        moe_score="sigmoid", moe_bias=True, moe_scale=5.0,
+        moe_shared_d_ff=24, moe_held=(2, 4), moe_act="relu2", moe_latent=16,
+    )
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    return Engine(params, cfg, EngineConfig(
+        slots=2, kv_block_len=8, max_prefill_chunk=8, **serving
+    ))
+
+
+@pytest.fixture(scope="module")
+def hybrid_texts():
+    eng = tiny_hybrid_engine()
+    slot, chunk = jnp.int32(0), jnp.zeros((8,), jnp.int32)
+    lowered = {
+        "_decode": eng._decode_jit.lower(eng.params, eng.state),
+        "_prefill": eng._prefill_jit.lower(
+            eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(8)
+        ),
+    }
+    return {k: v.compile().as_text() for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [
+    # the Mamba layer's own scopes: one ``step`` a tick, the chunked
+    # ``scan`` (its carry a loop) for a chunk
+    ("_decode", "blk2/mamba/in_proj"), ("_decode", "blk2/mamba/conv"),
+    ("_decode", "blk2/mamba/step"), ("_decode", "blk2/mamba/gate_norm"),
+    ("_decode", "blk2/mamba/out_proj"),
+    ("_prefill", "blk2/mamba/in_proj"), ("_prefill", "blk2/mamba/conv"),
+    ("_prefill", "blk2/mamba/scan"), ("_prefill", "blk2/mamba/gate_norm"),
+    ("_prefill", "blk2/mamba/out_proj"),
+    # the latent projections beside the expert layer's known names
+    ("_decode", "blk1/moe/latent_down"), ("_decode", "blk1/moe/latent_up"),
+    ("_decode", "blk1/moe/route"), ("_decode", "blk1/moe/experts"),
+    ("_decode", "blk1/moe/combine"), ("_decode", "blk1/moe/shared"),
+    ("_prefill", "blk1/moe/latent_down"), ("_prefill", "blk1/moe/latent_up"),
+    # attention in its one layer, by the names the readers know
+    ("_decode", "blk0/attend/kv_write"), ("_decode", "blk0/attend/gather_kv"),
+    ("_decode", "blk0/attend/cache_attend"), ("_decode", "blk0/attn_out"),
+    ("_decode", "blk0/ln1"), ("_decode", "blk2/ln1"),
+    ("_decode", "lm_head"), ("_decode", "sample"), ("_decode", "embed"),
+])
+def test_hybrid_programs_name_their_operations(hybrid_texts, program, scope):
+    assert f"HloModule jit_{program}," in hybrid_texts[program]
+    names = {n for _, n in instructions(hybrid_texts[program])}
+    assert any(f"jit({program})/{scope}/" in n for n in names), scope
+
+
+def test_hybrid_scope_names_and_counters():
+    """A one-mixer block has no second norm and no mixer it was not
+    given; the tick holds no ``scan`` and the chunk no ``step``; the new
+    names are plain segments; and the pass's counters end in the state's
+    own (``STATE_COUNTERS``), after ``DECODE_COUNTERS`` unchanged."""
+    from singa_tpu.ops import ssm
+    from singa_tpu.serve import engine as engine_mod
+
+    eng = tiny_hybrid_engine()
+    assert eng.decode_counter_names == (
+        "experts_hit", "expert_max_load", "held_pairs", "cache_rows",
+        "chunk_held_pairs", "state_slots_live",
+    ) == engine_mod.DECODE_COUNTERS + engine_mod.STATE_COUNTERS
+    assert set(eng.mamba_forms) == {"jit__decode", "jit__prefill"}
+    assert not any("/" in n for n in ssm.MAMBA_PARAMS + (
+        "mamba", "in_proj", "conv", "scan", "step", "gate_norm", "out_proj",
+        "latent_down", "latent_up",
+    ))
+    jaxpr = str(jax.make_jaxpr(eng._decode)(eng.params, eng.state))
+    assert "pallas_call" not in jaxpr      # no kernel here: plain XLA
+
+
+def test_hybrid_blocks_hold_their_one_mixer(hybrid_texts):
+    for program, text in hybrid_texts.items():
+        names = {n for _, n in instructions(text)}
+        assert not any("/ln2/" in n for n in names)
+        assert not any("/blk0/moe/" in n or "/blk0/mamba/" in n for n in names)
+        assert not any("/blk2/attend/" in n or "/blk1/attend/" in n
+                       for n in names)
+    decode = {n for _, n in instructions(hybrid_texts["_decode"])}
+    prefill = {n for _, n in instructions(hybrid_texts["_prefill"])}
+    assert not any("/mamba/scan/" in n for n in decode)
+    assert not any("/mamba/step/" in n for n in prefill)
