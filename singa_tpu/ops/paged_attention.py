@@ -16,8 +16,10 @@ block-granular K/V, the PagedAttention idea from vLLM-style serving.
 No dense ``(S, H, C, D)`` intermediate ever exists.
 
 WHAT THE KERNEL IS HANDED: the pools as the engine stores them,
-``(n_blocks, block_len, H * D)`` (serve/kv_pool.py: one row a token,
-the heads side by side in it), with no view or relayout in between.
+``(n_blocks, block_len, Hkv * D)`` (serve/kv_pool.py: one row a token,
+the K/V heads side by side in it), with no view or relayout in between,
+and queries of H heads, H a multiple of Hkv: query head ``j`` reads K/V
+head ``j // (H // Hkv)``, as in ``cache_attend``.
 
 TWO FORMS behind the two entry points, chosen in ``_call`` from the
 call's shape:
@@ -41,7 +43,11 @@ the one-query form (``_one_query_kernel``)
     chunk's blocks from HBM by hand (the next chunk's while this one
     is folded) and folds all heads at once. On a v5e it reads the
     live blocks of GPT-2 medium's pools at 0.95 of what a plain sum
-    over a pool reads them at (PERF.md §6, PR 29).
+    over a pool reads them at (PERF.md §6, PR 29). Query heads over
+    fewer K/V heads (PR 38) are two products a chunk over a
+    block-diagonal query; a chunk is sized by bytes, some 512 KB of K
+    rows. It is the only form that knows fewer K/V heads: the grid
+    form refuses them.
 
 Two entry points cover the engine's call shapes:
 
@@ -212,9 +218,17 @@ def _kernel(
             o_ref[0, h] = (acc[h] / safe[:, None]).astype(o_ref.dtype)
 
 
-#: pool positions one step of the one-query kernel fetches and folds
-#: (whole blocks: ``_CHUNK_POSITIONS // block_len`` of them, at least 1)
-_CHUNK_POSITIONS = 128
+#: bytes of K (and as many of V) one step of the one-query kernel copies
+#: and folds: 128 positions of GPT-2 medium's 4 KB float32 rows, 1,024 of
+#: a 512 B bfloat16 row of 2 K/V heads (``_item_positions``)
+_CHUNK_BYTES = 512 * 1024
+
+
+def _item_positions(row_width: int, dtype) -> int:
+    """Pool positions one item of the one-query kernel copies, from the
+    bytes of a pool row (whole blocks of them are copied: this over the
+    block length, at least 1)."""
+    return _CHUNK_BYTES // (row_width * jnp.dtype(dtype).itemsize)
 
 
 def _spread(x, heads_to_columns):
@@ -228,10 +242,8 @@ def _spread(x, heads_to_columns):
 
 
 def _one_query_kernel(
-    tab_ref, nlive_ref, seq_ref, chunk_ref, n_ref, pos_ref,
-    q_ref, fold_ref, spread_ref, k_hbm, v_hbm, o_ref,
-    kbuf, vbuf, sem, acc, m, l,
-    *, block_len, mb, group,
+    tab_ref, nlive_ref, seq_ref, chunk_ref, n_ref, pos_ref, q_ref, *refs,
+    block_len, mb, group, per_kv,
 ):
     """The decode tick's shape — ONE query a sequence — as one program
     over a flat list of LIVE chunks: item ``i`` is chunk
@@ -240,7 +252,7 @@ def _one_query_kernel(
     a sequence's live range costs no step at all.
 
     The pools stay in HBM: a chunk's live blocks are copied by hand
-    into one of two ``(group * block_len, H * D)`` buffers, the next
+    into one of two ``(group * block_len, Hkv * D)`` buffers, the next
     item's while this one is folded, across sequence boundaries too.
 
     The heads are folded together instead of walked: a pool row holds
@@ -253,10 +265,28 @@ def _one_query_kernel(
     precision, as the reference path's are (on a TPU one bfloat16 pass
     of ``K * q`` and of the probabilities, accumulated in float32; on
     a CPU float32 throughout); ``K * q``, ``p * V`` and every statistic
-    are float32 everywhere."""
+    are float32 everywhere.
+
+    ``per_kv`` query heads over ONE K/V head (``per_kv`` > 1: query head
+    ``j`` reads K/V head ``j // per_kv``): the pool row holds the Hkv
+    K/V heads alone, and ``q_ref`` holds a sequence's query laid out
+    block-diagonally, (H, Hkv * D) with query head j in the D columns of
+    its K/V head and zeros elsewhere, so a chunk's scores (H, positions)
+    are ONE product with the copied K rows, as are its values
+    (H, positions) x (positions, Hkv * D) into the running (H, Hkv * D);
+    ``_finish`` keeps each query head's own D columns. Both products
+    take the operands as they lie (the pool's dtype) and accumulate in
+    float32, as ``cache_attend``'s do; the statistics are a column a
+    head, float32."""
+    if per_kv == 1:
+        fold_ref, spread_ref, *refs = refs
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m, l = refs
     gbl = group * block_len
     n = n_ref[0]
-    scale = 1.0 / math.sqrt(q_ref.shape[-1] // m.shape[-1])
+    if per_kv == 1:
+        scale = 1.0 / math.sqrt(q_ref.shape[-1] // m.shape[-1])
+    else:
+        scale = 1.0 / math.sqrt(o_ref.shape[-1])
 
     def copies(i, buf):
         """Item i's (is it live?, its K copy, its V copy), a block each."""
@@ -291,6 +321,40 @@ def _one_query_kernel(
     def _first():
         each_copy(0, 0, lambda copy: copy.start())
 
+    def fold_grouped(seq, c, buf):
+        """One item of ``per_kv`` query heads a K/V head: scores and
+        values as two products on the MXU, the statistics a column a
+        head (the latent kernel's form over the block-diagonal query)."""
+        rows = kbuf[buf]                                         # (GBL, HD)
+        scores = jax.lax.dot_general(
+            q_ref[seq], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                                # (H, GBL)
+        kpos = c * gbl + jax.lax.broadcasted_iota(jnp.int32, (1, gbl), 1)
+        mask = kpos <= pos_ref[seq]
+        scores = jnp.where(mask, scores, NEG_INF)
+        m_prev = m[...]                                          # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)        # (H, GBL)
+        l[...] = l[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m[...] = m_new
+        values = vbuf[buf]
+        acc[...] = acc[...] * alpha + jnp.dot(
+            p.astype(values.dtype), values, preferred_element_type=jnp.float32
+        )                                                        # (H, HD)
+
+        @pl.when((c + 1) * group >= nlive_ref[seq])
+        def _finish():
+            total = l[...]
+            out = acc[...] / jnp.where(total == 0.0, 1.0, total)
+            d = o_ref.shape[-1]
+            for j in range(out.shape[-1] // d):
+                heads = slice(j * per_kv, (j + 1) * per_kv)
+                o_ref[seq, heads, :] = out[heads, j * d:(j + 1) * d].astype(
+                    o_ref.dtype
+                )
+
     def fold_item(i, carry):
         buf = i % 2
 
@@ -300,7 +364,8 @@ def _one_query_kernel(
 
         each_copy(i, buf, lambda copy: copy.wait())
         seq, c = seq_ref[i], chunk_ref[i]
-        spread = spread_ref[...]
+        if per_kv == 1:
+            spread = spread_ref[...]
 
         @pl.when(c == 0)
         def _init():
@@ -308,6 +373,9 @@ def _one_query_kernel(
             m[...] = jnp.full_like(m, NEG_INF)
             l[...] = jnp.zeros_like(l)
 
+        if per_kv > 1:
+            fold_grouped(seq, c, buf)
+            return carry
         q = q_ref[seq].astype(jnp.float32) * scale               # (1, HD)
         products = kbuf[buf].astype(jnp.float32) * q             # (GBL, HD)
         scores = jnp.dot(
@@ -342,54 +410,63 @@ def _one_query_kernel(
 
 
 def _call_one_query(q, k_pool, v_pool, tables, positions, interpret):
-    """``_one_query_kernel`` on (S, H, 1, D) queries: the list of live
-    chunks, the two 0/1 matrices and the queries as H * D-wide rows
-    are made here, in plain XLA (a tick's 24 layers ask for the same
-    list, which the compiler computes once)."""
+    """``_one_query_kernel`` on (S, H, 1, D) queries over pools of
+    ``Hkv * D``-wide rows: the list of live chunks and the queries as
+    the kernel reads them are made here, in plain XLA (a tick's layers
+    ask for the same list, which the compiler computes once). One K/V
+    head a query head: the queries as H * D-wide rows and the two 0/1
+    matrices; fewer: each query block-diagonal, (H, Hkv * D)."""
     s, h, _, d = q.shape
     _, bl, hd = k_pool.shape
+    per_kv = h * d // hd
     mb = tables.shape[1]
-    group = max(1, min(_CHUNK_POSITIONS // bl, mb))
+    group = max(1, min(_item_positions(hd, k_pool.dtype) // bl, mb))
     pos = positions[:, 0].astype(jnp.int32)
     nlive = live_blocks(pos, bl, mb).astype(jnp.int32)
     seq, chunk, n_items = _live_items(nlive, group, mb)
-    fold = (
-        jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]
-    ).astype(jnp.float32)                                # (HD, H)
 
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
+    if per_kv == 1:
+        fold = (
+            jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]
+        ).astype(jnp.float32)                            # (HD, H)
+        tflat = tables.reshape(-1).astype(jnp.int32)
+        queries = [jnp.moveaxis(q, 1, 2).reshape(s, 1, hd), fold, fold.T]
+        query_specs = [whole(s, 1, hd), whole(hd, h), whole(h, hd)]
+        out_shape, stats = (s, 1, hd), [(1, hd), (1, h), (1, h)]
+    else:
+        tflat = tables.reshape(-1).astype(jnp.int32)
+        own = jnp.arange(hd)[None, :] // d == jnp.arange(h)[:, None] // per_kv
+        queries = [jnp.where(own, jnp.tile(q[:, :, 0], (1, 1, hd // d)), 0)]
+        query_specs = [whole(s, h, hd)]
+        out_shape, stats = (s, h, d), [(h, hd), (h, 1), (h, 1)]
+
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(
-            _one_query_kernel, block_len=bl, mb=mb, group=group
+            _one_query_kernel, block_len=bl, mb=mb, group=group, per_kv=per_kv,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(1,),
-            in_specs=[
-                whole(s, 1, hd), whole(hd, h), whole(h, hd), in_hbm, in_hbm,
-            ],
-            out_specs=whole(s, 1, hd),
+            in_specs=[*query_specs, in_hbm, in_hbm],
+            out_specs=whole(*out_shape),
             scratch_shapes=[
                 pltpu.VMEM((2, group * bl, hd), k_pool.dtype),
                 pltpu.VMEM((2, group * bl, hd), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),       # (K or V, buffer)
-                pltpu.VMEM((1, hd), jnp.float32),      # acc
-                pltpu.VMEM((1, h), jnp.float32),       # m (running max)
-                pltpu.VMEM((1, h), jnp.float32),       # l (running sum)
-            ],
+                *(pltpu.VMEM(shape, jnp.float32) for shape in stats),
+            ],                                         # acc, m (max), l (sum)
         ),
-        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(
-        tables.reshape(-1).astype(jnp.int32), nlive, seq, chunk, n_items,
-        pos, jnp.moveaxis(q, 1, 2).reshape(s, 1, hd), fold, fold.T,
-        k_pool, v_pool,
-    )
-    return jnp.moveaxis(out.reshape(s, 1, h, d), 2, 1)
+    )(tflat, nlive, seq, chunk, n_items, pos, *queries, k_pool, v_pool)
+    if per_kv == 1:
+        return jnp.moveaxis(out.reshape(s, 1, h, d), 2, 1)
+    return out[:, :, None]
 
 
 #: pool positions one step of the latent kernel fetches and folds: four
@@ -499,10 +576,12 @@ def _latent_kernel(
     jax.lax.fori_loop(0, n, fold_item, 0)
 
 
-def latent_fusable(block_len: int, dtype) -> str | None:
-    """None if the latent kernel can serve this pool, else the reason it
-    cannot: it copies blocks by hand onto whole register tiles, so a
-    block is a multiple of the pool dtype's tile rows."""
+def one_query_fusable(block_len: int, dtype) -> str | None:
+    """None if the one-query kernels with no grid form beside them (the
+    latent kernel, and ``_one_query_kernel`` over K/V heads that query
+    heads share) can serve this pool, else the reason they cannot: they
+    copy blocks by hand onto whole register tiles, so a block is a
+    multiple of the pool dtype's tile rows."""
     if block_len % _sublanes(dtype):
         return (
             f"kv_block_len {block_len} is no multiple of "
@@ -605,10 +684,11 @@ def _sublanes(dtype) -> int:
 def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
     s, h, nq, d = q.shape
     _, bl, hd = k_pool.shape
-    if hd != h * d:
+    if hd % d or h % (hd // d):
         raise ValueError(
             f"pool rows are {hd} wide, the queries' {h} heads of {d} "
-            f"need {h * d}: the pool is (n_blocks, block_len, H * D)"
+            f"need a whole number of K/V heads that divides {h}: the pool "
+            f"is (n_blocks, block_len, Hkv * D)"
         )
     mb = tables.shape[1]
     if interpret is None:
@@ -619,6 +699,13 @@ def _call(q, k_pool, v_pool, tables, positions, chunk, interpret):
         # the decode tick: blocks copied by hand land on whole tiles
         return _call_one_query(
             q, k_pool, v_pool, tables, positions, bool(interpret)
+        )
+    if hd != h * d:
+        raise ValueError(
+            f"the grid form walks one K/V head a query head: {h} query "
+            f"heads over {hd // d} K/V heads are read by the one-query "
+            f"form alone (one query a sequence, no overlay, blocks of "
+            f"whole {jnp.dtype(k_pool.dtype).name} register tiles)"
         )
     if chunk is None:
         # write-then-read: blocks must cover every query position

@@ -89,9 +89,9 @@ above; ``fused`` swaps the Pallas paged-attention kernel
 table, no dense ``(S, H, cache_len, D)`` materialization per layer.
 Left unset (no ``kernels { paged_attention }`` in the model conf), the
 kernel runs where it compiles and knows the model — on a TPU, with no
-mesh, one K/V head a query head, one token a tick — and the gather
-path everywhere else; the scheduler's ``kernel_select`` event says
-which and why. Naming either in the conf pins it. The choice covers
+mesh, one token a tick (query heads over as many K/V heads or fewer) —
+and the gather path everywhere else; the scheduler's ``kernel_select``
+event says which and why. Naming either in the conf pins it. The choice covers
 the decode tick and the verify pass; a prefill chunk always takes the
 one-slot gather, where the kernel's many-query shape does not win.
 Fused output is allclose to the reference (online softmax reorders the
@@ -239,8 +239,9 @@ def choose_attend(cfg, serving, mesh, platform: str) -> str:
 
     A pinned ``serving.attend_impl`` is returned as it is. Unset, the
     kernel runs where it compiles through Mosaic and knows the model:
-    it walks one K/V head a query head, or a latent cache's one row a
-    token, and has no block step (``Engine._no_kernel``), GSPMD cannot
+    it reads one query a sequence over K/V heads that one query head or
+    several share, or over a latent cache's one row a token, and has no
+    block step (``Engine._no_kernel``), GSPMD cannot
     partition a Mosaic call (a mesh), and off a TPU it would run
     through the Pallas interpreter, a grid step at a time."""
     if serving.attend_impl is not None:
@@ -312,11 +313,11 @@ class Engine:
         #: model without such layers
         self.mamba_forms = self._mamba_forms(cfg, self.serving)
         if self._fused:
-            from ..ops.paged_attention import fusable, latent_fusable
+            from ..ops.paged_attention import fusable, one_query_fusable
 
             reason = fusable(self.serving.kv_block_len)
-            if reason is None and cfg.kv_latent:
-                reason = latent_fusable(
+            if reason is None and (cfg.kv_latent or cfg.gqa):
+                reason = one_query_fusable(
                     self.serving.kv_block_len, params["embed/tok"].dtype
                 )
             if reason is not None:
@@ -571,14 +572,13 @@ class Engine:
 
     @staticmethod
     def _no_kernel(cfg) -> str | None:
-        """The field (with its value) of a model that the paged kernels
-        (ops/paged_attention.py) do not know, None where one of them
-        serves the decode tick: one K/V head a query head, or a latent
-        cache."""
+        """The field (with its value) of a model whose decode tick the
+        paged kernels (ops/paged_attention.py) do not serve, None where
+        one of them does: one query a sequence over K/V heads that one
+        query head or several share, or over a latent cache. A block step is a
+        block of queries with the block laid over the pool."""
         if cfg.diffusion_block:
             return f"diffusion_block = {cfg.diffusion_block}"
-        if cfg.gqa:
-            return f"n_kv_heads = {cfg.n_kv_heads} != n_heads = {cfg.n_heads}"
         return None
 
     @staticmethod
@@ -593,6 +593,10 @@ class Engine:
             # content, rewound, or in the fleet's wire format
             kinds = sorted(set(cfg.layers))
             return f"layers = {len(cfg.layers)} one-mixer blocks of {kinds}"
+        if cfg.gqa and not cfg.diffusion_block:
+            # the prefix cache, the verify pass's overlay, the fleet's
+            # wire format and a TP mesh know one K/V head a query head
+            return f"n_kv_heads = {cfg.n_kv_heads} != n_heads = {cfg.n_heads}"
         return Engine._no_kernel(cfg)
 
     # ------------------------------------------------------------------

@@ -118,6 +118,30 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("s,h,hkv,d,bl,mb,dtype", [
+    (128, 32, 2, 128, 128, 38, jnp.bfloat16),  # nemotron_3_super_serve_chat
+    (64, 32, 4, 128, 16, 96, jnp.bfloat16),    # 8 a K/V head, blocks of 16
+    (8, 4, 2, 128, 16, 32, jnp.float32),       # 2 query heads a K/V head
+], ids=["hybrid_cell", "per_kv_8", "per_kv_2"])
+def test_one_query_kernel_over_fewer_kv_heads_compiles(
+    one_chip, s, h, hkv, d, bl, mb, dtype
+):
+    """The decode tick's form with query heads over fewer K/V heads
+    (scores and values as products over a block-diagonal query, each
+    head's own columns kept at the end) reaches Mosaic: at the hybrid
+    cell's shape, 16 query heads a K/V head and items of 8 blocks."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((s * mb + 1, bl, hkv * d), dtype)
+    text = _compiled_text(
+        lambda *a: paged_attention(*a, interpret=False),
+        sds((s, h, 1, d), dtype), pool, pool, sds((s, mb), jnp.int32),
+        sds((s, 1), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 def _serve_cell(one_chip):
     """The engine of ``gpt2_medium_serve_closed`` (32 slots, blocks of
     16, 128-token chunks; GPT-2 medium's width) cut to 2 layers, and
@@ -555,7 +579,26 @@ def test_hybrid_programs_relayout_no_state(hybrid_cell_on_tpu, program):
     for layout in re.findall(r"= bf16\[3,128,10240\](\{[^}]*\})\S* copy\(", text):
         assert layout.startswith("{2,1,0:"), layout
     assert not re.findall(r"= bf16\[128,3,10240\]\S* copy\(", text)
-    assert "tpu_custom_call" not in text or program == "prefill"
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_tick_reads_the_pool_in_place(hybrid_cell_on_tpu, program):
+    """PR 38: the engine picks the paged kernel for the attention layer's
+    32 query heads over 2 K/V heads, so the tick holds it and neither a
+    gathered view of a pool nor its relayout copy
+    ``bf16[128,4864,2,128]`` (2 x 1.29 ms a tick on the chip, PERF.md
+    §5, PR 37); the chunk keeps its one-slot gather."""
+    eng, texts = hybrid_cell_on_tpu
+    assert eng.attend_choice == "fused"
+    text = texts[program]
+    kernel = re.findall(
+        r'= [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text,
+    )
+    paged = [k for k in kernel if "paged_attention" in k]
+    assert len(paged) == (program == "decode"), kernel
+    assert "bf16[128,4864,2,128]" not in text
+    assert "bf16[128,4864,256]" not in text
 
 
 def test_hybrid_programs_take_their_forms(hybrid_cell_on_tpu):

@@ -72,12 +72,12 @@ def gaps(params, cfg, prompt, tokens):
     return rows.max(-1) - rows[np.arange(hi - lo), full[lo + 1:hi + 1]], rows
 
 
-def serve(params, mcfg, shapes, *, slots=3, chunk=16, seed=0):
+def serve(params, mcfg, shapes, *, slots=3, chunk=16, seed=0, **kw):
     """Requests of ``shapes`` (prompt length, tokens) through a
     scheduler: chunked prefill from a slot's state, then one step a
     tick."""
     engine = Engine(params, mcfg, EngineConfig(
-        slots=slots, kv_block_len=8, max_prefill_chunk=chunk
+        slots=slots, kv_block_len=8, max_prefill_chunk=chunk, **kw
     ))
     sched = Scheduler(engine)
     rng = np.random.default_rng(seed)
@@ -321,6 +321,26 @@ def test_chunked_prefill_then_ticks_against_the_reference(
     assert np.ptp(rows, axis=-1).min() > 0.5    # logits that could differ
 
 
+def test_the_paged_kernel_serves_the_same_streams(params):
+    """The decode tick's attention layer read in place by the paged
+    kernel (4 query heads over 2 K/V heads, through the interpreter)
+    serves every request token for token as the gather path does, over
+    ticks in which slots are admitted and retired: six requests on three
+    slots."""
+    def streams(impl):
+        sched, engine = serve(
+            params, MCFG, SHAPES, attend_impl=impl, interpret=True
+        )
+        assert engine.attend_choice == impl
+        return {r.rid: r.tokens for r in sched.finished}
+
+    fused = streams("fused")
+    assert fused == streams("reference")
+    assert [len(fused[i]) for i in range(len(SHAPES))] == [
+        m for _, m in SHAPES
+    ]
+
+
 def test_pools_for_the_attention_layer_alone_and_state_beside_them(served):
     _, engine = served
     assert engine.attend_choice.startswith("reference")
@@ -535,10 +555,13 @@ def test_the_chooser_on_the_cells_two_passes(n, form):
 def test_what_cannot_run_beside_recurrent_state_is_refused_by_name(params):
     for kw, what in (
         ({"spec_k": 2}, "speculate"), ({"prefix_cache": True}, "prefix_cache"),
-        ({"attend_impl": "fused"}, "kernels.paged_attention"),
     ):
         with pytest.raises(ValueError, match="layers = 5 one-mixer blocks"):
             Engine(params, MCFG, EngineConfig(kv_block_len=8, **kw))
+    # the paged kernel reads the attention layer's pools (PR 38)
+    assert Engine(params, MCFG, EngineConfig(
+        kv_block_len=8, attend_impl="fused",
+    )).attend_choice == "fused"
     engine = Engine(params, MCFG, EngineConfig(kv_block_len=8))
     for call in (
         lambda: engine.export_slot(0),

@@ -92,19 +92,22 @@ def stored(pool_arr):
 
 
 @pytest.mark.parametrize(
-    "block_len,head_dim,fill",
+    "block_len,head_dim,fill,per_kv",
     [
-        (4, 8, 3),     # partial first block
-        (8, 16, 17),   # mid-pool fill, blocks crossed
-        (8, 16, 31),   # cache full to the last position
-        (16, 32, 40),  # wide blocks, deeper pool
-        (2, 4, 9),     # tiny blocks: many grid steps
+        (4, 8, 3, 1),     # partial first block
+        (8, 16, 17, 1),   # mid-pool fill, blocks crossed
+        (8, 16, 31, 1),   # cache full to the last position
+        (16, 32, 40, 1),  # wide blocks, deeper pool
+        (2, 4, 9, 1),     # tiny blocks: many grid steps
+        (8, 16, 17, 2),   # query heads over fewer K/V heads: 2 a K/V head
+        (16, 32, 40, 8),  # 8 a K/V head
+        (8, 8, 63, 16),   # 16 a K/V head, the cache full
     ],
 )
-def test_kernel_matches_gather_oracle(block_len, head_dim, fill):
+def test_kernel_matches_gather_oracle(block_len, head_dim, fill, per_kv):
     """Write-then-read form == cache_attend over the dense gather,
-    across block_len / head_dim / cache-fill geometry (allclose: the
-    online softmax reorders the reduction)."""
+    across block_len / head_dim / cache-fill geometry and query heads a
+    K/V head (allclose: the online softmax reorders the reduction)."""
     rs = np.random.RandomState(fill)
     s, h, q = 3, 2, 1
     max_len = 64
@@ -112,7 +115,7 @@ def test_kernel_matches_gather_oracle(block_len, head_dim, fill):
     nb = s * mb + 1
     kp = jnp.asarray(rs.randn(nb, h, block_len, head_dim), jnp.float32)
     vp = jnp.asarray(rs.randn(nb, h, block_len, head_dim), jnp.float32)
-    qh = jnp.asarray(rs.randn(s, h, q, head_dim), jnp.float32)
+    qh = jnp.asarray(rs.randn(s, h * per_kv, q, head_dim), jnp.float32)
     # each sequence owns a disjoint table slice (1-based: 0 is trash)
     tables = jnp.asarray(
         1 + np.arange(s * mb).reshape(s, mb), jnp.int32
@@ -134,47 +137,96 @@ def test_kernel_matches_gather_oracle(block_len, head_dim, fill):
     )
 
 
+@pytest.mark.parametrize("per_kv", [1, 2, 8, 16])
 @pytest.mark.parametrize("chunk_positions", [16, 128, 4096])
-def test_one_query_kernel_walks_the_live_chunks(monkeypatch, chunk_positions):
+def test_one_query_kernel_walks_the_live_chunks(
+    monkeypatch, chunk_positions, per_kv
+):
     """The decode tick's form (one query a sequence, blocks copied by
     hand a chunk at a time over a flat list of live chunks) at
     sequences of very different lengths: one position, a chunk's last
-    and first, a block's edge, the whole table; a chunk of two blocks,
-    of sixteen, and wider than the table. Each against the oracle and
-    against the grid form, which the same call takes for a block
-    length off the register tile."""
+    and first, a block's edge, the whole table, and a dead lane (-1:
+    nothing to attend to, zeros out); a chunk of two blocks, of sixteen,
+    and wider than the table; one query head a K/V head and 2, 8 or 16.
+    Table entries past a sequence's live blocks are the trash block,
+    poisoned. Each against the oracle; one query head a K/V head also
+    against the grid form, which the same call takes for a block length
+    off the register tile and which refuses fewer K/V heads."""
     from singa_tpu.ops import paged_attention as pa
 
-    monkeypatch.setattr(pa, "_CHUNK_POSITIONS", chunk_positions)
-    rs = np.random.RandomState(chunk_positions)
-    h, d, bl, mb = 3, 8, 8, 48
-    positions = [0, 7, 8, 127, 128, 200, mb * bl - 1]
+    rs = np.random.RandomState(chunk_positions + per_kv)
+    hkv, d, bl, mb = 3, 8, 8, 48
+    h = hkv * per_kv
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", chunk_positions * hkv * d * 4)
+    positions = [0, 7, 8, 127, 128, 200, mb * bl - 1, -1]
     s = len(positions)
     nb = s * mb + 1
-    kp = jnp.asarray(rs.randn(nb, h, bl, d), jnp.float32)
-    vp = jnp.asarray(rs.randn(nb, h, bl, d), jnp.float32)
+    kp = rs.randn(nb, hkv, bl, d).astype(np.float32)
+    vp = rs.randn(nb, hkv, bl, d).astype(np.float32)
+    kp[0], vp[0] = 1e9, -1e9                 # the trash block
     qh = jnp.asarray(rs.randn(s, h, 1, d), jnp.float32)
-    tables = jnp.asarray(
-        1 + rs.permutation(s * mb).reshape(s, mb), jnp.int32
-    )
+    tables = 1 + rs.permutation(s * mb).reshape(s, mb)
+    for row, p in enumerate(positions):
+        tables[row, p // bl + 1:] = 0
+    tables = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)[:, None]
-    got = paged_attention(
-        qh, stored(kp), stored(vp), tables, pos, interpret=True
-    )
     want = cache_attend(
         qh, oracle_gather(kp, tables, mb * bl),
         oracle_gather(vp, tables, mb * bl), pos,
     )
+    kp, vp = stored(kp), stored(vp)
+    got = np.asarray(paged_attention(qh, kp, vp, tables, pos, interpret=True))
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5
+        got[:-1], np.asarray(want)[:-1], atol=1e-5, rtol=1e-5
     )
+    assert not got[-1].any()                 # the dead lane
     monkeypatch.setattr(pa, "_sublanes", lambda dtype: bl + 1)
-    grid = paged_attention(
-        qh, stored(kp), stored(vp), tables, pos, interpret=True
-    )
+    if per_kv > 1:
+        with pytest.raises(
+            ValueError, match=f"{h} query heads over {hkv} K/V heads"
+        ):
+            paged_attention(qh, kp, vp, tables, pos, interpret=True)
+        return
+    grid = paged_attention(qh, kp, vp, tables, pos, interpret=True)
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(grid), atol=1e-5, rtol=1e-5
+        got[:-1], np.asarray(grid)[:-1], atol=1e-5, rtol=1e-5
     )
+
+
+@pytest.mark.parametrize("row,dtype,positions", [
+    (16 * 64, jnp.float32, 128),     # gpt2_medium_serve_closed's pools
+    (2 * 128, jnp.bfloat16, 1024),   # nemotron_3_super_serve_chat's
+])
+def test_an_item_copies_half_a_megabyte(row, dtype, positions):
+    """Positions an item of the one-query kernel copies follow the
+    row's bytes: 128 at GPT-2 medium's 4 KB float32 rows, as before
+    they were sized by bytes, and 8 blocks of 128 of a 512 B row."""
+    from singa_tpu.ops.paged_attention import _item_positions
+
+    assert _item_positions(row, dtype) == positions
+
+
+@pytest.mark.parametrize("form", ["many_queries", "overlay"])
+def test_the_grid_form_refuses_fewer_kv_heads(form):
+    """The grid form (a sequence's several queries, the verify pass's
+    overlay; a block off the register tile is the walk's case above)
+    walks one K/V head a query head, and says so by both head counts
+    where it is handed fewer."""
+    rs = np.random.RandomState(2)
+    s, hkv, per_kv, d, bl, mb = 2, 2, 4, 8, 8, 4
+    nq = 3 if form == "many_queries" else 1
+    pool = jnp.asarray(rs.randn(s * mb + 1, bl, hkv * d), jnp.float32)
+    qh = jnp.asarray(rs.randn(s, hkv * per_kv, nq, d), jnp.float32)
+    tables = jnp.asarray(1 + np.arange(s * mb).reshape(s, mb), jnp.int32)
+    pos = jnp.broadcast_to(jnp.arange(nq, dtype=jnp.int32), (s, nq))
+    with pytest.raises(ValueError, match="8 query heads over 2 K/V heads"):
+        if form == "overlay":
+            paged_attention_overlay(
+                qh, pool, pool, tables, pos, qh, qh,
+                jnp.ones((s, nq), bool), interpret=True,
+            )
+        else:
+            paged_attention(qh, pool, pool, tables, pos, interpret=True)
 
 
 def test_trash_block_garbage_never_moves_the_output():
@@ -479,6 +531,16 @@ def test_engine_rejects_untileable_fused_geometry(lm):
         ))
     with pytest.raises(ValueError, match="reference"):
         Engine(params, cfg, EngineConfig(slots=2, attend_impl="fusedx"))
+    # fewer K/V heads have the one-query form alone, which copies whole
+    # register tiles: 8 rows of bfloat16 are half a tile
+    gqa = tiny_cfg(n_heads=4, n_kv_heads=2)
+    half = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), init_lm(jax.random.PRNGKey(0), gqa)
+    )
+    with pytest.raises(ValueError, match="no multiple of 16 rows"):
+        Engine(half, gqa, EngineConfig(
+            slots=2, kv_block_len=8, attend_impl="fused",
+        ))
 
 
 # ---------------------------------------------------------------------------
@@ -499,13 +561,25 @@ def sdar_shaped_cfg(**kw):
     return TransformerConfig(**base)
 
 
+def nemotron_shaped_cfg():
+    """One-mixer layers: ONE attention layer of 4 query heads over 2 K/V
+    heads with no positions, an expert layer, a Mamba-2 layer."""
+    return TransformerConfig(
+        vocab=40, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        n_layers=3, layers=("attn", "moe", "mamba"), d_ff=16, max_len=32,
+        norm="rmsnorm", pos="none", mlp="relu2", tied_head=False,
+        mamba_heads=4, mamba_head_dim=8, ssm_state=8, ssm_groups=2,
+        moe_experts=4, moe_top_k=2, moe_d_ff=16, moe_act="relu2",
+    )
+
+
 @pytest.mark.parametrize("cfg,serving,mesh,platform,choice", [
     (tiny_cfg(), {}, None, "tpu", "fused"),
     (tiny_cfg(), {}, None, "cpu", "reference: platform = cpu"),
     (tiny_cfg(), {}, None, "gpu", "reference: platform = gpu"),
     (tiny_cfg(), {}, "a mesh", "tpu", "reference: a tensor-parallel mesh"),
-    (tiny_cfg(n_heads=4, n_kv_heads=2), {}, None, "tpu",
-     "reference: n_kv_heads = 2 != n_heads = 4"),
+    (tiny_cfg(n_heads=4, n_kv_heads=2), {}, None, "tpu", "fused"),
+    (nemotron_shaped_cfg(), {}, None, "tpu", "fused"),
     (sdar_shaped_cfg(n_kv_heads=4), {}, None, "tpu",
      "reference: diffusion_block = 4"),
     (sdar_shaped_cfg(), {}, "a mesh", "tpu",
@@ -516,8 +590,8 @@ def sdar_shaped_cfg(**kw):
     (tiny_cfg(), {"attend_impl": "fused"}, "a mesh", "cpu", "fused"),
 ], ids=[
     "tpu_mha_no_mesh", "cpu", "gpu", "mesh", "fewer_kv_heads",
-    "diffusion_block", "the_model_is_named_first", "pinned_reference",
-    "pinned_fused", "pinned_fused_under_a_mesh",
+    "one_mixer_layers", "diffusion_block", "the_model_is_named_first",
+    "pinned_reference", "pinned_fused", "pinned_fused_under_a_mesh",
 ])
 def test_choose_attend(cfg, serving, mesh, platform, choice):
     """The kernel where it compiles and knows the model, the gather
