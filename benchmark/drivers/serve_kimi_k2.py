@@ -115,6 +115,15 @@ class Driver(serve.Driver):
     #: True while a prefill chunk's tokens are being counted
     _in_chunk = False
 
+    def weights_seed(self) -> int:
+        """The seed the weights are drawn from: the traffic file's
+        ``weights_seed`` where it names one, else the run's. The weights
+        decide how many of a chunk's token-expert pairs fall to the held
+        experts, and so how long a chunk and a tick take: drawn from the
+        run's seed they made the work differ from seed to seed, so a
+        traffic file can fix them and leave the seed the prompts."""
+        return int(self.traffic.get("weights_seed", self.seed))
+
     def make_engine(self) -> None:
         import jax.numpy as jnp
 
@@ -123,7 +132,7 @@ class Driver(serve.Driver):
         t = self.traffic
         self.mcfg = model_config(self.config, t)
         params = weights.make(
-            self.reference_specs(), self.seed,
+            self.reference_specs(), self.weights_seed(),
             jnp.dtype(self.config["torch_dtype"]),
         )
         self.engine = Engine(params, self.mcfg, EngineConfig(
@@ -233,7 +242,7 @@ class Driver(serve.Driver):
         import jax.numpy as jnp
 
         params = weights.make(
-            self.reference_specs(), self.seed,
+            self.reference_specs(), self.weights_seed(),
             jnp.dtype(self.config["torch_dtype"]),
         )
         size = self.mcfg.max_len

@@ -4,7 +4,8 @@ tick spent in attention.
 
 Bytes (``bytes_a_tick``): the live slots' cached positions, the one each
 writes in the tick among them (the program's ``cache_rows`` counter,
-summed over the passes read, over those passes), times one latent — the
+summed over the passes that the traced window read, over those passes:
+``run["traced_counters"]``), times one latent — the
 K/V latent and the rotary key, ``kv_lora_rank + qk_rope_head_dim``
 values in the pool's type — in every layer. A lower bound whatever
 attends: a row within a live query's reach must be read once a layer,
@@ -28,7 +29,7 @@ def bytes_a_tick(config: dict, rows_a_tick: float) -> float:
 
 
 def read(run):
-    c = run["counters"]
+    c = run.get("traced_counters") or {}
     ms = program_trace.ms_under_a_run(
         program_trace.of_run(run), "attend", "jit__decode"
     )

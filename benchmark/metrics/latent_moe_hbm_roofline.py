@@ -3,8 +3,9 @@ decode tick: the bytes they had to read a tick over the time they took.
 
 Bytes (``hbm_nemotron_h.latent_moe_bytes_a_tick``): the held experts
 that at least one live token was routed to (the program's
-``experts_hit`` counter, summed over the passes read and the expert
-layers, over those passes) times an expert's TWO matrices of latent x
+``experts_hit`` counter, summed over the passes that the traced window
+read and over the expert layers, over those passes:
+``run["traced_counters"]``) times an expert's TWO matrices of latent x
 width, plus in every expert layer the shared expert, the router and the
 two latent projections: a lower bound whatever computes the layer, so
 the share cannot pass 100 %. Time: ``moe_ms_per_tick``'s (device time
@@ -15,7 +16,7 @@ from benchmark import hbm_nemotron_h, program_trace
 
 
 def read(run):
-    c = run["counters"]
+    c = run.get("traced_counters") or {}
     ms = program_trace.ms_under_a_run(
         program_trace.of_run(run), "moe", "jit__decode"
     )

@@ -2,8 +2,10 @@
 step: the bytes they HAD to read a pass over the time they took.
 
 Bytes: per layer, the experts that at least one live token was routed to
-(the program's ``experts_hit`` counter, summed over passes and layers,
-over the passes counted) times an expert's three matrices (3 x hidden x
+(the program's ``experts_hit`` counter, summed over the passes that the
+traced window read and over the layers, over those passes:
+``run["traced_counters"]``, so that bytes and time cover the same
+passes) times an expert's three matrices (3 x hidden x
 expert width, in the weights' type), plus the router's. That is a lower
 bound on what any implementation of the layer reads — an expert no token
 chose need not be touched, one that a token chose must be read whole —
@@ -52,7 +54,7 @@ def bytes_a_pass(config: dict, experts_hit_a_layer: float) -> float:
 
 
 def read(run):
-    c = run["counters"]
+    c = run.get("traced_counters") or {}
     ms = program_trace.ms_under_a_run(
         program_trace.of_run(run), "moe", "jit__block_step"
     )

@@ -4,7 +4,9 @@ read a tick over the time they took.
 
 Bytes (``bytes_a_tick``): the held experts that at least one live token
 was routed to (the program's ``experts_hit`` counter, summed over the
-passes read and the expert layers, over those passes) times an expert's
+passes that the traced window read and over the expert layers, over
+those passes: ``run["traced_counters"]``, so that bytes and time cover
+the same ticks) times an expert's
 three matrices, plus in every expert layer the shared expert's three
 and the router's one, in the weights' type. A lower bound on what any
 implementation reads — a held expert that no token chose need not be
@@ -33,7 +35,7 @@ def bytes_a_tick(config: dict, experts_hit_a_tick: float) -> float:
 
 
 def read(run):
-    c = run["counters"]
+    c = run.get("traced_counters") or {}
     ms = program_trace.ms_under_a_run(
         program_trace.of_run(run), "moe", "jit__decode"
     )

@@ -164,6 +164,23 @@ class _Span:
         self.spans.rows.append((self.name, self.t0, t1, self.attrs))
 
 
+def counters_now(driver) -> dict | None:
+    """The scheduler's own counters as they stand: every whole number it
+    keeps (``decode_ticks``, ``block_passes``, ``experts_hit``,
+    ``cache_rows``, ``state_slots_live``, ``held_pairs``, ...), and no
+    statistic derived from them. None for a driver with no scheduler
+    (training). Read when the traced window closes, so that a reader
+    divides what the traced ticks did by the time they took on the
+    device (``run["traced_counters"]``)."""
+    sched = getattr(driver, "sched", None)
+    if sched is None:
+        return None
+    return {
+        k: v for k, v in vars(sched).items()
+        if type(v) is int and not k.startswith("_")
+    }
+
+
 def memory_peak_bytes(devices) -> int:
     """Peak on the fullest chip: the runtime's peak of live buffers
     plus its peak of memory reserved for running programs' temporaries
@@ -221,7 +238,7 @@ def run(argv=None) -> dict:
     setup_s = time.perf_counter() - T_START
     spans.rows.clear()  # per-layer metrics read the window's spans only
 
-    trace_summary = None
+    trace_summary = traced_counters = None
     with CacheCounter() as window_cache:
         if args.trace:
             from benchmark import trace_reduce
@@ -234,6 +251,7 @@ def run(argv=None) -> dict:
             t0 = time.perf_counter()
             driver.window(traced)
             window_s = time.perf_counter() - t0
+            traced_counters = counters_now(driver)
             jax.profiler.stop_trace()
             if args.seconds - traced > 0.5:
                 driver.window(args.seconds - traced)
@@ -263,6 +281,7 @@ def run(argv=None) -> dict:
     kind = "per_layer" if args.trace else "end_to_end"
     run_view = {
         "spans": spans, "counters": counters, "trace": trace_summary,
+        "traced_counters": traced_counters,
         "end_to_end": end_to_end, "config": config, "traffic": traffic,
         "device_kind": devices[0].device_kind, "chips": len(devices),
         "driver": driver,

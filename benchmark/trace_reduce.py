@@ -23,9 +23,10 @@ import glob
 import os
 
 #: operations that only contain others (a scan's ``while`` holds every
-#: step's operations as events of their own): counting them would hide
-#: the bubbles between their children and double every second
-CONTAINERS = ("while", "conditional", "call")
+#: step's operations as events of their own; a ``lax.cond`` runs as
+#: ``cond.N.clone``): counting them would hide the bubbles between their
+#: children and double every second
+CONTAINERS = ("while", "conditional", "cond", "call")
 
 #: lines of a device plane that summarize others and would double count
 SUMMARY_LINES = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",

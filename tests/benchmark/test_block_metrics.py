@@ -255,6 +255,10 @@ def view(trace, counters, config):
     return {
         "spans": harness.Spans(False), "chips": 1,
         "device_kind": "TPU v5 lite", "end_to_end": {}, "counters": counters,
+        # the traced window read the whole run's counters here (PR 39:
+        # the rooflines read ``traced_counters``; test_traced_counters.py
+        # holds runs in which the two differ)
+        "traced_counters": counters if trace else None,
         "trace": {"busy_s": 1.0, "window_s": 1.0} if trace else None,
         "driver": FakeDriver(), "config": config, "traffic": {},
     }

@@ -160,6 +160,27 @@ def test_traffic_is_the_issues(config, traffic):
     assert all(r["prompt"].max() < 20480 for r in reqs)
 
 
+def test_every_seed_serves_one_draw_of_weights(traffic):
+    """The traffic file fixes the weights (they set the held experts'
+    share of the work, PERF.md section 6, PR 39) and ``--seed`` draws the
+    prompts; a traffic file without ``weights_seed`` leaves both to it."""
+    from types import SimpleNamespace
+
+    from benchmark import traffic as gen
+
+    fixed = traffic["weights_seed"]
+    assert isinstance(fixed, int) and traffic["weights_seed_why"]
+    pick = serve_kimi_k2.Driver.weights_seed
+    for seed in (1, 2**31 + 5, 3900000111):
+        assert pick(SimpleNamespace(traffic=traffic, seed=seed)) == fixed
+        assert pick(SimpleNamespace(traffic={}, seed=seed)) == seed
+    a, b = (
+        gen.requests(traffic | {"pool": 2}, 20480, s)[0]["prompt"]
+        for s in (3900000111, 3900000112)
+    )
+    assert len(a) == len(b) and (a != b).any()
+
+
 # -- the readers --------------------------------------------------------
 
 D = "jit(_decode)"
@@ -199,6 +220,10 @@ def view(trace, counters, config):
     return {
         "spans": harness.Spans(False), "chips": 1,
         "device_kind": "TPU v5 lite", "end_to_end": {}, "counters": counters,
+        # the traced window read the whole run's counters here (PR 39:
+        # the rooflines read ``traced_counters``; test_traced_counters.py
+        # holds runs in which the two differ)
+        "traced_counters": counters if trace else None,
         "trace": {"busy_s": 1.0, "window_s": 1.0} if trace else None,
         "driver": FakeDriver(), "config": config, "traffic": {},
     }

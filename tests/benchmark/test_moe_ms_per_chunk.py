@@ -2,9 +2,10 @@
 inside a run of ``jit__prefill``, on a hand-made run (the dense form's
 operations and the grouped form's, whose kernels sit under a
 ``while/body/.../cond`` inside ``moe``), on the cut recorded on the chip
-for ``kimi_k2_serve_long`` and where there is nothing to read (None,
+for ``kimi_k2_serve_long``, where there is nothing to read (None,
 never 0: the parent of a PR that names the scope otherwise, a run with
-no trace)."""
+no trace), and its entry in BENCHMARK.json, found by name (PR 39: a
+later PR appends its own entries after it)."""
 
 from __future__ import annotations
 
@@ -77,19 +78,42 @@ def test_on_a_hand_made_run_in_both_forms():
     assert read(view(TRACE)) == pytest.approx((dense + grouped) / 2 / 1e6)
 
 
-def test_it_is_declared_for_the_cell_it_reads():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry == {
+def assert_declared(bench: dict) -> None:
+    """``moe_ms_per_chunk``'s entry, found by its name wherever it
+    stands in ``per_layer``: its fields, the two cells whose chunks run
+    expert layers (K since PR 35, N since PR 39), and not SDAR's, whose
+    block steps are not chunks of this kind."""
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == NAME]
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "expert layer",
-        "moves": "serve_tokens_per_s", "workloads": [CELL],
+        "moves": "serve_tokens_per_s",
     }
+    assert {CELL, "nemotron_3_super_serve_chat"} <= set(entry["workloads"])
+    assert "sdar_30b_a3b_serve_blocks" not in entry["workloads"]
     names = [m["name"] for m in harness.metrics_of(bench, "per_layer", CELL)]
     assert NAME in names and "moe_ms_per_tick" in names
     other = harness.metrics_of(bench, "per_layer", "sdar_30b_a3b_serve_blocks")
     assert NAME not in [m["name"] for m in other]
+
+
+#: what a later PR appends: one more per-layer entry at the list's end
+TOY = {"name": "toy_ms_per_tick", "unit": "ms", "better": "lower",
+       "source": "device_trace", "layer": "expert layer",
+       "moves": "serve_tokens_per_s", "workloads": [CELL]}
+
+
+@pytest.mark.parametrize("appended", [False, True], ids=["as_shipped", "one_more_entry"])
+def test_it_is_declared_for_the_cell_it_reads(appended):
+    """The declaration holds on BENCHMARK.json as it is and on a copy
+    with one more per-layer entry appended: no place in the list is
+    pinned, so a later PR can append."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    if appended:
+        bench["per_layer"].append(TOY)
+        assert bench["per_layer"][-1]["name"] != NAME
+    assert_declared(bench)
 
 
 @pytest.mark.parametrize("trace", [

@@ -30,13 +30,17 @@ from benchmark import hbm_nemotron_h  # noqa: E402
 from benchmark import run as harness  # noqa: E402
 from benchmark.drivers import serve_nemotron_h  # noqa: E402
 
-#: the readers this PR brings under ``benchmark/metrics/``. They wait
-#: for their ``per_layer`` entries: a new entry goes to the list's end,
-#: and ``test_moe_ms_per_chunk.py`` (a file the benchmark has, so not
-#: this PR's to edit) pins the list's last entry and that metric's
-#: cells (PERF.md section 7)
-NEW = ("mamba_ms_per_tick", "mamba_ms_per_chunk", "ssm_state_hbm_roofline",
-       "latent_moe_hbm_roofline", "state_slots_live")
+#: the readers PR 37 brought under ``benchmark/metrics/``, with the
+#: entries PR 39 appended for them: (unit, better, source, layer)
+NEW = {
+    "mamba_ms_per_tick": ("ms", "lower", "device_trace", "state-space layer"),
+    "mamba_ms_per_chunk": ("ms", "lower", "device_trace", "state-space layer"),
+    "ssm_state_hbm_roofline": ("%", "higher", "device_trace", "state-space layer"),
+    "latent_moe_hbm_roofline": ("%", "higher", "device_trace", "expert layer"),
+    "state_slots_live": ("slots", "higher", "program_counter", "scheduler"),
+}
+#: the accepted metrics whose ``workloads`` PR 39 appended the cell to
+JOINED_39 = ("moe_ms_per_chunk", "paged_attention_ms_per_tick")
 #: the accepted metrics whose ``workloads`` gained the cell
 JOINED = ("serve_tokens_per_s", "serve_itl_p95_ms", "step_mfu.serve",
           "device_idle_share.serve", "decode_tick_ms", "prefill_chunk_ms",
@@ -140,12 +144,20 @@ def test_configuration_is_the_published_one_but_for_the_cut(config):
     by_name = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in JOINED:
         assert CELL in by_name[name]["workloads"], name
-    # the gather path has no paged kernel to time, that share's bytes
-    # are another model's, and the third's list is pinned (see ``NEW``)
-    for name in ("paged_attention_ms_per_tick", "moe_share_hbm_roofline",
-                 "moe_ms_per_chunk"):
-        assert CELL not in by_name[name]["workloads"], name
-    assert not set(NEW) & set(by_name)
+    # that share's bytes are three matrices an expert and a leading dense
+    # layer: another model's (``latent_moe_hbm_roofline`` counts this
+    # one's)
+    assert CELL not in by_name["moe_share_hbm_roofline"]["workloads"]
+    # PR 39: the tick's paged kernel (PR 38) and the chunk's expert
+    # layers, and the five entries of PR 37's readers, appended
+    for name in JOINED_39:
+        assert CELL in by_name[name]["workloads"], name
+    for name, (unit, better, source, layer) in NEW.items():
+        assert by_name[name] == {
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": layer, "moves": "serve_tokens_per_s",
+            "workloads": [CELL],
+        }, name
 
 
 def test_the_cut_and_its_arithmetic_by_hand(config, traffic):
@@ -280,6 +292,10 @@ def view(trace, counters, config):
     return {
         "spans": harness.Spans(False), "chips": 1,
         "device_kind": "TPU v5 lite", "end_to_end": {}, "counters": counters,
+        # the traced window read the whole run's counters here (PR 39:
+        # the rooflines read ``traced_counters``; test_traced_counters.py
+        # holds runs in which the two differ)
+        "traced_counters": counters if trace else None,
         "trace": {"busy_s": 1.0, "window_s": 1.0} if trace else None,
         "driver": FakeDriver(), "config": config, "traffic": {},
     }
@@ -357,8 +373,8 @@ def test_new_readers_return_nothing_where_there_is_nothing(config):
 
 def test_new_readers_on_the_cut_recorded_on_the_chip(config):
     """The cut of a ``--trace 1`` run of the cell on a v5e (PERF.md, PR
-    37): runs of ``jit__decode`` and ``jit__prefill`` with ``mamba``,
-    ``moe`` and ``attend`` inside."""
+    39, from PR 38's program): runs of ``jit__decode`` and
+    ``jit__prefill`` with ``mamba``, ``moe`` and ``attend`` inside."""
     from benchmark import program_trace
 
     cut = load(HERE, "data", f"scopes_{CELL}.json")
@@ -384,6 +400,17 @@ def test_new_readers_on_the_cut_recorded_on_the_chip(config):
     paths = {op[3] for dev in cut["devices"] for op in dev["ops"]}
     assert any("/moe/latent_down" in p for p in paths)
     assert any("/moe/latent_up" in p for p in paths)
+
+
+@pytest.mark.parametrize("name", list(NEW) + list(JOINED_39))
+def test_each_metric_listed_by_pr39_reads_the_cut(config, name):
+    """Each metric whose ``workloads`` gained the cell in PR 39 reads a
+    number from the cut: ``paged_attention_ms_per_tick`` the tick's one
+    Mosaic call (PR 38), ``moe_ms_per_chunk`` the chunk's grouped
+    experts."""
+    cut = load(HERE, "data", f"scopes_{CELL}.json")
+    got = harness.load_reader(name)(view(cut, COUNTERS, config))
+    assert got is not None and got > 0, name
 
 
 # -- the controls ---------------------------------------------------------
