@@ -50,7 +50,12 @@ All programs run the SAME ``_block_apply``/``cache_attend``/``lm_head``
 body as models/transformer.generate — paged-vs-dense parity AND
 speculative-vs-sequential parity are shared code, not a tolerance.
 Admission-path work (table updates, first-token sampling) is small
-host-driven device ops, off the decode hot path.
+host-driven device ops, off the decode hot path. Each such hand-over is
+an ``obs.span`` on the profiler's clock — ``engine.admit``,
+``engine.prefill`` (a chunk until its program is dispatched),
+``engine.activate``, ``engine.retire`` — so a trace names the device
+idle it leaves; a pass (decode, block step, verify) is timed by the
+scheduler's ``sched.dispatch`` and has no span here.
 
 Sampling is a per-slot TEMPERATURE LANE: a (slots,) array + masked
 categorical, so mixed sampling configs (greedy and temperature slots
@@ -121,6 +126,7 @@ from ..models.transformer import (
     latent_lift,
     lm_head,
 )
+from ..obs import span
 from .kv_pool import BlockAllocator, KVPool, PoolExhausted
 
 
@@ -1382,80 +1388,81 @@ class Engine:
         identical left context, so they are bitwise what this
         sequence's own cold prefill would write."""
         needed = self.pool.blocks_for(n_total_tokens)
-        alloc = self.allocator
-        hit: list[int] = []
-        chain: list[bytes] = []
-        if alloc.cache is not None and prompt is not None:
-            # ONE digest pass per admission: the same chain serves the
-            # match here and register_prefix() after prefill completes
-            chain = alloc.cache.chain(prompt)
-            hit = alloc.cache.match_chain(chain)
-        cached = len(hit) * self.pool.block_len
-        cow = bool(hit) and cached >= len(prompt)
-        tail_src = tail_tokens = 0
-        if (
-            not cow
-            and alloc.cache is not None
-            and prompt is not None
-            and alloc.cache.tail_stride
-        ):
-            tail_src, tail_tokens = alloc.cache.match_tail(
-                prompt, len(hit), chain
+        with span("engine.admit", slot=slot, blocks=needed):
+            alloc = self.allocator
+            hit: list[int] = []
+            chain: list[bytes] = []
+            if alloc.cache is not None and prompt is not None:
+                # ONE digest pass per admission: the same chain serves the
+                # match here and register_prefix() after prefill completes
+                chain = alloc.cache.chain(prompt)
+                hit = alloc.cache.match_chain(chain)
+            cached = len(hit) * self.pool.block_len
+            cow = bool(hit) and cached >= len(prompt)
+            tail_src = tail_tokens = 0
+            if (
+                not cow
+                and alloc.cache is not None
+                and prompt is not None
+                and alloc.cache.tail_stride
+            ):
+                tail_src, tail_tokens = alloc.cache.match_tail(
+                    prompt, len(hit), chain
+                )
+                cached += tail_tokens
+            fresh_n = needed - len(hit) + (1 if cow else 0)
+            protect = hit + ([tail_src] if tail_tokens else [])
+            if fresh_n > alloc.headroom_excluding(protect):
+                raise PoolExhausted(
+                    f"need {fresh_n} fresh blocks beyond a {len(hit)}-block "
+                    f"prefix hit, {alloc.headroom_excluding(protect)} "
+                    "allocatable"
+                )
+            if hit:
+                alloc.retain(hit)
+            if tail_tokens:
+                # pin the tail source across alloc(): a fresh allocation may
+                # otherwise LRU-reclaim the very block we are about to copy
+                alloc.retain([tail_src])
+            fresh = alloc.alloc(fresh_n)
+            if cow:
+                # the whole prompt is cached: COW the last matched block so
+                # the re-derivation chunk can write without touching the
+                # shared source, then drop our extra reference to it
+                src, dst = hit[-1], fresh[0]
+                blocks = hit[:-1] + [dst] + fresh[1:]
+                self.state = self._cow_jit(
+                    self.state, jnp.int32(src), jnp.int32(dst)
+                )
+                alloc.release([src])
+            elif tail_tokens:
+                # partial-tail hit: copy the matched tail block into this
+                # sequence's own block at the next chain position; bytes
+                # beyond the covered tokens are re-prefilled or causally
+                # masked, so only the covered prefix is ever observed
+                blocks = hit + fresh
+                self.state = self._cow_jit(
+                    self.state, jnp.int32(tail_src), jnp.int32(fresh[0])
+                )
+                alloc.release([tail_src])
+            else:
+                blocks = hit + fresh
+            row = np.zeros((self.pool.max_blocks_per_seq,), np.int32)
+            row[: len(blocks)] = blocks
+            self.state = self._admit_jit(
+                self.state, jnp.int32(slot), jnp.asarray(row)
             )
-            cached += tail_tokens
-        fresh_n = needed - len(hit) + (1 if cow else 0)
-        protect = hit + ([tail_src] if tail_tokens else [])
-        if fresh_n > alloc.headroom_excluding(protect):
-            raise PoolExhausted(
-                f"need {fresh_n} fresh blocks beyond a {len(hit)}-block "
-                f"prefix hit, {alloc.headroom_excluding(protect)} "
-                "allocatable"
+            self._slot_blocks[slot] = blocks
+            self._slot_chain[slot] = chain
+            self._slot_version[slot] = self.params_version
+            return Admission(
+                blocks=blocks,
+                cached_tokens=cached,
+                prefill_from=min(cached, max(len(prompt), 1) - 1)
+                if prompt is not None else 0,
+                cow_copied=cow,
+                tail_tokens=tail_tokens,
             )
-        if hit:
-            alloc.retain(hit)
-        if tail_tokens:
-            # pin the tail source across alloc(): a fresh allocation may
-            # otherwise LRU-reclaim the very block we are about to copy
-            alloc.retain([tail_src])
-        fresh = alloc.alloc(fresh_n)
-        if cow:
-            # the whole prompt is cached: COW the last matched block so
-            # the re-derivation chunk can write without touching the
-            # shared source, then drop our extra reference to it
-            src, dst = hit[-1], fresh[0]
-            blocks = hit[:-1] + [dst] + fresh[1:]
-            self.state = self._cow_jit(
-                self.state, jnp.int32(src), jnp.int32(dst)
-            )
-            alloc.release([src])
-        elif tail_tokens:
-            # partial-tail hit: copy the matched tail block into this
-            # sequence's own block at the next chain position; bytes
-            # beyond the covered tokens are re-prefilled or causally
-            # masked, so only the covered prefix is ever observed
-            blocks = hit + fresh
-            self.state = self._cow_jit(
-                self.state, jnp.int32(tail_src), jnp.int32(fresh[0])
-            )
-            alloc.release([tail_src])
-        else:
-            blocks = hit + fresh
-        row = np.zeros((self.pool.max_blocks_per_seq,), np.int32)
-        row[: len(blocks)] = blocks
-        self.state = self._admit_jit(
-            self.state, jnp.int32(slot), jnp.asarray(row)
-        )
-        self._slot_blocks[slot] = blocks
-        self._slot_chain[slot] = chain
-        self._slot_version[slot] = self.params_version
-        return Admission(
-            blocks=blocks,
-            cached_tokens=cached,
-            prefill_from=min(cached, max(len(prompt), 1) - 1)
-            if prompt is not None else 0,
-            cow_copied=cow,
-            tail_tokens=tail_tokens,
-        )
 
     def register_prefix(self, slot: int, prompt) -> int:
         """Index ``slot``'s fully-prompt-covered blocks by their chained
@@ -1539,12 +1546,13 @@ class Engine:
         n = len(tokens)
         if n > c:
             raise ValueError(f"prefill chunk {n} > max_prefill_chunk {c}")
-        buf = np.zeros((c,), np.int32)
-        buf[:n] = tokens
-        self.state, last = self._prefill_jit(
-            self.params, self.state, jnp.int32(slot), jnp.asarray(buf),
-            jnp.int32(pos0), jnp.int32(n),
-        )
+        with span("engine.prefill", slot=slot, tokens=n, pos0=pos0):
+            buf = np.zeros((c,), np.int32)
+            buf[:n] = tokens
+            self.state, last = self._prefill_jit(
+                self.params, self.state, jnp.int32(slot), jnp.asarray(buf),
+                jnp.int32(pos0), jnp.int32(n),
+            )
         return last
 
     def activate(self, slot: int, last_logits, plen: int, seed: int,
@@ -1557,10 +1565,11 @@ class Engine:
         caller can dispatch its decode before it waits for the chunk
         (``int()`` of it is that wait)."""
         temp = self.temperature if temperature is None else float(temperature)
-        self.state, first = self._activate_jit(
-            self.state, jnp.int32(slot), last_logits,
-            jnp.int32(plen), jnp.int32(seed), jnp.float32(temp),
-        )
+        with span("engine.activate", slot=slot):
+            self.state, first = self._activate_jit(
+                self.state, jnp.int32(slot), last_logits,
+                jnp.int32(plen), jnp.int32(seed), jnp.float32(temp),
+            )
         return first
 
     def activate_block(self, slot: int, prompt) -> None:
@@ -1569,12 +1578,13 @@ class Engine:
         prompt's tail starts its first block, the rest of it masked."""
         b = self.cfg.diffusion_block
         n_tail = len(prompt) % b
-        tail = np.zeros((b,), np.int32)
-        tail[:n_tail] = prompt[len(prompt) - n_tail:]
-        self.state = self._activate_block_jit(
-            self.state, jnp.int32(slot), jnp.int32(len(prompt) - n_tail),
-            jnp.asarray(tail), jnp.int32(n_tail),
-        )
+        with span("engine.activate", slot=slot):
+            tail = np.zeros((b,), np.int32)
+            tail[:n_tail] = prompt[len(prompt) - n_tail:]
+            self.state = self._activate_block_jit(
+                self.state, jnp.int32(slot), jnp.int32(len(prompt) - n_tail),
+                jnp.asarray(tail), jnp.int32(n_tail),
+            )
 
     def block_step(self):
         """One pass over every live slot's current block (a model with a
@@ -1795,12 +1805,13 @@ class Engine:
         blocks park on the LRU list, the rest return to the free list
         as reusable garbage, masked wherever gathered) and kill its
         lane."""
-        self.state = self._retire_jit(self.state, jnp.int32(slot))
-        self._slot_chain.pop(slot, None)
-        self._slot_version.pop(slot, None)
-        blocks = self._slot_blocks.pop(slot, None)
-        if blocks:
-            self.allocator.release(blocks)
+        with span("engine.retire", slot=slot):
+            self.state = self._retire_jit(self.state, jnp.int32(slot))
+            self._slot_chain.pop(slot, None)
+            self._slot_version.pop(slot, None)
+            blocks = self._slot_blocks.pop(slot, None)
+            if blocks:
+                self.allocator.release(blocks)
 
     # ------------------------------------------------------------------
     # live weight rollout (serve/rollout.py): dual-version param slots

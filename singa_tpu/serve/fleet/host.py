@@ -512,6 +512,8 @@ class FleetHost:
             req.status = "decoding"
             req.slot = slot
             req.tokens = list(mseq.emitted)
+            # what the tokens made elsewhere waited behind is not known
+            req.chunks_ahead = [0] * len(req.tokens)
             req._prefilled = len(req.prompt)
             # queue-inclusive latency survives migration inside one
             # clock domain; a cross-host import re-stamps at arrival

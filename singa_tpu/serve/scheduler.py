@@ -70,7 +70,12 @@ inside it, ``admit`` / ``prefill`` / ``decode`` holding ``draft`` /
 scheduler's work beside the device's operations. Each carries
 ``tick=``; the spans of one request carry its ``rid=``. ``sched.pull``
 is the one place a tick waits for the device, and the one place it
-reads from it: after the tick's ``sched.dispatch``.
+reads from it: after the tick's ``sched.dispatch``. The engine's calls
+that hand work over name themselves inside those (``engine.admit`` in
+``admit``, ``engine.prefill`` and ``engine.activate`` in ``prefill``,
+``engine.retire`` where a request finishes); the pass's own dispatch is
+``sched.dispatch``. ``Request.chunks_ahead`` counts, for each token, the
+prefill chunks its gap waited behind.
 """
 
 from __future__ import annotations
@@ -106,11 +111,22 @@ class Request:
     #: block steps only: per delivered token, which denoising pass of
     #: its block (0-based) fixed it
     unmask_pass: list = dataclasses.field(default_factory=list)
+    #: per delivered token, the prefill chunks dispatched between the
+    #: pass that delivered the token before it and the pass that
+    #: delivered it, that pass's own tick's included: the chunks its gap
+    #: waited behind. 0 for the first token, and for each token after
+    #: the first that one pass delivers (block steps, speculation)
+    chunks_ahead: list = dataclasses.field(default_factory=list)
     enqueue_mono: float = 0.0
     admit_mono: float = 0.0
     first_token_mono: float = 0.0
     finish_mono: float = 0.0
     _prefilled: int = 0
+    #: the scheduler's count of chunks sent (``Scheduler._chunks_sent``)
+    #: when the pass that delivered its last token went out (a first
+    #: token: when its tick read it); None before this scheduler
+    #: delivered it any (a sequence migrated in)
+    _chunk_mark: int | None = None
     #: admission to retirement, for the recorder's ``requests`` track
     _life: object = None
     #: block steps only: the block in flight, as the host follows it —
@@ -168,8 +184,9 @@ class Scheduler:
         self.state_slots_live = 0
         #: decode and block steps run ONE PASS AHEAD of the host: (the
         #: device result of the pass dispatched last tick, the requests
-        #: it served by slot), read this tick after the next is
-        #: dispatched (None under speculation: it reads its own tick's)
+        #: it served by slot, ``_chunks_sent`` when it went out), read
+        #: this tick after the next is dispatched (None under
+        #: speculation: it reads its own tick's)
         self._in_flight = None
         #: what that costs: lanes of the passes read (``_live_ticks``)
         #: whose request had finished by then, one a finished request
@@ -203,6 +220,10 @@ class Scheduler:
         self.decode_blocks_registered = 0
         self.prefill_chunks = 0
         self.prefill_chunks_saved = 0
+        #: prefill chunks dispatched over the scheduler's life, never
+        #: reset: ``Request.chunks_ahead`` is its difference between two
+        #: passes
+        self._chunks_sent = 0
         # allocator lifecycle (lru_evict/lru_reclaim) rides the same
         # event path as the scheduler's own admissions
         engine.allocator.on_event = self._event
@@ -353,7 +374,7 @@ class Scheduler:
         # a handed-back (drained) request restarts from scratch on
         # re-admission: its partial output was delivered at evict
         # time, regeneration must not append to it
-        req.tokens, req.unmask_pass = [], []
+        req.tokens, req.unmask_pass, req.chunks_ahead = [], [], []
         req._life = span("sched.request", nested=False).start()
         req.admit_mono = req._life.t0
         self._event(
@@ -438,6 +459,7 @@ class Scheduler:
             )
             req._prefilled += n
             self.prefill_chunks += 1
+            self._chunks_sent += 1
             self._event(
                 "prefill", rid=req.rid, slot=slot, tokens=int(n),
                 done=int(req._prefilled), of=int(len(req.prompt)),
@@ -470,6 +492,9 @@ class Scheduler:
         now = time.perf_counter()
         for (slot, req, _), tok in zip(firsts, toks):
             req.tokens.append(int(tok))
+            # read after every chunk of its tick: none is behind the next
+            req.chunks_ahead.append(0)
+            req._chunk_mark = self._chunks_sent
             req.first_token_mono = now
             self._check_done(slot, req, int(tok))
 
@@ -651,6 +676,7 @@ class Scheduler:
                 )
                 with span("sched.dispatch", tick=tick, live=len(served)):
                     emitted_dev, accepted_dev = self.engine.verify(drafts, nd)
+                sent = self._chunks_sent
                 with span("sched.pull", tick=tick):
                     emitted = np.asarray(emitted_dev)
                     accepted_n = int(np.asarray(accepted_dev).sum())
@@ -664,11 +690,13 @@ class Scheduler:
                     with span(
                         "sched.dispatch", tick=tick, live=len(decoding)
                     ):
-                        self._in_flight = (step(), decoding)
+                        self._in_flight = (
+                            step(), decoding, self._chunks_sent
+                        )
                 self._first_tokens(firsts, tick)
                 if in_flight is None:
                     return 0
-                out_dev, served = in_flight
+                out_dev, served, sent = in_flight
                 with span("sched.pull", tick=tick):
                     out = np.asarray(out_dev)
                 if self.block_len:
@@ -709,11 +737,19 @@ class Scheduler:
                     continue
                 # fan the slot's accepted run out token by token: EOS
                 # or budget INSIDE the run stops exactly where
-                # sequential decode would have — the tail is discarded
+                # sequential decode would have — the tail is discarded.
+                # The run's first token waited behind the chunks sent
+                # since the pass that delivered the token before it
+                ahead = (
+                    0 if req._chunk_mark is None
+                    else sent - req._chunk_mark
+                )
                 for tok in emitted[slot]:
                     if tok < 0:
                         break
+                    req.chunks_ahead.append(ahead if req.tokens else 0)
                     req.tokens.append(int(tok))
+                    req._chunk_mark, ahead = sent, 0
                     emitted_n += 1
                     if self.block_len and len(req.tokens) == 1:
                         req.first_token_mono = time.perf_counter()

@@ -261,7 +261,7 @@ def test_a_slot_is_retired_and_taken_again_while_a_pass_is_in_flight(params):
         sched.tick()
     # a's slot is free, and the pass dispatched for it this tick rides on
     slot = next(s for s in range(2) if s not in sched._slot_req)
-    late, served_by_slot = sched._in_flight
+    late, served_by_slot, _ = sched._in_flight
     assert served_by_slot[slot] is a and late is not None
     unread = sched.lanes_unread
     sched.tick()               # b: admitted, prefilled, its first pass sent

@@ -41,6 +41,16 @@ SCHED_SPANS = {
     "sched.pull": {"tick"},
     "sched.emit": {"tick", "emitted"},
 }
+#: the engine's hand-overs: their attributes, and the scheduler spans
+#: that call them (a request its first token ends retires in the tick's
+#: ``sched.decode``). A pass (decode, block step, verify) has no engine
+#: span: ``sched.dispatch`` bounds it
+ENGINE_SPANS = {
+    "engine.admit": ({"slot", "blocks"}, {"sched.admit"}),
+    "engine.prefill": ({"slot", "tokens", "pos0"}, {"sched.prefill"}),
+    "engine.activate": ({"slot"}, {"sched.prefill"}),
+    "engine.retire": ({"slot"}, {"sched.emit", "sched.decode"}),
+}
 
 
 class Recorder:
@@ -76,8 +86,9 @@ def serve_some(sched, n=3):
     sched.serve()
 
 
-def read_spans(trace_dir):
-    """-> [(name, start, end, attrs)] of the ``singa/`` events."""
+def read_spans(trace_dir, lines=False):
+    """-> [(name, start, end, attrs)] of the ``singa/`` events; with
+    ``lines``, each with its thread line's plane and name last."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
@@ -93,7 +104,7 @@ def read_spans(trace_dir):
                     out.append((
                         e.name[len("singa/"):], e.start_ns,
                         e.start_ns + e.duration_ns, attrs,
-                    ))
+                    ) + (((plane.name, line.name),) if lines else ()))
     return sorted(out, key=lambda s: (s[1], -s[2]))
 
 
@@ -156,6 +167,66 @@ def test_request_spans_carry_the_rid_they_served(traced, span, event):
         if n == span and "stalled" not in a
     )
     assert got == served
+
+
+@pytest.fixture(scope="module")
+def engine_traced(tmp_path_factory):
+    """One-token ticks (one request its first token ends among them) and
+    block steps, one after the other under one profiler session: the
+    spans with their thread lines."""
+    trace_dir = str(tmp_path_factory.mktemp("engine_trace"))
+    rs = np.random.RandomState(1)
+    with jax.profiler.trace(trace_dir):
+        sched = Scheduler(tiny_engine())
+        serve_some(sched)
+        sched.submit(Request(rid=3, prompt=rs.randint(0, 32, size=(5,)),
+                             max_new_tokens=1))
+        sched.serve()
+        blocks = Scheduler(tiny_block_engine())
+        for rid in range(2):
+            blocks.submit(Request(
+                rid=rid, prompt=rs.randint(0, 39, size=(6 + rid,)),
+                max_new_tokens=6,
+            ))
+        blocks.serve()
+    return read_spans(trace_dir, lines=True)
+
+
+@pytest.mark.parametrize("name", sorted(ENGINE_SPANS))
+def test_engine_span_lies_in_the_scheduler_span_that_calls_it(
+    engine_traced, name
+):
+    attrs_wanted, callers = ENGINE_SPANS[name]
+    mine = [s for s in engine_traced if s[0] == name]
+    assert mine, name
+    for _, start, end, attrs, line in mine:
+        assert set(attrs) >= attrs_wanted, (name, attrs)
+        # host scalars, never an array the annotation would wait for
+        assert all(type(v) is int for v in attrs.values()), attrs
+        outer = [
+            s for s in engine_traced
+            if s[0] in callers and s[4] == line and s[1] <= start
+            and end <= s[2]
+        ]
+        assert outer, (name, attrs)
+        if name == "engine.prefill":
+            (chunk,) = outer
+            assert chunk[3]["slot"] == attrs["slot"]
+            assert chunk[3]["tokens"] == attrs["tokens"]
+    if name == "engine.retire":
+        # the request of one token retired where its first token landed
+        assert {s[0] for s in engine_traced if s[0] in callers and any(
+            s[1] <= r[1] and r[2] <= s[2] for r in mine
+        )} == callers
+
+
+def test_no_engine_span_around_a_pass(engine_traced):
+    names = {s[0] for s in engine_traced if s[0].startswith("engine.")}
+    assert names == set(ENGINE_SPANS)
+    assert not names & {"engine.decode", "engine.block_step", "engine.verify"}
+    # a chunk's positions follow each other within its slot's prompt
+    chunks = [s[3] for s in engine_traced if s[0] == "engine.prefill"]
+    assert any(c["pos0"] > 0 for c in chunks)
 
 
 def test_trainer_phase_is_on_the_profilers_clock(traced):

@@ -353,7 +353,7 @@ def test_a_slot_is_retired_and_taken_again_while_a_pass_is_in_flight():
         sched.tick()
     # a's slot is free, and the pass dispatched for it this tick rides on
     slot = next(s for s in range(2) if s not in sched._slot_req)
-    late, served_by_slot = sched._in_flight
+    late, served_by_slot, _ = sched._in_flight
     assert served_by_slot[slot] is a and late is not None
     # the riding pass wrote row pos - 1: the last of a's one block
     assert int(eng.state["pos"][slot]) == len(first) + 3 == 8
