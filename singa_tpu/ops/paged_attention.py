@@ -34,7 +34,11 @@ the grid form (``_kernel``)
     skips its fetch and its compute, but not the step.
 
 the one-query form (``_one_query_kernel``)
-    the decode tick: ONE query a sequence, nothing overlaid. At a
+    the decode tick: ONE query a sequence, nothing overlaid; and the
+    block step of a model generated by diffusion over blocks, whose B
+    queries of a head all see up to the block's end, so the engine
+    hands them in as B more query rows over that head's K/V head
+    (``Engine._block_step``: (S, H, B, D) as (S, H * B, 1, D)). At a
     serving cell's shape the grid form is all steps and small
     products — 32 slots x 64 table entries x 24 layers are 49,152
     steps a tick, under a third of them live, each walking 16 heads of
@@ -52,12 +56,12 @@ the one-query form (``_one_query_kernel``)
 Two entry points cover the engine's call shapes:
 
 ``paged_attention``
-    write-then-read — the decode tick's ``(slots, 1)`` pattern (and a
-    chunk of queries, ``(1, chunk)``, which the engine's prefill no
-    longer asks for): the fresh K/V were already
-    scattered into the pool (padding/dead lanes to the trash block),
-    so every attended entry lives behind the table and the mask is
-    ``cache_attend``'s exactly: pool position <= query position.
+    write-then-read — the decode tick's and the block step's
+    ``(slots, 1)`` pattern (and a chunk of queries, ``(1, chunk)``,
+    which the engine's prefill no longer asks for): the fresh K/V were
+    already scattered into the pool (padding/dead lanes to the trash
+    block), so every attended entry lives behind the table and the
+    mask is ``cache_attend``'s exactly: pool position <= query position.
 
 ``paged_attention_overlay``
     the speculative verify ``(slots, k+1)`` pattern: the pool must NOT

@@ -37,14 +37,18 @@ it:
            cache and the whole block (``block_limits``). A slot whose
            block has masked positions DENOISES: the most confident of
            them take their greedy tokens for good. A slot whose block
-           has none COMMITS: the block's K and V go to the pool and the
-           slot moves on to a fresh masked block. Verify's discipline:
-           attention reads the gathered views with the block's fresh
-           K/V overlaid, and the pool takes one masked scatter after
-           the forward — to real blocks for committing slots, to the
-           trash block for the rest — so nothing is written before
-           commit. Prefill of such a model is block-causal (the same
-           limits) and yields no token.
+           has none COMMITS: the block's K and V stand in the pool and
+           the slot moves on to a fresh masked block. The gather path
+           keeps verify's discipline: attention reads the gathered
+           views with the block's fresh K/V overlaid, and the pool
+           takes one masked scatter after the forward — to real blocks
+           for committing slots, to the trash block for the rest. The
+           kernel takes the decode tick's: every live slot's block is
+           written to its own rows past its committed length first, and
+           the block's queries read the slot's live blocks in place
+           (``_block_step`` says why those rows are safe to write).
+           Prefill of such a model is block-causal (the same limits)
+           and yields no token.
 
 All programs run the SAME ``_block_apply``/``cache_attend``/``lm_head``
 body as models/transformer.generate — paged-vs-dense parity AND
@@ -93,12 +97,13 @@ above; ``fused`` swaps the Pallas paged-attention kernel
 ``_block_apply`` — K/V blocks are read IN PLACE through the block
 table, no dense ``(S, H, cache_len, D)`` materialization per layer.
 Left unset (no ``kernels { paged_attention }`` in the model conf), the
-kernel runs where it compiles and knows the model — on a TPU, with no
-mesh, one token a tick (query heads over as many K/V heads or fewer) —
-and the gather path everywhere else; the scheduler's ``kernel_select``
-event says which and why. Naming either in the conf pins it. The choice covers
-the decode tick and the verify pass; a prefill chunk always takes the
-one-slot gather, where the kernel's many-query shape does not win.
+kernel runs where it compiles — on a TPU, with no mesh: one token a
+tick, or a block step's block as query rows of one token, over as many
+K/V heads or fewer — and the gather path everywhere else; the
+scheduler's ``kernel_select`` event says which and why. Naming either
+in the conf pins it. The choice covers the decode tick, the block step
+and the verify pass; a prefill chunk always takes the one-slot gather,
+where the kernel's many-query shape does not win.
 Fused output is allclose to the reference (online softmax reorders the
 reduction — the PR 9 cross-shape caveat at kernel granularity); greedy
 token STREAMS are pinned identical in tests. The kernel's form follows
@@ -244,17 +249,18 @@ def choose_attend(cfg, serving, mesh, platform: str) -> str:
     CPU test can ask it about a TPU.
 
     A pinned ``serving.attend_impl`` is returned as it is. Unset, the
-    kernel runs where it compiles through Mosaic and knows the model:
-    it reads one query a sequence over K/V heads that one query head or
-    several share, or over a latent cache's one row a token, and has no
-    block step (``Engine._no_kernel``), GSPMD cannot
+    kernel runs where it compiles through Mosaic, whatever the model:
+    it reads one query a sequence (or a block step's queries, all seeing
+    up to the block's end, as rows of one query) over K/V heads that one
+    query head or several share, or over a latent cache's one row a
+    token, so no field of ``cfg`` refuses it today. GSPMD cannot
     partition a Mosaic call (a mesh), and off a TPU it would run
     through the Pallas interpreter, a grid step at a time."""
     if serving.attend_impl is not None:
         return serving.attend_impl
     from ..ops.paged_attention import fusable
 
-    why = Engine._no_kernel(cfg) or fusable(serving.kv_block_len)
+    why = fusable(serving.kv_block_len)
     if why is None and mesh is not None:
         why = "a tensor-parallel mesh"
     if why is None and platform != "tpu":
@@ -322,7 +328,9 @@ class Engine:
             from ..ops.paged_attention import fusable, one_query_fusable
 
             reason = fusable(self.serving.kv_block_len)
-            if reason is None and (cfg.kv_latent or cfg.gqa):
+            if reason is None and (
+                cfg.kv_latent or cfg.gqa or cfg.diffusion_block
+            ):
                 reason = one_query_fusable(
                     self.serving.kv_block_len, params["embed/tok"].dtype
                 )
@@ -498,9 +506,6 @@ class Engine:
         refused = {
             "speculate (spec_k)": serving.spec_k > 0,
             "prefix_cache": serving.prefix_cache,
-            "kernels.paged_attention = fused (attend_impl)":
-                serving.attend_impl == "fused"
-                and Engine._no_kernel(cfg) is not None,
             "a tensor-parallel mesh": mesh is not None,
         }
         for what, asked in refused.items():
@@ -577,17 +582,6 @@ class Engine:
         }
 
     @staticmethod
-    def _no_kernel(cfg) -> str | None:
-        """The field (with its value) of a model whose decode tick the
-        paged kernels (ops/paged_attention.py) do not serve, None where
-        one of them does: one query a sequence over K/V heads that one
-        query head or several share, or over a latent cache. A block step is a
-        block of queries with the block laid over the pool."""
-        if cfg.diffusion_block:
-            return f"diffusion_block = {cfg.diffusion_block}"
-        return None
-
-    @staticmethod
     def _beyond_gpt2(cfg) -> str | None:
         """The field (with its value) for which the refusals above
         hold, None for a model that every path here serves."""
@@ -599,11 +593,15 @@ class Engine:
             # content, rewound, or in the fleet's wire format
             kinds = sorted(set(cfg.layers))
             return f"layers = {len(cfg.layers)} one-mixer blocks of {kinds}"
-        if cfg.gqa and not cfg.diffusion_block:
+        if cfg.diffusion_block:
+            # a block in flight lives in lanes that no prefix index,
+            # draft, wire format or mesh rule knows
+            return f"diffusion_block = {cfg.diffusion_block}"
+        if cfg.gqa:
             # the prefix cache, the verify pass's overlay, the fleet's
             # wire format and a TP mesh know one K/V head a query head
             return f"n_kv_heads = {cfg.n_kv_heads} != n_heads = {cfg.n_heads}"
-        return Engine._no_kernel(cfg)
+        return None
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -1124,9 +1122,22 @@ class Engine:
         positions denoises: per masked position the greedy token and
         its confidence (the softmax probability of that token), and the
         ``block_fix`` most confident — all of them if fewer are left —
-        take their tokens for good (``low_confidence_static``); its K/V
-        go to the trash block. Nothing reaches the pool before commit:
-        ``_verify``'s overlay-then-masked-write, with its helpers.
+        take their tokens for good (``low_confidence_static``).
+
+        How the block meets the cache follows the engine's choice. The
+        gather path lays the block's K/V over gathered views and writes
+        nothing before commit (``_verify``'s overlay-then-masked-write:
+        a denoising slot's K/V go to the trash block). The kernel writes
+        first and reads in place (the decode tick's way): every live
+        slot's block goes to its own rows ``pos .. pos + B - 1``, and
+        the block's B queries of a head ride the one-query kernel as B
+        more query rows over that head's K/V head, since all of them
+        see up to the block's end. Those rows lie past the slot's
+        committed length and belong to it alone: every later pass of
+        the slot writes them again before it reads them, the commit
+        pass (nothing masked) leaves the final K/V, and nothing else
+        reads a slot's rows (no prefix cache, speculation or export for
+        such a model).
 
         -> (state', one int32 vector: the (S, B) tokens this pass
         fixed, by position in the block, -1 elsewhere, flattened; then
@@ -1145,9 +1156,33 @@ class Engine:
             x = embed(params, seq, p_safe, mcfg)
         limits = block_limits(p, mcfg)
         fresh, stats = [], []
+        # where the block's K/V go: on the kernel's way every live slot's
+        # to its own rows before the pass reads them, on the gather
+        # path a committing slot's after the forward; the rest to the
+        # trash block. The kernel sees a slot up to its block's end (a
+        # dead lane: -1, nothing)
+        bid, off = self._write_targets(
+            state["tables"], p_safe,
+            valid if self._fused
+            else jnp.broadcast_to(commit[:, None], p.shape),
+        )
+        ends = jnp.where(live, limits[:, 0], -1)[:, None]
 
         def mk_attend(i):
             def attend(qh, kh, vh):
+                if self._fused:
+                    kp = self._kv_write(
+                        state["k"][i], bid, off, jnp.moveaxis(kh, 1, 2)
+                    )
+                    vp = self._kv_write(
+                        state["v"][i], bid, off, jnp.moveaxis(vh, 1, 2)
+                    )
+                    s, h, _, d = qh.shape
+                    o = self._paged_attend(
+                        qh.reshape(s, h * bl, 1, d), kp, vp,
+                        state["tables"], ends,
+                    )
+                    return o.reshape(qh.shape), (kp, vp)
                 o = cache_attend(
                     qh,
                     self._overlay(state["k"][i], state["tables"], p_safe, kh),
@@ -1177,18 +1212,20 @@ class Engine:
             fix = jnp.zeros(masked.shape, bool).at[
                 jnp.arange(n_slots)[:, None], best
             ].set(True) & masked & live[:, None]
-        bid, off = self._write_targets(
-            state["tables"], p_safe, jnp.broadcast_to(commit[:, None], p.shape)
-        )
-        new_k, new_v = [], []
-        for i, (kh, vh) in enumerate(fresh):
-            with jax.named_scope(f"blk{i}"):
-                new_k.append(self._kv_write(
-                    state["k"][i], bid, off, jnp.moveaxis(kh, 1, 2)
-                ))
-                new_v.append(self._kv_write(
-                    state["v"][i], bid, off, jnp.moveaxis(vh, 1, 2)
-                ))
+        if self._fused:
+            # the pass's own writes already put every block in place
+            new_k = [kp for kp, _ in fresh]
+            new_v = [vp for _, vp in fresh]
+        else:
+            new_k, new_v = [], []
+            for i, (kh, vh) in enumerate(fresh):
+                with jax.named_scope(f"blk{i}"):
+                    new_k.append(self._kv_write(
+                        state["k"][i], bid, off, jnp.moveaxis(kh, 1, 2)
+                    ))
+                    new_v.append(self._kv_write(
+                        state["v"][i], bid, off, jnp.moveaxis(vh, 1, 2)
+                    ))
         moved = commit[:, None]
         new_state = {
             **state,

@@ -301,6 +301,94 @@ def test_no_pass_is_left_in_flight_when_the_server_runs_dry(params):
     assert sched.engine.allocator.used_blocks == 0
 
 
+# -- the paged kernel: a block's queries as query rows --------------------
+
+
+#: prompts on and across the 16-row pool blocks' edges, shorter than a
+#: block too; answers of three blocks or more past the prompt's tail
+KERNEL_SHAPES = [(21, 14), (9, 16), (30, 12), (3, 17), (16, 13)]
+
+
+def _live_rows(eng, slot):
+    """Every pool's rows of ``slot`` below its committed length, in
+    position order: (pools, positions, width)."""
+    pos = int(eng.state["pos"][slot])
+    bl = eng.pool.block_len
+    blocks = np.asarray(eng.state["tables"][slot])[:-(-pos // bl)]
+    return np.stack([
+        p[blocks].reshape(-1, p.shape[-1])[:pos] for p in pools(eng)
+    ])
+
+
+def test_the_kernel_serves_block_steps_as_the_gather_path_does(params):
+    """The block step on the paged kernel (interpreted): every live
+    slot's block written to its own rows first, the block's 4 queries
+    of each head read as 4 more query rows over the head's K/V head.
+    Beside the gather path, ticked in turn on the same requests (three
+    slots for five, so a slot is admitted and prefilled while the others
+    denoise, and a lane stands dead): every pass fixes the same tokens
+    at the same places, every slot's committed pool rows agree to
+    float32's rounding after every tick, and each request gets the
+    tokens it gets alone."""
+    rng = np.random.default_rng(41)
+    prompts = [rng.integers(0, 199, (p,)).astype(np.int32)
+               for p, _ in KERNEL_SHAPES]
+    scheds, passes = [], []
+    for impl in ("fused", "reference"):
+        eng = Engine(params, MCFG, EngineConfig(
+            slots=3, kv_block_len=16, max_prefill_chunk=16,
+            block_steps=STEPS, attend_impl=impl, interpret=True,
+        ))
+        assert eng._fused == (impl == "fused")
+        seen, step = [], eng.block_step
+
+        def recorded(step=step, seen=seen):
+            out = step()
+            seen.append(out)
+            return out
+
+        eng.block_step = recorded
+        sched = Scheduler(eng)
+        for rid, (prompt, (_, n)) in enumerate(zip(prompts, KERNEL_SHAPES)):
+            sched.submit(Request(rid=rid, prompt=prompt, max_new_tokens=n))
+        scheds.append(sched)
+        passes.append(seen)
+    fused, reference = scheds
+    gap, dead_lane, admitted_among_live = 0.0, False, False
+    while fused.busy or reference.busy:
+        for sched in scheds:
+            sched.tick()
+        a, b = fused.engine.state, reference.engine.state
+        for lane in ("pos", "live", "tables", "blk_tok", "blk_masked"):
+            np.testing.assert_array_equal(a[lane], b[lane])
+        live = np.asarray(a["live"])
+        for slot in np.flatnonzero(live):
+            if int(a["pos"][slot]):
+                gap = max(gap, float(np.max(np.abs(
+                    _live_rows(fused.engine, slot)
+                    - _live_rows(reference.engine, slot)
+                ))))
+        dead_lane |= bool(live.any() and not live.all())
+        admitted_among_live |= live.any() and any(
+            not live[slot] for slot in fused._slot_req
+        )
+    assert dead_lane and admitted_among_live
+    assert len(passes[0]) == len(passes[1]) > 0
+    for out_f, out_r in zip(*passes):
+        np.testing.assert_array_equal(
+            np.asarray(out_f)[:-2], np.asarray(out_r)[:-2]
+        )
+    assert 0.0 < gap <= 2e-5
+    assert fused.block_commits >= 3 * len(KERNEL_SHAPES)
+    for f, r in zip(
+        sorted(fused.finished, key=lambda q: q.rid),
+        sorted(reference.finished, key=lambda q: q.rid),
+    ):
+        assert (f.tokens, f.unmask_pass) == (r.tokens, r.unmask_pass)
+        assert len(f.tokens) == KERNEL_SHAPES[f.rid][1]
+    assert fused.engine.allocator.used_blocks == 0
+
+
 # -- refused loudly, not run wrongly --------------------------------------
 
 
@@ -327,8 +415,9 @@ def test_what_cannot_run_is_refused_by_the_fields_name(mcfg, field, what, kw):
     def build(**more):
         return Engine(p, mcfg, EngineConfig(**base, **kw), **more)
 
-    if (mcfg, what) == (GQA, "attend_impl"):
-        # the paged kernel reads query heads over fewer K/V heads (PR 38)
+    if what == "attend_impl":
+        # the paged kernel reads query heads over fewer K/V heads, and a
+        # block step's queries as query rows of one query
         assert build().attend_choice == "fused"
         return
     with pytest.raises(ValueError, match=field) as e:
@@ -347,6 +436,32 @@ def test_what_cannot_run_is_refused_by_the_fields_name(mcfg, field, what, kw):
         else:
             build()
     assert what.split()[-1] in str(e.value)
+
+
+@pytest.mark.parametrize("what", [
+    "spec_k", "prefix_cache", "mesh", "slot export", "slot import",
+])
+def test_block_steps_on_the_kernel_keep_their_refusals(params, what):
+    """The kernel serves the block step, nothing more: speculation, the
+    prefix cache, a mesh and slot export / import are refused by
+    ``diffusion_block``'s name with the kernel pinned, as on the gather
+    path."""
+    conf = dict(slots=2, kv_block_len=8, max_prefill_chunk=8,
+                attend_impl="fused")
+    more = {"spec_k": {"spec_k": 2}, "prefix_cache": {"prefix_cache": True}}
+    with pytest.raises(ValueError, match="diffusion_block"):
+        if what == "mesh":
+            from singa_tpu.parallel.mesh import axis_pair_mesh
+
+            Engine(params, MCFG, EngineConfig(**conf), mesh=axis_pair_mesh(
+                1, 1, "model", None, "tp mesh"))
+            return
+        eng = Engine(params, MCFG, EngineConfig(**conf, **more.get(what, {})))
+        assert eng._fused
+        if what == "slot export":
+            eng.export_slot(0)
+        elif what == "slot import":
+            eng.import_slot(0, {})
 
 
 @pytest.mark.parametrize("kw,name", [

@@ -122,14 +122,17 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
     (128, 32, 2, 128, 128, 38, jnp.bfloat16),  # nemotron_3_super_serve_chat
     (64, 32, 4, 128, 16, 96, jnp.bfloat16),    # 8 a K/V head, blocks of 16
     (8, 4, 2, 128, 16, 32, jnp.float32),       # 2 query heads a K/V head
-], ids=["hybrid_cell", "per_kv_8", "per_kv_2"])
+    (64, 128, 4, 128, 16, 96, jnp.bfloat16),   # sdar_30b_a3b_serve_blocks
+], ids=["hybrid_cell", "per_kv_8", "per_kv_2", "block_step"])
 def test_one_query_kernel_over_fewer_kv_heads_compiles(
     one_chip, s, h, hkv, d, bl, mb, dtype
 ):
     """The decode tick's form with query heads over fewer K/V heads
     (scores and values as products over a block-diagonal query, each
     head's own columns kept at the end) reaches Mosaic: at the hybrid
-    cell's shape, 16 query heads a K/V head and items of 8 blocks."""
+    cell's shape, 16 query heads a K/V head and items of 8 blocks; at
+    the block step's, 32 heads x a block of 4 as 128 query rows over 4
+    K/V heads, an 8 MiB block-diagonal query within the default VMEM."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -458,7 +461,9 @@ def test_a_block_step_over_every_expert_stays_dense(one_chip, monkeypatch):
     """``sdar_30b_a3b_serve_blocks``' pass (64 slots x a block of 4 =
     256 tokens over 128 of 128 experts, published widths, one layer)
     rides on the weight reads: the chooser leaves it the dense product
-    and its compiled text holds no grouped kernel."""
+    and its compiled text holds no grouped kernel. Its attention is the
+    paged kernel, one call a layer over the block's 128 query rows, and
+    no gathered view of a pool is made."""
     cfg = TransformerConfig(
         vocab=4096, d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
         n_layers=1, d_ff=768, max_len=1536, norm="rmsnorm", norm_eps=1e-6,
@@ -481,11 +486,21 @@ def test_a_block_step_over_every_expert_stays_dense(one_chip, monkeypatch):
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
+    assert eng.attend_choice == "fused"
     text = jax.jit(eng._block_step, donate_argnums=(1,)).lower(
         jax.tree.map(sds, params), jax.tree.map(sds, eng.state)
     ).compile().as_text()
-    assert "jit(gmm)" not in text and "tpu_custom_call" not in text
+    kernels = re.findall(
+        r'= [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text,
+    )
+    assert "jit(gmm)" not in text and len(kernels) == 1, kernels
+    assert kernels[0] == (
+        "jit(_block_step)/blk0/attend/paged_attention/paged_attention/"
+        "pallas_call"
+    )
     assert "bf16[128,256,768]" in text or "f32[128,256,768]" in text
+    assert not re.search(r"bf16\[64,[\d,]*1536", text)   # a slot's whole view
 
 
 # -- one-mixer layers: recurrent state beside a paged pool ---------------

@@ -571,7 +571,7 @@ def test_compile_cache_key_is_salted_by_the_names_version(monkeypatch):
 # ---------------------------------------------------------------------
 
 
-def tiny_block_engine():
+def tiny_block_engine(**serving):
     cfg = TransformerConfig(
         vocab=40, d_model=32, n_heads=4, n_layers=2, max_len=32,
         norm="rmsnorm", pos="rope", n_kv_heads=2, head_dim=8, qk_norm=True,
@@ -581,6 +581,7 @@ def tiny_block_engine():
     params = init_lm(jax.random.PRNGKey(0), cfg)
     return Engine(params, cfg, EngineConfig(
         slots=2, kv_block_len=8, max_prefill_chunk=4, block_steps=2,
+        **serving,
     ))
 
 
@@ -605,6 +606,24 @@ def test_block_step_program_name(block_step_text):
 def test_block_step_program_names_its_operations(block_step_text, scope):
     names = {n for _, n in instructions(block_step_text)}
     assert any(f"jit(_block_step)/{scope}/" in n for n in names), scope
+
+
+def test_block_step_under_the_kernel_names_it_inside_attend():
+    """On the kernel (interpreted here; on a TPU the engine chooses it
+    itself) a block step writes and reads inside ``attend``, which
+    ``attend_ms_per_block_step`` reads: the write under ``kv_write``,
+    the kernel under ``paged_attention``; no gather, no overlay and no
+    write after the forward."""
+    eng = tiny_block_engine(attend_impl="fused")
+    text = eng._block_step_jit.lower(eng.params, eng.state).compile().as_text()
+    names = {n for _, n in instructions(text)}
+    for scope in ("blk0/attend/paged_attention", "blk1/attend/paged_attention",
+                  "blk0/attend/kv_write", "blk1/attend/kv_write"):
+        assert any(f"jit(_block_step)/{scope}/" in n for n in names), scope
+    assert not any(
+        "/gather_kv/" in n or "/cache_attend/" in n
+        or "jit(_block_step)/blk1/kv_write/" in n for n in names
+    )
 
 
 def test_block_step_tick_has_the_unchanged_span_set(tmp_path):

@@ -550,7 +550,7 @@ def test_engine_rejects_untileable_fused_geometry(lm):
 
 def sdar_shaped_cfg(**kw):
     """Fewer K/V heads than query heads, generated by diffusion over
-    blocks: the model the kernel does not know."""
+    blocks: a block step's queries ride the kernel as query rows."""
     base = dict(
         vocab=40, d_model=32, n_heads=4, n_layers=2, max_len=32,
         norm="rmsnorm", pos="rope", n_kv_heads=2, head_dim=8, qk_norm=True,
@@ -580,18 +580,22 @@ def nemotron_shaped_cfg():
     (tiny_cfg(), {}, "a mesh", "tpu", "reference: a tensor-parallel mesh"),
     (tiny_cfg(n_heads=4, n_kv_heads=2), {}, None, "tpu", "fused"),
     (nemotron_shaped_cfg(), {}, None, "tpu", "fused"),
-    (sdar_shaped_cfg(n_kv_heads=4), {}, None, "tpu",
-     "reference: diffusion_block = 4"),
+    (sdar_shaped_cfg(n_kv_heads=4), {}, None, "tpu", "fused"),
+    # no field of the model refuses the kernel any more: the mesh is
+    # the reason (and the engine refuses a mesh for this model)
     (sdar_shaped_cfg(), {}, "a mesh", "tpu",
-     "reference: diffusion_block = 4"),
+     "reference: a tensor-parallel mesh"),
     # a pin wins over everything the choice looks at
     (tiny_cfg(), {"attend_impl": "reference"}, None, "tpu", "reference"),
     (tiny_cfg(), {"attend_impl": "fused"}, None, "cpu", "fused"),
     (tiny_cfg(), {"attend_impl": "fused"}, "a mesh", "cpu", "fused"),
+    (sdar_shaped_cfg(), {}, None, "tpu", "fused"),
+    (sdar_shaped_cfg(), {}, None, "cpu", "reference: platform = cpu"),
 ], ids=[
     "tpu_mha_no_mesh", "cpu", "gpu", "mesh", "fewer_kv_heads",
     "one_mixer_layers", "diffusion_block", "the_model_is_named_first",
     "pinned_reference", "pinned_fused", "pinned_fused_under_a_mesh",
+    "block_steps_on_a_tpu", "block_steps_on_a_cpu",
 ])
 def test_choose_attend(cfg, serving, mesh, platform, choice):
     """The kernel where it compiles and knows the model, the gather
@@ -643,18 +647,21 @@ def test_unset_engine_on_a_tpu_runs_the_kernel(lm, monkeypatch):
 def test_sdar_shaped_engine_lowers_the_same_block_step_unset_or_pinned(
     monkeypatch,
 ):
-    """A model the kernel does not know takes the gather path on a TPU
-    too: ``_block_step`` and ``_prefill`` lower to the text they lower
-    to under an explicit ``reference``."""
+    """Left to itself on a TPU, a model generated by diffusion over
+    blocks runs its block step on the kernel: ``_block_step`` lowers to
+    the text it lowers to under an explicit ``fused`` and holds the
+    kernel, and ``_prefill`` to the text of an explicit ``reference``
+    (a chunk keeps its one-slot gather)."""
     cfg = sdar_shaped_cfg()
     params = init_lm(jax.random.PRNGKey(0), cfg)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def texts(**kw):
         eng = Engine(params, cfg, EngineConfig(
-            slots=2, kv_block_len=8, max_prefill_chunk=4, block_steps=2, **kw,
+            slots=2, kv_block_len=8, max_prefill_chunk=4, block_steps=2,
+            interpret=True, **kw,
         ))
-        return eng, (
+        return str(jax.make_jaxpr(eng._block_step)(params, eng.state)), (
             eng._block_step_jit.lower(eng.params, eng.state).as_text(),
             eng._prefill_jit.lower(
                 eng.params, eng.state, jnp.int32(0),
@@ -663,8 +670,12 @@ def test_sdar_shaped_engine_lowers_the_same_block_step_unset_or_pinned(
         )
 
     unset, unset_texts = texts()
-    assert unset.attend_choice == "reference: diffusion_block = 4"
-    assert unset_texts == texts(attend_impl="reference")[1]
+    _, fused = texts(attend_impl="fused")
+    reference_jaxpr, reference = texts(attend_impl="reference")
+    assert unset_texts == fused
+    assert "name=paged_attention" in unset
+    assert "pallas_call" not in reference_jaxpr
+    assert fused[1] == reference[1]
 
 
 # ---------------------------------------------------------------------------
