@@ -33,7 +33,7 @@ from ..parallel.ring import ring_attention
 
 
 #: what ``TransformerConfig.layers`` may name
-LAYER_KINDS = ("mamba", "attn", "moe", "mlp")
+LAYER_KINDS = ("mamba", "attn", "moe", "mlp", "shortconv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,14 +121,16 @@ class TransformerConfig:
     rope_yarn: tuple = ()
     #: layer kinds, one a layer: a block of such a model is ONE mixer,
     #: ``x + mixer(norm(x))``, the mixer a Mamba-2 layer ("mamba",
-    #: ops/ssm.py), attention ("attn"), a top-k expert layer ("moe") or
-    #: the dense MLP ("mlp"). () = today's blocks: attention then MLP or
-    #: experts in every one.
+    #: ops/ssm.py), attention ("attn"), a top-k expert layer ("moe"),
+    #: the dense MLP ("mlp") or a gated short convolution ("shortconv",
+    #: ops/ssm.py ``short_conv``). () = today's blocks: attention then
+    #: MLP or experts in every one.
     layers: tuple = ()
     #: the Mamba-2 layers' sizes: heads of ``mamba_head_dim``, a state
     #: of ``ssm_state`` a head channel, B and C shared by the heads of
     #: each of ``ssm_groups`` groups, a causal depthwise convolution
-    #: ``conv_kernel`` long, the chunked scan's blocks of ``ssm_block``
+    #: ``conv_kernel`` long (a short convolution's taps too), the
+    #: chunked scan's blocks of ``ssm_block``
     mamba_heads: int = 0
     mamba_head_dim: int = 0
     ssm_state: int = 0
@@ -207,6 +209,11 @@ class TransformerConfig:
                 raise ValueError(
                     "layers: a 'mamba' layer needs mamba_heads (a multiple "
                     "of ssm_groups), mamba_head_dim and ssm_state"
+                )
+            if "shortconv" in self.layers and self.conv_kernel < 2:
+                raise ValueError(
+                    "layers: a 'shortconv' layer needs conv_kernel >= 2 "
+                    "(taps, of which the last K - 1 rows are its state)"
                 )
             if self.kv_latent or self.diffusion_block or self.dense_layers:
                 raise ValueError(
@@ -290,6 +297,8 @@ def init_lm(rng: jax.Array, cfg: TransformerConfig) -> dict:
         norm_params(f"{p}/ln1", cfg.d_model)
         if kind == "mamba":
             _init_mamba(params, p, cfg, norm, keys)
+        if kind == "shortconv":
+            _init_shortconv(params, p, cfg, norm, keys)
         if kind in (None, "attn"):
             _init_attention(params, p, cfg, norm, keys)
         if kind is None:
@@ -392,6 +401,25 @@ def _init_mamba(params, p, cfg: TransformerConfig, norm, keys) -> None:
         f"{p}/mamba/norm": jnp.ones((d_in,)),
         f"{p}/mamba/out_proj": norm(
             next(keys), (d_in, d), 1 / math.sqrt(d_in * 2 * cfg.n_layers)
+        ),
+    })
+
+
+def _init_shortconv(params, p, cfg: TransformerConfig, norm, keys) -> None:
+    """A block's short-convolution parameters under ``p`` (ops/ssm.py
+    ``SHORTCONV_PARAMS``): the projections scaled normals, the taps
+    uniform in +-1/sqrt(K) (a depthwise convolution's fan-in)."""
+    d, k = cfg.d_model, cfg.conv_kernel
+    params.update({
+        f"{p}/shortconv/in_proj": norm(
+            next(keys), (d, 3 * d), 1 / math.sqrt(d)
+        ),
+        f"{p}/shortconv/conv_w": jax.random.uniform(
+            next(keys), (k, d), minval=-1 / math.sqrt(k),
+            maxval=1 / math.sqrt(k),
+        ),
+        f"{p}/shortconv/out_proj": norm(
+            next(keys), (d, d), 1 / math.sqrt(d * 2 * cfg.n_layers)
         ),
     })
 
@@ -710,10 +738,12 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     A model with ``cfg.layers`` has ONE mixer a block,
     ``x + mixer(ln1(x))``, of the block's kind: attention as above (an
     ``attend`` is only read there), the top-k expert layer or the dense
-    MLP, or a Mamba-2 layer (ops/ssm.py ``mamba2_mixer``). The last
-    starts from ``carried`` — a (state, convolution tail) a sequence, or
-    None for a sequence's start — steps over the positions ``valid``
-    marks, and hands the new pair back as ``extra``.
+    MLP, a Mamba-2 layer (ops/ssm.py ``mamba2_mixer``) or a gated short
+    convolution (``short_conv``). The last two start from ``carried`` —
+    a (state, convolution tail) a sequence for Mamba, the tail alone for
+    the short convolution, or None for a sequence's start — step over
+    the positions ``valid`` marks, and hand what they carry on back as
+    ``extra``, in the same form.
     ``moe_capacity_factor`` overrides the Switch MoE's capacity (decode
     passes E so routing is drop-free; None keeps the training default).
     ``positions`` (B, S) are the tokens' own positions, read by rotary
@@ -730,8 +760,9 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
     (whatever implements it), ``attn_out``, ``ln2``, ``mlp`` or ``moe``
     (the top-k layer: ``route``, ``experts``, ``combine``, ``shared``,
     and ``latent_down`` / ``latent_up`` where the experts live in a
-    latent), or ``mamba`` (``in_proj``, ``conv``, ``scan`` or ``step``,
-    ``gate_norm``, ``out_proj``). Latent attention's ``qkv`` holds
+    latent), ``mamba`` (``in_proj``, ``conv``, ``scan`` or ``step``,
+    ``gate_norm``, ``out_proj``), or ``shortconv`` (``in_proj``,
+    ``conv``, ``out_proj``). Latent attention's ``qkv`` holds
     ``q_latent`` and ``kv_latent``. A trace is read by these names."""
     scope = jax.named_scope
     i = int(p.removeprefix("blk"))
@@ -750,6 +781,16 @@ def _block_apply(params, p, x, attend, cfg, mesh=None,
                     state_dim=cfg.ssm_state, groups=cfg.ssm_groups,
                     block=cfg.ssm_block, eps=cfg.norm_eps, carried=carried,
                     valid=valid,
+                )
+                x = x + y
+        if kind == "shortconv":
+            from ..ops import ssm
+
+            with scope("shortconv"):
+                y, extra = ssm.short_conv(
+                    {k: params[f"{p}/shortconv/{k}"]
+                     for k in ssm.SHORTCONV_PARAMS},
+                    h, carried=carried, valid=valid,
                 )
                 x = x + y
         if kind in (None, "attn"):

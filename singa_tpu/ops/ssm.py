@@ -38,6 +38,10 @@ Precision: the products take their operands as stored (the compute
 type, bfloat16 on the chip) and accumulate in float32; ``z``, ``dt``,
 its softplus, the decays, the cumulative sums, the state, every product
 that reads the state, the gate and the norm are float32.
+
+Beside it, ``short_conv``: the gated short convolution of the LFM2
+lineage, whose only state is the convolution's tail, carried by the
+same ``causal_conv`` with no bias and no silu.
 """
 
 from __future__ import annotations
@@ -73,26 +77,29 @@ def choose_mamba_form(s: int, block: int, carried: bool) -> str:
 
 
 @jax.named_scope("conv")
-def causal_conv(xbc, tail, w, b, n_valid):
+def causal_conv(xbc, tail, w, b, n_valid, silu: bool = True):
     """The depthwise causal convolution with its carried tail.
 
     ``xbc`` (B, S, C) the sequence's inputs, ``tail`` (B, K - 1, C) the
     inputs before it (zeros at a sequence's start), ``w`` (K, C), ``b``
-    (C,), ``n_valid`` (B,) how many of the S positions count (a prefix).
-    -> (silu(b + sum_k w_k x_{t-K+1+k}) (B, S, C) in ``xbc``'s type, the
-    new tail: the last K - 1 inputs up to the valid ones' end, which is
-    the old tail itself where none is valid). Sums and the silu are
+    (C,) or None for none, ``n_valid`` (B,) how many of the S positions
+    count (a prefix). -> (silu(b + sum_k w_k x_{t-K+1+k}) (B, S, C) in
+    ``xbc``'s type — the sum alone where ``silu`` is False — and the new
+    tail: the last K - 1 inputs up to the valid ones' end, which is the
+    old tail itself where none is valid). Sums and the silu are
     float32."""
     k = w.shape[0]
     s = xbc.shape[1]
     f32 = jnp.float32
     full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    acc = b.astype(f32)
+    acc = None if b is None else b.astype(f32)
     for j in range(k):
-        acc = acc + w[j].astype(f32) * full[:, j:j + s].astype(f32)
+        tap = w[j].astype(f32) * full[:, j:j + s].astype(f32)
+        acc = tap if acc is None else acc + tap
     at = n_valid[:, None] + jnp.arange(k - 1)[None, :]          # (B, K-1)
     new_tail = jnp.take_along_axis(full, at[:, :, None], axis=1)
-    return jax.nn.silu(acc).astype(xbc.dtype), new_tail.astype(tail.dtype)
+    out = jax.nn.silu(acc) if silu else acc
+    return out.astype(xbc.dtype), new_tail.astype(tail.dtype)
 
 
 @jax.named_scope("step")
@@ -264,3 +271,49 @@ def mamba2_mixer(w: dict, u, *, heads: int, head_dim: int, state_dim: int,
     with jax.named_scope("out_proj"):
         out = y.astype(u.dtype) @ w["out_proj"]
     return out, (new_state, new_tail)
+
+
+#: a gated short convolution's parameters: ``in_proj`` (d, 3 d), its
+#: columns B, C and x in that order, ``conv_w`` (K, d), one filter a
+#: channel and no bias, and ``out_proj`` (d, d)
+SHORTCONV_PARAMS = ("in_proj", "conv_w", "out_proj")
+
+
+def short_conv(w: dict, u, *, carried=None, valid=None):
+    """The gated short convolution (the LFM2 lineage's ``conv`` layers)
+    on ``u`` (B, S, d) -> (out (B, S, d), the new tail (B, K - 1, d)).
+
+        [B | C | x] = u W_in              three widths of d
+        v_t = B_t * x_t
+        w_t = sum_k k_k * v_{t-K+1+k}     per channel, no bias, zeros
+                                          before the sequence
+        out = (C * w) W_out
+
+    No other nonlinearity, and no state but the last K - 1 rows of ``v``,
+    which ``carried`` (B, K - 1, d) brings in (None: a sequence's start)
+    and the second result hands on, cut where the positions ``valid``
+    (B, S) marks end (``causal_conv``): a lane with none keeps its tail
+    bit for bit. ``w`` holds ``SHORTCONV_PARAMS``. ``v`` is rounded to
+    ``u``'s type, the type the tail is kept in, before the taps read it,
+    so that a sequence's rows meet the same values whether a chunk or a
+    tail brings them; the product accumulates in float32 and the gates
+    are float32. In a trace its operations lie under ``in_proj``,
+    ``conv`` and ``out_proj``."""
+    f32 = jnp.float32
+    bsz, s, d = u.shape
+    k = w["conv_w"].shape[0]
+    if valid is None:
+        valid = jnp.ones((bsz, s), bool)
+    tail = jnp.zeros((bsz, k - 1, d), u.dtype) if carried is None else carried
+    with jax.named_scope("in_proj"):
+        bcx = jnp.matmul(u, w["in_proj"], preferred_element_type=f32)
+        gate_b, gate_c, xs = jnp.split(bcx, 3, axis=-1)
+        v = (gate_b * xs).astype(u.dtype)
+    conv, new_tail = causal_conv(
+        v, tail, w["conv_w"], None,
+        jnp.sum(valid, axis=1, dtype=jnp.int32), silu=False,
+    )
+    with jax.named_scope("out_proj"):
+        y = (gate_c * conv.astype(f32)).astype(u.dtype)
+        out = y @ w["out_proj"]
+    return out, new_tail

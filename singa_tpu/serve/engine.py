@@ -146,9 +146,13 @@ DECODE_COUNTERS = (
     "experts_hit", "expert_max_load", "held_pairs", "cache_rows",
     "chunk_held_pairs",
 )
-#: and what a decode pass of a model with recurrent layers appends after
-#: them: the slots whose state the pass advanced (its live lanes)
+#: and what a decode pass of a model with recurrent layers (of either
+#: kind) appends after them: the slots whose state the pass advanced
+#: (its live lanes)
 STATE_COUNTERS = ("state_slots_live",)
+#: the layer kinds that keep state a slot: a convolution tail each, and
+#: a Mamba-2 layer its recurrent state beside it
+RECURRENT_KINDS = ("mamba", "shortconv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,12 +354,17 @@ class Engine:
             self.serving.kv_blocks, self.serving.slots,
         )
         #: layer -> its place in ``state["k"]`` / ``state["v"]``: pools
-        #: are kept for the layers that hold attention alone, and layer
-        #: -> its place in ``state["ssm"]`` / ``state["conv"]`` for those
-        #: that hold a Mamba-2 mixer (every layer and none for a model
+        #: are kept for the layers that hold attention alone; layer ->
+        #: its place in ``state["conv"]`` for those that hold a recurrent
+        #: mixer of either kind (a Mamba-2 layer or a short convolution:
+        #: a convolution tail each), and in ``state["ssm"]`` for the
+        #: Mamba-2 layers alone (every layer and none for a model
         #: without ``layers``)
         self._kv_at = {i: j for j, i in enumerate(cfg.layers_of("attn"))}
-        self._state_at = {i: j for j, i in enumerate(cfg.layers_of("mamba"))}
+        self._state_at = {i: j for j, i in enumerate(sorted(
+            i for kind in RECURRENT_KINDS for i in cfg.layers_of(kind)
+        ))}
+        self._ssm_at = {i: j for j, i in enumerate(cfg.layers_of("mamba"))}
         self.allocator = BlockAllocator(
             self.pool,
             prefix_cache=self.serving.prefix_cache,
@@ -414,23 +423,31 @@ class Engine:
                 for _ in (() if cfg.kv_latent else self._kv_at)
             ),
         }
-        if self._state_at:
+        if self._ssm_at:
             # slot-resident recurrent state, one array a Mamba-2 layer:
-            # the float32 state and the convolution's tail, zeroed at
-            # admission, advanced by the valid positions alone
+            # the float32 state, zeroed at admission, advanced by the
+            # valid positions alone
             self.state["ssm"] = tuple(
                 jnp.zeros(
                     (s, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
                     jnp.float32,
-                ) for _ in self._state_at
+                ) for _ in self._ssm_at
             )
+        if self._state_at:
+            # and one convolution tail a recurrent layer, in the
+            # parameters' type: the last K - 1 rows of what the taps
+            # read, a Mamba-2 layer's x, B and C or a short
+            # convolution's gated input, likewise zeroed and advanced
             # (the tail's few rows lead: (K - 1, slots, C) tiles whole,
             # and is how the TPU compiler lays it out whatever it is
             # handed; slots-major it copied the tail in and out of every
             # tick, read off the compiled text)
             self.state["conv"] = tuple(
-                jnp.zeros((cfg.conv_kernel - 1, s, cfg.conv_dim), pool_dtype)
-                for _ in self._state_at
+                jnp.zeros(
+                    (cfg.conv_kernel - 1, s,
+                     cfg.conv_dim if i in self._ssm_at else cfg.d_model),
+                    pool_dtype,
+                ) for i in self._state_at
             )
         #: the counters a one-token decode pass appends to its tokens,
         #: by name (the scheduler reads them with the pass, one tick
@@ -747,6 +764,21 @@ class Engine:
         split = jax.vmap(jax.random.split)(state["rng"])
         return split[:, 0], split[:, 1]
 
+    def _carried(self, state, layer, slot=None):
+        """What ``layer`` starts a pass from, None where it keeps no
+        state: a short convolution's tail, a Mamba-2 layer's (state,
+        tail), for every slot (tails as (slots, K - 1, C)) or for
+        ``slot`` alone, as a batch of one."""
+        j, m = self._state_at.get(layer), self._ssm_at.get(layer)
+        if j is None:
+            return None
+        if slot is None:
+            tail = jnp.moveaxis(state["conv"][j], 0, 1)
+            return tail if m is None else (state["ssm"][m], tail)
+        ssm = None if m is None else state["ssm"][m][slot][None]
+        tail = state["conv"][j][:, slot][None]
+        return tail if m is None else (ssm, tail)
+
     def _decode(self, params, state):
         cfg = self.pool
         tokens, pos, live = state["tokens"], state["pos"], state["live"]
@@ -800,20 +832,20 @@ class Engine:
 
         stats, new_ssm, new_conv = [], [], []
         for i in range(mcfg.n_layers):
-            j = self._state_at.get(i)
+            j, m = self._state_at.get(i), self._ssm_at.get(i)
             x, aux, extra = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
                 positions=pos[:, None], valid=live[:, None],
-                carried=None if j is None else (
-                    state["ssm"][j], jnp.moveaxis(state["conv"][j], 0, 1)
-                ),
+                carried=self._carried(state, i),
             )
             if j is not None:
                 # one step a live lane from its own state; a dead lane's
                 # state and tail come back as they went in
-                new_ssm.append(extra[0])
-                new_conv.append(jnp.moveaxis(extra[1], 1, 0))
+                if m is not None:
+                    new_ssm.append(extra[0])
+                    extra = extra[1]
+                new_conv.append(jnp.moveaxis(extra, 1, 0))
             elif i in self._kv_at:
                 new_k.append(extra[0])
                 new_v.append(extra[1])
@@ -832,7 +864,9 @@ class Engine:
             "v": tuple(v for v in new_v if v is not None),
         }
         if new_ssm:
-            new_state["ssm"], new_state["conv"] = tuple(new_ssm), tuple(new_conv)
+            new_state["ssm"] = tuple(new_ssm)
+        if new_conv:
+            new_state["conv"] = tuple(new_conv)
         out = jnp.where(live, nxt, jnp.int32(-1))
         if self.decode_counters:
             counted = {}
@@ -846,7 +880,7 @@ class Engine:
                     chunk_held_pairs=state["chunk_pairs"],
                 )
                 new_state["chunk_pairs"] = jnp.zeros((), jnp.int32)
-            if new_ssm:
+            if new_conv:
                 counted["state_slots_live"] = jnp.sum(live, dtype=jnp.int32)
             out = jnp.concatenate([out, jnp.stack(
                 [counted[name] for name in self.decode_counter_names]
@@ -917,23 +951,20 @@ class Engine:
         held_pairs = jnp.zeros((), jnp.int32)
         new_ssm, new_conv = [], []
         for i in range(mcfg.n_layers):
-            j = self._state_at.get(i)
+            j, m = self._state_at.get(i), self._ssm_at.get(i)
             x, aux, extra = _block_apply(
                 params, f"blk{i}", x, mk_attend(i), mcfg,
                 moe_capacity_factor=float(max(mcfg.moe_experts, 1)),
                 positions=p[None], valid=valid[None],
-                carried=None if j is None else (
-                    state["ssm"][j][slot][None],
-                    state["conv"][j][:, slot][None],
-                ),
+                carried=self._carried(state, i, slot),
             )
             if j is not None:
                 # the chunk starts from the slot's state (zeros at
                 # admission) and leaves what its valid positions made
-                new_ssm.append(state["ssm"][j].at[slot].set(extra[0][0]))
-                new_conv.append(
-                    state["conv"][j].at[:, slot].set(extra[1][0])
-                )
+                if m is not None:
+                    new_ssm.append(state["ssm"][m].at[slot].set(extra[0][0]))
+                    extra = extra[1]
+                new_conv.append(state["conv"][j].at[:, slot].set(extra[0]))
             elif i in self._kv_at:
                 new_k.append(extra[0])
                 new_v.append(extra[1])
@@ -944,7 +975,9 @@ class Engine:
             "v": tuple(v for v in new_v if v is not None),
         }
         if new_ssm:
-            new_state["ssm"], new_state["conv"] = tuple(new_ssm), tuple(new_conv)
+            new_state["ssm"] = tuple(new_ssm)
+        if new_conv:
+            new_state["conv"] = tuple(new_conv)
         if "chunk_pairs" in state:
             new_state["chunk_pairs"] = state["chunk_pairs"] + held_pairs
         if mcfg.diffusion_block:
@@ -1274,6 +1307,7 @@ class Engine:
         # tail; retirement needs nothing more
         if "ssm" in state:
             out["ssm"] = tuple(a.at[slot].set(0) for a in state["ssm"])
+        if "conv" in state:
             out["conv"] = tuple(a.at[:, slot].set(0) for a in state["conv"])
         return out
 

@@ -123,7 +123,8 @@ def test_paged_attention_compiles(one_chip, geometry, form, q_len, dtype):
     (64, 32, 4, 128, 16, 96, jnp.bfloat16),    # 8 a K/V head, blocks of 16
     (8, 4, 2, 128, 16, 32, jnp.float32),       # 2 query heads a K/V head
     (64, 128, 4, 128, 16, 96, jnp.bfloat16),   # sdar_30b_a3b_serve_blocks
-], ids=["hybrid_cell", "per_kv_8", "per_kv_2", "block_step"])
+    (128, 32, 8, 64, 128, 32, jnp.bfloat16),   # lfm2_8b_a1b_serve_rag
+], ids=["hybrid_cell", "per_kv_8", "per_kv_2", "block_step", "rag_cell"])
 def test_one_query_kernel_over_fewer_kv_heads_compiles(
     one_chip, s, h, hkv, d, bl, mb, dtype
 ):
@@ -132,7 +133,9 @@ def test_one_query_kernel_over_fewer_kv_heads_compiles(
     head's own columns kept at the end) reaches Mosaic: at the hybrid
     cell's shape, 16 query heads a K/V head and items of 8 blocks; at
     the block step's, 32 heads x a block of 4 as 128 query rows over 4
-    K/V heads, an 8 MiB block-diagonal query within the default VMEM."""
+    K/V heads, an 8 MiB block-diagonal query within the default VMEM; at
+    the RAG cell's, 4 query heads over each of 8 K/V heads of 64 (rows
+    of 512, each head's output 64 of them)."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -647,3 +650,117 @@ def test_hybrid_programs_take_their_forms(hybrid_cell_on_tpu):
     assert "[128,512,2688]" not in texts["prefill"]
     assert "/mamba/step/" in texts["decode"]
     assert "/mamba/scan/" in texts["prefill"]
+
+
+# -- short convolutions: a tail alone beside a paged pool ----------------
+
+
+@pytest.fixture(scope="module")
+def rag_cell_on_tpu(one_chip):
+    """The engine of ``lfm2_8b_a1b_serve_rag`` (128 slots, blocks of 128,
+    512-token chunks, the published widths, every expert held) cut to
+    one short convolution, the dense MLP, one attention layer and one
+    expert layer, as the chip builds it, and its arguments as shapes on
+    the described chip: the tail is ``bf16[2, 128, 2048]``, the pools
+    ``bf16[4097, 128, 512]``."""
+    cfg = TransformerConfig(
+        vocab=65536, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+        n_layers=4, layers=("shortconv", "mlp", "attn", "moe"), d_ff=7168,
+        max_len=4096, norm="rmsnorm", pos="rope", rope_theta=1e6,
+        qk_norm=True, mlp="swiglu", conv_kernel=3, moe_experts=32,
+        moe_top_k=4, moe_d_ff=1792, moe_score="sigmoid", moe_bias=True,
+    )
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)),
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        eng = Engine(params, cfg, EngineConfig(
+            slots=_FEW, kv_block_len=128, kv_blocks=33, max_prefill_chunk=512,
+        ))
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def sized(a):
+        if a.shape[:2] == (33, 128):            # a pool: every slot's blocks
+            return arg(a.dtype, 128 * 32 + 1, *a.shape[1:])
+        return arg(a.dtype, *(128 if d == _FEW else d for d in a.shape))
+
+    head = (
+        jax.tree.map(lambda a: arg(a.dtype, *a.shape), params),
+        jax.tree.map(sized, eng.state),
+    )
+    i32 = lambda *shape: arg(jnp.int32, *shape)  # noqa: E731
+    programs = {
+        "decode": (eng._decode, head),
+        "prefill": (eng._prefill, head + (i32(), i32(512), i32(), i32())),
+    }
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        texts = {
+            name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+            .as_text()
+            for name, (fn, args) in programs.items()
+        }
+    return eng, texts
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_rag_programs_keep_the_tail_in_place(rag_cell_on_tpu, program):
+    """The short convolution's tick and chunk compile: the tail and the
+    pools arrive and leave in one layout and alias their inputs, no
+    copy of the tail leaves its layout, and the layer's operations lie
+    under ``shortconv`` (``in_proj``, ``conv``, ``out_proj``) with no
+    recurrent state beside the tail."""
+    eng, texts = rag_cell_on_tpu
+    assert "ssm" not in eng.state and len(eng.state["conv"]) == 1
+    text = texts[program]
+    header = text[:text.index("\n")]
+    sides = re.search(
+        r"entry_computation_layout=\{\((.*)\)->(.*)\}", header
+    ).groups()
+    for dims, n in ((r"bf16\[2,128,2048\]", 1), (r"bf16\[4097,128,512\]", 2)):
+        arrive, leave = (
+            re.findall(dims + r"(\{[^}]*\})", side) for side in sides
+        )
+        assert len(arrive) == n and arrive == leave, (dims, arrive, leave)
+    assert header.count("-alias)") >= 3
+    assert not re.findall(r"= bf16\[4097,128,512\]\S* copy\(", text)
+    for layout in re.findall(r"= bf16\[2,128,2048\](\{[^}]*\})\S* copy\(", text):
+        assert layout.startswith("{2,1,0:"), layout
+    for scope in ("in_proj", "conv", "out_proj"):
+        assert f"/blk0/shortconv/{scope}/" in text, scope
+
+
+def test_rag_programs_take_their_forms(rag_cell_on_tpu):
+    """The tick's attention is the paged kernel (4 query heads over each
+    of 8 K/V heads of 64) and the chunk keeps its one-slot gather; a
+    tick's 128 tokens over 32 experts stay dense, a chunk's 512 (64
+    routed rows an expert) go grouped: THREE megablox calls, the
+    experts' gate, up and down."""
+    from singa_tpu.parallel.moe import choose_expert_form
+
+    eng, texts = rag_cell_on_tpu
+    assert eng.attend_choice == "fused"
+    assert choose_expert_form(128, 32, 32, 4, "tpu").startswith("dense")
+    assert eng.expert_forms["jit__prefill"].startswith("grouped: 512 tokens")
+    kernel = re.findall(
+        r'= [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', texts["decode"],
+    )
+    assert [k for k in kernel if "paged_attention" in k], kernel
+    assert "jit(gmm)" not in texts["decode"]
+    calls = re.findall(
+        r'%gmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', texts["prefill"],
+    )
+    assert len(calls) == 3 and all(
+        c.startswith("jit(_prefill)/blk3/moe/") for c in calls
+    ), calls
+    chunk_kernels = re.findall(
+        r'= [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', texts["prefill"],
+    )
+    assert not [k for k in chunk_kernels if "paged_attention" in k]

@@ -842,3 +842,73 @@ def test_hybrid_blocks_hold_their_one_mixer(hybrid_texts):
     prefill = {n for _, n in instructions(hybrid_texts["_prefill"])}
     assert not any("/mamba/scan/" in n for n in decode)
     assert not any("/mamba/step/" in n for n in prefill)
+
+
+# ---------------------------------------------------------------------
+# short convolutions: a tail alone, beside rotated GQA attention
+# ---------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def rag_texts():
+    cfg = TransformerConfig(
+        vocab=40, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        n_layers=3, layers=("shortconv", "attn", "moe"), d_ff=24,
+        max_len=32, norm="rmsnorm", pos="rope", qk_norm=True, mlp="swiglu",
+        conv_kernel=3, moe_experts=4, moe_top_k=2, moe_d_ff=16,
+        moe_score="sigmoid", moe_bias=True,
+    )
+    eng = Engine(init_lm(jax.random.PRNGKey(0), cfg), cfg, EngineConfig(
+        slots=2, kv_block_len=8, max_prefill_chunk=8,
+    ))
+    slot, chunk = jnp.int32(0), jnp.zeros((8,), jnp.int32)
+    lowered = {
+        "_decode": eng._decode_jit.lower(eng.params, eng.state),
+        "_prefill": eng._prefill_jit.lower(
+            eng.params, eng.state, slot, chunk, jnp.int32(0), jnp.int32(8)
+        ),
+    }
+    return eng, {k: v.compile().as_text() for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [
+    # the short convolution's own scopes, read by shortconv_ms_per_tick
+    # and shortconv_ms_per_chunk
+    ("_decode", "blk0/shortconv/in_proj"), ("_decode", "blk0/shortconv/conv"),
+    ("_decode", "blk0/shortconv/out_proj"),
+    ("_prefill", "blk0/shortconv/in_proj"),
+    ("_prefill", "blk0/shortconv/conv"), ("_prefill", "blk0/shortconv/out_proj"),
+    # rotated, QK-normed attention in the one-mixer path, by the names
+    # the readers know
+    ("_decode", "blk1/qkv/qk_norm"), ("_decode", "blk1/qkv/rope"),
+    ("_decode", "blk1/attend/kv_write"), ("_decode", "blk1/attn_out"),
+    ("_prefill", "blk1/qkv/rope"), ("_prefill", "blk1/attend/cache_attend"),
+    ("_decode", "blk2/moe/route"), ("_decode", "blk0/ln1"),
+    ("_decode", "lm_head"), ("_decode", "sample"),
+])
+def test_rag_programs_name_their_operations(rag_texts, program, scope):
+    _, texts = rag_texts
+    assert f"HloModule jit_{program}," in texts[program]
+    names = {n for _, n in instructions(texts[program])}
+    assert any(f"jit({program})/{scope}/" in n for n in names), scope
+
+
+def test_rag_scope_names_and_counters(rag_texts):
+    """``shortconv`` is a plain segment that no reader of
+    ``program_trace.KNOWN`` books time to (its readers find it by name);
+    the block holds no other mixer; and a model whose only recurrent
+    state is a tail counts its live slots as Mamba's does."""
+    from benchmark import program_trace
+    from singa_tpu.ops import ssm
+    from singa_tpu.serve import engine as engine_mod
+
+    eng, texts = rag_texts
+    assert not any("/" in n for n in ssm.SHORTCONV_PARAMS + ("shortconv",))
+    assert not program_trace.KNOWN.match("shortconv")
+    assert eng.decode_counter_names == (
+        engine_mod.DECODE_COUNTERS + engine_mod.STATE_COUNTERS
+    )
+    for text in texts.values():
+        names = {n for _, n in instructions(text)}
+        assert not any("/blk0/attend/" in n or "/mamba/" in n for n in names)
+        assert not any("/blk1/shortconv/" in n for n in names)
