@@ -184,6 +184,45 @@ GROUPED_TOKENS = 2048
 #: share of the held experts a flat router must leave without a token
 #: before skipping their weights pays for the grouped form's overhead
 IDLE_SHARE = 0.25
+#: row-tile edges an expert's pairs cross, ``(routed - 1) / PAIR_TILE``
+#: for ``routed`` pairs at a random offset, at or over which a grouped
+#: product's weight block spans the whole contraction
+#: (``whole_contraction``). A k-tiled block is fetched again for each
+#: row tile a group crosses into (the k-steps between the two visits
+#: change its index), so a crossing re-reads a whole expert; a block of
+#: the whole contraction is asked for again by the very next grid step
+#: and is not fetched again, but it is narrower, so there are more
+#: n-tiles and each row tile is read once for each of them (128 rows
+#: against the expert's 896 to 2048 columns of the same depth). At a
+#: quarter the re-fetches saved are nearly
+#: three times the row tiles added; under it (10.7 and 22 routed rows a
+#: chunk: 0.08 and 0.16) the products are bound by the row tile's
+#: arithmetic as much as by the reads (PERF.md, §6).
+STRADDLE_SHARE = 0.25
+#: bytes of a grouped product's weight block: two in flight, the row
+#: tiles and the float32 accumulator fit the kernel's default 16 MiB of
+#: VMEM, and a block is long enough to stream at the HBM's rate
+WEIGHT_BLOCK = 4 << 20
+
+
+def whole_contraction(routed: float) -> bool:
+    """Whether a grouped product whose held experts draw ``routed``
+    pairs each (``n * top_k / experts`` under a flat router) takes
+    weight blocks over its whole contraction: its groups cross a row
+    tile's edge ``STRADDLE_SHARE`` of a time or more."""
+    return (routed - 1) / PAIR_TILE >= STRADDLE_SHARE
+
+
+def _blocks_reason(routed: float) -> str:
+    if whole_contraction(routed):
+        return (
+            f"weight blocks over the whole contraction: {routed:.1f} "
+            f"routed rows an expert straddle {PAIR_TILE}-row tiles"
+        )
+    return (
+        f"weight blocks k-tiled: {routed:.1f} routed rows an expert "
+        f"straddle {PAIR_TILE}-row tiles under {STRADDLE_SHARE} of a time"
+    )
 
 
 def choose_expert_form(n: int, held: int, experts: int, top_k: int,
@@ -207,7 +246,8 @@ def choose_expert_form(n: int, held: int, experts: int, top_k: int,
     flat router leaves ``(1 - top_k / experts) ** n`` of them without
     (``IDLE_SHARE`` or more). Dense everywhere else: there every
     expert's weights are read whatever is done and the idle products
-    ride on the reads."""
+    ride on the reads. A grouped reason ends with the weight blocks its
+    products take (``whole_contraction``)."""
     if platform != "tpu":
         return f"dense: platform = {platform}"
     routed = n * top_k / experts
@@ -218,12 +258,14 @@ def choose_expert_form(n: int, held: int, experts: int, top_k: int,
         return (
             f"grouped: {n} tokens a pass are over {DENSE_TOKENS}, "
             f"{routed:.1f} routed rows an expert for {n} dense "
-            f"({n * top_k * held // experts} pairs for {n * held})"
+            f"({n * top_k * held // experts} pairs for {n * held}); "
+            + _blocks_reason(routed)
         )
     if idle >= IDLE_SHARE:
         return (
             f"grouped: a flat router leaves {100 * idle:.0f} % of the "
-            f"held experts without one of {n} tokens"
+            f"held experts without one of {n} tokens; "
+            + _blocks_reason(routed)
         )
     if n <= DENSE_TOKENS:
         return (
@@ -236,13 +278,20 @@ def choose_expert_form(n: int, held: int, experts: int, top_k: int,
     )
 
 
-def _grouped_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
-    """(tm, tk, tn) of one grouped product (P, k) x (H, k, n): whole
-    lanes, the widest n-tile up to 2048 and the deepest k-tile that
-    keep a weight block at 4 MiB or under — two of them in flight, the
-    row tiles and the float32 accumulator fit the kernel's default
-    16 MiB of VMEM (12.3 MB at the published widths), and a block is
-    long enough to stream at the HBM's rate."""
+def _grouped_tiling(k: int, n: int, itemsize: int,
+                    routed: float) -> tuple[int, int, int]:
+    """(tm, tk, tn) of one grouped product (P, k) x (H, k, n) whose held
+    experts draw ``routed`` pairs each: whole lanes and a weight block
+    of ``WEIGHT_BLOCK`` or under (12.3 MB of VMEM at the published
+    widths). K-TILED, the widest n-tile up to 2048 and the deepest
+    k-tile under it. OVER THE WHOLE CONTRACTION where
+    ``whole_contraction(routed)`` (and 128 lanes of it fit a block):
+    ``tk = k`` and the widest n-tile up to 2048 under it, so a group's
+    consecutive visits to two row tiles ask for one block and the
+    pipeline fetches it once (9.8 MB of VMEM at 2048 x 1792). ``gmm``'s
+    derivative takes the same tiles; its ``tgmm`` holds a (tk, tn)
+    float32 accumulator, as large under either tiling (7.3 MB at
+    2048 x 1792), and compiles (``tests/test_chip_compile.py``)."""
     def widest(size, limit):
         fits = [
             t for t in range(128, min(size, limit) + 1, 128)
@@ -250,8 +299,12 @@ def _grouped_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
         ]
         return fits[-1] if fits else size
 
+    if whole_contraction(routed) and k * 128 * itemsize <= WEIGHT_BLOCK:
+        return PAIR_TILE, k, widest(
+            n, min(2048, WEIGHT_BLOCK // (k * itemsize))
+        )
     tn = widest(n, 2048)
-    tk = widest(k, max(128, (4 << 20) // (tn * itemsize)))
+    tk = widest(k, max(128, WEIGHT_BLOCK // (tn * itemsize)))
     return PAIR_TILE, tk, tn
 
 
@@ -282,14 +335,17 @@ def _experts_dense(x2d, params, gates):
         return jnp.einsum("enf,efd->nd", h, params["w_down"])
 
 
-def _experts_grouped(x2d, params, gates, pairs, top_k: int, start=None):
+def _experts_grouped(x2d, params, gates, pairs, top_k: int, routed: float,
+                     start=None):
     """The token-expert ``pairs`` (N, H) alone: the list of pairs sorted
     by expert, walked a WINDOW of R = N (a whole number of row tiles)
     pairs at a time; a window gathers its pairs' rows of ``x``, runs
     the three grouped products (megablox ``gmm``: it visits the row
-    tiles the group sizes cover and skips an expert with no pair) and
-    adds each pair's output, times its gate, into its token's row in
-    float32, on top of ``start`` (N, D) where there is one.
+    tiles the group sizes cover and skips an expert with no pair;
+    ``_grouped_tiling`` sizes its weight blocks from ``routed``, a held
+    expert's pairs under a flat router) and adds each pair's output,
+    times its gate, into its token's row in float32, on top of
+    ``start`` (N, D) where there is one.
     ``min(top_k, H)`` windows hold the worst case (every token on its
     full count of held experts); a window past the last pair is skipped
     by a ``lax.cond``, so the work follows the router and nothing is
@@ -305,11 +361,8 @@ def _experts_grouped(x2d, params, gates, pairs, top_k: int, start=None):
     windows = -(-n * min(top_k, held) // rows)
 
     def product(lhs, w, sizes):
-        return megablox.gmm(
-            lhs, w, sizes, f32,
-            _grouped_tiling(w.shape[1], w.shape[2], w.dtype.itemsize),
-            interpret=interpret,
-        )
+        tiling = _grouped_tiling(*w.shape[1:], w.dtype.itemsize, routed)
+        return megablox.gmm(lhs, w, sizes, f32, tiling, interpret=interpret)
 
     with jax.named_scope("route"):
         flat = pairs.T.reshape(-1)                    # expert-major
@@ -444,10 +497,11 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
     held = params["w_up"].shape[0]
     with jax.named_scope("route"):
         gates, chosen = topk_gates(x2d, params, top_k, score, scale)
+        experts = chosen.shape[1]
         form = choose_expert_form(
-            n, held, chosen.shape[1], top_k, jax.default_backend()
+            n, held, experts, top_k, jax.default_backend()
         )
-        if held != chosen.shape[1]:
+        if held != experts:
             gates = gates[:, held_from:held_from + held]
             chosen = chosen[:, held_from:held_from + held]
         counted = chosen if valid is None else (
@@ -476,7 +530,7 @@ def moe_topk_ffn(x: jnp.ndarray, params: dict, top_k: int, valid=None, *,
         # the windows' float32 sums start from the shared expert's,
         # where both are as wide as the model
         y = _experts_grouped(
-            xe, params, gates, counted, top_k,
+            xe, params, gates, counted, top_k, n * top_k / experts,
             shared(jnp.float32) if has_shared and not latent else None,
         )
     else:

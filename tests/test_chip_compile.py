@@ -445,6 +445,7 @@ def test_latent_programs_hold_the_grouped_expert_product(
     assert eng.expert_forms[f"jit__{program}"].startswith("grouped"), (
         eng.expert_forms
     )
+    assert "weight blocks k-tiled" in eng.expert_forms[f"jit__{program}"]
     text = latent_texts[program]
     calls = re.findall(
         r'%gmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
@@ -631,6 +632,7 @@ def test_hybrid_programs_take_their_forms(hybrid_cell_on_tpu):
     eng, texts = hybrid_cell_on_tpu
     assert choose_expert_form(128, 128, 512, 22, "tpu").startswith("dense")
     assert eng.expert_forms["jit__prefill"].startswith("grouped: 512 tokens")
+    assert "weight blocks k-tiled: 22.0" in eng.expert_forms["jit__prefill"]
     assert eng.mamba_forms["jit__decode"].startswith("step")
     assert eng.mamba_forms["jit__prefill"].startswith(
         "chunked: 512 positions in 4 blocks of 128"
@@ -746,6 +748,11 @@ def test_rag_programs_take_their_forms(rag_cell_on_tpu):
     assert eng.attend_choice == "fused"
     assert choose_expert_form(128, 32, 32, 4, "tpu").startswith("dense")
     assert eng.expert_forms["jit__prefill"].startswith("grouped: 512 tokens")
+    # whose products take weight blocks over the whole contraction
+    assert eng.expert_forms["jit__prefill"].endswith(
+        "; weight blocks over the whole contraction: 64.0 routed rows an "
+        "expert straddle 128-row tiles"
+    )
     kernel = re.findall(
         r'= [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
         r'op_name="([^"]*)"', texts["decode"],
@@ -764,3 +771,41 @@ def test_rag_programs_take_their_forms(rag_cell_on_tpu):
         r'op_name="([^"]*)"', texts["prefill"],
     )
     assert not [k for k in chunk_kernels if "paged_attention" in k]
+
+
+def test_a_grouped_layer_at_the_rag_widths_trains(one_chip, monkeypatch):
+    """An expert layer of ``lfm2_8b_a1b_serve_rag`` (512 tokens, 32
+    experts of 2048 x 1792, top-4) in the grouped form compiles forward
+    and backward in whole-contraction weight blocks: megablox's own
+    derivative takes the same tiles, and its ``tgmm`` accumulator,
+    (2048, 896) float32, fits the default VMEM beside its blocks."""
+    from singa_tpu.parallel import moe
+
+    d, f, e = 2048, 1792, 32
+    assert moe._grouped_tiling(d, f, 2, 64.0) == (128, 2048, 896)
+    assert moe._grouped_tiling(f, d, 2, 64.0) == (128, 1792, 1024)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {
+        "gate": arg(jnp.float32, d, e), "bias": arg(jnp.float32, e),
+        "w_gate": arg(jnp.bfloat16, e, d, f),
+        "w_up": arg(jnp.bfloat16, e, d, f),
+        "w_down": arg(jnp.bfloat16, e, f, d),
+    }
+    x = arg(jnp.bfloat16, 1, 512, d)
+
+    def loss(p, x):
+        y, _ = moe.moe_topk_ffn(x, p, 4, score="sigmoid")
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    forward = _compiled_text(loss, params, x)
+    backward = _compiled_text(jax.grad(loss), params, x)
+    gmm = r'%gmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"'
+    assert len(re.findall(gmm, forward)) == 3
+    assert len(re.findall(
+        r'%tgmm[.\d]* = [^\n]*custom_call_target="tpu_custom_call"',
+        backward,
+    )) == 3

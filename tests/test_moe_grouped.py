@@ -155,6 +155,78 @@ def test_gradients_of_both_forms_agree(name, monkeypatch):
         )
 
 
+# widths at which a k-tiled weight block leaves two k-steps (float32:
+# (128, 512, 2048) and (128, 1024, 1024)), and 160 tokens top-2 over 8
+# experts, 40 routed rows an expert: the rule takes blocks over the
+# whole contraction, (128, 1024, 1024) and (128, 2048, 512)
+WIDE_D, WIDE_F, WIDE_N = 1024, 2048, 160
+
+
+@pytest.mark.parametrize("what", ["values", "gradients"])
+def test_whole_contraction_blocks_are_the_dense_layer(what, monkeypatch):
+    """The grouped form in whole-contraction blocks, with groups that
+    straddle row tiles, against the dense form: values, the three
+    counters and the gradients, to ``test_grouped_is_the_dense_layer``'s
+    tolerance (megablox's own derivative takes the same blocks)."""
+    routed = WIDE_N * K / E
+    assert moe.whole_contraction(routed)
+    assert moe._grouped_tiling(WIDE_D, WIDE_F, 4, 1.0) == (128, 512, 2048)
+    assert moe._grouped_tiling(WIDE_F, WIDE_D, 4, 1.0) == (128, 1024, 1024)
+    assert moe._grouped_tiling(WIDE_D, WIDE_F, 4, routed) == (
+        128, 1024, 1024)
+    assert moe._grouped_tiling(WIDE_F, WIDE_D, 4, routed) == (
+        128, 2048, 512)
+    p = moe.init_moe_topk(
+        jax.random.PRNGKey(3), WIDE_D, WIDE_F, E, bias=True
+    )
+    kw = dict(score="sigmoid", scale=2.5)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, WIDE_N, WIDE_D))
+    _, chosen = moe.topk_gates(x[0], p, K, **kw)
+    load = np.asarray(jnp.sum(chosen, axis=0))
+    ends = np.cumsum(load)
+    starts = ends - load
+    assert any(
+        s // moe.PAIR_TILE != (e - 1) // moe.PAIR_TILE
+        for s, e in zip(starts, ends) if e > s
+    ), load                              # a group straddles a row tile
+    taken = []
+    real = moe._grouped_tiling
+
+    def spy(*a):
+        taken.append(real(*a))
+        return taken[-1]
+
+    monkeypatch.setattr(moe, "_grouped_tiling", spy)
+
+    if what == "values":
+        (yd, sd), (yg, sg) = both_forms(
+            monkeypatch, lambda: moe.moe_topk_ffn(x, p, K, **kw)
+        )
+        np.testing.assert_array_equal(np.asarray(sd), np.asarray(sg))
+        assert int(sg[2]) == WIDE_N * K
+        np.testing.assert_allclose(
+            np.asarray(yg), np.asarray(yd), rtol=2e-5, atol=2e-5
+        )
+        assert set(taken) == {(128, 1024, 1024), (128, 2048, 512)}
+        return
+
+    def loss(p, x):
+        return jnp.sum(moe.moe_topk_ffn(x, p, K, **kw)[0] ** 2)
+
+    dense, grouped = both_forms(
+        monkeypatch, lambda: jax.grad(loss, argnums=(0, 1))(p, x)
+    )
+    assert set(taken) == {(128, 1024, 1024), (128, 2048, 512)}
+    flat_d, _ = jax.tree_util.tree_flatten_with_path(dense)
+    for (path, d), g in zip(flat_d, jax.tree.leaves(grouped)):
+        assert np.isfinite(np.asarray(g)).all(), path
+        scale = float(jnp.abs(d).max()) or 1.0
+        np.testing.assert_allclose(
+            np.asarray(g) / scale, np.asarray(d) / scale, atol=2e-5,
+            err_msg=str(path),
+        )
+
+
 def test_a_tpu_training_pass_differentiates_through_the_form_it_takes(
     monkeypatch,
 ):
@@ -187,6 +259,14 @@ def test_a_tpu_training_pass_differentiates_through_the_form_it_takes(
 TABLE = [
     ("K chunk", (512, 12, 384, 8), "tpu", "grouped", "over 300"),
     ("K tick", (48, 12, 384, 8), "tpu", "grouped", "36 % of the held"),
+    ("K chunk's blocks", (512, 12, 384, 8), "tpu", "grouped",
+     "weight blocks k-tiled: 10.7 routed rows"),
+    ("N chunk", (512, 128, 512, 22), "tpu", "grouped",
+     "weight blocks k-tiled: 22.0 routed rows"),
+    ("L chunk", (512, 32, 32, 4), "tpu", "grouped",
+     "weight blocks over the whole contraction: 64.0 routed rows an "
+     "expert straddle 128-row tiles"),
+    ("L tick", (128, 32, 32, 4), "tpu", "dense", "weight reads"),
     ("B block step", (256, 128, 128, 8), "tpu", "dense", "weight reads"),
     ("B chunk", (256, 128, 128, 8), "tpu", "dense", "weight reads"),
     ("B at a chunk of 1024", (1024, 128, 128, 8), "tpu", "grouped", "64.0"),
@@ -284,8 +364,52 @@ def test_the_grouped_kernel_sits_under_the_layers_scopes(monkeypatch):
             n.startswith("jit(run)/moe/") and f"/{scope}/" in n
             and inside in n for n in names
         ), scope
-    tiling = moe._grouped_tiling(7168, 2048, 2), moe._grouped_tiling(2048, 7168, 2)
+    k_chunk = 512 * 8 / 384                 # K's chunk: 10.7 rows an expert
+    tiling = (
+        moe._grouped_tiling(7168, 2048, 2, k_chunk),
+        moe._grouped_tiling(2048, 7168, 2, k_chunk),
+    )
     assert tiling == ((128, 1024, 2048), (128, 1024, 1792))
     # a weight block of 4 MiB or under, whole lanes
     assert all(tk * tn * 2 <= 4 << 20 and tn % 128 == 0 for _, tk, tn in tiling)
-    assert moe._grouped_tiling(32, 16, 4) == (128, 32, 16)
+    assert moe._grouped_tiling(32, 16, 4, k_chunk) == (128, 32, 16)
+
+
+# cell's chunk -> (routed rows an expert, (k, n) of its gate / up and of
+# its down product, the (tm, tk, tn) each takes, and the k-tiled ones).
+# L = lfm2_8b_a1b_serve_rag (32 experts top-4, 2048 x 1792), K =
+# kimi_k2_serve_long (12 of 384 held, top-8, 7168 x 2048), N =
+# nemotron_3_super_serve_chat (128 of 512 held, top-22, a latent of 1024,
+# experts of 2688)
+K_TILED = {
+    "L_chunk": ((128, 1024, 1792), (128, 896, 2048)),
+    "K_chunk": ((128, 1024, 2048), (128, 1024, 1792)),
+    "N_chunk": ((128, 1024, 896), (128, 896, 1024)),
+}
+TILINGS = {
+    "L_chunk": (512 * 4 / 32, ((2048, 1792), (1792, 2048)),
+                ((128, 2048, 896), (128, 1792, 1024))),
+    "K_chunk": (512 * 8 / 384, ((7168, 2048), (2048, 7168)),
+                K_TILED["K_chunk"]),
+    "N_chunk": (512 * 22 / 512, ((1024, 2688), (2688, 1024)),
+                K_TILED["N_chunk"]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TILINGS))
+def test_the_tiling_of_each_cells_chunk(cell):
+    """L's chunk puts 64 pairs on an expert, which cross a 128-row
+    tile's edge half of the time: its blocks span the whole contraction
+    (``tk = k``), the widest whole-lane n-tile under 4 MiB beside it. K's
+    10.7 and N's 22 rows cross under a quarter of the time: today's
+    k-tiled blocks, which one routed row an expert takes everywhere."""
+    routed, shapes, want = TILINGS[cell]
+    got = tuple(moe._grouped_tiling(k, n, 2, routed) for k, n in shapes)
+    assert got == want
+    assert moe.whole_contraction(routed) == (cell == "L_chunk")
+    assert tuple(
+        moe._grouped_tiling(k, n, 2, 1.0) for k, n in shapes
+    ) == K_TILED[cell]
+    for (k, n), (_, tk, tn) in zip(shapes, got):
+        assert tk * tn * 2 <= moe.WEIGHT_BLOCK and tn % 128 == 0
+        assert k % tk == 0 and n % tn == 0
